@@ -1,0 +1,590 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the main train and serve paths ONCE, end to end, in ONE process, on
+one TPU, through the entry points a user calls, at the published widths of
+the two models ``bench.py`` is built around (ResNet-50 v1 at 224x224x3 /
+1000 classes / batch 128, BERT-base at 12 layers / 768 wide / 12 heads /
+seq 512 / batch 16 / vocab 30,522; bf16, weights random from ``--seed``).
+
+    python chip_smoke.py            # one chip, every phase below
+    python chip_smoke.py --chips 4  # ONLY the cross-chip paths (one host)
+
+Every phase prints one JSON line (``phase``, ``seconds``,
+``compile_seconds`` and what it checked); the LAST line of stdout is
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+The run fails, non-zero and without that line, the moment
+``jax.devices()[0].platform`` is not ``tpu`` or any check fails — no phase
+is wrapped in a ``try`` that lets the run go on.
+
+Compile cache: ``JAX_COMPILATION_CACHE_DIR`` if the environment sets it,
+else the fixed ``<checkout>/.jax_cache`` (mxtpu/compile_service.py — the one
+rule, the one writer).
+
+``run()`` is the whole program as a function: the tier-1 rehearsal
+(tests/test_chip_smoke.py) calls it with ``TINY`` sizes on the CPU, where the
+checks that only a chip can meet (compiled Mosaic kernel, peak table,
+``memory_stats``) are skipped by what ``jax.devices()`` reports, not by an
+option.
+"""
+import argparse
+import gc
+import json
+import os
+import shutil
+import sys
+import time
+import urllib.request
+
+import numpy as np
+
+# ---------------------------------------------------------------- sizes
+# FULL is what the driver runs: every width as published, depth uncut.
+FULL = dict(
+    sync_n=8192, sync_chain=32,
+    resnet=dict(model="resnet50_v1", image=224, classes=1000),
+    resnet_batch=128, gluon_batch=32, serve_batches=(1, 8),
+    serve_requests=(1, 3, 8, 2),
+    bert=dict(batch=16, seq=512, vocab=30522, dim=768, heads=12, layers=12),
+    train_steps=5, gluon_steps=3,
+    # 1-device vs 4-device losses, same batch and seed: reduce-order
+    # tolerance for bf16 parameters (the 4-way psum sums partial
+    # gradients in another order), set before the first chip run
+    parity_rtol=5e-2,
+)
+# TINY is the CPU rehearsal: same phases, same code, toy sizes.
+TINY = dict(
+    sync_n=256, sync_chain=4,
+    resnet=dict(model="resnet18_v1", image=32, classes=10),
+    resnet_batch=8, gluon_batch=8, serve_batches=(1, 4),
+    serve_requests=(1, 3, 4, 2),
+    bert=dict(batch=4, seq=128, vocab=512, dim=64, heads=2, layers=2),
+    train_steps=5, gluon_steps=3,
+    # the toy memorizes its 8 images in three steps (loss 3.4 -> 0.02),
+    # which amplifies one bf16 ULP of reduce order into tens of percent:
+    # here the parity check rehearses control flow, it proves nothing
+    parity_rtol=0.5,
+)
+
+
+# ------------------------------------------------------------ plumbing
+class _CompileClock:
+    """Seconds JAX spent tracing, lowering and compiling, and how many
+    backend compiles the persistent cache served instead — read off
+    ``jax.monitoring`` so a phase's ``compile_seconds`` is JAX's own
+    account, not a guess from wall time."""
+
+    _EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+               "/jax/core/compile/jaxpr_to_mlir_module_duration",
+               "/jax/core/compile/backend_compile_duration")
+
+    def __init__(self):
+        from jax import monitoring
+        self.seconds = 0.0
+        self.cache_hits = 0
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, name, secs, **_):
+        if name in self._EVENTS:
+            self.seconds += secs
+
+    def _on_event(self, name, **_):
+        if name == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+
+
+def _check(cond, what):
+    if not cond:
+        raise AssertionError("chip_smoke: " + what)
+
+
+def _retraces():
+    """Compiles reported at every retrace-watchdog site (the program's own
+    count — a disk-served executable is not one)."""
+    from mxtpu import telemetry
+    snap = telemetry.snapshot()["counters"]
+    return int(sum(v for k, v in snap.items()
+                   if k.startswith("retrace.") and isinstance(v, (int, float))
+                   and k != "retrace.watchdog_trips"))
+
+
+def _tagged_total(name):
+    from mxtpu import telemetry
+    return int(sum(telemetry.tagged(name).values()))
+
+
+def _losses(step_fn, n):
+    """``n`` calls of ``step_fn`` -> python floats (the fetch is the sync)."""
+    return [float(np.asarray(step_fn().asnumpy(), np.float32).mean())
+            for _ in range(n)]
+
+
+def _check_training(losses, what):
+    _check(all(np.isfinite(losses)), "%s: non-finite loss %s" % (what, losses))
+    _check(losses[-1] < losses[0],
+           "%s: loss did not fall on one fixed batch: %s" % (what, losses))
+
+
+def _seed(seed):
+    import mxtpu as mx
+    np.random.seed(seed)
+    mx.random.seed(seed)
+
+
+# -------------------------------------------------------------- phases
+def phase_device(on_tpu):
+    import jax
+    from mxtpu import _native, perf_model
+    d = jax.devices()[0]
+    _native.get_lib()
+    rec = {"platform": d.platform, "kind": d.device_kind,
+           "count": len(jax.devices()),
+           "jax": jax.__version__,
+           # a failed native build silently becomes the pure-Python path:
+           # say so here, so a missing toolchain on this machine is seen
+           "native_build_error": (str(_native.build_error())
+                                  if _native.build_error() else None)}
+    if on_tpu:
+        stats = d.memory_stats()
+        _check(stats and "bytes_limit" in stats,
+               "device.memory_stats() missing: %r" % (stats,))
+        rec["hbm_bytes_limit"] = int(stats["bytes_limit"])
+        # raises LookupError for a device_kind that is in no table
+        rec["peak_tflops"] = perf_model.peak_flops() / 1e12
+        rec["peak_gbps"] = perf_model.peak_bandwidth() / 1e9
+    return rec
+
+
+def phase_sync(sizes, on_tpu):
+    """Is ``block_until_ready`` a sound end of a timed region here? One
+    jitted chain of large bf16 matmuls, timed two ways: ended by
+    ``block_until_ready``, and ended by fetching one element to the host.
+    They must agree; the enqueue alone must not."""
+    import jax
+    import jax.numpy as jnp
+    n, chain = sizes["sync_n"], sizes["sync_chain"]
+
+    @jax.jit
+    def f(x, w):
+        for _ in range(chain):
+            x = jnp.dot(x, w, preferred_element_type=jnp.bfloat16) * 0.01
+        return x
+
+    k = jax.random.PRNGKey(0)
+    x = jax.random.normal(k, (n, n), jnp.bfloat16)
+    w = jax.random.normal(jax.random.fold_in(k, 1), (n, n), jnp.bfloat16)
+    f(x, w).block_until_ready()                      # compile + warm
+    blocks, fetches, enqueues = [], [], []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        y = f(x, w)
+        enqueues.append(time.perf_counter() - t0)
+        y.block_until_ready()
+        blocks.append(time.perf_counter() - t0)
+        t0 = time.perf_counter()
+        float(f(x, w)[0, 0])
+        fetches.append(time.perf_counter() - t0)
+    block, fetch = float(np.median(blocks)), float(np.median(fetches))
+    rec = {"matmul_n": n, "chain": chain,
+           "block_until_ready_s": block, "fetch_to_host_s": fetch,
+           "enqueue_only_s": float(np.median(enqueues)),
+           "ratio_block_over_fetch": block / fetch}
+    if on_tpu:
+        rec["tflops_by_block"] = chain * 2 * n ** 3 / block / 1e12
+        _check(abs(block - fetch) <= 0.15 * fetch + 0.002,
+               "block_until_ready (%.4fs) and fetch-to-host (%.4fs) "
+               "disagree: one of them is not a sync" % (block, fetch))
+    return rec
+
+
+def phase_train_resnet50(sizes, seed):
+    import bench
+    _seed(seed)
+    step, (x, y) = bench.build_resnet50_step(
+        sizes["resnet_batch"], "bfloat16", "NHWC", **sizes["resnet"])
+    losses = _losses(lambda: step(x, y), sizes["train_steps"])
+    _check_training(losses, "train_resnet50")
+    return {"model": sizes["resnet"], "batch": sizes["resnet_batch"],
+            "losses": losses}
+
+
+def phase_train_bert_base(sizes, seed, on_tpu):
+    import importlib
+
+    import bench
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    _seed(seed)
+    fa.reset_dispatch_stats()
+    step, (tokens, labels) = bench.build_bert_base_step(
+        dtype="bfloat16", **sizes["bert"])
+    losses = _losses(lambda: step(tokens, labels), sizes["train_steps"])
+    _check_training(losses, "train_bert_base")
+    stats = dict(fa.DISPATCH_STATS.items())
+    rec = {"model": sizes["bert"], "losses": losses, "pallas_flash": stats}
+    if on_tpu:
+        # the flash kernel ran compiled: not interpreted (the flag is an
+        # error on the chip), not replaced by the XLA softmax
+        _check(stats["pallas"] > 0 and not stats["fallback_reasons"],
+               "flash attention fell back: %s" % stats)
+        _check("tpu_custom_call" in step.compiled().as_text(),
+               "no tpu_custom_call in the compiled BERT step")
+        rec["tpu_custom_call_in_step"] = True
+    return rec
+
+
+def _gluon_loop(sizes, seed, mesh=None):
+    """The user-facing loop — hybridize / record / backward / Trainer.step
+    (CachedOp + FusedUpdater; mesh-native with ZeRO-1 when ``mesh`` is
+    given). Returns ``(losses, net)``."""
+    import bench
+    from mxtpu import autograd, gluon
+    _seed(seed)
+    net, x, y = bench.build_resnet50(sizes["gluon_batch"], "bfloat16",
+                                     "NHWC", **sizes["resnet"])
+    net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.01, "momentum": 0.9},
+                            mesh=mesh, zero1=True)
+    xs, ys = trainer.shard_batch(x, y)      # the identity without a mesh
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+
+    def one():
+        with autograd.record():
+            loss = loss_fn(net(xs), ys)
+        loss.backward()
+        trainer.step(sizes["gluon_batch"])
+        return loss
+
+    return _losses(one, sizes["gluon_steps"]), net
+
+
+def phase_gluon_trainer(sizes, seed):
+    """Returns the trained net too, for ``serve`` and ``warm_start``."""
+    losses, net = _gluon_loop(sizes, seed)
+    _check_training(losses, "gluon_trainer")
+    return {"batch": sizes["gluon_batch"], "losses": losses}, net
+
+
+def _http_predict(address, x):
+    req = urllib.request.Request(
+        "http://%s:%d/predict" % address,
+        data=json.dumps({"data": x.tolist()}).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=120) as r:
+        _check(r.status == 200, "/predict answered %d" % r.status)
+        return json.loads(r.read())
+
+
+def _example(sizes):
+    """One bf16 request row: the shape template a Predictor settles on."""
+    import mxtpu as mx
+    img = sizes["resnet"]["image"]
+    return mx.nd.array(np.zeros((1, img, img, 3), np.float32),
+                       dtype="bfloat16")
+
+
+def _requests(sizes, seed):
+    """The mixed-batch request payloads: bf16-representable float32."""
+    import jax.numpy as jnp
+    img = sizes["resnet"]["image"]
+    rng = np.random.RandomState(seed)
+    return [np.asarray(jnp.asarray(
+        rng.uniform(-1, 1, (n, img, img, 3)), jnp.bfloat16).astype(
+            jnp.float32)) for n in sizes["serve_requests"]]
+
+
+def phase_serve(sizes, seed, net):
+    """Predictor -> MicroBatcher -> ModelServer over HTTP; answers checked
+    against a direct ``net(x)`` on the same device."""
+    import mxtpu as mx
+    from mxtpu import telemetry
+    from mxtpu.serving import (BucketSpec, MicroBatcher, ModelServer,
+                               Predictor)
+    top = max(sizes["serve_batches"])
+    pred = Predictor(net, BucketSpec(batch_sizes=list(sizes["serve_batches"])),
+                     example=_example(sizes), warmup=True)
+    warm_compiles = telemetry.value("retrace.serving.predict")
+    _check(warm_compiles == len(sizes["serve_batches"]),
+           "warmup compiled %d executables for %d buckets"
+           % (warm_compiles, len(sizes["serve_batches"])))
+    server = ModelServer(MicroBatcher(pred, max_batch_size=top,
+                                      max_wait_ms=1)).start()
+    worst = 0.0
+    try:
+        for x in _requests(sizes, seed):
+            out = _http_predict(server.address, x)
+            n = x.shape[0]
+            got = np.asarray(out["outputs"][0], np.float32)
+            _check(out["n"] == n and got.shape[0] == n,
+                   "request of %d rows answered %s" % (n, got.shape))
+            # direct reference at ONE shape (rows are independent in
+            # inference, so padding to the top bucket changes no row)
+            padded = np.zeros((top,) + x.shape[1:], np.float32)
+            padded[:n] = x
+            ref = net(mx.nd.array(padded, dtype="bfloat16")).asnumpy()
+            ref = np.asarray(ref, np.float32)[:n]
+            _check(np.isfinite(got).all(), "non-finite served output")
+            err = float(np.max(np.abs(got - ref)))
+            scale = max(1.0, float(np.max(np.abs(ref))))
+            worst = max(worst, err / scale)
+            _check(err <= 0.05 * scale,
+                   "served output off the direct net(x) by %g (scale %g)"
+                   % (err, scale))
+    finally:
+        server.close()
+    after = telemetry.value("retrace.serving.predict")
+    _check(after == warm_compiles,
+           "%d compiles at serving.predict after warmup"
+           % (after - warm_compiles))
+    return {"buckets": list(sizes["serve_batches"]),
+            "requests": list(sizes["serve_requests"]),
+            "compiles_after_warmup": int(after - warm_compiles),
+            "worst_rel_err_vs_direct": worst}
+
+
+def _store_dir():
+    """The program's own executable store for this run: a subdirectory of
+    the in-checkout cache directory, emptied when a phase starts."""
+    from mxtpu import compile_service
+    d = os.path.join(compile_service.CHECKOUT_XLA_CACHE, "smoke_store")
+    shutil.rmtree(d, ignore_errors=True)
+    os.makedirs(d)
+    return d
+
+
+def phase_warm_start(sizes, seed, net):
+    """Build one Predictor against an empty executable store, drop it,
+    rebuild it: the second is served from disk — zero compiles, bit-equal
+    outputs (the ``execution_devices`` reload path on a real backend)."""
+    import mxtpu as mx
+    from mxtpu import compile_service, telemetry
+    from mxtpu.serving import BucketSpec, Predictor
+    spec = BucketSpec(batch_sizes=list(sizes["serve_batches"]))
+    example = _example(sizes)
+    x = mx.nd.array(_requests(sizes, seed)[1], dtype="bfloat16")
+    os.environ["MXTPU_COMPILE_CACHE_DIR"] = _store_dir()
+    try:
+        cold = Predictor(net, spec, example=example, warmup=True,
+                         site="serving.predict.cold")
+        ref = cold.predict(x).asnumpy()
+        writes = _tagged_total("compile.disk.writes")
+        _check(writes >= len(spec), "cold build spilled %d blobs" % writes)
+        del cold
+        compile_service.reset()          # a fresh process, as far as the
+        gc.collect()                     # service's memory goes
+        c0, h0 = _retraces(), _tagged_total("compile.disk.hits")
+        warm = Predictor(net, spec, example=example, warmup=True,
+                         site="serving.predict.warm")
+        out = warm.predict(x).asnumpy()
+        compiles = _retraces() - c0
+        hits = _tagged_total("compile.disk.hits") - h0
+    finally:
+        del os.environ["MXTPU_COMPILE_CACHE_DIR"]
+    _check(hits > 0, "warm rebuild had no compile.disk.hits")
+    _check(compiles == 0, "warm rebuild compiled %d times" % compiles)
+    _check(np.array_equal(np.asarray(out), np.asarray(ref)),
+           "disk-served executable is not bit-equal to the built one")
+    return {"disk_writes": writes, "disk_hits": hits,
+            "warm_compiles": compiles, "bit_equal": True,
+            "disk_drops": dict(telemetry.tagged("compile.disk.drops"))}
+
+
+# ----------------------------------------------------- four-chip phases
+def _four(devices):
+    _check(len(devices) >= 4, "--chips 4 needs 4 devices, found %d"
+           % len(devices))
+    return devices[:4]
+
+
+def _device_spans(arrays):
+    return {len(a.sharding.device_set) for a in arrays}
+
+
+def _check_parity(one, four, rtol, what):
+    _check(all(np.isfinite(one + four)), "%s: non-finite loss" % what)
+    _check(np.allclose(one, four, rtol=rtol, atol=rtol),
+           "%s: 1-device losses %s vs 4-device %s (rtol %g)"
+           % (what, one, four, rtol))
+
+
+def phase_dp_resnet50(sizes, seed, on_tpu):
+    """Data-parallel ShardedTrainStep on a 4-device mesh against the same
+    batch and seed on one device."""
+    import jax
+
+    import bench
+    devs = _four(jax.devices())
+    runs = {}
+    for name, sub in (("one", devs[:1]), ("four", devs)):
+        _seed(seed)
+        step, (x, y) = bench.build_resnet50_step(
+            sizes["resnet_batch"], "bfloat16", "NHWC", devices=sub,
+            **sizes["resnet"])
+        runs[name] = _losses(lambda: step(x, y), sizes["train_steps"])
+    _check_parity(runs["one"], runs["four"], sizes["parity_rtol"],
+                  "dp_resnet50")
+    _check_training(runs["four"], "dp_resnet50")
+    spans = _device_spans(step._param_datas)
+    _check(spans == {4}, "parameters span %s devices, not 4" % spans)
+    text = step.compiled().as_text()
+    collectives = [c for c in ("all-reduce", "reduce-scatter", "all-gather")
+                   if c in text]
+    _check(collectives, "no collective in the compiled 4-device step")
+    rec = {"losses_one": runs["one"], "losses_four": runs["four"],
+           "param_device_span": 4, "collectives": collectives}
+    if on_tpu:
+        used = [int(d.memory_stats()["bytes_in_use"]) for d in devs]
+        _check(all(u > 1 << 20 for u in used[1:]),
+               "devices 1-3 hold %s bytes" % used[1:])
+        rec["bytes_in_use"] = used
+    return rec
+
+
+def phase_dp_gluon_trainer(sizes, seed):
+    """``gluon.Trainer(mesh=...)`` with ZeRO-1 on a 4-device mesh against
+    the plain one-device Trainer, same batch and seed."""
+    import jax
+
+    from mxtpu.parallel import data_parallel_mesh
+    one, _ = _gluon_loop(sizes, seed)
+    four, net = _gluon_loop(sizes, seed,
+                            data_parallel_mesh(_four(jax.devices())))
+    _check_parity(one, four, sizes["parity_rtol"], "dp_gluon_trainer")
+    spans = _device_spans(p.data()._data
+                          for p in net.collect_params().values())
+    _check(spans == {4}, "parameters span %s devices, not 4" % spans)
+    return {"losses_one": one, "losses_four": four, "zero1": True,
+            "param_device_span": 4}
+
+
+def phase_replicas(sizes, seed, on_tpu):
+    """``ReplicaSet(n=4)``: one replica per device, each answers, and a
+    replaced replica comes back from the disk store with zero compiles."""
+    import jax
+
+    import bench
+    import mxtpu as mx
+    from mxtpu import telemetry
+    from mxtpu.serving import BucketSpec, ReplicaSet
+    devs = _four(jax.devices())
+    _seed(seed)
+    top = max(sizes["serve_batches"])
+    net, _, _ = bench.build_resnet50(top, "bfloat16", "NHWC",
+                                     **sizes["resnet"])
+    x = mx.nd.array(_requests(sizes, seed)[2][:top], dtype="bfloat16")
+    os.environ["MXTPU_COMPILE_CACHE_DIR"] = _store_dir()
+    try:
+        rs = ReplicaSet(net, BucketSpec(batch_sizes=[top]), devices=devs,
+                        example=_example(sizes), warmup=True)
+        outs, homes = [], []
+        for rep in rs.replicas:
+            where = set()
+            for d in rep.predictor.param_args()[0]:
+                where |= set(d.devices())
+            _check(where == {rep.device},
+                   "replica %d parameters live on %s" % (rep.index, where))
+            homes.append(rep.device.id)
+            outs.append(np.asarray(rep.predictor.predict(x).asnumpy(),
+                                   np.float32))
+        _check(len(set(homes)) == 4, "replicas share devices: %s" % homes)
+        for o in outs[1:]:
+            _check(np.allclose(o, outs[0], rtol=1e-2, atol=1e-2),
+                   "replicas disagree on one request")
+        # replace replica 2: retire it, bring a new one up on its device
+        dev = rs.remove_replica(2).device
+        rs.finalize_retiring()
+        c0, h0 = _retraces(), _tagged_total("compile.disk.hits")
+        rep = rs.add_replica(device=dev)
+        compiles = _retraces() - c0
+        hits = _tagged_total("compile.disk.hits") - h0
+        again = np.asarray(rep.predictor.predict(x).asnumpy(), np.float32)
+    finally:
+        del os.environ["MXTPU_COMPILE_CACHE_DIR"]
+    _check(hits > 0 and compiles == 0,
+           "replacement replica: %d disk hits, %d compiles"
+           % (hits, compiles))
+    _check(np.array_equal(again, outs[2]),
+           "disk-served replica is not bit-equal to the one it replaced")
+    return {"replica_devices": homes, "replacement_disk_hits": hits,
+            "replacement_compiles": compiles, "bit_equal": True,
+            "disk_drops": dict(telemetry.tagged("compile.disk.drops"))}
+
+
+# ----------------------------------------------------------------- run
+def run(sizes, chips=1, seed=0, out=sys.stdout):
+    """Every phase of the one-chip run (``chips=1``) or only the
+    cross-chip paths (``chips=4``), one JSON line each on ``out``.
+    Returns the device record of the last line. Raises on any failed
+    check. The platform is whatever ``jax.devices()`` reports —
+    ``main()`` is what refuses anything but a TPU."""
+    import jax
+
+    import bench
+    from mxtpu import compile_service
+    cache_dir = compile_service.use_checkout_xla_cache()
+    clock = _CompileClock()
+    devices = jax.devices()
+    on_tpu = devices[0].platform == "tpu"
+    t_run = time.perf_counter()
+
+    def phase(name, fn, *args):
+        c0, h0, t0 = clock.seconds, clock.cache_hits, time.perf_counter()
+        rec = fn(*args)
+        extra = None
+        if isinstance(rec, tuple):
+            rec, extra = rec
+        line = {"phase": name,
+                "seconds": round(time.perf_counter() - t0, 3),
+                "compile_seconds": round(clock.seconds - c0, 3),
+                "xla_cache_hits": clock.cache_hits - h0}
+        line.update(rec)
+        print(json.dumps(line), file=out, flush=True)
+        gc.collect()
+        return extra
+
+    # the bench's resnet stem variant (MLPerf s2d, default on for NHWC)
+    with bench.s2d_stem_env("1"):
+        phase("device", phase_device, on_tpu)
+        if chips == 1:
+            phase("sync", phase_sync, sizes, on_tpu)
+            phase("train_resnet50", phase_train_resnet50, sizes, seed)
+            phase("train_bert_base", phase_train_bert_base, sizes, seed,
+                  on_tpu)
+            net = phase("gluon_trainer", phase_gluon_trainer, sizes, seed)
+            phase("serve", phase_serve, sizes, seed, net)
+            phase("warm_start", phase_warm_start, sizes, seed, net)
+        else:
+            phase("dp_resnet50", phase_dp_resnet50, sizes, seed, on_tpu)
+            phase("dp_gluon_trainer", phase_dp_gluon_trainer, sizes, seed)
+            phase("replicas", phase_replicas, sizes, seed, on_tpu)
+    print(json.dumps({"phase": "total",
+                      "seconds": round(time.perf_counter() - t_run, 3),
+                      "compile_seconds": round(clock.seconds, 3),
+                      "xla_cache_hits": clock.cache_hits,
+                      "xla_cache_dir": cache_dir}), file=out, flush=True)
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4 = only the cross-chip paths, on one 4-chip host")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+    import jax
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        sys.exit("chip_smoke.py: jax.devices()[0].platform is %r, not 'tpu' "
+                 "— this script proves the chip path and has no CPU "
+                 "fallback" % devices[0].platform)
+    if len(devices) != args.chips:
+        sys.exit("chip_smoke.py: --chips %d but JAX reports %d device(s)"
+                 % (args.chips, len(devices)))
+    device = run(FULL, chips=args.chips, seed=args.seed)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

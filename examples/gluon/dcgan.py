@@ -117,4 +117,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from mxtpu import compile_service
+    compile_service.use_checkout_xla_cache()
     main()

@@ -81,7 +81,7 @@ _log = logging.getLogger("mxtpu.compile_service")
 
 # disk blob format version: bump on any layout change — old blobs then
 # drop as version_mismatch and silently recompile
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2
 _MAGIC = "MXTPU-CC"
 
 _LOCK = threading.Lock()            # store/group/inflight structural ops
@@ -142,35 +142,40 @@ def cache_dir():
     return os.environ.get("MXTPU_COMPILE_CACHE_DIR") or None
 
 
-# jax's own persistent compilation cache rides along under <dir>/xla: it
-# catches the compiles the service cannot key (deferred-init eager ops,
-# initializers, incidental library jits) so a warm dir accelerates the
-# WHOLE process start, not just the ten declared sites
-_XLA_CACHE = {"configured": None}
+# ------------------------------------------------------- the XLA cache rule
+# ONE rule for jax's persistent compilation cache, and ONE writer of
+# ``jax_compilation_cache_dir`` in the repo (:func:`use_checkout_xla_cache`):
+#
+# * ``JAX_COMPILATION_CACHE_DIR`` set -> nothing is written. JAX reads
+#   the variable itself and no code of ours overwrites it, so a cache
+#   handed in from outside (a machine that keeps one between calls) is
+#   the cache that is used.
+# * unset -> entry points that run on the chip (chip_smoke.py, bench.py,
+#   the bench tools, the examples) call the helper: one FIXED directory
+#   inside the checkout, git-ignored. The path is part of XLA's cache
+#   key, so a directory named after a pid, a time or ``mkdtemp`` never
+#   hits. Library code (``import mxtpu``) sets nothing.
+# * the executable store below (``MXTPU_COMPILE_CACHE_DIR``) is opt-in
+#   and separate: it never touches jax's cache. A tool that wants jax's
+#   cache beside a store of its own (tools/startup_bench.py) hands its
+#   children ``JAX_COMPILATION_CACHE_DIR`` like anybody else.
+CHECKOUT_XLA_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 
-def _ensure_xla_cache():
-    d = cache_dir()
-    if _XLA_CACHE["configured"] == d:   # unlocked fast path (hot sites
-        return                          # call this per dispatch miss)
-    with _LOCK:
-        if _XLA_CACHE["configured"] == d:
-            return
-        _XLA_CACHE["configured"] = d
-    try:
-        import jax
-        if d is None:
-            jax.config.update("jax_compilation_cache_dir", None)
-            return
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(d, "xla"))
-        # the eager tier is all sub-second compiles — persist them too
-        # (the dir is opt-in; without these the thresholds skip exactly
-        # the compiles a cold process start is made of)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # noqa: BLE001 — acceleration only, never fatal
-        pass
+def use_checkout_xla_cache():
+    """Entry-point helper and THE one writer of
+    ``jax_compilation_cache_dir``: the fixed in-checkout directory
+    (``<checkout>/.jax_cache``) unless ``JAX_COMPILATION_CACHE_DIR``
+    placed the cache from outside (then nothing is written). Returns
+    the directory in force."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_XLA_CACHE)
+    return CHECKOUT_XLA_CACHE
 
 
 def cache_entries():
@@ -416,32 +421,83 @@ def _mark_unloadable(path):
         pass
 
 
-def _device_span(compiled):
-    """Distinct device count an executable is bound to, read off its
-    input shardings (0 when introspection fails — treated as unknown)."""
-    try:
-        import jax
-        ins, _ = compiled.input_shardings
-        devs = set()
-        for s in jax.tree_util.tree_leaves(ins):
-            devs |= set(getattr(s, "device_set", ()))
-        return len(devs)
-    except Exception:  # noqa: BLE001 — stages API moved / no inputs
-        return 0
+def _device_ids(compiled):
+    """Ids, in execution order, of the devices an executable was built
+    for. Stored in the blob: ``deserialize_and_load`` otherwise loads
+    over EVERY device of the backend, and a single-device executable
+    reloaded on an 8-device host then dies at its first call."""
+    return [int(d.id) for d in
+            compiled.runtime_executable().local_devices()]
 
 
-def _cpu_serialization_unsound(num_devices):
-    """XLA:CPU cannot round-trip multi-device executables: the
-    generated fusion symbols either fail to resolve at load ("Symbols
-    not found" — the loud case ``_known_unloadable`` already handles)
-    or, worse, resolve to the WRONG kernels and the deserialized
-    executable silently computes garbage (measured: an sgd-momentum
-    fused update over a 2-device mesh returns ~2x-scaled momentum
-    terms after a round-trip; the same build on 1 device is bit-exact).
-    Single-device CPU blobs are sound and stay served; multi-device
-    ones are refused at write AND load. TPU/GPU are unaffected."""
+def _reloadable(ids):
+    """Can an executable built for devices ``ids`` (in execution order)
+    be reloaded onto them here? Established per backend, on the chip:
+
+    * one device — yes, anywhere (:func:`_load_executable` names it);
+    * several devices on XLA:CPU — yes, any sub-mesh in any order: the
+      CPU client takes the assignment from ``execution_devices``;
+    * several devices on a TPU — the client reloads onto its DEFAULT
+      assignment (the backend's devices in order) whatever
+      ``execution_devices`` says, and an assignment handed in through
+      ``compile_options`` halted the core on a sub-mesh (four-chip
+      v5e host, PR 21). So only the mesh that IS the default assignment
+      is served; any other is refused at spill and at load
+      (``compile.disk.drops{device_assignment}``) and recompiles."""
     import jax
-    return jax.default_backend() == "cpu" and num_devices != 1
+    if len(ids) == 1 or jax.default_backend() == "cpu":
+        return True
+    return list(ids) == [int(d.id) for d in jax.devices()]
+
+
+def _load_executable(rec):
+    """``serialize_executable.deserialize_and_load`` with the executable's
+    devices named BOTH ways the backends need:
+
+    * ``execution_devices`` — without it jax 0.9.0 loads over every
+      device of the backend, and a single-device executable reloaded on
+      an 8-device host dies at its first call (``Expected args to
+      execute_sharded_on_local_devices to have 8 shards``);
+    * for a single-device executable, its device assignment in
+      ``compile_options`` — the TPU client does not take the assignment
+      from ``execution_devices``: without the option an executable
+      built for chip 2 reloads assigned to chip 0 and its first call
+      fails with ``Buffer passed to Execute() ... is on device TPU_2
+      ... but replica is assigned to device TPU_0`` (found on a
+      four-chip host; invisible on one chip and on XLA:CPU). jax's own
+      persistent cache passes the option the same way
+      (``compilation_cache.get_executable_and_time``). Multi-device
+      executables take no option (see :func:`_reloadable`).
+    """
+    import io
+
+    import jax
+    import numpy as np
+    from jax._src import compiler
+    from jax.experimental import serialize_executable as se
+
+    ids = list(rec["devices"])
+    by_id = {d.id: d for d in jax.devices()}
+    devices = [by_id[i] for i in ids]
+    options = None
+    if len(ids) == 1:
+        options = compiler.get_compile_options(
+            num_replicas=1, num_partitions=1,
+            device_assignment=np.array(ids).reshape(1, 1))
+
+    class Unpickler(se._JaxPjrtUnpickler):
+        def persistent_load(self, pid):
+            if pid[0] == "exec":
+                return self.backend.deserialize_executable(
+                    pid[1], executable_devices=self.execution_devices,
+                    compile_options=options)
+            return super().persistent_load(pid)
+
+    unloaded, args_info_flat, no_kwargs = Unpickler(
+        io.BytesIO(rec["payload"]), devices[0].client, devices).load()
+    return jax.stages.Compiled(
+        unloaded.load(), [], rec["in_tree"].unflatten(args_info_flat),
+        rec["out_tree"], no_kwargs=no_kwargs)
 
 
 def _disk_load(key):
@@ -471,15 +527,10 @@ def _disk_load(key):
         # digest collision or a forged rename: the executable was built
         # for a DIFFERENT canonical key (other policy/sharding/donation)
         return _drop_blob("key_mismatch", key.site, path)
-    if _cpu_serialization_unsound(rec.get("devices") or 0):
-        # a pre-guard blob (no recorded span) or a multi-device one on
-        # XLA:CPU: deserializing risks SILENT numeric corruption, not
-        # just a load error — never serve it (see the guard's docstring)
-        return _drop_blob("cpu_multidevice", key.site, path)
+    if not _reloadable(rec.get("devices") or ()):
+        return _drop_blob("device_assignment", key.site, path)
     try:
-        from jax.experimental import serialize_executable as se
-        compiled = se.deserialize_and_load(
-            rec["payload"], rec["in_tree"], rec["out_tree"])
+        compiled = _load_executable(rec)
     except Exception:  # noqa: BLE001 — topology/backends moved under us,
         # or a backend whose serialized form cannot restore (marked so
         # later restarts skip straight to the recompile)
@@ -514,18 +565,18 @@ def _disk_write(key, compiled, meta, provenance, compile_s):
         # serialization (that cost per restart is the exact churn the
         # marker exists to stop)
         return False
-    span = _device_span(compiled)
-    if _cpu_serialization_unsound(span):
-        # refuse BEFORE paying serialization: the blob would load as
-        # garbage (or not at all) on every warm start
-        telemetry.inc("compile.disk.drops", tag="cpu_multidevice")
+    ids = _device_ids(compiled)
+    if not _reloadable(ids):
+        # refuse BEFORE paying serialization: the blob could not be
+        # served back (see _reloadable)
+        telemetry.inc("compile.disk.drops", tag="device_assignment")
         return False
     try:
         from jax.experimental import serialize_executable as se
         payload, in_tree, out_tree = se.serialize(compiled)
         rec = {"magic": _MAGIC, "env": _env_material(),
                "key": key.digest_material(), "site": key.site,
-               "devices": span,
+               "devices": ids,
                "payload": payload, "in_tree": in_tree,
                "out_tree": out_tree, "meta": meta,
                "provenance": _json_safe(provenance),
@@ -696,7 +747,6 @@ def get_or_build(key, build, provenance=None, example_args=None,
 
     Concurrent misses on the same key build once: losers wait on the
     winner's in-flight event and adopt its entry."""
-    _ensure_xla_cache()
     with _LOCK:
         e = _lookup_locked(key)
     if e is not None:
@@ -805,9 +855,3 @@ def _catching(fn):
         except BaseException as exc:  # noqa: BLE001 — collected, re-raised
             return exc
     return run
-
-
-# configure the riding XLA cache at import when the dir is already set:
-# a fresh process's deferred-init eager compiles happen BEFORE any
-# service call, and they are exactly what a warm start wants cached
-_ensure_xla_cache()

@@ -23,6 +23,8 @@ __all__ = ["to_dlpack_for_read", "to_dlpack_for_write", "from_dlpack",
            "from_numpy"]
 
 _DLTENSOR = b"dltensor"
+# platforms whose buffers jax.Array.__dlpack__ exports in place
+_DLPACK_PLATFORMS = ("cpu", "cuda", "rocm", "gpu")
 
 
 def _host_export(data: NDArray):
@@ -38,13 +40,15 @@ def _capsule_from(data: NDArray):
         raise MXNetError("to_dlpack expects an NDArray, got %s"
                          % type(data).__name__)
     data.wait_to_read()
-    try:
+    if next(iter(data._data.devices())).platform in _DLPACK_PLATFORMS:
         return data._data.__dlpack__()
-    except Exception:
-        # backends without direct buffer export (e.g. tunneled PJRT
-        # plugins): stage through a host copy — the consumer gets a CPU
-        # DLPack tensor, matching torch_interop's copy-always policy
-        return _host_export(data)
+    # DLPack names no TPU device type and jax exports CPU and GPU buffers
+    # only (on the v5e: ``JaxRuntimeError: INVALID_ARGUMENT: Device TPU_0
+    # ... cannot be used as a DLPack device``): stage through a host copy — the consumer gets a CPU DLPack
+    # tensor, matching torch_interop's copy-always policy. Decided from
+    # the buffer's platform, so a failed export on a platform that CAN
+    # export is an error, not a silent copy
+    return _host_export(data)
 
 
 def to_dlpack_for_read(data):

@@ -88,12 +88,8 @@ def _bwd(strides, padding, lhs_dilation, rhs_dilation, dims, groups, res, g):
               dimension_numbers=dn, feature_group_count=groups,
               batch_group_count=1, precision=lax.Precision.DEFAULT,
               preferred_element_type=jnp.float32)
-    try:
-        gx = _t_lhs(g, x, w, out_sharding=None, **kw)
-        gw = _t_rhs(g, x, w, out_sharding=None, **kw)
-    except TypeError:  # out_sharding kwarg is newer than some jax versions
-        gx = _t_lhs(g, x, w, **kw)
-        gw = _t_rhs(g, x, w, **kw)
+    gx = _t_lhs(g, x, w, out_sharding=None, **kw)
+    gw = _t_rhs(g, x, w, out_sharding=None, **kw)
     return gx.astype(x.dtype), gw.astype(w.dtype)
 
 
@@ -103,7 +99,7 @@ conv_acc.defvjp(_fwd, _bwd)
 def _enabled():
     """DEFAULT OFF as of round 5: the same-session on-chip A/B measured
     the custom conv path at −2.8% end-to-end ResNet-50 (2331.7 control
-    vs 2267.2, perf_watch.log 16:16) and the best-known config excludes
+    vs 2267.2, round-5 builder chip session) and the best-known config excludes
     it (resnet_best 2580.3 img/s, perf_followup.log) — the +10%
     conv-stack microbench win does not survive the real mixed graph.
     MXTPU_CONV_ACC=1 re-enables for A/Bs. The f32-accumulate MATMUL

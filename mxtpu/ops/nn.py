@@ -355,7 +355,7 @@ def _leaky_impl(x, gamma, act_type, slope):
 def _bn_onepass():
     """Single-read batch statistics, DEFAULT ON as of round 5: the
     same-session on-chip A/B measured +7.8% end-to-end ResNet-50
-    throughput (2331.7 -> 2512.7 img/s, perf_watch.log 16:18) and -9.4%
+    throughput (2331.7 -> 2512.7 img/s, round-5 builder chip session) and -9.4%
     on the conv+BN microbench; numerics are pinned eager+hybridized both
     ways (tests/test_precision.py). MXTPU_BN_ONEPASS=0 restores two-pass
     jnp.var stats. Baked into compiled executables: registry.policy_key()

@@ -52,7 +52,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from . import autotune
-from .flash_attention import _platform  # one platform resolver per package
+# one platform resolver and one interpret rule per package
+from .flash_attention import _interpret_flag, _platform
 
 __all__ = ["fused_conv", "pallas_applicable", "shape_class_of",
            "DISPATCH_STATS", "reset_dispatch_stats"]
@@ -122,9 +123,10 @@ def reset_dispatch_stats():
 
 def _interpret():
     """MXTPU_PALLAS_CONV_INTERPRET=1 runs the kernel via the Pallas
-    interpreter on any platform — the tier-1 parity path (CPU, no chip).
-    Trace-time, so it rides policy_key like every other lever."""
-    return os.environ.get("MXTPU_PALLAS_CONV_INTERPRET", "0") == "1"
+    interpreter off the chip — the tier-1 parity path; an error on a TPU
+    (``flash_attention._interpret_flag``). Trace-time, so it rides
+    policy_key like every other lever."""
+    return _interpret_flag("MXTPU_PALLAS_CONV_INTERPRET")
 
 
 class _Cfg(NamedTuple):

@@ -39,11 +39,29 @@ from . import autotune
 _NEG_INF = -1e30
 
 
+def _platform():
+    return jax.devices()[0].platform
+
+
+def _interpret_flag(var):
+    """``var``=1 runs a kernel through the Pallas interpreter — the
+    tier-1 parity path (CPU, no chip). On a TPU it is an error, not a
+    slow run: a forgotten flag would otherwise put interpreted-kernel
+    times under a device metric."""
+    on = os.environ.get(var, "0") == "1"
+    if on and _platform() == "tpu":
+        from ...base import MXNetError
+        raise MXNetError(
+            "%s=1 while the platform is tpu: the interpreter is the "
+            "off-chip parity path; unset it to run the compiled kernel"
+            % var)
+    return on
+
+
 def _interpret():
-    """MXTPU_FLASH_INTERPRET=1 runs the kernel via the Pallas interpreter
-    on any platform — the tier-1 parity path (CPU, no chip). Trace-time,
+    """MXTPU_FLASH_INTERPRET=1 (see :func:`_interpret_flag`). Trace-time,
     so it rides policy_key like every other lever."""
-    return os.environ.get("MXTPU_FLASH_INTERPRET", "0") == "1"
+    return _interpret_flag("MXTPU_FLASH_INTERPRET")
 
 
 # observability: how often the hand kernel ran vs why it fell back — the
@@ -199,12 +217,8 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k):
                                block_q=block_q, block_k=block_k, n_k=n_k)
     interpret = _interpret()
     extra = {}
-    if not interpret:
-        # jax 0.4.37 renamed CompilerParams -> TPUCompilerParams; the
-        # interpreter needs neither (Mosaic-only hint)
-        cp = (getattr(pltpu, "CompilerParams", None)
-              or pltpu.TPUCompilerParams)
-        extra["compiler_params"] = cp(
+    if not interpret:  # Mosaic-only hint: the interpreter takes none
+        extra["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     out, lse = pl.pallas_call(
         kernel,
@@ -280,13 +294,6 @@ def _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale, block_k,
     dk = jnp.moveaxis(dk_blocks, 0, 2).reshape(k.shape)
     dv = jnp.moveaxis(dv_blocks, 0, 2).reshape(v.shape)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
-
-
-def _platform():
-    try:
-        return jax.devices()[0].platform
-    except Exception:  # noqa: BLE001
-        return "unknown"
 
 
 def _pick_block(n, want, mult):

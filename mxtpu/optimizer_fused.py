@@ -674,11 +674,11 @@ def _portable_build():
     reduction (wrong values immediately, plus heap corruption). The
     same HLO without donation/constraints round-trips bit-exact, and on
     a single CPU device both are pure memory hints anyway: dropping
-    them changes no value. Only the single-device build needs this —
-    multi-device CPU executables are refused by the disk cache outright
-    (compile_service ``cpu_multidevice`` drop), so their in-process
-    donated/constrained form is never serialized; TPU/GPU keep the
-    donated, constrained build — there the aliasing is the whole point
+    them changes no value. Only the single-device CPU build is held to
+    this (the fault was measured there; a multi-device CPU build's
+    donated, constrained form round-trips bit-equal on jax 0.9.0 now
+    that the reload names its devices — tests/test_compile_service.py);
+    TPU/GPU keep the donated, constrained build — there the aliasing is the whole point
     of fusing the update. Local (not global) device count: on the CPU
     fleet tier every host jits over its own local mesh, so a 2-host
     world of 1-device hosts still builds — and disk-serves — the

@@ -1,11 +1,5 @@
-"""``shard_map`` version compatibility.
-
-``jax.shard_map`` is top-level API (with the ``check_vma`` kwarg) only on
-newer jax; on the 0.4.x line it lives at
-``jax.experimental.shard_map.shard_map`` with the same semantics under the
-``check_rep`` kwarg. The parallel layer (ring attention, pipeline) calls
-through this one resolver so the whole test tier runs on either.
-"""
+"""``shard_map`` for the parallel layer (ring attention, pipeline): one
+call form over ``jax.shard_map``, replica checking off by default."""
 from __future__ import annotations
 
 import jax
@@ -14,10 +8,5 @@ __all__ = ["shard_map"]
 
 
 def shard_map(fn, mesh, in_specs, out_specs, check_vma=False):
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

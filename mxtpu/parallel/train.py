@@ -489,6 +489,21 @@ class ShardedTrainStep:
             p.data()._set_data(d)
         return NDArray(loss)
 
+    def compiled(self):
+        """The step's compiled executable (``as_text()``,
+        ``cost_analysis()``, ``memory_analysis()``). Requires at least one
+        __call__ (shapes must be known). A plain jit is re-lowered at the
+        captured abstract signature — one extra compile, which jax's
+        persistent cache serves when it is on."""
+        if self._jit is None or self._last_abstract is None:
+            raise MXNetError("run at least one step before asking for the "
+                             "compiled step")
+        if hasattr(self._jit, "cost_analysis"):
+            # the compile service handed back an AOT executable (disk-warm
+            # or spill path): its own analyses are the exact HLO that runs
+            return self._jit
+        return self._jit.lower(*self._last_abstract).compile()
+
     def compiled_step_flops(self):
         """FLOPs of one compiled step per XLA's own cost model.
 
@@ -497,16 +512,8 @@ class ShardedTrainStep:
         hand-derived formula. Requires at least one __call__ (shapes must be
         known); pays one extra (cached-HLO) compile.
         """
-        if self._jit is None or self._last_abstract is None:
-            raise MXNetError("run at least one step before asking for FLOPs")
-        if hasattr(self._jit, "cost_analysis"):
-            # the compile service handed back an AOT executable (disk-warm
-            # or spill path): its own analyses are the exact HLO that runs
-            compiled = self._jit
-        else:
-            compiled = self._jit.lower(*self._last_abstract).compile()
         from .. import perf_model
-        flops = perf_model.flops_of(compiled)  # list/dict/None-proof
+        flops = perf_model.flops_of(self.compiled())
         if flops is None:
             raise MXNetError(
                 "XLA cost analysis exposes no flops for this "

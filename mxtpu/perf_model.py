@@ -3,9 +3,8 @@ convention, and version-proof accessors over XLA's cost/memory analyses.
 
 Before this module the chip peak-FLOPs table and the MFU convention lived
 twice (``bench.py`` and ``tools/perf_peak.py``) and every consumer of
-``Compiled.cost_analysis()`` hand-rolled the same "list-of-dicts vs dict
-vs None" dance (``parallel/train.py``, ``tools/perf_bisect.py``). This
-module is the single copy both the offline benches and the runtime
+``Compiled.cost_analysis()`` read it its own way (``parallel/train.py``,
+``tools/perf_bisect.py``). This module is the single copy both the offline benches and the runtime
 observatory (:mod:`mxtpu.xprof`) draw from.
 
 **The MFU convention** (one convention, everywhere): model FLOPs counted
@@ -32,6 +31,9 @@ __all__ = ["NOMINAL_PEAK_TFLOPS", "HBM_BANDWIDTH_GBPS",
 # Datasheet dense bf16 peak per chip, TFLOP/s, matched by substring
 # against ``device.device_kind`` (PJRT kinds look like "TPU v5 lite",
 # "TPU v4", ...). MAC=2 convention — the number printed on the datasheet.
+# The v5e this repo is brought up on reports device_kind "TPU v5 lite"
+# (chip_smoke.py ``device`` phase). A TPU kind that matches no row is an
+# error (:func:`_lookup`), never a default.
 NOMINAL_PEAK_TFLOPS = {
     "v5 lite": 197.0,   # v5e PJRT device_kind spelling
     "v5e": 197.0,
@@ -55,9 +57,6 @@ HBM_BANDWIDTH_GBPS = {
     "v2": 700.0,
 }
 
-_DEFAULT_TPU_PEAK_TFLOPS = 197.0   # unknown TPU kind: assume the fleet's
-_DEFAULT_TPU_BW_GBPS = 819.0       # workhorse v5e rather than refusing
-
 
 def _device_kind(device):
     """(platform, kind) of ``device`` (an int index, a jax Device, or
@@ -74,11 +73,14 @@ def _device_kind(device):
         return ("unknown", "")
 
 
-def _lookup(table, kind, default):
+def _lookup(table, kind):
     for sub, v in table.items():
         if sub in kind:
             return v
-    return default
+    raise LookupError(
+        "TPU device_kind %r is in no peak table of mxtpu/perf_model.py: "
+        "add its datasheet row (a utilization against another chip's "
+        "peak would be a wrong number, not an estimate)" % kind)
 
 
 def nominal_tflops(device=None):
@@ -88,7 +90,7 @@ def nominal_tflops(device=None):
     platform, kind = _device_kind(device)
     if platform != "tpu":
         return None
-    return _lookup(NOMINAL_PEAK_TFLOPS, kind, _DEFAULT_TPU_PEAK_TFLOPS)
+    return _lookup(NOMINAL_PEAK_TFLOPS, kind)
 
 
 def peak_flops(device=None):
@@ -118,7 +120,7 @@ def peak_bandwidth(device=None):
     platform, kind = _device_kind(device)
     if platform != "tpu" and not env:
         return None
-    return _lookup(HBM_BANDWIDTH_GBPS, kind, _DEFAULT_TPU_BW_GBPS) * 1e9
+    return _lookup(HBM_BANDWIDTH_GBPS, kind) * 1e9
 
 
 def critical_intensity(device=None):
@@ -143,23 +145,9 @@ def mfu(flops_per_s, device=None, n_devices=1):
 
 # ------------------------------------------------ XLA analysis accessors
 def cost_dict(cost):
-    """Normalize ``Compiled.cost_analysis()`` across jax versions: newer
-    jax returns a dict, 0.4.x returns a singleton list-of-dicts, some
-    backends return None or an empty list. Always a plain dict ({} when
-    absent) — THE accessor every consumer routes through instead of raw
-    ``cost[0]["flops"]`` indexing."""
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        if not cost:
-            return {}
-        cost = cost[0]
-    if cost is None:
-        return {}
-    try:
-        return dict(cost)
-    except (TypeError, ValueError):
-        return {}
+    """``Compiled.cost_analysis()`` as a plain dict ({} when the backend
+    gives none) — THE accessor every consumer routes through."""
+    return dict(cost) if cost else {}
 
 
 def flops_of(compiled):

@@ -67,9 +67,9 @@ def set_config(filename="profile.json", profile_all=False,
 def _stop_xla_trace():
     """Idempotent device-trace stop, safe from any thread/signal context.
 
-    A device trace left running when the client dies can wedge a remote
-    TPU server-side for hours (every later dispatch from any process
-    hangs). The reference's profiler is always-stoppable
+    A capture must not outlive the work it traces: a trace left running
+    keeps buffering and its dump is never written. The reference's
+    profiler is always-stoppable
     (src/profiler/profiler.h:256-437); this is the analog for the
     XLA-capture path: every exit route — normal stop(), atexit, SIGTERM/
     SIGINT, or the bounded-duration watchdog — funnels here, and only the
@@ -90,9 +90,8 @@ def _stop_xla_trace():
 
 def _install_xla_guards():
     """atexit + SIGTERM/SIGINT hooks so an interrupted capture still sends
-    stop_trace. SIGKILL cannot be caught — for watchdog-supervised runs use
-    tools/safe_trace.py, which runs the capture in a child that also stops
-    the trace when its parent disappears."""
+    stop_trace. SIGKILL cannot be caught; the bounded-duration watchdog
+    (``xla_trace_max_s``) is what ends a capture whose workload hangs."""
     if _PROF._xla_guard_installed:
         return
     _PROF._xla_guard_installed = True
@@ -150,24 +149,6 @@ def stop():
             # stoppers no-op); stop() is synchronous like the reference's
             # profiler (src/profiler/profiler.h), so wait for the dump
             w.join(30)
-
-
-def install_orphan_guard(poll_s=2.0):
-    """Stop any live device trace if this process is orphaned (parent
-    died, e.g. the supervising tools/safe_trace.py was SIGKILLed). Child
-    half of the safe-capture protocol."""
-    ppid0 = os.getppid()
-
-    def watch():
-        while True:
-            time.sleep(poll_s)
-            if os.getppid() != ppid0:
-                _stop_xla_trace()
-                return
-
-    t = threading.Thread(target=watch, daemon=True, name="mxtpu-trace-guard")
-    t.start()
-    return t
 
 
 def pause():

@@ -173,7 +173,10 @@ class Kernel:
         # signature — a shape-unstable caller shows up here by name
         entry = csvc.get_or_build(
             key, build,
+            # interpret: off the chip launch() picks the Pallas
+            # interpreter by itself — the provenance says which ran
             provenance=lambda: {"kernel": self.name,
+                                "interpret": bool(interpret),
                                 "args": [(tuple(a.shape), str(a.dtype))
                                          for a in args]},
             example_args=example)

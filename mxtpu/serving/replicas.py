@@ -1,8 +1,7 @@
 """Fault-tolerant replica serving: ReplicaSet router + wedge watchdog.
 
 PR 5's server is one Predictor on one device behind one dispatch thread.
-A wedged chip (exactly what the training side hit in BENCH_r03-r05: a
-device call that never returns) therefore hangs the sole worker inside
+A wedged chip (a device call that never returns) therefore hangs the sole worker inside
 ``MicroBatcher._dispatch`` forever — every queued future strands, and the
 box fails its SLO while still answering ``/healthz`` 200. This module is
 the serving half of the resilience story (ROADMAP item 2(a)):

@@ -1,0 +1,88 @@
+"""Rehearsal of ``chip_smoke.py`` (the on-chip bring-up proof) on the CPU.
+
+The script's whole program is the function ``chip_smoke.run(sizes, chips)``;
+here it runs at ``TINY`` sizes in a child process (its own JAX, its own
+compile cache directory handed in through ``JAX_COMPILATION_CACHE_DIR``), so
+wrong paths, arguments and control flow are found without chip time. Nothing
+here is a device result: the checks only a chip can meet are skipped inside
+``run`` by what ``jax.devices()`` reports. The script itself must REFUSE the
+CPU — that is the other half of the contract.
+"""
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_REHEARSE = ("import chip_smoke; "
+             "print(chip_smoke.run(chip_smoke.TINY, chips=%d)['count'])")
+
+
+def _child(argv, tmp_path, devices=1, timeout=600):
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=%d"
+                        % devices)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla_cache")
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env.pop("MXTPU_COMPILE_CACHE_DIR", None)
+    return subprocess.run([sys.executable] + argv, env=env, cwd=REPO,
+                          capture_output=True, text=True, timeout=timeout)
+
+
+def _phases(stdout):
+    recs = [json.loads(ln) for ln in stdout.splitlines()
+            if ln.startswith("{")]
+    return {r["phase"]: r for r in recs}
+
+
+def test_rehearsal_one_chip_phases(tmp_path):
+    proc = _child(["-c", _REHEARSE % 1], tmp_path)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    phases = _phases(proc.stdout)
+    assert list(phases) == ["device", "sync", "train_resnet50",
+                            "train_bert_base", "gluon_trainer", "serve",
+                            "warm_start", "total"]
+    for rec in phases.values():
+        assert rec["seconds"] >= 0 and rec["compile_seconds"] >= 0
+    assert phases["device"]["platform"] == "cpu"
+    assert phases["serve"]["compiles_after_warmup"] == 0
+    assert phases["warm_start"]["warm_compiles"] == 0
+    assert phases["warm_start"]["disk_hits"] > 0
+    assert phases["warm_start"]["bit_equal"] is True
+    # the cache rule, end to end: the environment placed the XLA cache,
+    # so the entries are THERE and the run says so
+    assert phases["total"]["xla_cache_dir"] == str(tmp_path / "xla_cache")
+    assert os.listdir(tmp_path / "xla_cache")
+
+
+@pytest.mark.multidevice
+def test_rehearsal_four_chip_phases(tmp_path):
+    """``--chips 4`` on four virtual CPU devices: meshes, sharding rules
+    and the disk-warm replica, which the driver never runs."""
+    proc = _child(["-c", _REHEARSE % 4], tmp_path, devices=4)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.splitlines()[-1] == "4"
+    phases = _phases(proc.stdout)
+    assert list(phases) == ["device", "dp_resnet50", "dp_gluon_trainer",
+                            "replicas", "total"]
+    assert phases["dp_resnet50"]["param_device_span"] == 4
+    assert phases["dp_resnet50"]["collectives"]
+    assert phases["dp_gluon_trainer"]["param_device_span"] == 4
+    assert len(set(phases["replicas"]["replica_devices"])) == 4
+    assert phases["replicas"]["replacement_compiles"] == 0
+    assert phases["replicas"]["replacement_disk_hits"] > 0
+
+
+@pytest.mark.parametrize("argv", [[], ["--chips", "4"]],
+                         ids=["one_chip", "four_chips"])
+def test_script_refuses_the_cpu(tmp_path, argv):
+    """No accelerator -> non-zero, a message that names the platform it
+    found, and NO result line."""
+    proc = _child(["chip_smoke.py"] + argv, tmp_path, timeout=120)
+    assert proc.returncode != 0
+    assert "'cpu'" in proc.stderr and "tpu" in proc.stderr
+    assert '"ok"' not in proc.stdout

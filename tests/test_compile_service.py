@@ -172,6 +172,69 @@ def test_disk_roundtrip_zero_compiles_bit_parity(tmp_path, monkeypatch):
     np.testing.assert_array_equal(np.asarray(warm.fn(x)), ref)
 
 
+@pytest.mark.multidevice
+@pytest.mark.parametrize("ids", [(5,), (2, 3, 6, 7)],
+                         ids=["one_device", "four_device_mesh"])
+def test_disk_reload_runs_on_its_own_devices(tmp_path, monkeypatch, ids):
+    """The blob records the executable's devices, and the reload hands
+    them to ``deserialize_and_load`` as ``execution_devices``: an
+    executable built for device 5 (or a 4-device mesh) of an 8-device
+    backend reloads onto exactly those devices. Without that JAX loads
+    it over every device of the backend and the first call dies with
+    ``Expected args to execute_sharded_on_local_devices to have 8
+    shards`` (the multi-device form used to be refused outright as
+    "XLA:CPU loads it as garbage" — the same fault)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", str(tmp_path))
+    devs = [jax.devices()[i] for i in ids]
+    sharding = NamedSharding(Mesh(np.array(devs), ("d",)), P("d"))
+    x = jax.device_put(jnp.arange(8, dtype=jnp.float32), sharding)
+    k = csvc.canonical_key(
+        site="executor", fn_id="svc-devs", signature=((8,), "f32"),
+        device=csvc.device_token(mesh=sharding.mesh))
+    cold = csvc.get_or_build(k, _build_mul(), example_args=(x,))
+    ref = np.asarray(cold.fn(x))
+    rec = pickle.load(open(csvc.disk_path_of(k), "rb"))
+    assert rec["devices"] == list(ids)
+    csvc.reset()
+    warm = csvc.get_or_build(k, _build_mul(), example_args=(x,))
+    assert warm.origin == "disk"
+    out = warm.fn(x)
+    assert {d.id for d in out.devices()} == set(ids)
+    np.testing.assert_array_equal(np.asarray(out), ref)
+
+
+@pytest.mark.multidevice
+def test_tpu_multidevice_reload_is_default_assignment_only(tmp_path,
+                                                           monkeypatch):
+    """What the four-chip host showed (PR 21): the TPU client reloads a
+    multi-device executable onto its default assignment whatever
+    ``execution_devices`` says. So, on a TPU, a single-device executable
+    is served on any chip, the all-devices-in-order mesh is served, and
+    any other mesh is refused at spill with a counted reason — never a
+    blob that would reload onto the wrong chips."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    every = [d.id for d in jax.devices()]
+    assert csvc._reloadable([5]) and csvc._reloadable([2, 3])   # XLA:CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert csvc._reloadable([5])
+    assert csvc._reloadable(every)
+    assert not csvc._reloadable([2, 3])
+    assert not csvc._reloadable(every[::-1])
+    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", str(tmp_path))
+    sharding = NamedSharding(Mesh(np.array(jax.devices()[2:4]), ("d",)),
+                             P("d"))
+    x = jax.device_put(jnp.arange(8, dtype=jnp.float32), sharding)
+    k = csvc.canonical_key(
+        site="executor", fn_id="svc-submesh", signature=((8,), "f32"),
+        device=csvc.device_token(mesh=sharding.mesh))
+    d0 = _counter("compile.disk.drops", tag="device_assignment")
+    e = csvc.get_or_build(k, _build_mul(), example_args=(x,))
+    assert e.origin == "built" and float(e.fn(x)[1]) == 3.0
+    assert _counter("compile.disk.drops", tag="device_assignment") == d0 + 1
+    assert not os.path.exists(csvc.disk_path_of(k))
+
+
 def test_disk_meta_persists(tmp_path, monkeypatch):
     monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", str(tmp_path))
 
@@ -466,6 +529,60 @@ def test_predictor_warm_start_zero_compiles(tmp_path):
     assert cold["compiles"] > 0
     assert warm["compiles"] == 0, warm
     assert warm["disk_hits"] > 0
+
+
+# ------------------------------------------------- the XLA cache rule
+_CACHE_RULE_CHILD = """
+import jax
+import numpy as np
+import mxtpu as mx
+from mxtpu import compile_service as csvc
+from mxtpu.gluon import nn
+net = nn.HybridSequential()
+net.add(nn.Dense(4))
+net.initialize()
+net.hybridize()
+net(mx.nd.array(np.ones((2, 3), np.float32))).asnumpy()  # service + jit
+print("IN_FORCE=%s" % jax.config.jax_compilation_cache_dir)
+print("HELPER=%s" % csvc.use_checkout_xla_cache())
+print("AFTER_HELPER=%s" % jax.config.jax_compilation_cache_dir)
+"""
+
+
+@pytest.mark.parametrize("store", [False, True],
+                         ids=["store_off", "store_on"])
+@pytest.mark.parametrize("placed", [False, True],
+                         ids=["env_unset", "env_set"])
+def test_xla_cache_dir_has_one_rule(tmp_path, placed, store):
+    """``JAX_COMPILATION_CACHE_DIR`` set: it is still what jax uses after
+    ``import mxtpu`` and a first dispatch through the compile service,
+    with the executable store on or off, and the entry-point helper
+    writes nothing. Unset: the library sets nothing, and the helper
+    yields the fixed in-checkout directory."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    for var in ("JAX_COMPILATION_CACHE_DIR", "MXTPU_COMPILE_CACHE_DIR"):
+        env.pop(var, None)
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "placed")
+    if store:
+        env["MXTPU_COMPILE_CACHE_DIR"] = str(tmp_path / "store")
+    proc = subprocess.run([sys.executable, "-c", _CACHE_RULE_CHILD],
+                          env=env, cwd=str(tmp_path), capture_output=True,
+                          text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    got = dict(ln.split("=", 1) for ln in proc.stdout.splitlines()
+               if "=" in ln)
+    fixed = os.path.join(REPO, ".jax_cache")
+    if placed:
+        want = str(tmp_path / "placed")
+        assert got == {"IN_FORCE": want, "HELPER": want,
+                       "AFTER_HELPER": want}
+    else:
+        assert got == {"IN_FORCE": "None", "HELPER": fixed,
+                       "AFTER_HELPER": fixed}
+    assert csvc.CHECKOUT_XLA_CACHE == fixed
 
 
 # ---------------------------------------------- site integration details

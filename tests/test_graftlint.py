@@ -389,7 +389,7 @@ def test_cli_exit_codes(tmp_path):
 
 
 def test_cli_self_clean_and_inventory(tmp_path):
-    """The exact perf_battery.sh pre-flight invocation exits 0, and
+    """The command-line self-clean invocation exits 0, and
     --inventory lands the scouting-report JSON."""
     inv = tmp_path / "jit_surfaces.json"
     proc = _run_cli(["mxtpu/", "--inventory", str(inv)], cwd=REPO)

@@ -45,8 +45,7 @@ def test_profiler_off_records_nothing(tmp_path):
 
 def test_xla_trace_bounded_and_idempotent(tmp_path):
     """A hung workload cannot leave a device capture running: the bounded
-    watchdog stops it, and every later stop path is a no-op (the round-3
-    chip wedge came from a capture with no surviving stopper)."""
+    watchdog stops it, and every later stop path is a no-op."""
     import glob
     import time
 
@@ -68,13 +67,6 @@ def test_xla_trace_bounded_and_idempotent(tmp_path):
     profiler.set_config(filename=str(tmp_path / "t.json"))  # reset config
 
 
-def test_orphan_guard_noops_while_parent_alive():
-    t = profiler.install_orphan_guard(poll_s=0.05)
-    import time
-    time.sleep(0.2)
-    assert t.is_alive()  # parent (us) still alive -> guard keeps watching
-
-
 def test_profiler_autostart_env(tmp_path):
     """MXTPU_PROFILER_AUTOSTART=1 profiles the whole program with no code
     changes and dumps profile.json at exit (ref env_var.md:152)."""
@@ -88,8 +80,7 @@ def test_profiler_autostart_env(tmp_path):
            if k not in ("XLA_FLAGS",)}
     env.update({"PYTHONPATH": repo, "JAX_PLATFORMS": "cpu",
                 "MXTPU_PROFILER_AUTOSTART": "1"})
-    code = ("import jax; jax.config.update('jax_platforms', 'cpu')\n"
-            "import mxtpu as mx\n"
+    code = ("import mxtpu as mx\n"
             "mx.nd.dot(mx.nd.ones((4, 4)), mx.nd.ones((4, 4))).asnumpy()\n")
     out = subprocess.run([sys.executable, "-c", code], cwd=str(tmp_path),
                          env=env, capture_output=True, text=True,

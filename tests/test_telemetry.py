@@ -366,7 +366,7 @@ def test_jsonl_sink_roundtrips_through_report(tmp_path, monkeypatch):
 
 
 def test_report_counters_fold_across_process_restarts(tmp_path):
-    """perf_battery shares ONE sink file across several sessions, each
+    """Several sessions may share ONE sink file, each
     restarting its cumulative counters at 0 — the report must bank each
     session (Prometheus reset semantics), not take the max."""
     sink = str(tmp_path / "multi.jsonl")

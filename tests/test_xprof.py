@@ -509,14 +509,12 @@ def test_decode_oom_threaded_crash_barrier(monkeypatch, tmp_path):
 # -------------------------------------------------------------- perf_model
 def test_cost_dict_normalizes_every_shape():
     assert perf_model.cost_dict(None) == {}
-    assert perf_model.cost_dict([]) == {}
-    assert perf_model.cost_dict([None]) == {}
+    assert perf_model.cost_dict({}) == {}
     assert perf_model.cost_dict({"flops": 5.0}) == {"flops": 5.0}
-    assert perf_model.cost_dict([{"flops": 5.0}]) == {"flops": 5.0}
 
     class _C:
         def cost_analysis(self):
-            return [{"flops": -1.0}]  # XLA's "unknown" spelling
+            return {"flops": -1.0}  # XLA's "unknown" spelling
 
     assert perf_model.flops_of(_C()) is None
 
@@ -524,6 +522,11 @@ def test_cost_dict_normalizes_every_shape():
 def test_peak_tables_and_roofline():
     assert perf_model.nominal_tflops("TPU v5 lite") == 197.0
     assert perf_model.nominal_tflops("TPU v4") == 275.0
+    # a TPU kind in no table is an error, never another chip's peak
+    with pytest.raises(LookupError, match="v9 mystery"):
+        perf_model.nominal_tflops("TPU v9 mystery")
+    with pytest.raises(LookupError):
+        perf_model.peak_bandwidth("TPU v9 mystery")
     os.environ["MXTPU_PEAK_TFLOPS"] = "2"
     os.environ["MXTPU_PEAK_GBPS"] = "1"
     try:

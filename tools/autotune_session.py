@@ -135,4 +135,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from perf_common import use_xla_cache
+    use_xla_cache()
     sys.exit(main())

@@ -40,7 +40,7 @@ count, the mesh-RESHAPE lever):
    *straggler_tripped* — the ``flight_record("straggler")`` artifact
    names rank 1 with ``data.wait`` dominant within 16 steps.
 
-JSON lines ride ``bench.py fleet_resume`` (tools/perf_battery.sh phase).
+JSON lines ride ``BENCH_CONFIG=fleet_resume python bench.py``.
 Knobs: ``BENCH_FLEET_STEPS`` (default 6), ``BENCH_FLEET_KILL_STEP``
 (default 3), ``BENCH_FLEET_CHILD_TIMEOUT_S``, ``BENCH_FLEET_DIR`` (pin
 the work dir; default fresh tempdir).
@@ -95,6 +95,11 @@ def _phase(name, world, ckpt_dir, steps, workdir, cache_dir, devices=1,
 
     base_env = {
         "MXTPU_COMPILE_CACHE_DIR": cache_dir,
+        # jax's own cache beside the store, for what the service cannot
+        # key (the one cache rule: children are handed JAX's variable)
+        "JAX_COMPILATION_CACHE_DIR": os.path.join(cache_dir, "xla"),
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+        "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0",
         "MXTPU_FLEET_BRINGUP_TIMEOUT_S": "90",
         "MXTPU_FLEET_HEARTBEAT_S": "0.5",
         # the post-kill wedge bound: the survivor's step-K collective
@@ -131,6 +136,8 @@ def run_fleet_resume(emit=None):
             print(json.dumps(rec), flush=True)
     steps, kill = _steps(), _kill_step()
     pinned = os.environ.get("BENCH_FLEET_DIR")
+    # cold against warm rejoin is the measurement: a FRESH directory of
+    # this tool's own, never the checkout's .jax_cache
     root = pinned or tempfile.mkdtemp(prefix="mxtpu-fleet-bench-")
     cache_dir = os.path.join(root, "compile_cache")
     ckpt = os.path.join(root, "ckpt")

@@ -57,7 +57,7 @@ def _snapshot_counts():
         return sum(v.values()) if isinstance(v, dict) else v
     return {"compiles": int(compiles),
             "disk_hits": int(total("compile.disk.hits")),
-            # a found-but-refused blob (key_mismatch, cpu_multidevice,
+            # a found-but-refused blob (key_mismatch, load_error,
             # corrupt...) is the difference between "cache cold" and
             # "cache rejected us" when a zero-compile gate fails
             "disk_drops": int(total("compile.disk.drops"))}

@@ -70,4 +70,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from perf_common import use_xla_cache
+    use_xla_cache()
     main()

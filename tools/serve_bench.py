@@ -65,8 +65,7 @@ Usage::
         [--decode-requests 80] [--decode-slots 8] [--decode-max-new 32]
         [--decode-qps 20,60,200]
 
-``bench.py``'s ``serving`` config drives the same functions in-process,
-and ``tools/perf_battery.sh`` runs this script as its serving phase.
+``bench.py``'s ``serving`` config drives the same functions in-process.
 """
 from __future__ import annotations
 
@@ -1309,12 +1308,17 @@ def run_zoo(n_models=None, n_devices=None, n_requests=None, qps=None,
         # paging pressure the bench exists to measure — without the
         # capacity-1 degenerate case where the hot model itself thrashes
         max_resident = max(1, -(-2 // len(devs)))
-    # evictions release executables (csvc.drop); the disk cache is what
-    # makes the page-in BACK a no-compile event, so give the run one
+    # evictions release executables (csvc.drop); the disk store is what
+    # makes the page-in BACK a no-compile event, so the run needs one:
+    # the caller's, or a fixed directory inside the checkout's cache
+    # home, emptied so every run starts its store cold
     if not os.environ.get("MXTPU_COMPILE_CACHE_DIR"):
-        import tempfile
-        os.environ["MXTPU_COMPILE_CACHE_DIR"] = tempfile.mkdtemp(
-            prefix="zoo_bench_cache_")
+        import shutil
+        from mxtpu import compile_service
+        store = os.path.join(compile_service.CHECKOUT_XLA_CACHE,
+                             "zoo_bench_store")
+        shutil.rmtree(store, ignore_errors=True)
+        os.environ["MXTPU_COMPILE_CACHE_DIR"] = store
 
     zoo = ModelZoo()
     spec = BucketSpec.pow2(8)
@@ -1593,4 +1597,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from perf_common import use_xla_cache
+    use_xla_cache()
     sys.exit(main())

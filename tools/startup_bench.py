@@ -15,8 +15,10 @@ deserializes), and gates:
   because nothing ran,
 * warm wall < cold wall — ``vs_baseline`` is the cold/warm speedup.
 
-JSON lines ride ``bench.py startup_time`` (tools/perf_battery.sh phase).
-Knobs: ``BENCH_STARTUP_HIDDEN`` / ``BENCH_STARTUP_LAYERS`` size the
+JSON lines ride ``BENCH_CONFIG=startup_time python bench.py``. The
+children open the backend themselves and a chip belongs to one process
+at a time, so this orchestrator imports no JAX (and the config is not
+part of ``BENCH_CONFIG=all``, whose parent holds the chip). Knobs: ``BENCH_STARTUP_HIDDEN`` / ``BENCH_STARTUP_LAYERS`` size the
 model, ``BENCH_STARTUP_ROUNDS`` extra warm rounds (min taken),
 ``BENCH_STARTUP_CACHE_DIR`` pins the dir (default: fresh tempdir).
 """
@@ -122,6 +124,13 @@ def run_child(scenario, t0):
 def _spawn(scenario, cache_dir, timeout_s=600):
     env = dict(os.environ)
     env["MXTPU_COMPILE_CACHE_DIR"] = cache_dir
+    # cold against warm is the measurement, so this tool keeps a fresh
+    # directory of its own (never the checkout's .jax_cache): jax's
+    # cache beside the store catches what the service cannot key, with
+    # the thresholds zeroed — a process start is all sub-second compiles
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(cache_dir, "xla")
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    env["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     t0 = time.time()
     proc = subprocess.run(
@@ -145,6 +154,7 @@ def run_startup(emit=None):
         def emit(rec):
             print(json.dumps(rec), flush=True)
     pinned = os.environ.get("BENCH_STARTUP_CACHE_DIR")
+    # a FRESH directory of this tool's own (see _spawn)
     root = pinned or tempfile.mkdtemp(prefix="mxtpu-startup-bench-")
     rounds = max(1, int(os.environ.get("BENCH_STARTUP_ROUNDS", "1")))
     out = {"scenarios": {}, "ok": True}

@@ -7,7 +7,7 @@ flush. This tool folds a file of those lines into the per-metric table —
 count / mean / p50 / p99 / max for observations, the final cumulative
 value for counters, the last write for gauges — the telemetry analog of
 ``profiler.dumps()``'s aggregate stats, runnable after the fact on a
-battery artifact (tools/perf_battery.sh runs it after each session).
+sink file a bench session left behind.
 
 With causal tracing on (``MXTPU_TRACE``, default 1), span observations
 carry their trace linkage (``trace``/``span``/``parent`` keys) and
@@ -75,9 +75,9 @@ def aggregate(lines):
     """Fold decoded JSONL records into {metric: summary-dict}.
 
     Counters are cumulative WITHIN one process and repeat per flush, but
-    several sessions may append to one file (perf_battery.sh shares a
-    single MXTPU_TELEMETRY path across the battery, benchmark_score, and
-    bandwidth runs, each restarting at 0) — so they fold Prometheus-style:
+    several sessions may append to one file (one MXTPU_TELEMETRY path
+    shared by bench, benchmark_score and bandwidth runs, each restarting
+    at 0) — so they fold Prometheus-style:
     a value that DROPS marks a process restart, banking the previous
     session's total. Multi-file merges (``load_many``) tag records with
     their source file index ``_src``: the restart fold then runs PER
