@@ -67,30 +67,15 @@ TINY = dict(
 
 
 # ------------------------------------------------------------ plumbing
-class _CompileClock:
-    """Seconds JAX spent tracing, lowering and compiling, and how many
-    backend compiles the persistent cache served instead — read off
-    ``jax.monitoring`` so a phase's ``compile_seconds`` is JAX's own
-    account, not a guess from wall time."""
-
-    _EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-               "/jax/core/compile/jaxpr_to_mlir_module_duration",
-               "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        from jax import monitoring
-        self.seconds = 0.0
-        self.cache_hits = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, name, secs, **_):
-        if name in self._EVENTS:
-            self.seconds += secs
-
-    def _on_event(self, name, **_):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
+def _compile_clock():
+    """(seconds JAX spent tracing, lowering and compiling; backend compiles
+    its persistent cache served instead) so far: JAX's own account, which
+    the program keeps (``telemetry.watch_compiles``), not a guess from
+    wall time."""
+    from mxtpu import telemetry
+    return (sum(telemetry.value(k) for k in
+                ("compile.trace_s", "compile.lower_s", "compile.backend_s")),
+            int(telemetry.value("compile.xla_cache_hits")))
 
 
 def _check(cond, what):
@@ -522,21 +507,24 @@ def run(sizes, chips=1, seed=0, out=sys.stdout):
     import bench
     from mxtpu import compile_service
     cache_dir = compile_service.use_checkout_xla_cache()
-    clock = _CompileClock()
+    from mxtpu import telemetry
+    telemetry.watch_compiles()
     devices = jax.devices()
     on_tpu = devices[0].platform == "tpu"
     t_run = time.perf_counter()
+    compile_s0, hits0 = _compile_clock()
 
     def phase(name, fn, *args):
-        c0, h0, t0 = clock.seconds, clock.cache_hits, time.perf_counter()
+        (c0, h0), t0 = _compile_clock(), time.perf_counter()
         rec = fn(*args)
         extra = None
         if isinstance(rec, tuple):
             rec, extra = rec
+        c1, h1 = _compile_clock()
         line = {"phase": name,
                 "seconds": round(time.perf_counter() - t0, 3),
-                "compile_seconds": round(clock.seconds - c0, 3),
-                "xla_cache_hits": clock.cache_hits - h0}
+                "compile_seconds": round(c1 - c0, 3),
+                "xla_cache_hits": h1 - h0}
         line.update(rec)
         print(json.dumps(line), file=out, flush=True)
         gc.collect()
@@ -557,10 +545,11 @@ def run(sizes, chips=1, seed=0, out=sys.stdout):
             phase("dp_resnet50", phase_dp_resnet50, sizes, seed, on_tpu)
             phase("dp_gluon_trainer", phase_dp_gluon_trainer, sizes, seed)
             phase("replicas", phase_replicas, sizes, seed, on_tpu)
+    compile_s, hits = _compile_clock()
     print(json.dumps({"phase": "total",
                       "seconds": round(time.perf_counter() - t_run, 3),
-                      "compile_seconds": round(clock.seconds, 3),
-                      "xla_cache_hits": clock.cache_hits,
+                      "compile_seconds": round(compile_s - compile_s0, 3),
+                      "xla_cache_hits": hits - hits0,
                       "xla_cache_dir": cache_dir}), file=out, flush=True)
     return {"platform": devices[0].platform, "kind": devices[0].device_kind,
             "count": len(devices)}

@@ -323,7 +323,6 @@ def _store_locked(key, entry):
     while bound > 0 and len(_STORE) > bound:
         old_key, _old = _STORE.popitem(last=False)
         telemetry.inc("compile.evictions", tag=old_key.site)
-    telemetry.gauge("compile.service.entries", len(_STORE))
 
 
 def get(key):
@@ -350,7 +349,6 @@ def drop(site=None, fn_id=None, nonce=None):
                       if fn_id is None or (isinstance(g, tuple)
                                            and fn_id in g)]:
                 del _GROUPS[g]
-        telemetry.gauge("compile.service.entries", len(_STORE))
     return len(victims)
 
 
@@ -361,7 +359,6 @@ def reset():
         _STORE.clear()
         _GROUPS.clear()
         _INFLIGHT.clear()
-        telemetry.gauge("compile.service.entries", 0)
 
 
 def stats():

@@ -404,6 +404,7 @@ def _forward_pallas(x, w, scale, bias, residual, cfg, geom):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=_interpret(),
+        name="conv_fwd",              # the kernel's name in a device trace
     )(*operands)
     out = res[0].reshape(n, oh, ow, cout)
     craw = res[1].reshape(n, oh, ow, cout) if cfg.has_scale else None

@@ -242,11 +242,13 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k):
             pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention_fwd",   # the kernel's name in a device trace
         **extra,
     )(q3, k3, v3)
     return out.reshape(b, h, t, d), lse[:, :, 0].reshape(b, h, t)
 
 
+@jax.named_scope("flash_attention_bwd")   # plain XLA: found by this scope
 def _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale, block_k,
                            g_lse=None):
     """Flash-attention-2 backward, blockwise over k in plain jax:

@@ -730,10 +730,9 @@ def _build(rule, static, mp_flags, out_dtypes, plan=None, zflags=None,
     zflags = zflags or (False,) * len(mp_flags)
 
     def fused(w_list, g_list, s_list, h_list, rescale):
-        # trace-time only (host-side): counts real recompiles, mirrored
-        # into the telemetry registry for report()/the JSONL sink
+        # trace-time only (host-side): counts real recompiles (the
+        # registry's twin is ``retrace.fused_optimizer``)
         FUSED_STATS["traces"] += 1
-        telemetry.inc("fused_optimizer.retraces")
         new_w, new_s = [], []
         for w, g, s, h, mp, odt, zf in zip(w_list, g_list, s_list, h_list,
                                            mp_flags, out_dtypes, zflags):
@@ -776,10 +775,9 @@ def _build_guarded(rule, static, mp_flags, out_dtypes, scaler_cfg,
     zflags = zflags or (False,) * len(mp_flags)
 
     def fused(w_list, g_list, s_list, lw_list, rescale, gstate, ext_sq):
-        # trace-time only (host-side): counts real recompiles, mirrored
-        # into the telemetry registry for report()/the JSONL sink
+        # trace-time only (host-side): counts real recompiles (the
+        # registry's twin is ``retrace.fused_optimizer``)
         FUSED_STATS["traces"] += 1
-        telemetry.inc("fused_optimizer.retraces")
         scale, streak, t_good = gstate
         # ONE fused reduction serves flag AND norm: the sum of squares is
         # finite iff every grad element is (an f32 overflow of the sum also
@@ -978,7 +976,6 @@ class FusedUpdater(Updater):
         for i, g, w in eager:
             opt.update_multi_precision(i, w, g, self.states[i])
             FUSED_STATS["eager_updates"] += 1
-            telemetry.inc("fused_optimizer.eager_updates")
 
     def _gather_items(self, items, hyper_of):
         """Per-item device buffers + the jit cache-key specs, ONE copy
@@ -1091,7 +1088,6 @@ class FusedUpdater(Updater):
             new_w, new_s = out
             self.last_fingerprint = None
         FUSED_STATS["fused_steps"] += 1
-        telemetry.inc("fused_optimizer.steps")
         for (i, _, w), nw, ns in zip(items, new_w, new_s):
             w._set_data(nw)
             _tree_writeback(self.states[i], ns)
@@ -1166,7 +1162,6 @@ class FusedUpdater(Updater):
                     for i, g, w in eager:
                         opt.update_multi_precision(i, w, g, self.states[i])
                         FUSED_STATS["eager_updates"] += 1
-                        telemetry.inc("fused_optimizer.eager_updates")
                 finally:
                     opt.rescale_grad = saved
             # skipped: eager per-index update counts stay untouched too
@@ -1206,7 +1201,6 @@ class FusedUpdater(Updater):
             new_w, new_s, new_gstate, ok, grad_norm = out
             self.last_fingerprint = None
         FUSED_STATS["fused_steps"] += 1
-        telemetry.inc("fused_optimizer.steps")
         for (i, _, w), nw, ns in zip(items, new_w, new_s):
             w._set_data(nw)
             _tree_writeback(self.states[i], ns)

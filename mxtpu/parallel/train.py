@@ -37,6 +37,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .. import optimizer as opt_mod
 from .. import optimizer_fused as _fused
 from .. import random as _random
+from .. import telemetry
 from ..base import MXNetError
 from ..gluon.block import _flatten_nd, _regroup, _run_traced
 from ..ndarray import NDArray
@@ -317,7 +318,12 @@ class ShardedTrainStep:
 
         wd = self._wd  # static: `if wd:` in the kernels
 
-        def step(param_datas, opt_states, hyper, rng, in_datas):
+        # the jitted function's name is the compiled module's in a device
+        # trace (``jit_sharded_train_step_<hash>``), and the two scopes
+        # below are on every operation's metadata: ``forward`` (its
+        # transpose, the backward, reads ``transpose(jvp(forward))``) and
+        # ``optimizer``. Names only: no operation changes
+        def sharded_train_step(param_datas, opt_states, hyper, rng, in_datas):
             lr, t = hyper  # traced scalars: lr schedule / step count don't recompile
             frozen = list(param_datas)
 
@@ -338,8 +344,9 @@ class ShardedTrainStep:
                     out = block(*args[:-1])
                     return loss_blk(out, args[-1])
 
-                out, aux = _run_traced(params, datas, rng, True, body)
-                scalar = jnp.mean(out._data)
+                with jax.named_scope("forward"):
+                    out, aux = _run_traced(params, datas, rng, True, body)
+                    scalar = jnp.mean(out._data)
                 return scalar, aux
 
             train_datas = [param_datas[i] for i in t_idx]
@@ -352,20 +359,22 @@ class ShardedTrainStep:
             # thyper): bias-correction terms are built IN-GRAPH from the
             # traced (lr, wd, t), so schedules and step count never
             # recompile — same tuples the fused Trainer step traces
-            h = thyper(static, lr, wd, t)
-            for j, i in enumerate(t_idx):
-                w, st = rule.step(new_datas[i], grads[j], opt_states[i],
-                                  h, 1.0, static)
-                # the f32 lr/state promote the arithmetic to f32 (precision),
-                # but storage keeps the parameter dtype (bf16 fast path) —
-                # the reference's multi-precision update pattern
-                # (optimizer.py:500 mp_sgd_update), shared with FusedUpdater
-                new_datas[i] = w.astype(param_datas[i].dtype)
-                new_states[i] = jax.tree_util.tree_map(
-                    lambda n, o: n.astype(o.dtype), st, opt_states[i])
-            for i, a in enumerate(aux):
-                if a is not None:  # BatchNorm moving stats etc.
-                    new_datas[i] = a.astype(new_datas[i].dtype)
+            with jax.named_scope("optimizer"):
+                h = thyper(static, lr, wd, t)
+                for j, i in enumerate(t_idx):
+                    w, st = rule.step(new_datas[i], grads[j], opt_states[i],
+                                      h, 1.0, static)
+                    # the f32 lr/state promote the arithmetic to f32
+                    # (precision), but storage keeps the parameter dtype
+                    # (bf16 fast path) — the reference's multi-precision
+                    # update pattern (optimizer.py:500 mp_sgd_update),
+                    # shared with FusedUpdater
+                    new_datas[i] = w.astype(param_datas[i].dtype)
+                    new_states[i] = jax.tree_util.tree_map(
+                        lambda n, o: n.astype(o.dtype), st, opt_states[i])
+                for i, a in enumerate(aux):
+                    if a is not None:  # BatchNorm moving stats etc.
+                        new_datas[i] = a.astype(new_datas[i].dtype)
             return new_datas, new_states, loss_val
 
         mesh = self._mesh
@@ -375,7 +384,7 @@ class ShardedTrainStep:
 
         def build():
             return jax.jit(
-                step,
+                sharded_train_step,
                 in_shardings=(self._param_shardings,
                               list(self._state_shardings),
                               None, None, self._in_shardings),
@@ -427,67 +436,92 @@ class ShardedTrainStep:
     def __call__(self, *batch):
         """Run one step on a batch (``(data, label)`` by default). Returns the
         scalar loss as a lazy NDArray — no host sync (SURVEY §1: frontend
-        never blocks; sync at asnumpy())."""
-        in_fmt = []
-        flat = _flatten_nd(batch, in_fmt)
-        in_datas = [x._data if isinstance(x, NDArray) else jnp.asarray(x)
-                    for x in flat]
-        # rebuild on a policy flip too: the traced block consults the
-        # registry.policy_key levers (BN one-pass, conv routing, ...) at
-        # trace time — reusing the old executable would silently run the
-        # stale policy (the aliasing hazard documented at registry.py:90)
-        from ..ops.registry import policy_key
-        policy = policy_key()
-        # input shapes join the rebuild condition: the compile service
-        # may hand back a shape-pinned AOT executable (disk-warm start),
-        # and a changed signature is a real compile either way — a
-        # repeated signature is a service hit, not a retrace
-        in_sig = tuple((tuple(d.shape), str(d.dtype)) for d in in_datas)
-        rebuild = self._jit is None or self._in_fmt != in_fmt \
-            or self._policy != policy or self._in_sig != in_sig
-        prev_shardings = getattr(self, "_in_shardings", None)
-        if rebuild:
-            self._resolve_in_shardings(len(in_datas))
-            self._last_abstract = None
-        in_datas = [self._place(d, s, local=True)
-                    for d, s in zip(in_datas, self._in_shardings)]
-        self._num_update += 1
-        lr = (self._lr_scheduler(self._num_update)
-              if self._lr_scheduler else float(self._opt.learning_rate))
-        hyper = (jnp.float32(lr), jnp.float32(self._num_update))
-        rng = _random.next_key()
+        never blocks; sync at asnumpy()).
+
+        Each call is one ``train_step`` trace (mxtpu/telemetry.py), the
+        root a profiler step numbered by the update it makes, with the
+        children ``train_step.place`` / ``.rng`` / ``.build`` (rebuilds
+        only) or ``.launch`` / ``.commit``: host timers, no device work."""
+        with telemetry.span("train_step", d2h=True, new_trace=True,
+                            step=self._num_update + 1):
+            return self._step(batch)
+
+    def _step(self, batch):
+        with telemetry.span("train_step.place"):
+            in_fmt = []
+            flat = _flatten_nd(batch, in_fmt)
+            in_datas = [x._data if isinstance(x, NDArray) else jnp.asarray(x)
+                        for x in flat]
+            # rebuild on a policy flip too: the traced block consults the
+            # registry.policy_key levers (BN one-pass, conv routing, ...)
+            # at trace time — reusing the old executable would silently run
+            # the stale policy (the aliasing hazard documented at
+            # registry.py:90)
+            from ..ops.registry import policy_key
+            policy = policy_key()
+            # input shapes join the rebuild condition: the compile service
+            # may hand back a shape-pinned AOT executable (disk-warm start),
+            # and a changed signature is a real compile either way — a
+            # repeated signature is a service hit, not a retrace
+            in_sig = tuple((tuple(d.shape), str(d.dtype)) for d in in_datas)
+            rebuild = self._jit is None or self._in_fmt != in_fmt \
+                or self._policy != policy or self._in_sig != in_sig
+            prev_shardings = getattr(self, "_in_shardings", None)
+            if rebuild:
+                self._resolve_in_shardings(len(in_datas))
+            in_datas = [self._place(d, s, local=True)
+                        for d, s in zip(in_datas, self._in_shardings)]
+            self._num_update += 1
+            lr = (self._lr_scheduler(self._num_update)
+                  if self._lr_scheduler else float(self._opt.learning_rate))
+            hyper = (jnp.float32(lr), jnp.float32(self._num_update))
+        with telemetry.span("train_step.rng"):
+            rng = _random.next_key()
+        args = (self._param_datas, self._opt_states, hyper, rng, in_datas)
         if rebuild:
             # built AFTER placement so the service can AOT-lower (and
             # persist) against the real placed argument signature; the
             # rebuild-condition state (incl. the input shardings the
             # placement consumed) commits only on SUCCESS — a transient
             # build failure must not leave a stale-policy executable or
-            # mismatched shardings looking current on the next step
-            try:
-                self._jit = self._build(in_fmt, len(in_datas),
-                                        example_args=(self._param_datas,
-                                                      self._opt_states,
-                                                      hyper, rng,
-                                                      in_datas))
-            except BaseException:
-                self._in_shardings = prev_shardings
-                raise
-            self._in_fmt = in_fmt
-            self._policy = policy
-            self._in_sig = in_sig
-        if self._last_abstract is None:
-            # abstract shapes for compiled_step_flops; shapes are invariant
-            # per (in_fmt, shapes) so capture once, off the per-step path
-            self._last_abstract = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                (self._param_datas, self._opt_states, hyper, rng, in_datas))
-        new_datas, new_states, loss = self._jit(
-            self._param_datas, self._opt_states, hyper, rng, in_datas)
-        self._param_datas = new_datas
-        self._opt_states = new_states
-        for p, d in zip(self._params, new_datas):
-            p.data()._set_data(d)
-        return NDArray(loss)
+            # mismatched shardings looking current on the next step.
+            # The span covers the first call of what was built too: a
+            # plain jit traces, lowers and compiles on that dispatch
+            with telemetry.span("train_step.build"):
+                try:
+                    self._jit = self._build(in_fmt, len(in_datas),
+                                            example_args=args)
+                except BaseException:
+                    self._in_shardings = prev_shardings
+                    raise
+                self._in_fmt = in_fmt
+                self._policy = policy
+                self._in_sig = in_sig
+                # abstract shapes for compiled_step_flops; invariant per
+                # (in_fmt, shapes), so captured on the rebuild alone
+                self._last_abstract = jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+                new_datas, new_states, loss = self._jit(*args)
+        else:
+            with telemetry.span("train_step.launch"):
+                new_datas, new_states, loss = self._jit(*args)
+        with telemetry.span("train_step.commit"):
+            self._param_datas = new_datas
+            self._opt_states = new_states
+            for p, d in zip(self._params, new_datas):
+                p.data()._set_data(d)
+            # the last references to the step's donated inputs (several
+            # hundred arrays): dropped here and not at the return, so
+            # that what freeing them costs is inside this span
+            del args, in_datas
+            return NDArray(loss)
+
+    def optimizer_states(self):
+        """The optimizer state of each trainable parameter, in parameter
+        order, in the rule's own structure (an array, a tuple of arrays,
+        or None for a stateless rule). The arrays are the live ones: the
+        next step donates them, so copy what has to outlast it."""
+        return [st for st, t in zip(self._opt_states, self._trainable) if t]
 
     def compiled(self):
         """The step's compiled executable (``as_text()``,

@@ -16,7 +16,14 @@ counters) with no common surface. This module is that surface:
 * **Spans** — ``with telemetry.span("trainer.step"): ...`` times a host
   region into a histogram AND a bounded event ring that
   :func:`mxtpu.profiler.dump` merges into the chrome-trace JSON, so one
-  file shows the host step phases alongside the XLA trace.
+  file shows the host step phases alongside the XLA trace. Every span is
+  also a ``jax.profiler.TraceAnnotation``: under any running JAX trace it
+  lies on the ``.xplane.pb`` host plane, on the trace's one timeline with
+  the device's operations (the two agree to about half a millisecond).
+* **Compile events** — :func:`watch_compiles`: JAX's own account of every
+  Python trace, lowering and backend compile, as ring events
+  (``jax.trace`` / ``jax.lower`` / ``jax.backend_compile``) and
+  ``compile.*_s`` counters tagged with the span that was open.
 * **Retrace watchdog** — jit-cache owners (``optimizer_fused.
   FusedUpdater``, gluon ``CachedOp``) report every compile with its
   cache-key / ``registry.policy_key`` provenance via
@@ -75,7 +82,8 @@ __all__ = ["enabled", "retrace_budget", "inc", "gauge", "observe", "value",
            "trace_handoff", "add_stage", "trace_mark", "link", "pend_link",
            "link_pending", "trace_breakdown", "trace_events", "trace_flows",
            "flight_record", "flight_snapshot", "prometheus",
-           "on_flush", "register_prometheus_extra"]
+           "on_flush", "register_prometheus_extra",
+           "watch_compiles", "EVENT_RING_CAP"]
 
 _log = logging.getLogger("mxtpu.telemetry")
 
@@ -85,7 +93,9 @@ _LOCK = threading.Lock()
 _COUNTERS = {}            # (name, tag-or-None) -> float
 _GAUGES = {}              # name -> float
 _HISTS = {}               # name -> [count, sum, min, max, reservoir-deque]
-_EVENTS = collections.deque(maxlen=65536)  # (name, cat, ts_us, dur_us, tid)
+EVENT_RING_CAP = 65536    # a reader that finds this many lost the head
+_EVENTS = collections.deque(maxlen=EVENT_RING_CAP)
+#                         ^ (name, cat, ts_us, dur_us, tid)
 _RESERVOIR = 2048         # per-histogram quantile sample bound
 
 # retrace watchdog: site -> {"compiles", "trips", "last"}
@@ -107,6 +117,30 @@ class _D2HLocal(threading.local):
 
 
 _D2H_LOCAL = _D2HLocal()
+
+
+class _OpenLocal(threading.local):
+    """The innermost span open on this thread (None outside any): what a
+    JAX compile event that fires on the thread is tagged with."""
+
+    def __init__(self):
+        self.span = None
+
+
+_OPEN = _OpenLocal()
+
+# jax.profiler's (TraceAnnotation, StepTraceAnnotation) once the first span
+# imported them: this module imports nothing of JAX when it is imported
+# (the flight recorder runs in processes that are dying)
+_TRACE_ME = None
+# jax.monitoring's compile events -> (ring event, counter compile.<x>_s)
+_JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("jax.trace", "trace"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("jax.lower", "lower"),
+    "/jax/core/compile/backend_compile_duration":
+        ("jax.backend_compile", "backend"),
+}
+_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 # JSONL sink: hot path appends to the queue; a flush (explicit, atexit, or
 # the off-thread timer) drains it to the file
@@ -448,6 +482,17 @@ class span:
     fresh trace when none is active (the per-request / per-step roots);
     with one already active it simply nests, preserving causality.
 
+    Profiler mirror: the span also enters a ``jax.profiler.
+    TraceAnnotation(name, cat=cat)`` (``step=n`` makes it a
+    ``StepTraceAnnotation`` with ``step_num=n``: the per-step roots; the
+    ``cat`` stat marks the event as one of the program's among JAX's own
+    host events), so whenever ANY JAX trace is
+    running the span lies on the ``.xplane.pb`` host plane, on the
+    timeline of the device's ``XLA Ops`` line (a v5e trace showed device
+    events up to half a millisecond ahead of the host call that enqueued
+    them: good for gaps of milliseconds, not of microseconds); with no
+    trace running it is one inactive ``TraceMe``.
+
     Pure host bookkeeping: no device ops, no syncs — safe under a
     ``jax.transfer_guard`` and inside the zero-sync Trainer.step contract.
     The enter/exit pair is hand-tuned for sub-millisecond hot loops: ONE
@@ -456,13 +501,18 @@ class span:
     """
 
     __slots__ = ("name", "cat", "_d2h", "_t0", "_d0", "_sink",
-                 "_new_trace", "_parent", "_tok", "ctx")
+                 "_new_trace", "_parent", "_tok", "ctx", "_step", "_ann",
+                 "_outer")
 
-    def __init__(self, name, cat="phase", d2h=False, new_trace=False):
+    def __init__(self, name, cat="phase", d2h=False, new_trace=False,
+                 step=None):
         self.name = name
         self.cat = cat
         self._d2h = d2h
         self._new_trace = new_trace
+        self._step = step
+        self._ann = None
+        self._outer = None
         self._t0 = None
         self._d0 = None
         self._sink = None
@@ -483,6 +533,15 @@ class span:
                 self.ctx = TraceContext(parent.trace_id, next(_SPAN_IDS),
                                         parent._stages)
                 self._tok = _TRACE_CV.set(self.ctx)
+            self._outer = _OPEN.span
+            _OPEN.span = self
+            tm = _TRACE_ME or watch_compiles()
+            # the ``cat`` stat is what tells the program's spans from
+            # JAX's own host events in a trace (tools/perf_trace.py)
+            self._ann = ann = tm[0](self.name, cat=self.cat) \
+                if self._step is None \
+                else tm[1](self.name, step_num=self._step, cat=self.cat)
+            ann.__enter__()
             self._t0 = time.perf_counter_ns()
             if self._d2h:
                 # thread-local snapshot: only syncs issued by THIS thread
@@ -496,6 +555,8 @@ class span:
         if t0 is None:
             return False
         dur_ns = time.perf_counter_ns() - t0
+        self._ann.__exit__(None, None, None)
+        _OPEN.span = self._outer
         v = dur_ns * 1e-9
         name = self.name
         if self._tok is not None:
@@ -549,6 +610,69 @@ class span:
             "warmup (occurrence %d) — the hot loop should be transfer-free; "
             "fetch verdicts/metrics asynchronously off the step path "
             "(docs/observability.md)", delta, self.name, occurrences)
+
+
+# ------------------------------------------------------- JAX compile events
+def watch_compiles():
+    """Import ``jax.profiler`` and register this module's ONE pair of
+    ``jax.monitoring`` listeners; idempotent. The first span does it; an
+    entry point that reads the ``compile.*`` counters without having
+    opened a span calls it itself. Every Python trace, lowering and
+    backend compile JAX reports then lands, where it happens:
+
+    * in the event ring (and, with tracing on, the trace ring, under the
+      context open on the compiling thread) as ``jax.trace`` /
+      ``jax.lower`` / ``jax.backend_compile`` with ``ts = now - secs``.
+      A jitted function traced inside another reports its own
+      ``jax.trace``, nested in the outer one's interval: add intervals
+      up by their union, not by their sum;
+    * in the counters ``compile.trace_s`` / ``compile.lower_s`` /
+      ``compile.backend_s`` (seconds; nested traces counted once each,
+      so ``trace_s`` is an upper bound) and ``compile.xla_cache_hits``
+      (backend compiles JAX's persistent cache served), tagged with the
+      innermost span open on that thread, ``untraced`` when none.
+
+    Returns ``(TraceAnnotation, StepTraceAnnotation)``."""
+    global _TRACE_ME
+    import jax.profiler as jp
+    from jax import monitoring
+    with _LOCK:
+        if _TRACE_ME is None:
+            monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            monitoring.register_event_listener(_on_jax_event)
+            _TRACE_ME = (jp.TraceAnnotation, jp.StepTraceAnnotation)
+    return _TRACE_ME
+
+
+def _open_span_name():
+    sp = _OPEN.span
+    return "untraced" if sp is None else sp.name
+
+
+def _on_jax_duration(event, secs, **_):
+    names = _JAX_DURATIONS.get(event)
+    if names is None:
+        return
+    inc("compile.%s_s" % names[1], secs, tag=_open_span_name())
+    if not enabled():
+        return
+    dur_us = int(secs * 1e6)
+    ts_us = time.perf_counter_ns() // 1000 - dur_us
+    tid = threading.get_ident() & 0xFFFF
+    if tracing_enabled():
+        ctx = _TRACE_CV.get()
+        _TRACE_EVENTS.append(
+            ("span", None if ctx is None else ctx.trace_id,
+             next(_SPAN_IDS), None if ctx is None else ctx.span_id,
+             names[0], ts_us, dur_us, tid))
+    with _LOCK:
+        _EVENTS.append((names[0], "compile", ts_us, dur_us, tid))
+
+
+def _on_jax_event(event, **_):
+    if event == _JAX_CACHE_HIT:
+        inc("compile.xla_cache_hits", tag=_open_span_name())
 
 
 # ----------------------------------------------------------- causal tracing
