@@ -193,6 +193,28 @@ def phase_train_resnet50(sizes, seed):
             "losses": losses}
 
 
+def _flash_backward_gap(fa, seed):
+    """The flash backward where the BERT step does not take it: causal,
+    Tq != Tk, several q and k blocks, a cotangent on lse (what ring
+    attention differentiates). ``jax.vjp`` through the public function
+    against the float32 blockwise oracle on the same residuals; the
+    largest gap of dq, dk, dv, each over its tensor's largest entry."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.RandomState(seed % (2 ** 31))
+    q, k, v, g = (jnp.asarray(rng.randn(2, 4, t, 64), jnp.bfloat16)
+                  for t in (256, 384, 384, 256))
+    g_lse = jnp.asarray(rng.randn(2, 4, 256), jnp.float32)
+    (out, lse), vjp = jax.vjp(
+        lambda q_, k_, v_: fa.flash_attention_with_lse(
+            q_, k_, v_, True, None, 128, 128), q, k, v)
+    oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, True, 0.125,
+                                       128, g_lse=g_lse)
+    f32 = lambda x: np.asarray(x, np.float32)
+    return max(float(np.max(np.abs(f32(a) - f32(b))) / np.max(np.abs(f32(b))))
+               for a, b in zip(vjp((g, g_lse)), oracle))
+
+
 def phase_train_bert_base(sizes, seed, on_tpu):
     import importlib
 
@@ -207,13 +229,29 @@ def phase_train_bert_base(sizes, seed, on_tpu):
     stats = dict(fa.DISPATCH_STATS.items())
     rec = {"model": sizes["bert"], "losses": losses, "pallas_flash": stats}
     if on_tpu:
-        # the flash kernel ran compiled: not interpreted (the flag is an
-        # error on the chip), not replaced by the XLA softmax
+        # the flash kernels ran compiled, forward and backward: not
+        # interpreted (the flag is an error on the chip), not replaced by
+        # the XLA softmax or the blockwise XLA backward
         _check(stats["pallas"] > 0 and not stats["fallback_reasons"],
                "flash attention fell back: %s" % stats)
-        _check("tpu_custom_call" in step.compiled().as_text(),
-               "no tpu_custom_call in the compiled BERT step")
+        _check(stats["bwd_pallas"] > 0 and stats["bwd_xla"] == 0,
+               "the flash backward took the XLA path: %s" % stats)
+        text = step.compiled().as_text()
+        _check("tpu_custom_call" in text and "flash_attention_bwd" in text,
+               "no flash kernels in the compiled BERT step")
         rec["tpu_custom_call_in_step"] = True
+    # bf16 against the float32 oracle: tier-1 measures 0.0084 at most
+    # through the interpreter; a wrong mask or lse term reads 0.1 or more
+    kernel_backwards = fa.DISPATCH_STATS["bwd_pallas"]
+    rec["flash_backward_gap"] = _flash_backward_gap(fa, seed)
+    _check(rec["flash_backward_gap"] <= 2e-2,
+           "flash backward (causal, Tq != Tk, g_lse) is %.4f from the "
+           "blockwise oracle" % rec["flash_backward_gap"])
+    if on_tpu:
+        after = dict(fa.DISPATCH_STATS.items())
+        _check(after["bwd_pallas"] == kernel_backwards + 1
+               and after["bwd_xla"] == 0,
+               "the small flash backward took the XLA path: %s" % after)
     return rec
 
 

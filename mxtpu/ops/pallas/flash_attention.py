@@ -12,14 +12,27 @@ Design (TPU-first):
   scratch across k steps; the [T, T] score matrix never materializes in HBM.
   Causal q/k block pairs above the diagonal are skipped (`pl.when`), saving
   ~half the FLOPs.
-* backward: custom_vjp recomputes probabilities blockwise from the saved
-  log-sum-exp via ``lax.scan`` over k-blocks (flash-attention-2 equations) —
-  memory stays O(T*D), no Pallas needed since the MXU work is plain matmuls
-  XLA already schedules well.
+* backward: custom_vjp, flash-attention-2 equations from the saved
+  log-sum-exp. Where the forward ran the kernel, ONE fused Pallas kernel
+  (``flash_attention_bwd``), grid (batch*heads, Tk/bk, Tq/bq) with the
+  q-block axis innermost: it recomputes P per (k block, q block) on the
+  transposed tile (k on sublanes, q on lanes), so s, p, dp and ds live in
+  VMEM only; dk/dv accumulate in scratch over the q blocks, dq of the whole
+  head stays in VMEM while the k blocks pass; operands in the input dtype
+  (bf16 = one MXU pass), statistics and accumulators float32 — the
+  forward's precision policy. It replaced a blockwise XLA backward that a
+  device trace of BERT-base showed at 4.1x the forward (30.8% of the step):
+  float32 einsums under ``jax_default_matmul_precision=float32`` are
+  multi-pass on the MXU, and at T = 512 its four [B, H, T, T] float32
+  matrices went through HBM in every layer (PERF.md §6, PR 25).
+  ``_fa_backward_blockwise`` stays as the path for a case the kernel
+  refuses and as the float32 oracle the tests compare the kernel against.
 * fallback: non-TPU platforms or non-divisible shapes use the XLA softmax
-  path with the same signature. Why each fallback happened is counted in
-  the reason-tagged ``pallas_flash.{pallas,xla,fallback}`` telemetry
-  family (the conv kernel's dispatch-stats discipline).
+  path with the same signature (its backward is XLA's own). Why each
+  fallback happened is counted in the reason-tagged
+  ``pallas_flash.{pallas,xla,fallback}`` telemetry family (the conv
+  kernel's dispatch-stats discipline); the backward of a kernel forward
+  counts in ``pallas_flash.{bwd_pallas,bwd_xla,bwd_fallback}``.
 * parity off-chip: ``MXTPU_FLASH_INTERPRET=1`` runs the kernel through
   the Pallas interpreter, so tier-1 pins the real online-softmax kernel
   against the XLA path on CPU without a chip (and the autotuner can
@@ -70,12 +83,15 @@ def _interpret():
 class _DispatchStatsView:
     """Read-only dict-shaped view over the telemetry counters."""
 
-    _KEYS = ("pallas", "xla", "fallback_reasons")
+    _KEYS = ("pallas", "xla", "fallback_reasons",
+             "bwd_pallas", "bwd_xla", "bwd_fallback_reasons")
+    _TAGGED = {"fallback_reasons": "pallas_flash.fallback",
+               "bwd_fallback_reasons": "pallas_flash.bwd_fallback"}
 
     def __getitem__(self, key):
         from ... import telemetry
-        if key == "fallback_reasons":
-            return telemetry.tagged("pallas_flash.fallback")
+        if key in self._TAGGED:
+            return telemetry.tagged(self._TAGGED[key])
         if key not in self._KEYS:
             raise KeyError(key)
         return int(telemetry.value("pallas_flash." + key))
@@ -107,9 +123,9 @@ DISPATCH_STATS = _DispatchStatsView()
 
 def reset_dispatch_stats():
     from ... import telemetry
-    telemetry.reset_metric("pallas_flash.pallas")
-    telemetry.reset_metric("pallas_flash.xla")
-    telemetry.reset_metric("pallas_flash.fallback")
+    for name in ("pallas", "xla", "fallback",
+                 "bwd_pallas", "bwd_xla", "bwd_fallback"):
+        telemetry.reset_metric("pallas_flash." + name)
 
 
 def _count_fallback(reason):
@@ -298,6 +314,164 @@ def _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale, block_k,
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                   dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                   *, scale, causal, block_q, block_k, n_q, n_k):
+    """One (head, k block, q block) step of the flash backward. Works on
+    the TRANSPOSED score tile ``s^T = k q^T`` [bk, bq]: dv and dk are then
+    plain matmuls with the tile on the left, lse and delta broadcast along
+    sublanes from [1, bq] rows, and only dq contracts the tile's first
+    axis. dk/dv accumulate over the inner (q) axis; dq for the whole head
+    stays in VMEM while the k blocks pass."""
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+
+    @pl.when(qi == 0)
+    def _init_kv():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(ki == 0)
+    def _init_q():
+        dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
+
+    # causal: a k block strictly above the q block's diagonal is all-masked
+    run = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+
+    @pl.when(run)
+    def _compute():
+        q = q_ref[0]                              # [bq, d]
+        k = k_ref[0]                              # [bk, d]
+        v = v_ref[0]                              # [bk, d]
+        g = g_ref[0]                              # [bq, d]
+        # the forward kernel's precision policy: operands in their input
+        # dtype (bf16 = one MXU pass), float32 accumulation and statistics
+        prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+                else jax.lax.Precision.DEFAULT)
+
+        def dot(a, b, contract):
+            return jax.lax.dot_general(
+                a, b, (contract, ((), ())),
+                preferred_element_type=jnp.float32, precision=prec)
+
+        st = dot(k, q, ((1,), (1,))) * scale      # [bk, bq]
+        if causal:
+            k_pos = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
+            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            st = jnp.where(q_pos >= k_pos, st, _NEG_INF)
+        pt = jnp.exp(st - lse_ref[0])             # P^T, lse as a [1, bq] row
+        dv_acc[:] += dot(pt.astype(g.dtype), g, ((1,), (0,)))
+        dpt = dot(v, g, ((1,), (1,)))             # dP^T [bk, bq]
+        # ds^T without its factor ``scale``: that is applied once to the
+        # [*, d] results instead of the [bk, bq] tile
+        dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
+        dk_acc[:] += dot(dst, q, ((1,), (0,)))
+        dq_acc[rows, :] += dot(dst, k, ((0,), (0,)))
+
+    @pl.when(qi == n_q - 1)
+    def _store_kv():
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(ki == n_k - 1)
+    def _store_q():
+        dq_ref[0, rows, :] = (dq_acc[rows, :] * scale).astype(dq_ref.dtype)
+
+
+def _resolve_bwd_blocks(q, k, block_q, block_k):
+    """``((block_q, block_k), None)`` for the backward kernel, or ``(None,
+    reason)`` where it refuses. The tile is transposed against the
+    forward's: q lies on the 128 lanes (a length off that granule is one
+    whole block, which is always tileable) and k on the sublanes. The
+    larger side halves until :func:`_bwd_vmem` fits the budget."""
+    t, tk, d = q.shape[2], k.shape[2], q.shape[3]
+    itm = jnp.dtype(q.dtype).itemsize
+    while True:
+        bq = _pick_block(t, block_q, 128) or (t if t % 8 == 0 else None)
+        bk = _pick_block(tk, block_k, 128)
+        if bq is None or bk is None:
+            return None, "sequence length has no TPU-tileable block"
+        if _bwd_vmem(bq, bk, t, d, itm) <= _BWD_VMEM_BUDGET:
+            return (bq, bk), None
+        smaller_q = _pick_block(t, bq // 2, 128) if bq > 128 else None
+        smaller_k = _pick_block(tk, bk // 2, 128) if bk > 128 else None
+        if smaller_q and (bq >= bk or not smaller_k):
+            block_q = smaller_q
+        elif smaller_k:
+            block_k = smaller_k
+        else:
+            return None, "dq of one head does not fit the VMEM budget"
+
+
+@jax.named_scope("flash_attention_bwd")   # prologue, kernel and epilogue
+def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
+                        block_k, g_lse=None):
+    """The flash backward as ONE fused Pallas kernel: P is recomputed per
+    (k block, q block) from the saved ``lse``; s, p, dp and ds never leave
+    VMEM. Same contract as :func:`_fa_backward_blockwise`."""
+    from jax.experimental.pallas import tpu as pltpu
+    f32 = jnp.float32
+    # the per-row constant of ds = P * (dP - delta); the lse cotangent
+    # folds into it (d lse_i / d s_ik = P_ik)
+    delta = jnp.sum(out.astype(f32) * g.astype(f32), axis=-1)
+    if g_lse is not None:
+        delta = delta - g_lse.astype(f32)
+    # head dims off the 128-lane granule are zero-padded as the forward
+    # pads them (zero columns change no score and give zero gradient
+    # columns, sliced off below): on the chip, at BERT-base's d = 64, the
+    # step is 4.4% shorter padded than with 64-wide blocks (PERF.md §6)
+    q, k, v, g, d_out = _pad_head_dim(q, k, v, g.astype(q.dtype))
+    b, h, t, d = q.shape
+    tk = k.shape[2]
+    bh = b * h
+    n_q = t // block_q
+    n_k = tk // block_k
+    kernel = functools.partial(_fa_bwd_kernel, scale=scale, causal=causal,
+                               block_q=block_q, block_k=block_k, n_q=n_q,
+                               n_k=n_k)
+    interpret = _interpret()
+    extra = {}
+    if not interpret:  # Mosaic-only hints: the interpreter takes none
+        itm = jnp.dtype(q.dtype).itemsize
+        extra["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(
+                16 * 2**20,
+                int(1.25 * _bwd_vmem(block_q, block_k, t, d, itm))))
+    q_spec = pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0))
+    k_spec = pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda b_, j, i: (b_, 0, i))
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        grid=(bh, n_k, n_q),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[
+            pl.BlockSpec((1, t, d), lambda b_, j, i: (b_, 0, 0)),
+            k_spec,
+            k_spec,
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
+            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((t, d), f32),          # dq of the head
+            pltpu.VMEM((block_k, d), f32),    # dk of the k block
+            pltpu.VMEM((block_k, d), f32),    # dv of the k block
+        ],
+        interpret=interpret,
+        name="flash_attention_bwd",   # the kernel's name in a device trace
+        **extra,
+    )(q.reshape(bh, t, d), k.reshape(bh, tk, d), v.reshape(bh, tk, d),
+      g.reshape(bh, t, d), lse.reshape(bh, 1, t), delta.reshape(bh, 1, t))
+    return (dq.reshape(q.shape)[..., :d_out], dk.reshape(k.shape)[..., :d_out],
+            dv.reshape(v.shape)[..., :d_out])
+
+
 def _pick_block(n, want, mult):
     """Largest block ≤ want that is a multiple of ``mult`` and divides n —
     so sequence lengths like 768 or 1536 (not divisible by the default 512)
@@ -390,16 +564,17 @@ def _resolve_blocks(q, k, block_q, block_k):
     return bq, bk
 
 
-def _pad_head_dim(q, k, v):
-    """Zero-pad [B, H, T, D] operands to the 128-lane granule. Zero key/
-    query columns contribute nothing to scores and zero value columns are
-    sliced off the output, so attention is exact under this padding."""
-    d = q.shape[-1]
+def _pad_head_dim(*xs):
+    """Zero-pad [B, H, T, D] operands to the 128-lane granule; returns
+    them with the original D. Zero key/query columns contribute nothing to
+    scores and zero value columns are sliced off the output, so attention
+    is exact under this padding."""
+    d = xs[0].shape[-1]
     d_pad = -(-d // 128) * 128
     if d_pad == d:
-        return q, k, v, d
+        return (*xs, d)
     pad = [(0, 0)] * 3 + [(0, d_pad - d)]
-    return jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad), d
+    return (*(jnp.pad(x, pad) for x in xs), d)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -425,20 +600,37 @@ def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
     return out, (q, k, v, out, lse)
 
 
+def _fa_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
+                 g_lse=None):
+    """The backward of a forward that ran the Pallas kernel (``lse`` was
+    saved): the fused kernel, or the blockwise XLA path for a case the
+    kernel refuses, counted with its reason like the forward's fallbacks."""
+    from ... import telemetry
+    blocks, refused = _resolve_bwd_blocks(q, k, block_q, block_k)
+    if blocks is None:
+        telemetry.inc("pallas_flash.bwd_xla")
+        telemetry.inc("pallas_flash.bwd_fallback", tag=refused)
+        # plain jax (no lane constraint), but its k-block must DIVIDE tk —
+        # the scan would silently drop a ragged tail otherwise
+        block_k = _pick_block(k.shape[2], block_k, 1) or k.shape[2]
+        return _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale,
+                                      block_k, g_lse=g_lse)
+    telemetry.inc("pallas_flash.bwd_pallas")
+    return _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, *blocks,
+                               g_lse=g_lse)
+
+
 def _fa_bwd(causal, scale, block_q, block_k, res, g):
     q, k, v, out, lse = res
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    # backward is plain jax (no lane constraint) but its k-block must
-    # DIVIDE tk — the scan would silently drop a ragged tail otherwise
-    block_k = _pick_block(k.shape[2], block_k, 1) or k.shape[2]
     if lse is None:
         # fallback path: differentiate the XLA implementation directly
         _, vjp = jax.vjp(lambda q_, k_, v_:
                          _xla_attention(q_, k_, v_, causal, scale), q, k, v)
         return vjp(g)
-    return _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale,
-                                  block_k)
+    return _fa_backward(q, k, v, out, lse, g, causal, scale, block_q,
+                        block_k)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
@@ -481,14 +673,13 @@ def _fa_lse_bwd(causal, scale, block_q, block_k, res, cots):
     q, k, v, out, lse = res
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    block_k = _pick_block(k.shape[2], block_k, 1) or k.shape[2]
     if lse is None:
         _, vjp = jax.vjp(lambda q_, k_, v_:
                          _xla_attention_lse(q_, k_, v_, causal, scale),
                          q, k, v)
         return vjp((g, g_lse))
-    return _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale,
-                                  block_k, g_lse=g_lse)
+    return _fa_backward(q, k, v, out, lse, g, causal, scale, block_q,
+                        block_k, g_lse=g_lse)
 
 
 flash_attention_with_lse.defvjp(_fa_lse_fwd, _fa_lse_bwd)
@@ -527,6 +718,22 @@ def _tune_vmem(bq, bk, d, itm):
             + bq * bk * 4                            # score/p tile (f32)
             + 2 * bq * 128 * 4 + bq * dp * 4         # m, l, acc scratch
             + 2 * (bq * dp * itm + bq * 128 * 4))    # out + lse tiles
+
+
+# VMEM the backward kernel may plan for: v5e's scoped default is 16 MiB of
+# 128 MiB; the kernel asks for what _bwd_vmem reckons, up to this
+_BWD_VMEM_BUDGET = 64 * 1024 * 1024
+
+
+def _bwd_vmem(bq, bk, t, d, itm):
+    """The backward kernel's :func:`_tune_vmem`: bytes one grid step
+    holds, with dq of the whole head resident (``t`` rows)."""
+    dp = -(-d // 128) * 128
+    return (2 * 2 * (bq + bk) * dp * itm     # q, g and k, v blocks (dbuf)
+            + 2 * 2 * 8 * bq * 4             # lse, delta rows (dbuf)
+            + bq * bk * (4 * 4 + 2 * itm)    # s^T, P^T, dP^T, ds^T + casts
+            + 2 * bk * dp * (4 + 2 * itm)    # dk, dv: scratch + out (dbuf)
+            + t * dp * (4 + 2 * itm))        # dq of the head: scratch + out
 
 
 def _tune_feasible(plan, sc):

@@ -1,7 +1,8 @@
 """Flash attention tests. On the CPU test mesh the Pallas path is skipped
 (`_supported` is False) — these validate the fallback and the blockwise
-backward math; the Pallas kernel itself is validated on the TPU chip
-(same comparisons, run via bench/verify flows)."""
+backward math, and, through the Pallas interpreter
+(``MXTPU_FLASH_INTERPRET=1``), the fused backward kernel against both. The
+compiled kernels are checked on the chip by ``chip_smoke.py``."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -257,3 +258,151 @@ def test_pad_head_dim_noop_on_granule():
     q = jnp.zeros((1, 1, 8, 128), jnp.float32)
     qp, kp, vp, d = fa._pad_head_dim(q, q, q)
     assert qp is q and kp is q and vp is q and d == 128
+
+
+# ------------------------------------------- the fused backward kernel
+_BWD_CASES = [
+    pytest.param(causal, t, tk, d, dtype, with_g_lse, want,
+                 id="%s-t%dx%d-d%d-%s-%s-%s" % (
+                     "causal" if causal else "full", t, tk, d, dtype,
+                     "g_lse" if with_g_lse else "no_g_lse",
+                     "one_block" if want == 512 else "blocks_of_128"))
+    for causal in (False, True)
+    for t, tk in ((256, 256), (256, 384))
+    for d in (64, 128)
+    for dtype in ("float32", "bfloat16")
+    for with_g_lse in (False, True)
+    for want in (512, 128)
+]
+
+
+def _gap(got, ref):
+    """max |got - ref| over max |ref|: the scale-free gap of one tensor."""
+    got, ref = (np.asarray(x, np.float32) for x in (got, ref))
+    return float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+
+
+@pytest.mark.parametrize("causal,t,tk,d,dtype,with_g_lse,want", _BWD_CASES)
+def test_pallas_backward_matches_oracles(monkeypatch, causal, t, tk, d,
+                                         dtype, with_g_lse, want):
+    """The kernel's dq, dk, dv against ``_fa_backward_blockwise`` (the
+    float32 oracle, same residuals) and against ``jax.vjp`` of the plain
+    XLA attention. float32 to 1e-5; bfloat16 to 2e-2 of each tensor's
+    largest entry (measured gap over these cases: 0.0084 at most — the
+    outputs' own rounding to bf16 is 0.004)."""
+    import importlib
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    rng = np.random.RandomState(7)
+    dt = jnp.dtype(dtype)
+    q = jnp.asarray(rng.randn(1, 2, t, d), dt)
+    k = jnp.asarray(rng.randn(1, 2, tk, d), dt)
+    v = jnp.asarray(rng.randn(1, 2, tk, d), dt)
+    g = jnp.asarray(rng.randn(1, 2, t, d), dt)
+    g_lse = (jnp.asarray(rng.randn(1, 2, t), jnp.float32)
+             if with_g_lse else None)
+    scale = d ** -0.5
+    out, lse = fa._xla_attention_lse(q, k, v, causal, scale)
+    blocks, refused = fa._resolve_bwd_blocks(q, k, want, want)
+    assert refused is None
+    assert blocks == ((t, tk) if want == 512 else (128, 128))
+    got = fa._fa_backward_pallas(q, k, v, out, lse, g, causal, scale,
+                                 *blocks, g_lse=g_lse)
+    oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, causal, scale,
+                                       blocks[1], g_lse=g_lse)
+    _, vjp = jax.vjp(lambda q_, k_, v_: fa._xla_attention_lse(
+        q_, k_, v_, causal, scale), q, k, v)
+    plain = vjp((g, jnp.zeros_like(lse) if g_lse is None else g_lse))
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for name, a, b, c in zip(("dq", "dk", "dv"), got, oracle, plain):
+        assert a.shape == b.shape and a.dtype == dt, name
+        assert _gap(a, b) <= tol, (name, "blockwise", _gap(a, b))
+        assert _gap(a, c) <= tol, (name, "plain vjp", _gap(a, c))
+
+
+def test_grad_reaches_the_backward_kernel(monkeypatch):
+    """``jax.grad`` through both public functions runs the fused backward
+    wherever the forward ran the kernel: ``bwd_pallas`` rises once per
+    differentiated call, ``bwd_xla`` does not."""
+    import importlib
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    q, k, v = (_rand((1, 2, 128, 64), s) for s in range(3))
+    fa.reset_dispatch_stats()
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, True) ** 2)
+
+    def loss_lse(q, k, v):
+        out, lse = fa.flash_attention_with_lse(q, k, v, True)
+        return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
+
+    def ref_lse(q, k, v):
+        out, lse = fa._xla_attention_lse(q, k, v, True, 64 ** -0.5)
+        return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
+
+    got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    assert fa.DISPATCH_STATS["bwd_pallas"] == 1
+    got_lse = jax.grad(loss_lse, argnums=(0, 1, 2))(q, k, v)
+    assert fa.DISPATCH_STATS["bwd_pallas"] == 2
+    assert fa.DISPATCH_STATS["bwd_xla"] == 0
+    assert fa.DISPATCH_STATS["bwd_fallback_reasons"] == {}
+    assert fa.DISPATCH_STATS["pallas"] == 2 and fa.DISPATCH_STATS["xla"] == 0
+    ref = jax.grad(lambda *a: jnp.sum(
+        fa._xla_attention(*a, True, 64 ** -0.5) ** 2), argnums=(0, 1, 2))(
+            q, k, v)
+    for a, b in zip(got, ref):
+        assert _gap(a, b) <= 1e-5
+    for a, b in zip(got_lse, jax.grad(ref_lse, argnums=(0, 1, 2))(q, k, v)):
+        assert _gap(a, b) <= 1e-5
+
+
+def test_backward_refusal_is_counted_and_takes_the_oracle(monkeypatch):
+    """A case the kernel refuses (here: a VMEM budget too small for any
+    block) runs ``_fa_backward_blockwise`` and says so with its reason."""
+    import importlib
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    monkeypatch.setattr(fa, "_BWD_VMEM_BUDGET", 1024)
+    q, k, v = (_rand((1, 1, 128, 64), s) for s in range(3))
+    fa.reset_dispatch_stats()
+    got = jax.grad(lambda *a: jnp.sum(fa.flash_attention(*a) ** 2),
+                   argnums=(0, 1, 2))(q, k, v)
+    assert fa.DISPATCH_STATS["bwd_pallas"] == 0
+    assert fa.DISPATCH_STATS["bwd_xla"] == 1
+    assert list(fa.DISPATCH_STATS["bwd_fallback_reasons"]) == [
+        "dq of one head does not fit the VMEM budget"]
+    ref = jax.grad(lambda *a: jnp.sum(
+        fa._xla_attention(*a, False, 64 ** -0.5) ** 2), argnums=(0, 1, 2))(
+            q, k, v)
+    for a, b in zip(got, ref):
+        assert _gap(a, b) <= 1e-5
+
+
+def test_backward_blocks_follow_the_shapes():
+    import importlib
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    spec = lambda t, d=64, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        (1, 1, t, d), dt)
+    # the BERT cell: one block a head
+    assert fa._resolve_bwd_blocks(spec(512), spec(512), 512, 512) == (
+        (512, 512), None)
+    # 768 = 2 x 384; a q length off the 128 lanes is one whole block
+    assert fa._resolve_bwd_blocks(spec(768), spec(768), 512, 512)[0] == (
+        384, 384)
+    assert fa._resolve_bwd_blocks(spec(200), spec(1024), 512, 512)[0] == (
+        200, 512)
+    # a long head: the tile shrinks until dq's residency fits
+    (bq, bk), _ = fa._resolve_bwd_blocks(spec(32768, 128), spec(32768, 128),
+                                         2048, 2048)
+    assert (bq, bk) == (1024, 1024)
+    assert fa._bwd_vmem(bq, bk, 32768, 128, 2) <= fa._BWD_VMEM_BUDGET
+    assert fa._resolve_bwd_blocks(spec(2 ** 20, 128), spec(2 ** 20, 128),
+                                  512, 512) == (
+        None, "dq of one head does not fit the VMEM budget")
+    # a long q off the lane granule has no smaller block to fall to
+    assert fa._resolve_bwd_blocks(spec(100000, 128), spec(1024, 128),
+                                  512, 512)[0] is None
+    assert fa._resolve_bwd_blocks(spec(100, 128), spec(1024, 128),
+                                  512, 512) == (
+        None, "sequence length has no TPU-tileable block")

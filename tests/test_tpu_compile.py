@@ -83,16 +83,40 @@ def test_flash_forward_compiles_to_mosaic(one_chip, as_tpu, causal):
 
 @pytest.mark.parametrize("causal", [False, True],
                          ids=["bert_base", "causal"])
-def test_flash_backward_compiles(one_chip, as_tpu, causal):
-    """Forward kernel + ``_fa_backward_blockwise`` (plain JAX) as one
-    differentiated program — it need only compile for the chip."""
+def test_flash_backward_compiles_to_mosaic(one_chip, as_tpu, causal):
+    """Forward and backward kernels as one differentiated program: both
+    are Mosaic calls under their stable names, the backward did not take
+    the blockwise XLA path, and no float32 [T, T] matrix is in the
+    program (the blockwise path made four of them a layer)."""
     q = _spec(_QKV, one_chip)
 
     def loss(q, k, v):
         return fa.flash_attention(q, k, v, causal).astype(jnp.float32).sum()
 
+    fa.reset_dispatch_stats()
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
-    assert "tpu_custom_call" in text     # the forward inside the vjp
+    assert text.count("tpu_custom_call") >= 2
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert "f32[16,12,512,512]" not in text and "f32[192,512,512]" not in text
+    assert fa.DISPATCH_STATS["bwd_pallas"] == 1
+    assert fa.DISPATCH_STATS["bwd_xla"] == 0
+
+
+def test_flash_backward_with_lse_compiles_to_mosaic(one_chip, as_tpu):
+    """What ring attention differentiates: causal, Tq != Tk, several q and
+    k blocks, a cotangent on lse, head dim on the lane granule."""
+    q = _spec((2, 8, 1024, 128), one_chip)
+    kv = _spec((2, 8, 2048, 128), one_chip)
+
+    def loss(q, k, v):
+        out, lse = fa.flash_attention_with_lse(q, k, v, True)
+        return out.astype(jnp.float32).sum() + jnp.sin(lse).sum()
+
+    fa.reset_dispatch_stats()
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert "flash_attention_bwd" in text
+    assert fa.DISPATCH_STATS["bwd_pallas"] == 1
+    assert fa.DISPATCH_STATS["bwd_xla"] == 0
 
 
 # ResNet-50 conv classes at batch 128 (NHWC x, HWIO w, stride, padding)

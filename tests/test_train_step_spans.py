@@ -215,15 +215,20 @@ def test_the_scopes_change_no_operation(monkeypatch):
     assert "optimizer" in debug and "forward" in debug
 
 
-@pytest.mark.parametrize("kernel", ["flash_attention_fwd", "conv_fwd"])
+@pytest.mark.parametrize("kernel", ["flash_attention_fwd",
+                                    "flash_attention_bwd", "conv_fwd"])
 def test_kernels_carry_their_name(kernel, monkeypatch):
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     monkeypatch.setenv("MXTPU_PALLAS_CONV_INTERPRET", "1")
-    if kernel == "flash_attention_fwd":
+    if kernel.startswith("flash_attention"):
         fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
         q = jnp.ones((1, 2, 128, 128), jnp.float32)
-        fn, args = (lambda q: fa._fa_forward_pallas(
-            q, q, q, False, 1.0, 128, 128)), (q,)
+        if kernel == "flash_attention_fwd":
+            fn, args = (lambda q: fa._fa_forward_pallas(
+                q, q, q, False, 1.0, 128, 128)), (q,)
+        else:
+            fn, args = (lambda q: fa._fa_backward_pallas(
+                q, q, q, q, q[..., 0], q, False, 1.0, 128, 128)), (q,)
     else:
         pc = importlib.import_module("mxtpu.ops.pallas.conv")
         fn, args = (lambda x, w: pc.fused_conv(
@@ -233,14 +238,24 @@ def test_kernels_carry_their_name(kernel, monkeypatch):
     assert kernel in jax.jit(fn).lower(*args).as_text(debug_info=True)
 
 
-def test_flash_backward_is_found_by_its_scope():
+@pytest.mark.parametrize("path", ["blockwise", "pallas"])
+def test_flash_backward_is_found_by_its_scope(path, monkeypatch):
+    """Either backward lies whole under the scope ``flash_attention_bwd``:
+    the join of PERF.md §5 finds the kernel with its prologue."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
     q = jnp.ones((1, 2, 128, 64))
     lse = jnp.ones((1, 2, 128))
-    text = jax.jit(lambda q: fa._fa_backward_blockwise(
-        q, q, q, q, lse, q, False, 1.0, 128)).lower(q).as_text(
-            debug_info=True)
+    if path == "blockwise":
+        fn = lambda q: fa._fa_backward_blockwise(
+            q, q, q, q, lse, q, False, 1.0, 128)
+    else:
+        fn = lambda q: fa._fa_backward_pallas(
+            q, q, q, q, lse, q, False, 1.0, 128, 128)
+    text = jax.jit(fn).lower(q).as_text(debug_info=True)
     assert "flash_attention_bwd" in text
+    # the prologue (delta's reduction) is inside the scope too
+    assert re.search(r"flash_attention_bwd/[^\"]*reduce_sum", text)
 
 
 def test_optimizer_states_are_the_trainable_leaves_in_order():
