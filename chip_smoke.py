@@ -4,7 +4,9 @@ Drives the main train and serve paths ONCE, end to end, in ONE process, on
 one TPU, through the entry points a user calls, at the published widths of
 the two models ``bench.py`` is built around (ResNet-50 v1 at 224x224x3 /
 1000 classes / batch 128, BERT-base at 12 layers / 768 wide / 12 heads /
-seq 512 / batch 16 / vocab 30,522; bf16, weights random from ``--seed``).
+seq 512 / batch 16 / vocab 30,522; bf16, weights random from ``--seed``),
+and both flash kernels once more at latent attention's shape (32 heads of
+8,192 positions, keys and queries 192 wide, values 128, causal).
 
     python chip_smoke.py            # one chip, every phase below
     python chip_smoke.py --chips 4  # ONLY the cross-chip paths (one host)
@@ -45,6 +47,8 @@ FULL = dict(
     resnet_batch=128, gluon_batch=32, serve_batches=(1, 8),
     serve_requests=(1, 3, 8, 2),
     bert=dict(batch=16, seq=512, vocab=30522, dim=768, heads=12, layers=12),
+    # latent attention's shape: keys and queries 192 wide, values 128
+    latent=dict(heads=32, seq=8192, qk=192, v=128, check_heads=2),
     train_steps=5, gluon_steps=3,
     # 1-device vs 4-device losses, same batch and seed: reduce-order
     # tolerance for bf16 parameters (the 4-way psum sums partial
@@ -58,6 +62,7 @@ TINY = dict(
     resnet_batch=8, gluon_batch=8, serve_batches=(1, 4),
     serve_requests=(1, 3, 4, 2),
     bert=dict(batch=4, seq=128, vocab=512, dim=64, heads=2, layers=2),
+    latent=dict(heads=2, seq=256, qk=24, v=16, check_heads=2),
     train_steps=5, gluon_steps=3,
     # the toy memorizes its 8 images in three steps (loss 3.4 -> 0.02),
     # which amplifies one bf16 ULP of reduce order into tens of percent:
@@ -253,6 +258,46 @@ def phase_train_bert_base(sizes, seed, on_tpu):
                and after["bwd_xla"] == 0,
                "the small flash backward took the XLA path: %s" % after)
     return rec
+
+
+def phase_flash_two_widths(sizes, seed, on_tpu):
+    """Both flash kernels, compiled, with keys and queries of one width and
+    values of another (latent attention), causal, over many blocks:
+    ``jax.vjp`` through the public function against the plain float32
+    attention and its ``jax.vjp`` on the first ``check_heads`` heads (the
+    scores of all heads in float32 would not fit). Fails on the chip if
+    either kernel was left for XLA."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    n = sizes["latent"]
+    rng = np.random.RandomState(seed % (2 ** 31))
+    shape = lambda d: (1, n["heads"], n["seq"], n[d])
+    q, k, v, g = (jnp.asarray(rng.randn(*shape(d)), jnp.bfloat16)
+                  for d in ("qk", "qk", "v", "v"))
+    fa.reset_dispatch_stats()
+    out, vjp = jax.vjp(lambda *a: fa.flash_attention(*a, True), q, k, v)
+    got = (out,) + vjp(g)
+    stats = dict(fa.DISPATCH_STATS.items())
+    if on_tpu:
+        _check(stats["pallas"] == 1 and stats["xla"] == 0,
+               "the two-width flash forward fell back: %s" % stats)
+        _check(stats["bwd_pallas"] == 1 and stats["bwd_xla"] == 0,
+               "the two-width flash backward took the XLA path: %s" % stats)
+    f32 = lambda x: x[:, :n["check_heads"]].astype(jnp.float32)
+    want, ref_vjp = jax.vjp(
+        lambda *a: fa._xla_attention(*a, True, n["qk"] ** -0.5),
+        f32(q), f32(k), f32(v))
+    want = (want,) + ref_vjp(f32(g))
+    gaps = {name: float(jnp.max(jnp.abs(f32(a) - b)) / jnp.max(jnp.abs(b)))
+            for name, a, b in zip(("out", "dq", "dk", "dv"), got, want)}
+    # bf16 against float32: tier-1 measures 0.0084 at most through the
+    # interpreter; a wrong mask, width or scale reads 0.1 or more
+    _check(max(gaps.values()) <= 2e-2,
+           "two-width flash attention is %s from the float32 oracle" % gaps)
+    return {"shape": n, "pallas_flash": stats, "gaps": gaps}
 
 
 def _gluon_loop(sizes, seed, mesh=None):
@@ -575,6 +620,8 @@ def run(sizes, chips=1, seed=0, out=sys.stdout):
             phase("sync", phase_sync, sizes, on_tpu)
             phase("train_resnet50", phase_train_resnet50, sizes, seed)
             phase("train_bert_base", phase_train_bert_base, sizes, seed,
+                  on_tpu)
+            phase("flash_two_widths", phase_flash_two_widths, sizes, seed,
                   on_tpu)
             net = phase("gluon_trainer", phase_gluon_trainer, sizes, seed)
             phase("serve", phase_serve, sizes, seed, net)

@@ -1,11 +1,13 @@
 """Contrib layers (ref: python/mxnet/gluon/contrib/nn/basic_layers.py)."""
 from __future__ import annotations
 
+import jax
+
 from ...block import HybridBlock
-from ...nn import BatchNorm, HybridSequential, Embedding
+from ...nn import BatchNorm, GatedMLP, HybridSequential, Embedding
 
 __all__ = ["Concurrent", "HybridConcurrent", "Identity", "SparseEmbedding",
-           "SyncBatchNorm", "SwitchMoE"]
+           "SyncBatchNorm", "SwitchMoE", "RoutedMoE"]
 
 
 class Concurrent(HybridSequential):
@@ -127,3 +129,59 @@ class SwitchMoE(HybridBlock):
     def __repr__(self):
         return "SwitchMoE(dim=%d, hidden=%d, experts=%d)" % (
             self._dim, self._hidden, self._num_experts)
+
+
+class RoutedMoE(HybridBlock):
+    """Top-k routed gated experts with optional shared experts, as
+    DeepSeek-V3 has them (arXiv:2412.19437 §2.1.2): sigmoid scores, a
+    selection bias that takes no gradient, weights normalised over the
+    chosen experts and scaled; no capacity, no token dropped.
+
+    ``experts_held`` of the router's ``num_experts`` live in this block,
+    starting at ``first_expert``: one chip's share of the layer under
+    expert parallelism (all of them by default). The block routes over
+    all ``num_experts`` and returns its own experts' part of the routed
+    sum plus the shared experts' output, which every holder computes
+    alike. Wraps the registered ``_contrib_routed_moe`` op
+    (mxtpu.parallel.moe.routed_ffn).
+
+    Input (..., dim) is flattened to tokens and restored.
+    """
+
+    def __init__(self, dim, hidden, num_experts, top_k, experts_held=None,
+                 first_expert=0, scale=1.0, shared_hidden=0, grouped=True,
+                 **kwargs):
+        super().__init__(**kwargs)
+        held = num_experts if experts_held is None else experts_held
+        self._dim, self._hidden = dim, hidden
+        self._num_experts, self._held = num_experts, held
+        self._attrs = {"top_k": top_k, "first_expert": first_expert,
+                       "scale": scale, "grouped": grouped}
+        with self.name_scope():
+            self.router = self.params.get("router_weight",
+                                          shape=(num_experts, dim))
+            self.score_bias = self.params.get(
+                "score_bias", shape=(num_experts,), init="zeros",
+                grad_req="null", differentiable=False)
+            self.w_gate = self.params.get("w_gate", shape=(held, dim, hidden))
+            self.w_up = self.params.get("w_up", shape=(held, dim, hidden))
+            self.w_down = self.params.get("w_down",
+                                          shape=(held, hidden, dim))
+            self.shared = GatedMLP(dim, shared_hidden, prefix="shared_") \
+                if shared_hidden else None
+
+    def hybrid_forward(self, F, x, router, score_bias, w_gate, w_up, w_down):
+        if x.shape[-1] != self._dim:
+            raise ValueError("RoutedMoE(dim=%d) got input with last axis %d"
+                             % (self._dim, x.shape[-1]))
+        out = F._contrib_routed_moe(x, router, score_bias, w_gate, w_up,
+                                    w_down, **self._attrs)
+        if self.shared is None:
+            return out
+        with jax.named_scope("moe.shared"):
+            return out + self.shared(x)
+
+    def __repr__(self):
+        return "RoutedMoE(dim=%d, hidden=%d, experts=%d of %d, top_k=%d)" % (
+            self._dim, self._hidden, self._held, self._num_experts,
+            self._attrs["top_k"])

@@ -1,0 +1,118 @@
+"""Decoder-only language model with multi-head latent attention and routed
+experts: the layer of DeepSeek-V2/V3 (arXiv:2405.04434, arXiv:2412.19437;
+``model_type: deepseek_v3`` configurations).
+
+No reference counterpart. Pre-norm blocks, every norm an RMSNorm, no bias
+anywhere:
+
+* attention keeps ONE low-rank latent a token for keys and values and one
+  rotary key shared by all heads (:class:`MultiHeadLatentAttention`); keys
+  and queries are ``nope + rope`` wide, values ``v`` wide, and both flash
+  kernels take the two widths;
+* the first ``dense_layers`` blocks have a gated MLP, the rest a
+  :class:`~mxtpu.gluon.contrib.nn.RoutedMoE`: top-k of ``num_experts`` small
+  gated experts by sigmoid scores, nothing dropped, plus shared experts. A
+  block may hold a contiguous range of each layer's experts (``experts_held``
+  from ``first_expert``): one chip's share under expert parallelism;
+* rotary positions on the ``rope`` part of each head, no position table;
+* a final norm and an untied vocabulary head.
+
+Trains under :class:`mxtpu.parallel.ShardedTrainStep` like
+:class:`~mxtpu.gluon.model_zoo.transformer.TransformerLM`.
+"""
+from __future__ import annotations
+
+from ..block import HybridBlock
+from .. import nn
+
+__all__ = ["LatentMoELM", "LatentMoEBlock", "MultiHeadLatentAttention"]
+
+
+class MultiHeadLatentAttention(HybridBlock):
+    """Causal latent attention without a query rank: ``q = x Wq``; ``x
+    Wkva`` gives the latent ``c`` (``kv_rank`` wide) and the shared rotary
+    key; ``norm(c) Wkvb`` gives each head's position-free key and value."""
+
+    def __init__(self, dim, num_heads, kv_rank, nope_dim, rope_dim, v_dim,
+                 rope_theta=10000.0, rope_interleave=False, epsilon=1e-6,
+                 causal=True, **kwargs):
+        super().__init__(**kwargs)
+        self._kv_rank, self._rope_dim = kv_rank, rope_dim
+        self._attrs = {"num_heads": num_heads, "nope_dim": nope_dim,
+                       "rope_dim": rope_dim, "v_dim": v_dim,
+                       "rope_theta": rope_theta,
+                       "rope_interleave": rope_interleave, "causal": causal}
+        with self.name_scope():
+            self.q = nn.Dense(num_heads * (nope_dim + rope_dim),
+                              use_bias=False, flatten=False, prefix="q_")
+            self.kv_a = nn.Dense(kv_rank + rope_dim, use_bias=False,
+                                 flatten=False, prefix="kva_")
+            self.kv_norm = nn.RMSNorm(epsilon=epsilon, prefix="kvnorm_")
+            self.kv_b = nn.Dense(num_heads * (nope_dim + v_dim),
+                                 use_bias=False, flatten=False, prefix="kvb_")
+            self.proj = nn.Dense(dim, use_bias=False, flatten=False,
+                                 prefix="proj_")
+
+    def hybrid_forward(self, F, x):
+        r = self._kv_rank
+        ckr = self.kv_a(x)                                   # [B, T, r + rope]
+        c = F.slice_axis(ckr, axis=-1, begin=0, end=r)
+        k_rope = F.slice_axis(ckr, axis=-1, begin=r, end=r + self._rope_dim)
+        out = F._contrib_latent_attention(
+            self.q(x), self.kv_b(self.kv_norm(c)), k_rope, **self._attrs)
+        return self.proj(out)
+
+
+class LatentMoEBlock(HybridBlock):
+    """``h = x + attn(norm1(x)); y = h + ffn(norm2(h))``; ``ffn`` is a gated
+    MLP (``moe=None``) or routed experts (``moe``: the keyword arguments of
+    :class:`~mxtpu.gluon.contrib.nn.RoutedMoE` after ``dim``)."""
+
+    def __init__(self, dim, attention, dense_hidden=0, moe=None,
+                 epsilon=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.norm1 = nn.RMSNorm(epsilon=epsilon, prefix="norm1_")
+            self.attn = MultiHeadLatentAttention(dim, epsilon=epsilon,
+                                                 prefix="attn_", **attention)
+            self.norm2 = nn.RMSNorm(epsilon=epsilon, prefix="norm2_")
+            if moe is None:
+                self.ffn = nn.GatedMLP(dim, dense_hidden, prefix="mlp_")
+            else:
+                from ..contrib.nn import RoutedMoE
+                self.ffn = RoutedMoE(dim, prefix="moe_", **moe)
+
+    def hybrid_forward(self, F, x):
+        x = x + self.attn(self.norm1(x))
+        return x + self.ffn(self.norm2(x))
+
+
+class LatentMoELM(HybridBlock):
+    """Embed → ``dense_layers`` dense blocks → routed-expert blocks → RMSNorm
+    → vocabulary head. Input: int token ids [B, T]; output: logits [B, T,
+    vocab].
+
+    ``attention``: ``num_heads, kv_rank, nope_dim, rope_dim, v_dim`` and
+    optionally ``rope_theta, rope_interleave``. ``moe``: ``hidden,
+    num_experts, top_k`` and optionally ``experts_held, first_expert, scale,
+    shared_hidden`` (:class:`~mxtpu.gluon.contrib.nn.RoutedMoE`).
+    """
+
+    def __init__(self, vocab_size, dim, num_layers, attention, dense_hidden,
+                 moe, dense_layers=1, epsilon=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.embed = nn.Embedding(vocab_size, dim, prefix="wte_")
+            self.blocks = nn.HybridSequential(prefix="h_")
+            with self.blocks.name_scope():
+                for i in range(num_layers):
+                    self.blocks.add(LatentMoEBlock(
+                        dim, attention, dense_hidden=dense_hidden,
+                        moe=None if i < dense_layers else moe,
+                        epsilon=epsilon))
+            self.norm_f = nn.RMSNorm(epsilon=epsilon, prefix="normf_")
+            self.head = nn.Dense(vocab_size, use_bias=False, flatten=False,
+                                 prefix="head_")
+
+    def hybrid_forward(self, F, tokens):
+        return self.head(self.norm_f(self.blocks(self.embed(tokens))))

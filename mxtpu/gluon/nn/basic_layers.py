@@ -6,7 +6,7 @@ from ...base import MXNetError
 from ..block import Block, HybridBlock
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
-           "InstanceNorm", "LayerNorm", "Embedding", "Flatten", "Lambda",
+           "InstanceNorm", "LayerNorm", "RMSNorm", "GatedMLP", "Embedding", "Flatten", "Lambda",
            "HybridLambda", "HybridConcurrent", "Concurrent", "Identity"]
 
 
@@ -280,6 +280,48 @@ class LayerNorm(HybridBlock):
 
     def hybrid_forward(self, F, x, gamma, beta):
         return F.LayerNorm(x, gamma, beta, axis=self._axis, eps=self._epsilon)
+
+
+class RMSNorm(HybridBlock):
+    """Root-mean-square normalization, a scale and no shift (op
+    ``RMSNorm``; no reference counterpart)."""
+
+    def __init__(self, axis=-1, epsilon=1e-6, gamma_initializer="ones",
+                 in_channels=0, **kwargs):
+        super().__init__(**kwargs)
+        self._axis = axis
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get(
+                "gamma", shape=(in_channels,), init=gamma_initializer,
+                allow_deferred_init=True)
+
+    def infer_shape(self, x, *args):
+        self.gamma._shape_resolved((x.shape[self._axis],))
+
+    def hybrid_forward(self, F, x, gamma):
+        return F.RMSNorm(x, gamma, axis=self._axis, eps=self._epsilon)
+
+
+class GatedMLP(HybridBlock):
+    """``down(act(gate(x)) * up(x))`` without biases: the gated
+    feed-forward of Shazeer (arXiv:2002.05202); SwiGLU with the default
+    ``silu``. Acts on the last axis."""
+
+    def __init__(self, units, hidden_units, activation="silu", **kwargs):
+        super().__init__(**kwargs)
+        self._activation = activation
+        with self.name_scope():
+            self.gate = Dense(hidden_units, use_bias=False, flatten=False,
+                              prefix="gate_")
+            self.up = Dense(hidden_units, use_bias=False, flatten=False,
+                            prefix="up_")
+            self.down = Dense(units, use_bias=False, flatten=False,
+                              prefix="down_")
+
+    def hybrid_forward(self, F, x):
+        return self.down(F.Activation(self.gate(x),
+                                      act_type=self._activation) * self.up(x))
 
 
 class Embedding(HybridBlock):

@@ -629,3 +629,17 @@ def switch_moe(data, router, w1, b1, w2, b2, capacity_factor=1.25):
     out, aux = switch_ffn(toks, router, w1, b1, w2, b2,
                           capacity_factor=capacity_factor)
     return out.reshape(data.shape), aux
+
+
+@register("_contrib_routed_moe", aliases=("routed_moe",))
+def routed_moe(data, router, score_bias, w_gate, w_up, w_down, top_k=1,
+               first_expert=0, scale=1.0, grouped=True):
+    """Top-k routed gated experts without a capacity, over the experts held
+    here (backs gluon.contrib.nn.RoutedMoE; mxtpu.parallel.moe.routed_ffn).
+    data (..., D) is flattened to tokens."""
+    from ..parallel.moe import routed_ffn
+    toks = data.reshape(-1, data.shape[-1])
+    out = routed_ffn(toks, router, score_bias, w_gate, w_up, w_down,
+                     top_k=top_k, first_expert=first_expert, scale=scale,
+                     grouped=grouped)
+    return out.reshape(data.shape)

@@ -317,6 +317,8 @@ def Activation(x, act_type="relu"):
         return jax.nn.softplus(x)
     if act_type == "softsign":
         return x / (1 + jnp.abs(x))
+    if act_type == "silu":
+        return jax.nn.silu(x)
     raise ValueError("unknown act_type " + act_type)
 
 
@@ -475,6 +477,86 @@ def LayerNorm(data, gamma, beta, axis=-1, eps=1e-5, output_mean_var=False):
     if output_mean_var:
         return [out, jnp.squeeze(mean, axis), jnp.squeeze(var, axis)]
     return out
+
+
+@register("RMSNorm", aliases=("rms_norm",))
+def RMSNorm(data, gamma, axis=-1, eps=1e-6):
+    """x / sqrt(mean(x^2) + eps) * gamma (Zhang & Sennrich,
+    arXiv:1910.07467). No reference counterpart. f32 statistics even for
+    bf16 inputs, as :func:`LayerNorm`."""
+    x32 = data.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(x32), axis=axis, keepdims=True) + eps)
+    shape = [1] * data.ndim
+    ax = axis % data.ndim
+    shape[ax] = data.shape[ax]
+    return (x32 * inv).astype(data.dtype) * jnp.reshape(gamma, shape)
+
+
+def rotary(x, theta=10000.0, interleave=False, seq_axis=1):
+    """Rotary position embedding (Su et al., arXiv:2104.09864) over the
+    whole last axis of ``x``, positions 0..T-1 along ``seq_axis``. Pair i
+    turns by ``pos * theta^(-2i/D)``; ``interleave`` pairs the entries
+    (2i, 2i+1), otherwise (i, i + D/2). The partner of each entry comes
+    from a product with a constant signed permutation (exact in any
+    dtype: one non-zero a column), so no array with a minor axis of 2 is
+    ever laid out on the TPU's 128 lanes. Angles and the result's
+    arithmetic are float32; the result has ``x``'s dtype."""
+    d = x.shape[-1]
+    half = d // 2
+    pos = jnp.arange(x.shape[seq_axis], dtype=jnp.float32)
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
+    ang = pos[:, None] * freq[None, :]                       # [T, D/2]
+    i = jnp.arange(half)
+    if interleave:
+        ang = jnp.repeat(ang, 2, axis=-1)
+        lo, hi = 2 * i, 2 * i + 1
+    else:
+        ang = jnp.concatenate([ang, ang], axis=-1)
+        lo, hi = i, i + half
+    # partner[lo] = -x[hi], partner[hi] = x[lo]
+    swap = jnp.zeros((d, d), x.dtype).at[hi, lo].set(-1).at[lo, hi].set(1)
+    partner = jnp.matmul(x, swap, precision=mxu_precision(x, swap))
+    shape = [1] * x.ndim
+    shape[seq_axis % x.ndim], shape[-1] = x.shape[seq_axis], d
+    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    return (x.astype(jnp.float32) * cos
+            + partner.astype(jnp.float32) * sin).astype(x.dtype)
+
+
+register("_contrib_rotary_embedding", aliases=("rotary_embedding",))(rotary)
+
+
+@register("_contrib_latent_attention", aliases=("latent_attention",))
+def latent_attention(q, kv, k_rope, num_heads=1, nope_dim=128, rope_dim=64,
+                     v_dim=128, rope_theta=10000.0, rope_interleave=False,
+                     causal=True):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1)
+    after its projections: ``q`` [B, T, H*(nope+rope)], ``kv`` [B, T,
+    H*(nope+v)] (the up-projected latent: each head's position-free key
+    and its value) and ``k_rope`` [B, T, rope], the ONE rotary key all
+    heads share. Rotary turns q's last ``rope`` entries and ``k_rope``;
+    each head's key is its position-free part with the shared rotary part
+    appended, so keys and queries are ``nope + rope`` wide and values
+    ``v_dim``: both flash kernels take the two widths. Scores are scaled
+    by ``1/sqrt(nope + rope)``. Returns [B, T, H*v_dim]."""
+    from .pallas import flash_attention
+    b, t = q.shape[:2]
+    h = num_heads
+    with jax.named_scope("mla_attention"):
+        q = q.reshape(b, t, h, nope_dim + rope_dim)
+        q = jnp.concatenate(
+            [q[..., :nope_dim],
+             rotary(q[..., nope_dim:], rope_theta, rope_interleave)], -1)
+        kv = kv.reshape(b, t, h, nope_dim + v_dim)
+        k_r = rotary(k_rope, rope_theta, rope_interleave)[:, :, None, :]
+        k = jnp.concatenate(
+            [kv[..., :nope_dim],
+             jnp.broadcast_to(k_r, (b, t, h, rope_dim))], -1)
+        out = flash_attention(q.transpose(0, 2, 1, 3),
+                              k.transpose(0, 2, 1, 3),
+                              kv[..., nope_dim:].transpose(0, 2, 1, 3),
+                              causal)                        # [B, H, T, v]
+        return out.transpose(0, 2, 1, 3).reshape(b, t, h * v_dim)
 
 
 @register("InstanceNorm")
@@ -684,6 +766,14 @@ def _ln_param_shapes(shapes, attrs):
     axis = int(attrs.get("axis", -1)) % len(data)
     c = (data[axis],)
     return {i: c for i in range(1, len(shapes))}
+
+
+@register_param_shapes("RMSNorm")
+def _rms_param_shapes(shapes, attrs):
+    data = shapes[0]
+    if data is None:
+        return {}
+    return {1: (data[int(attrs.get("axis", -1)) % len(data)],)}
 
 
 @register_param_shapes("LeakyReLU")

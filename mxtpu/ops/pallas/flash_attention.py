@@ -27,6 +27,11 @@ Design (TPU-first):
   matrices went through HBM in every layer (PERF.md §6, PR 25).
   ``_fa_backward_blockwise`` stays as the path for a case the kernel
   refuses and as the float32 oracle the tests compare the kernel against.
+* two head widths: keys and queries share one width, values (and the
+  output, and its cotangent) may have another (latent attention: 192 and
+  128). A width under 128 is zero-padded to 128, 192 runs as it is
+  (:func:`_pad_head_dim`); every block and accumulator of both kernels
+  takes the width of the operand it holds.
 * fallback: non-TPU platforms or non-divisible shapes use the XLA softmax
   path with the same signature (its backward is XLA's own). Why each
   fallback happened is counted in the reason-tagged
@@ -221,11 +226,11 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k):
     b, h, t, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]   # values may be narrower than keys
     bh = b * h
     q3 = q.reshape(bh, t, d)
     k3 = jnp.swapaxes(k.reshape(bh, tk, d), 1, 2)  # [bh, d, tk] for the MXU
-    v3 = v.reshape(bh, tk, d)
+    v3 = v.reshape(bh, tk, dv)
     n_q = t // block_q
     n_k = tk // block_k
     from jax.experimental.pallas import tpu as pltpu
@@ -236,32 +241,43 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k):
     if not interpret:  # Mosaic-only hint: the interpreter takes none
         extra["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+    def k_block(i, j):
+        # causal: the steps past a q block's diagonal compute nothing, and
+        # name the last block they needed, so that nothing is fetched
+        # for them either
+        if not causal:
+            return j
+        return jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, d, block_k), lambda b_, i, j: (b_, 0, j)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
+            pl.BlockSpec((1, d, block_k),
+                         lambda b_, i, j: (b_, 0, k_block(i, j))),
+            pl.BlockSpec((1, block_k, dv),
+                         lambda b_, i, j: (b_, k_block(i, j), 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0)),
             pl.BlockSpec((1, block_q, 128), lambda b_, i, j: (b_, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, t, 128), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max m
             pltpu.VMEM((block_q, 128), jnp.float32),  # running sum l
-            pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
+            pltpu.VMEM((block_q, dv), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
         name="flash_attention_fwd",   # the kernel's name in a device trace
         **extra,
     )(q3, k3, v3)
-    return out.reshape(b, h, t, d), lse[:, :, 0].reshape(b, h, t)
+    return out.reshape(b, h, t, dv), lse[:, :, 0].reshape(b, h, t)
 
 
 @jax.named_scope("flash_attention_bwd")   # plain XLA: found by this scope
@@ -343,8 +359,8 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     def _compute():
         q = q_ref[0]                              # [bq, d]
         k = k_ref[0]                              # [bk, d]
-        v = v_ref[0]                              # [bk, d]
-        g = g_ref[0]                              # [bq, d]
+        v = v_ref[0]                              # [bk, dv]
+        g = g_ref[0]                              # [bq, dv]
         # the forward kernel's precision policy: operands in their input
         # dtype (bf16 = one MXU pass), float32 accumulation and statistics
         prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
@@ -381,20 +397,28 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dq_ref[0, rows, :] = (dq_acc[rows, :] * scale).astype(dq_ref.dtype)
 
 
-def _resolve_bwd_blocks(q, k, block_q, block_k):
+def _first_q_block(j, i, block_q, block_k, n_q):
+    """The q block that step (k block ``j``, q block ``i``) of the causal
+    backward names: ``i`` where it computes, else the first q block whose
+    rows reach k block ``j``, held inside the array (a block index past
+    the end halts the chip; the interpreter clamps it and says nothing)."""
+    return jnp.minimum(jnp.maximum(i, (j * block_k) // block_q), n_q - 1)
+
+
+def _resolve_bwd_blocks(q, k, v, block_q, block_k):
     """``((block_q, block_k), None)`` for the backward kernel, or ``(None,
     reason)`` where it refuses. The tile is transposed against the
     forward's: q lies on the 128 lanes (a length off that granule is one
     whole block, which is always tileable) and k on the sublanes. The
     larger side halves until :func:`_bwd_vmem` fits the budget."""
-    t, tk, d = q.shape[2], k.shape[2], q.shape[3]
+    t, tk, d, dv = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
     itm = jnp.dtype(q.dtype).itemsize
     while True:
         bq = _pick_block(t, block_q, 128) or (t if t % 8 == 0 else None)
         bk = _pick_block(tk, block_k, 128)
         if bq is None or bk is None:
             return None, "sequence length has no TPU-tileable block"
-        if _bwd_vmem(bq, bk, t, d, itm) <= _BWD_VMEM_BUDGET:
+        if _bwd_vmem(bq, bk, t, d, dv, itm) <= _BWD_VMEM_BUDGET:
             return (bq, bk), None
         smaller_q = _pick_block(t, bq // 2, 128) if bq > 128 else None
         smaller_k = _pick_block(tk, bk // 2, 128) if bk > 128 else None
@@ -423,9 +447,10 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
     # pads them (zero columns change no score and give zero gradient
     # columns, sliced off below): on the chip, at BERT-base's d = 64, the
     # step is 4.4% shorter padded than with 64-wide blocks (PERF.md §6)
-    q, k, v, g, d_out = _pad_head_dim(q, k, v, g.astype(q.dtype))
+    d_out, dv_out = q.shape[3], v.shape[3]
+    q, k, v, g = _pad_head_dim(q, k, v, g.astype(q.dtype))
     b, h, t, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     bh = b * h
     n_q = t // block_q
     n_k = tk // block_k
@@ -440,36 +465,50 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=max(
                 16 * 2**20,
-                int(1.25 * _bwd_vmem(block_q, block_k, t, d, itm))))
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0))
+                int(1.25 * _bwd_vmem(block_q, block_k, t, d, dv, itm))))
+
+    def q_block(j, i):
+        # causal: the q blocks above a k block's diagonal compute nothing,
+        # and name the first block that does, which is then fetched once
+        # (the last q block where no row sees this k block: tk > t)
+        if not causal:
+            return i
+        return _first_q_block(j, i, block_q, block_k, n_q)
+
+    q_spec = pl.BlockSpec((1, block_q, d),
+                          lambda b_, j, i: (b_, q_block(j, i), 0))
+    g_spec = pl.BlockSpec((1, block_q, dv),
+                          lambda b_, j, i: (b_, q_block(j, i), 0))
     k_spec = pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b_, j, i: (b_, 0, i))
+    v_spec = pl.BlockSpec((1, block_k, dv), lambda b_, j, i: (b_, j, 0))
+    row_spec = pl.BlockSpec((1, 1, block_q),
+                            lambda b_, j, i: (b_, 0, q_block(j, i)))
     dq, dk, dv = pl.pallas_call(
         kernel,
         grid=(bh, n_k, n_q),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, g_spec, row_spec, row_spec],
         out_specs=[
             pl.BlockSpec((1, t, d), lambda b_, j, i: (b_, 0, 0)),
             k_spec,
-            k_spec,
+            v_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
             jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, tk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((t, d), f32),          # dq of the head
             pltpu.VMEM((block_k, d), f32),    # dk of the k block
-            pltpu.VMEM((block_k, d), f32),    # dv of the k block
+            pltpu.VMEM((block_k, dv), f32),   # dv of the k block
         ],
         interpret=interpret,
         name="flash_attention_bwd",   # the kernel's name in a device trace
         **extra,
-    )(q.reshape(bh, t, d), k.reshape(bh, tk, d), v.reshape(bh, tk, d),
-      g.reshape(bh, t, d), lse.reshape(bh, 1, t), delta.reshape(bh, 1, t))
+    )(q.reshape(bh, t, d), k.reshape(bh, tk, d), v.reshape(bh, tk, dv),
+      g.reshape(bh, t, dv), lse.reshape(bh, 1, t), delta.reshape(bh, 1, t))
     return (dq.reshape(q.shape)[..., :d_out], dk.reshape(k.shape)[..., :d_out],
-            dv.reshape(v.shape)[..., :d_out])
+            dv.reshape(v.shape)[..., :dv_out])
 
 
 def _pick_block(n, want, mult):
@@ -565,16 +604,23 @@ def _resolve_blocks(q, k, block_q, block_k):
 
 
 def _pad_head_dim(*xs):
-    """Zero-pad [B, H, T, D] operands to the 128-lane granule; returns
-    them with the original D. Zero key/query columns contribute nothing to
-    scores and zero value columns are sliced off the output, so attention
-    is exact under this padding."""
-    d = xs[0].shape[-1]
-    d_pad = -(-d // 128) * 128
-    if d_pad == d:
-        return (*xs, d)
-    pad = [(0, 0)] * 3 + [(0, d_pad - d)]
-    return (*(jnp.pad(x, pad) for x in xs), d)
+    """Zero-pad [B, H, T, D] operands narrower than the 128 lanes to 128,
+    each by its own D (queries and keys share one width, values and the
+    output's cotangent another: latent attention has 192 and 128). Zero
+    key/query columns contribute nothing to scores and zero value columns
+    are sliced off the output, so attention is exact under this padding.
+    A width above 128 that fills whole half-tiles (a multiple of 64: 192)
+    stays as it is: Mosaic takes such blocks, the MXU passes are the same
+    128-wide ones, and on the chip both kernels at 192 / 128 were 3-5%
+    faster unpadded, the whole step 2.3% (PERF.md §6, PR 26); at 64,
+    padded was the faster (PR 25)."""
+    def pad(x):
+        d = x.shape[-1]
+        d_pad = d if d > 128 and d % 64 == 0 else -(-d // 128) * 128
+        if d_pad == d:
+            return x
+        return jnp.pad(x, [(0, 0)] * 3 + [(0, d_pad - d)])
+    return tuple(pad(x) for x in xs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -593,10 +639,10 @@ def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
     if blocks is None:
         out = _xla_attention(q, k, v, causal, scale)
         return out, (q, k, v, out, None)
-    qp, kp, vp, d = _pad_head_dim(q, k, v)
-    out, lse = _fa_forward_pallas(qp, kp, vp, causal, scale, *blocks)
-    if qp is not q:
-        out = out[..., :d]
+    out, lse = _fa_forward_pallas(*_pad_head_dim(q, k, v), causal, scale,
+                                  *blocks)
+    if out.shape[-1] != v.shape[-1]:
+        out = out[..., :v.shape[-1]]
     return out, (q, k, v, out, lse)
 
 
@@ -606,7 +652,7 @@ def _fa_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     saved): the fused kernel, or the blockwise XLA path for a case the
     kernel refuses, counted with its reason like the forward's fallbacks."""
     from ... import telemetry
-    blocks, refused = _resolve_bwd_blocks(q, k, block_q, block_k)
+    blocks, refused = _resolve_bwd_blocks(q, k, v, block_q, block_k)
     if blocks is None:
         telemetry.inc("pallas_flash.bwd_xla")
         telemetry.inc("pallas_flash.bwd_fallback", tag=refused)
@@ -655,10 +701,10 @@ def _fa_lse_fwd_impl(q, k, v, causal, scale, block_q, block_k):
     if blocks is None:
         out, lse = _xla_attention_lse(q, k, v, causal, scale)
         return out, lse, (q, k, v, out, None)
-    qp, kp, vp, d = _pad_head_dim(q, k, v)
-    out, lse = _fa_forward_pallas(qp, kp, vp, causal, scale, *blocks)
-    if qp is not q:
-        out = out[..., :d]
+    out, lse = _fa_forward_pallas(*_pad_head_dim(q, k, v), causal, scale,
+                                  *blocks)
+    if out.shape[-1] != v.shape[-1]:
+        out = out[..., :v.shape[-1]]
     return out, lse, (q, k, v, out, lse)
 
 
@@ -725,15 +771,16 @@ def _tune_vmem(bq, bk, d, itm):
 _BWD_VMEM_BUDGET = 64 * 1024 * 1024
 
 
-def _bwd_vmem(bq, bk, t, d, itm):
+def _bwd_vmem(bq, bk, t, d, dv, itm):
     """The backward kernel's :func:`_tune_vmem`: bytes one grid step
-    holds, with dq of the whole head resident (``t`` rows)."""
-    dp = -(-d // 128) * 128
-    return (2 * 2 * (bq + bk) * dp * itm     # q, g and k, v blocks (dbuf)
-            + 2 * 2 * 8 * bq * 4             # lse, delta rows (dbuf)
-            + bq * bk * (4 * 4 + 2 * itm)    # s^T, P^T, dP^T, ds^T + casts
-            + 2 * bk * dp * (4 + 2 * itm)    # dk, dv: scratch + out (dbuf)
-            + t * dp * (4 + 2 * itm))        # dq of the head: scratch + out
+    holds, with dq of the whole head resident (``t`` rows). ``d`` is the
+    width of queries and keys, ``dv`` of values and the cotangent."""
+    dp, dvp = -(-d // 128) * 128, -(-dv // 128) * 128
+    return (2 * (bq + bk) * (dp + dvp) * itm     # q, g, k, v blocks (dbuf)
+            + 2 * 2 * 8 * bq * 4                 # lse, delta rows (dbuf)
+            + bq * bk * (4 * 4 + 2 * itm)        # s^T, P^T, dP^T, ds^T + casts
+            + bk * (dp + dvp) * (4 + 2 * itm)    # dk, dv: scratch + out (dbuf)
+            + t * dp * (4 + 2 * itm))            # dq of the head: scratch + out
 
 
 def _tune_feasible(plan, sc):

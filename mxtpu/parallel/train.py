@@ -29,6 +29,7 @@ lives (inside this one jit vs the eager autograd tape).
 from __future__ import annotations
 
 import re
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -310,9 +311,15 @@ class ShardedTrainStep:
             "block": type(self._block).__name__, "n_inputs": n_inputs,
             "donate": bool(self._donate),
             "policy_key": list(policy_key())}
-        params, trainable = self._params, self._trainable
-        block, loss_blk, forward = self._block, self._loss, self._forward
+        trainable = self._trainable
+        loss_blk, forward = self._loss, self._forward
         rule, static = self._rule, self._static
+        # the jitted step outlives this instance (the compile service's
+        # store and the executable ledger keep it), so it reaches the block
+        # and its parameters through a weak reference: held strongly, their
+        # arrays and gradient buffers would never be freed. It is traced
+        # only by this instance's own calls, so the reference is live then
+        me = weakref.ref(self)
         thyper = rule.thyper
         t_idx = [i for i, t in enumerate(trainable) if t]
 
@@ -325,6 +332,7 @@ class ShardedTrainStep:
         # ``optimizer``. Names only: no operation changes
         def sharded_train_step(param_datas, opt_states, hyper, rng, in_datas):
             lr, t = hyper  # traced scalars: lr schedule / step count don't recompile
+            params, block = me()._params, me()._block
             frozen = list(param_datas)
 
             def loss_of(train_datas):
@@ -401,7 +409,8 @@ class ShardedTrainStep:
         key = csvc.canonical_key(
             site="parallel.train_step",
             fn_id="train_step:%s:%s:%s:%s" % (
-                type(block).__name__, csvc.source_token(type(block)),
+                type(self._block).__name__,
+                csvc.source_token(type(self._block)),
                 csvc.source_token(loss_blk) if loss_blk is not None
                 else "-",
                 csvc.source_token(forward) if forward is not None

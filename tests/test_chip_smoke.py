@@ -44,8 +44,9 @@ def test_rehearsal_one_chip_phases(tmp_path):
     assert proc.returncode == 0, proc.stderr[-3000:]
     phases = _phases(proc.stdout)
     assert list(phases) == ["device", "sync", "train_resnet50",
-                            "train_bert_base", "gluon_trainer", "serve",
-                            "warm_start", "total"]
+                            "train_bert_base", "flash_two_widths",
+                            "gluon_trainer", "serve", "warm_start", "total"]
+    assert max(phases["flash_two_widths"]["gaps"].values()) <= 2e-2
     for rec in phases.values():
         assert rec["seconds"] >= 0 and rec["compile_seconds"] >= 0
     assert phases["device"]["platform"] == "cpu"

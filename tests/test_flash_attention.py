@@ -239,8 +239,8 @@ def test_head_dim_64_pads_instead_of_falling_back(monkeypatch):
     k = jnp.asarray(rng.randn(1, 2, 512, 64), jnp.float32)
     v = jnp.asarray(rng.randn(1, 2, 512, 64), jnp.float32)
     scale = 64 ** -0.5
-    qp, kp, vp, d = fa._pad_head_dim(q, k, v)
-    assert d == 64 and qp.shape[-1] == 128
+    qp, kp, vp = fa._pad_head_dim(q, k, v)
+    assert qp.shape[-1] == 128
     base = fa._xla_attention(q, k, v, False, scale)
     padded = fa._xla_attention(qp, kp, vp, False, scale)[..., :64]
     np.testing.assert_allclose(np.asarray(base), np.asarray(padded),
@@ -256,8 +256,8 @@ def test_pad_head_dim_noop_on_granule():
     import importlib
     fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
     q = jnp.zeros((1, 1, 8, 128), jnp.float32)
-    qp, kp, vp, d = fa._pad_head_dim(q, q, q)
-    assert qp is q and kp is q and vp is q and d == 128
+    qp, kp, vp = fa._pad_head_dim(q, q, q)
+    assert qp is q and kp is q and vp is q
 
 
 # ------------------------------------------- the fused backward kernel
@@ -303,7 +303,7 @@ def test_pallas_backward_matches_oracles(monkeypatch, causal, t, tk, d,
              if with_g_lse else None)
     scale = d ** -0.5
     out, lse = fa._xla_attention_lse(q, k, v, causal, scale)
-    blocks, refused = fa._resolve_bwd_blocks(q, k, want, want)
+    blocks, refused = fa._resolve_bwd_blocks(q, k, v, want, want)
     assert refused is None
     assert blocks == ((t, tk) if want == 512 else (128, 128))
     got = fa._fa_backward_pallas(q, k, v, out, lse, g, causal, scale,
@@ -385,24 +385,25 @@ def test_backward_blocks_follow_the_shapes():
     spec = lambda t, d=64, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
         (1, 1, t, d), dt)
     # the BERT cell: one block a head
-    assert fa._resolve_bwd_blocks(spec(512), spec(512), 512, 512) == (
-        (512, 512), None)
+    def resolve(q, k, block_q, block_k):
+        return fa._resolve_bwd_blocks(q, k, k, block_q, block_k)
+    assert resolve(spec(512), spec(512), 512, 512) == ((512, 512), None)
     # 768 = 2 x 384; a q length off the 128 lanes is one whole block
-    assert fa._resolve_bwd_blocks(spec(768), spec(768), 512, 512)[0] == (
+    assert resolve(spec(768), spec(768), 512, 512)[0] == (
         384, 384)
-    assert fa._resolve_bwd_blocks(spec(200), spec(1024), 512, 512)[0] == (
+    assert resolve(spec(200), spec(1024), 512, 512)[0] == (
         200, 512)
     # a long head: the tile shrinks until dq's residency fits
-    (bq, bk), _ = fa._resolve_bwd_blocks(spec(32768, 128), spec(32768, 128),
+    (bq, bk), _ = resolve(spec(32768, 128), spec(32768, 128),
                                          2048, 2048)
     assert (bq, bk) == (1024, 1024)
-    assert fa._bwd_vmem(bq, bk, 32768, 128, 2) <= fa._BWD_VMEM_BUDGET
-    assert fa._resolve_bwd_blocks(spec(2 ** 20, 128), spec(2 ** 20, 128),
+    assert fa._bwd_vmem(bq, bk, 32768, 128, 128, 2) <= fa._BWD_VMEM_BUDGET
+    assert resolve(spec(2 ** 20, 128), spec(2 ** 20, 128),
                                   512, 512) == (
         None, "dq of one head does not fit the VMEM budget")
     # a long q off the lane granule has no smaller block to fall to
-    assert fa._resolve_bwd_blocks(spec(100000, 128), spec(1024, 128),
+    assert resolve(spec(100000, 128), spec(1024, 128),
                                   512, 512)[0] is None
-    assert fa._resolve_bwd_blocks(spec(100, 128), spec(1024, 128),
+    assert resolve(spec(100, 128), spec(1024, 128),
                                   512, 512) == (
         None, "sequence length has no TPU-tileable block")

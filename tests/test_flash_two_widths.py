@@ -1,0 +1,122 @@
+"""Both flash kernels with keys and queries of one width and values of
+another (latent attention: 192 = 128 + 64 rotary against 128), causal, over
+several blocks, through the Pallas interpreter against the two oracles of
+``tests/test_flash_attention.py``: the blockwise float32 backward on the
+same residuals, and ``jax.vjp`` of the plain XLA attention."""
+import importlib
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+
+_CASES = [
+    pytest.param(d, dv, t, dtype, causal,
+                 id="qk%d-v%d-t%d-%s-%s" % (d, dv, t, dtype,
+                                            "causal" if causal else "full"))
+    for d, dv in ((192, 128), (64, 128), (192, 64))
+    for t in (256, 384)
+    for dtype in ("float32", "bfloat16")
+    for causal in (True, False)
+]
+
+
+def _gap(got, ref):
+    got, ref = (np.asarray(x, np.float32) for x in (got, ref))
+    return float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+
+
+def _operands(d, dv, t, dtype, seed=11):
+    rng = np.random.RandomState(seed)
+    dt = jnp.dtype(dtype)
+    return (jnp.asarray(rng.randn(1, 2, t, d), dt),
+            jnp.asarray(rng.randn(1, 2, t, d), dt),
+            jnp.asarray(rng.randn(1, 2, t, dv), dt),
+            jnp.asarray(rng.randn(1, 2, t, dv), dt))
+
+
+@pytest.mark.parametrize("d,dv,t,dtype,causal", _CASES)
+def test_forward_two_widths(monkeypatch, d, dv, t, dtype, causal):
+    """Blocks of 128: two or three a side, so the online softmax carries
+    its state over blocks and the causal skip is taken."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    q, k, v, _ = _operands(d, dv, t, dtype)
+    fa.reset_dispatch_stats()
+    out, lse = fa.flash_attention_with_lse(q, k, v, causal, None, 128, 128)
+    assert fa.DISPATCH_STATS["pallas"] == 1 and fa.DISPATCH_STATS["xla"] == 0
+    want, want_lse = fa._xla_attention_lse(q, k, v, causal, d ** -0.5)
+    assert out.shape == (1, 2, t, dv) and out.dtype == q.dtype
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    assert _gap(out, want) <= tol
+    assert _gap(lse, want_lse) <= tol
+
+
+@pytest.mark.parametrize("d,dv,t,dtype,causal", _CASES)
+def test_backward_two_widths(monkeypatch, d, dv, t, dtype, causal):
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    q, k, v, g = _operands(d, dv, t, dtype)
+    scale = d ** -0.5
+    out, lse = fa._xla_attention_lse(q, k, v, causal, scale)
+    blocks, refused = fa._resolve_bwd_blocks(q, k, v, 128, 128)
+    assert refused is None and blocks == (128, 128)
+    got = fa._fa_backward_pallas(q, k, v, out, lse, g, causal, scale, *blocks)
+    oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, causal, scale,
+                                       128)
+    _, vjp = jax.vjp(lambda q_, k_, v_: fa._xla_attention(
+        q_, k_, v_, causal, scale), q, k, v)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for name, a, b, c, like in zip(("dq", "dk", "dv"), got, oracle, vjp(g),
+                                   (q, k, v)):
+        assert a.shape == like.shape and a.dtype == like.dtype, name
+        assert _gap(a, b) <= tol, (name, "blockwise", _gap(a, b))
+        assert _gap(a, c) <= tol, (name, "plain vjp", _gap(a, c))
+
+
+@pytest.mark.parametrize("d,want", [(64, 128), (96, 128), (128, 128),
+                                    (160, 256), (192, 192), (256, 256),
+                                    (320, 320)])
+def test_which_widths_are_padded(d, want):
+    """Under 128 lanes: to 128. Above: only what does not fill whole
+    half-tiles of 64."""
+    x = jnp.zeros((1, 1, 8, d), jnp.bfloat16)
+    (padded,) = fa._pad_head_dim(x)
+    assert padded.shape[-1] == want
+    assert (padded is x) == (want == d)
+
+
+@pytest.mark.parametrize("n_q,n_k,bq,bk", [(2, 3, 128, 128), (16, 16, 512, 512),
+                                          (3, 2, 128, 128), (2, 8, 256, 128),
+                                          (1, 4, 200, 128)])
+def test_causal_backward_names_only_blocks_that_exist(n_q, n_k, bq, bk):
+    """With more keys than queries some k blocks are seen by no row: the
+    q block they name must still lie inside the array (the interpreter
+    would clamp a block index past the end; the chip halts on it). A step
+    that computes names its own block."""
+    for j in range(n_k):
+        for i in range(n_q):
+            got = int(fa._first_q_block(j, i, bq, bk, n_q))
+            assert 0 <= got < n_q
+            if j * bk <= i * bq + bq - 1:          # the kernel's ``run``
+                assert got == i
+
+
+def test_grad_two_widths_runs_both_kernels(monkeypatch):
+    """``jax.grad`` through the public function at 192 / 128: the forward
+    and the backward both run as kernels, and no fallback is counted."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    q, k, v, _ = _operands(192, 128, 256, "float32")
+    fa.reset_dispatch_stats()
+    got = jax.grad(lambda *a: jnp.sum(
+        fa.flash_attention(*a, True, None, 128, 128) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    stats = dict(fa.DISPATCH_STATS.items())
+    assert (stats["pallas"], stats["bwd_pallas"]) == (1, 1)
+    assert stats["xla"] == 0 and stats["bwd_xla"] == 0
+    ref = jax.grad(lambda *a: jnp.sum(
+        fa._xla_attention(*a, True, 192 ** -0.5) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, ref):
+        assert _gap(a, b) <= 1e-5

@@ -1,0 +1,359 @@
+"""``LatentMoELM`` (latent attention, routed experts, RMSNorm, rotary, a
+gated MLP) and ``parallel.moe.routed_ffn`` against the plain reference the
+benchmark keeps (``benchmark/reference/kanana2_30b_a3b.py``), at the
+configuration's rehearsal sizes, in float32 on seeded weights."""
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxtpu as mx
+from mxtpu import gluon
+from mxtpu.parallel import ShardedTrainStep
+from mxtpu.parallel import moe
+
+from benchmark.models import kanana2_30b_a3b as model
+from benchmark.reference import common as ref_common
+from benchmark.reference import kanana2_30b_a3b as ref
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "benchmark", "configs",
+                       "kanana2_30b_a3b.json")) as f:
+    CFG = json.load(f)
+CFG.update(CFG["rehearsal"], dtype="float32")
+SPECS = ref.param_specs(CFG)
+TRAINABLE = [s[0] for s in SPECS if s[3]]
+ADAM = {"name": "adam", "learning_rate": 1e-3}
+
+
+def _gap(got, want):
+    got, want = (np.asarray(x, np.float64) for x in (got, want))
+    return float(np.linalg.norm(got - want) / max(np.linalg.norm(want),
+                                                  1e-30))
+
+
+@pytest.fixture(scope="module")
+def case():
+    """The model with the reference's seeded leaves, two sequences, and the
+    reference's logits, loss and gradients on them."""
+    leaves = ref_common.init_params(SPECS, 5)
+    x, y = ref.sample_inputs(CFG, jax.random.PRNGKey(9), 2)
+    net = model.build(CFG, SPECS, leaves)
+    model._FIRST.clear()
+    t_idx = [i for i, s in enumerate(SPECS) if s[3]]
+    loss_fn = ref.forward_loss(CFG)
+
+    def of(train):
+        full = list(leaves)
+        for i, w in zip(t_idx, train):
+            full[i] = w
+        return loss_fn(full, x, y, "float32")[0]
+
+    loss, grads = jax.value_and_grad(of)([leaves[i] for i in t_idx])
+    return {"net": net, "leaves": leaves, "x": x, "y": y,
+            "logits": ref.forward(CFG, leaves, x)[0], "loss": float(loss),
+            "grads": dict(zip(TRAINABLE, grads))}
+
+
+@pytest.fixture(scope="module")
+def program_grads(case):
+    """The program's loss and gradients by its eager autograd."""
+    from mxtpu import autograd
+    net = case["net"]
+    loss_blk = gluon.loss.SoftmaxCrossEntropyLoss()
+    x, y = mx.nd.NDArray(case["x"]), mx.nd.NDArray(case["y"])
+    with autograd.record():
+        loss = loss_blk(net(x).reshape((-1, CFG["vocab_size"])),
+                        y.reshape((-1,))).mean()
+    loss.backward()
+    params = [p for p in net.collect_params().values()
+              if p.grad_req != "null"]
+    return float(loss.asnumpy()), {
+        n: p.grad().asnumpy() for n, p in zip(TRAINABLE, params)}
+
+
+def test_leaves_are_the_references(case):
+    params = list(case["net"].collect_params().values())
+    assert [tuple(p.shape) for p in params] == [tuple(s[1]) for s in SPECS]
+    assert [p.grad_req != "null" for p in params] == [s[3] for s in SPECS]
+
+
+def test_logits_match_the_reference(case):
+    got = case["net"](mx.nd.NDArray(case["x"])).asnumpy()
+    assert got.shape == (2, CFG["seq_len"], CFG["vocab_size"])
+    assert _gap(got, case["logits"]) <= 1e-5
+
+
+def test_loss_matches_the_reference(case, program_grads):
+    assert abs(program_grads[0] - case["loss"]) <= 1e-5 * case["loss"]
+
+
+@pytest.mark.parametrize("leaf", TRAINABLE)
+def test_gradient_matches_the_reference(case, program_grads, leaf):
+    assert _gap(program_grads[1][leaf], case["grads"][leaf]) <= 2e-4
+
+
+def test_three_adam_steps_match_the_reference(case):
+    """``ShardedTrainStep`` on one device against the reference's own
+    training loop: each step's loss and every leaf after three steps."""
+    leaves = ref_common.init_params(SPECS, 6)
+    net = model.build(CFG, SPECS, leaves)
+    model._FIRST.clear()
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("data",))
+    loss_blk = gluon.loss.SoftmaxCrossEntropyLoss()
+
+    def forward(block, tokens, labels):
+        return loss_blk(block(tokens).reshape((-1, CFG["vocab_size"])),
+                        labels.reshape((-1,)))
+
+    step = ShardedTrainStep(net, None, mesh, optimizer="adam",
+                            optimizer_params={"learning_rate": 1e-3},
+                            forward=forward)
+    batches = [ref.sample_inputs(CFG, jax.random.PRNGKey(k), 2)
+               for k in (1, 2, 3)]
+    start = [np.asarray(w) for w in leaves]
+    losses = [float(step(mx.nd.NDArray(x), mx.nd.NDArray(y)).asnumpy())
+              for x, y in batches]
+    want = ref_common.train_reference(ref.forward_loss(CFG), SPECS, ADAM, 6,
+                                      batches, "float32")
+    np.testing.assert_allclose(losses, want["losses"], rtol=2e-5)
+    got = ref_common.delta_norms(
+        [p.data()._data for p in net.collect_params().values()], start)
+    gaps = ref_common.leaf_gaps(np.asarray(got), want["delta_norms"])
+    assert float(np.max(gaps)) <= 2e-3, gaps
+    # the selection bias is held fixed
+    frozen = [i for i, s in enumerate(SPECS) if not s[3]]
+    assert frozen and all(np.asarray(got)[i] == 0.0 for i in frozen)
+
+
+# ------------------------------------------------------- the expert layer
+E, K, D, F_ = 16, 3, 32, 12          # experts, choices a token, widths
+
+
+def _layer(seed, t=40, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 9)
+    n = jax.random.normal
+    x = n(ks[0], (t, D), jnp.float32).astype(dtype)
+    leaves = [0.3 * n(ks[1], (E, D)),                          # router
+              jax.random.uniform(ks[2], (E,), jnp.float32, -0.01, 0.01),
+              0.2 * n(ks[3], (E, D, F_)), 0.2 * n(ks[4], (E, D, F_)),
+              0.2 * n(ks[5], (E, F_, D)),
+              0.2 * n(ks[6], (2 * F_, D)), 0.2 * n(ks[7], (2 * F_, D)),
+              0.2 * n(ks[8], (D, 2 * F_))]
+    return x, leaves
+
+
+def _cfg(held=E, first=0):
+    return {"num_experts_per_tok": K, "routed_scaling_factor": 2.448,
+            "n_routed_experts_held": held, "first_expert_held": first,
+            "num_attention_heads": 1, "qk_nope_head_dim": 1,
+            "qk_rope_head_dim": 2, "v_head_dim": 1, "kv_lora_rank": 1,
+            "rms_norm_eps": 1e-6, "rope_theta": 1.0}
+
+
+def _shared(x, leaves):
+    wg, wu, wd = leaves[5:]
+    return (jax.nn.silu(x @ wg.T) * (x @ wu.T)) @ wd.T
+
+
+def _routed(x, leaves, first=0, held=E, grouped=True):
+    router, bias, eg, eu, ed = leaves[:5]
+    part = slice(first, first + held)
+    return moe.routed_ffn(x, router, bias, eg[part], eu[part], ed[part],
+                          top_k=K, first_expert=first, scale=2.448,
+                          grouped=grouped)
+
+
+@pytest.mark.parametrize("shares", [1, 2, 8, 16])
+def test_shares_add_up_to_the_whole_layer(shares):
+    """model-configs §4: the routed parts that ``shares`` holders of
+    ``E / shares`` experts each give, with the shared expert (which every
+    holder computes alike) counted once, add up to the uncut layer's output
+    and to the reference's over all experts."""
+    x, leaves = _layer(3)
+    held = E // shares
+    parts = sum(_routed(x, leaves, first=i * held, held=held)
+                for i in range(shares))
+    whole = _routed(x, leaves) + _shared(x, leaves)
+    want = ref.expert_layer(_cfg(), x, leaves)
+    assert _gap(parts + _shared(x, leaves), whole) <= 1e-5
+    assert _gap(whole, want) <= 1e-5
+
+
+@pytest.mark.parametrize("first", [0, 4, 12])
+def test_a_share_is_the_references_share(first):
+    x, leaves = _layer(4)
+    got = _routed(x, leaves, first=first, held=4) + _shared(x, leaves)
+    part = slice(first, first + 4)
+    want = ref.expert_layer(
+        _cfg(4, first), x,
+        leaves[:2] + [w[part] for w in leaves[2:5]] + leaves[5:])
+    assert _gap(got, want) <= 1e-5
+
+
+@pytest.mark.parametrize("held,first", [(E, 0), (K, 5), (4, 4)])
+def test_no_token_is_dropped_when_all_choose_the_same(held, first):
+    """A selection bias that makes EVERY token choose experts 5, 6, 7:
+    each of the three then gets all T tokens, T*k rows in all. Nothing is
+    cut to a capacity: the grouped path equals the masked one and the
+    reference, and without the held experts' part the output changes."""
+    x, leaves = _layer(5, t=64)
+    leaves[1] = jnp.zeros(E).at[5:5 + K].set(10.0)
+    idx, _ = moe.route_top_k(x, leaves[0], leaves[1], K)
+    assert set(np.asarray(idx).ravel()) == {5, 6, 7}
+    got = _routed(x, leaves, first=first, held=held)
+    plain = _routed(x, leaves, first=first, held=held, grouped=False)
+    part = slice(first, first + held)
+    want = ref.expert_layer(
+        _cfg(held, first), x,
+        leaves[:2] + [w[part] for w in leaves[2:5]] + leaves[5:]) \
+        - _shared(x, leaves)
+    assert _gap(got, plain) <= 1e-5
+    assert _gap(got, want) <= 1e-5
+    assert float(jnp.min(jnp.linalg.norm(got, axis=-1))) > 1e-3
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_selections_are_the_references(seed, dtype):
+    """With the router's product in float32 the program chooses exactly
+    the reference's experts, with its weights, whatever x's dtype."""
+    x, leaves = _layer(seed, t=256, dtype=jnp.dtype(dtype))
+    idx, w = moe.route_top_k(x, leaves[0], leaves[1], K, 2.448)
+    want_idx, want_w = ref.route(_cfg(), x, leaves[0], leaves[1])
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_allclose(np.asarray(w), np.asarray(want_w), rtol=1e-6)
+    assert w.dtype == jnp.float32
+
+
+def test_routed_gradients_match_the_masked_form():
+    x, leaves = _layer(6)
+
+    def loss(grouped):
+        def f(x, router, eg, eu, ed):
+            out = moe.routed_ffn(x, router, leaves[1], eg, eu, ed, top_k=K,
+                                 first_expert=4, scale=2.448,
+                                 grouped=grouped)
+            return jnp.sum(jnp.sin(out))
+        return jax.grad(f, argnums=(0, 1, 2, 3, 4))(
+            x, leaves[0], *(w[4:12] for w in leaves[2:5]))
+
+    for a, b in zip(loss(True), loss(False)):
+        assert _gap(a, b) <= 1e-5
+
+
+def test_the_layer_counts_what_it_traced():
+    from mxtpu import telemetry
+    for name in ("moe.layers", "moe.experts_held", "moe.experts_total",
+                 "moe.grouped_mm.grouped", "moe.grouped_mm.dense"):
+        telemetry.reset_metric(name)
+    x, leaves = _layer(7)
+    _routed(x, leaves, first=4, held=4)
+    _routed(x, leaves, grouped=False)
+    assert telemetry.value("moe.layers") == 2
+    assert telemetry.value("moe.experts_held") == 4 + E
+    assert telemetry.value("moe.experts_total") == 2 * E
+    assert telemetry.value("moe.grouped_mm.grouped") == 1
+    assert telemetry.value("moe.grouped_mm.dense") == 1
+
+
+def test_a_range_outside_the_router_is_refused():
+    x, leaves = _layer(8)
+    router, bias, eg, eu, ed = leaves[:5]
+    with pytest.raises(mx.MXNetError):
+        moe.routed_ffn(x, router, bias, eg[:4], eu[:4], ed[:4], top_k=K,
+                       first_expert=13)
+
+
+# ------------------------------------------------------ the smaller parts
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_rms_norm(dtype):
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(3, 5, 16), dtype)
+    g = jnp.asarray(rng.rand(16) + 0.5, dtype)
+    got = mx.nd.RMSNorm(mx.nd.NDArray(x), mx.nd.NDArray(g)).asnumpy()
+    x32 = np.asarray(x, np.float32)
+    want = x32 / np.sqrt((x32 ** 2).mean(-1, keepdims=True) + 1e-6) \
+        * np.asarray(g, np.float32)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=1e-5 if dtype == "float32" else 2e-2)
+
+
+@pytest.mark.parametrize("interleave", [True, False])
+def test_rotary_turns_pairs_and_keeps_scores(interleave):
+    """Against the definition, pair by pair; and q . k depends on the
+    distance of the positions only."""
+    rng = np.random.RandomState(1)
+    x = rng.randn(2, 6, 3, 8).astype(np.float32)
+
+    def rotary(x, theta, interleave):
+        return mx.nd.rotary_embedding(mx.nd.NDArray(x), theta=theta,
+                                      interleave=interleave).asnumpy()
+
+    got = np.asarray(rotary(jnp.asarray(x), 100.0, interleave))
+    for i in range(4):
+        a, b = (2 * i, 2 * i + 1) if interleave else (i, i + 4)
+        ang = np.arange(6)[None, :, None] * 100.0 ** (-2.0 * i / 8)
+        np.testing.assert_allclose(
+            got[..., a], x[..., a] * np.cos(ang) - x[..., b] * np.sin(ang),
+            rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(
+            got[..., b], x[..., b] * np.cos(ang) + x[..., a] * np.sin(ang),
+            rtol=1e-5, atol=1e-6)
+    same = np.broadcast_to(x[:, :1], x.shape)     # one vector at every place
+    r = np.asarray(rotary(jnp.asarray(same), 100.0, interleave))
+    np.testing.assert_allclose((r[:, 1] * r[:, 3]).sum(-1),
+                               (r[:, 2] * r[:, 4]).sum(-1), rtol=1e-4)
+
+
+def test_routed_moe_op_restores_the_input_shape():
+    """The registered op flattens (..., D) to tokens and back."""
+    x, leaves = _layer(9, t=24)
+    got = mx.nd.routed_moe(mx.nd.NDArray(x.reshape(2, 12, D)),
+                           *(mx.nd.NDArray(w) for w in leaves[:5]),
+                           top_k=K, scale=2.448).asnumpy()
+    assert got.shape == (2, 12, D)
+    assert _gap(got.reshape(24, D), _routed(x, leaves)) <= 1e-6
+
+
+@pytest.mark.parametrize("interleave", [True, False])
+def test_latent_attention_op_is_attention_over_joined_keys(interleave):
+    """``latent_attention`` against plain causal softmax attention over
+    keys put together by hand: each head's own part and the ONE rotary
+    part, turned, shared by all heads."""
+    from mxtpu.ops.nn import rotary
+    h, nope, rope, vd, t = 3, 8, 4, 6, 10
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (2, t, h * (nope + rope)))
+    kv = jax.random.normal(ks[1], (2, t, h * (nope + vd)))
+    k_rope = jax.random.normal(ks[2], (2, t, rope))
+    got = mx.nd.latent_attention(
+        *(mx.nd.NDArray(a) for a in (q, kv, k_rope)), num_heads=h,
+        nope_dim=nope, rope_dim=rope, v_dim=vd, rope_theta=50.0,
+        rope_interleave=interleave).asnumpy()
+    q4, kv4 = q.reshape(2, t, h, -1), kv.reshape(2, t, h, -1)
+    q4 = jnp.concatenate([q4[..., :nope],
+                          rotary(q4[..., nope:], 50.0, interleave)], -1)
+    k_r = jnp.broadcast_to(rotary(k_rope, 50.0, interleave)[:, :, None],
+                           (2, t, h, rope))
+    k4 = jnp.concatenate([kv4[..., :nope], k_r], -1)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q4, k4) / np.sqrt(nope + rope)
+    s = jnp.where(jnp.arange(t)[:, None] >= jnp.arange(t)[None], s, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
+                      kv4[..., nope:]).reshape(2, t, h * vd)
+    assert _gap(got, want) <= 1e-5
+
+
+def test_gated_mlp_is_swiglu():
+    net = gluon.nn.GatedMLP(8, 12)
+    net.initialize()
+    x = mx.nd.array(np.random.RandomState(2).randn(3, 8))
+    got = net(x).asnumpy()
+    wg, wu, wd = (p.data().asnumpy() for p in net.collect_params().values())
+    h = x.asnumpy() @ wg.T
+    want = (h / (1 + np.exp(-h)) * (x.asnumpy() @ wu.T)) @ wd.T
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)

@@ -978,6 +978,11 @@ COVERED_ELSEWHERE = {
     "_scatter_minus_scalar": "test_straggler_ops.py",
     "_scatter_elemwise_div": "test_straggler_ops.py",
     "_sample_unique_zipfian": "test_straggler_ops.py",
+    # latent attention and routed experts: against the plain reference
+    "RMSNorm": "test_latent_moe.py",
+    "_contrib_rotary_embedding": "test_latent_moe.py",
+    "_contrib_latent_attention": "test_latent_moe.py",
+    "_contrib_routed_moe": "test_latent_moe.py",
     "CTCLoss": "test_ctc.py",
     "Custom": "test_custom_op.py",
     "RNN": "test_operator.py",

@@ -165,3 +165,46 @@ def test_interpret_flag_is_an_error_on_tpu(as_tpu, monkeypatch):
     monkeypatch.setenv("MXTPU_PALLAS_CONV_INTERPRET", "1")
     with pytest.raises(MXNetError, match="MXTPU_PALLAS_CONV_INTERPRET"):
         pc._interpret()
+
+
+# latent attention at the kanana2_30b_a3b cell's shape: keys and queries
+# 192 wide (blocks of 192 lanes, unpadded), values 128, one sequence of
+# 8,192 over 16 x 16 causal blocks
+def test_flash_two_widths_compile_to_mosaic(one_chip, as_tpu):
+    q = _spec((1, 32, 8192, 192), one_chip)
+    v = _spec((1, 32, 8192, 128), one_chip)
+    fa.reset_dispatch_stats()
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, True).astype(
+            jnp.float32) ** 2)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, v)
+    assert text.count("tpu_custom_call") >= 2
+    assert "bf16[32,8192,192]" in text and "bf16[32,8192,128]" in text
+    assert "bf16[32,8192,256]" not in text
+    stats = dict(fa.DISPATCH_STATS.items())
+    assert (stats["pallas"], stats["bwd_pallas"]) == (1, 1)
+    assert stats["xla"] == 0 and stats["bwd_xla"] == 0
+
+
+def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
+    """The expert layer at the cell's widths (16 of 128 experts held, 6
+    choices a token, 8,192 tokens): XLA:TPU takes ``ragged_dot`` as its
+    grouped matmul kernel, forward and both transposes; nothing is expanded
+    into a product over every expert held."""
+    from mxtpu.parallel import moe
+    x = _spec((8192, 2048), one_chip)
+    specs = (x, _spec((128, 2048), one_chip), _spec((128,), one_chip),
+             _spec((16, 2048, 768), one_chip),
+             _spec((16, 2048, 768), one_chip),
+             _spec((16, 768, 2048), one_chip))
+
+    def loss(x, router, bias, eg, eu, ed):
+        return jnp.sum(moe.routed_ffn(x, router, bias, eg, eu, ed, top_k=6,
+                                      scale=2.448).astype(jnp.float32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 3, 4, 5)), *specs)
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    # no [T*k, held, .] or [held, T*k, .] expansion of the products
+    assert "49152,16,768" not in text and "16,49152,768" not in text
