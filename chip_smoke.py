@@ -5,8 +5,10 @@ one TPU, through the entry points a user calls, at the published widths of
 the two models ``bench.py`` is built around (ResNet-50 v1 at 224x224x3 /
 1000 classes / batch 128, BERT-base at 12 layers / 768 wide / 12 heads /
 seq 512 / batch 16 / vocab 30,522; bf16, weights random from ``--seed``),
-and both flash kernels once more at latent attention's shape (32 heads of
-8,192 positions, keys and queries 192 wide, values 128, causal).
+both flash kernels once more at latent attention's shape (32 heads of
+8,192 positions, keys and queries 192 wide, values 128, causal), and the
+routed expert layer at one chip's share of kanana-2-30b-a3b's (8,192 tokens
+of 2,048, top-6 of 128 experts of width 768, 16 held).
 
     python chip_smoke.py            # one chip, every phase below
     python chip_smoke.py --chips 4  # ONLY the cross-chip paths (one host)
@@ -49,6 +51,9 @@ FULL = dict(
     bert=dict(batch=16, seq=512, vocab=30522, dim=768, heads=12, layers=12),
     # latent attention's shape: keys and queries 192 wide, values 128
     latent=dict(heads=32, seq=8192, qk=192, v=128, check_heads=2),
+    # one chip's share of a routed expert layer: 16 of 128 experts, top-6
+    routed=dict(tokens=8192, dim=2048, width=768, held=16, total=128,
+                top_k=6),
     train_steps=5, gluon_steps=3,
     # 1-device vs 4-device losses, same batch and seed: reduce-order
     # tolerance for bf16 parameters (the 4-way psum sums partial
@@ -63,6 +68,7 @@ TINY = dict(
     serve_requests=(1, 3, 4, 2),
     bert=dict(batch=4, seq=128, vocab=512, dim=64, heads=2, layers=2),
     latent=dict(heads=2, seq=256, qk=24, v=16, check_heads=2),
+    routed=dict(tokens=512, dim=64, width=32, held=2, total=16, top_k=3),
     train_steps=5, gluon_steps=3,
     # the toy memorizes its 8 images in three steps (loss 3.4 -> 0.02),
     # which amplifies one bf16 ULP of reduce order into tens of percent:
@@ -298,6 +304,71 @@ def phase_flash_two_widths(sizes, seed, on_tpu):
     _check(max(gaps.values()) <= 2e-2,
            "two-width flash attention is %s from the float32 oracle" % gaps)
     return {"shape": n, "pallas_flash": stats, "gaps": gaps}
+
+
+def phase_routed_layer(sizes, seed, on_tpu):
+    """The routed expert layer alone at one chip's share of the experts:
+    its output and the gradients of x, the router and the three expert
+    leaves through the grouped path (the rows routed here, at the rung
+    ``moe.piece_plan`` picks) against the masked form, which multiplies
+    every token by every expert held. On the chip the device's memory is
+    filled with NaN and freed first: the grouped kernel leaves the rows
+    past the last group unwritten, and whatever they hold must not reach a
+    result. Prints the plan: rows live / run / laid out at most."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.parallel import moe
+    n = sizes["routed"]
+    if on_tpu:      # NaN over half of what is free, a GiB at a time
+        stats = jax.devices()[0].memory_stats()
+        free = stats["bytes_limit"] - stats["bytes_in_use"] \
+            - stats.get("bytes_reserved", 0)
+        junk = [jnp.full((1 << 28,), jnp.nan, jnp.float32)
+                for _ in range(free // 2 >> 30)]
+        jax.block_until_ready(junk)
+        del junk
+    ks = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 5)
+    leaf = lambda k, *shape: (0.02 * jax.random.normal(
+        k, shape, jnp.float32)).astype(jnp.bfloat16)
+    x = jax.random.normal(ks[0], (n["tokens"], n["dim"]),
+                          jnp.float32).astype(jnp.bfloat16)
+    router = leaf(ks[1], n["total"], n["dim"])
+    bias = jnp.zeros((n["total"],), jnp.bfloat16)
+    experts = (leaf(ks[2], n["held"], n["dim"], n["width"]),
+               leaf(ks[3], n["held"], n["dim"], n["width"]),
+               leaf(ks[4], n["held"], n["width"], n["dim"]))
+
+    def grads(grouped):
+        def loss(x, router, *experts):
+            out = moe.routed_ffn(x, router, bias, *experts,
+                                 top_k=n["top_k"], scale=2.448,
+                                 grouped=grouped)
+            return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+        (_, out), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(x, router, *experts)
+        return (out,) + g
+
+    f32 = lambda a: a.astype(jnp.float32)
+    got, want = grads(True), grads(False)
+    _check(all(bool(jnp.all(jnp.isfinite(f32(a)))) for a in got),
+           "the routed layer's grouped path gave a value that is not finite")
+    gaps = {name: float(jnp.linalg.norm(f32(a) - f32(b))
+                        / jnp.linalg.norm(f32(b)))
+            for name, a, b in zip(("out", "dx", "drouter", "dgate", "dup",
+                                   "ddown"), got, want)}
+    # bf16 both: tier-1 reads 0.01 between the forms; a row dropped, a row
+    # of another token or a wrong weight reads 0.1 or more
+    _check(max(gaps.values()) <= 3e-2,
+           "the routed layer is %s from its masked form" % gaps)
+    idx, _ = moe.route_top_k(x, router, bias, n["top_k"], 2.448)
+    plan = moe.piece_plan(idx, 0, n["held"], n["total"])
+    rows = {"live": int(plan.n_live), "run": int(plan.rows),
+            "total": n["tokens"] * n["top_k"]}
+    _check(rows["live"] <= rows["run"] == min(
+        r for r in plan.rungs if r >= rows["live"]),
+        "the plan runs %s of the rungs %s" % (rows, plan.rungs))
+    return {"shape": n, "rows": rows, "rungs": list(plan.rungs),
+            "gaps": gaps}
 
 
 def _gluon_loop(sizes, seed, mesh=None):
@@ -623,6 +694,7 @@ def run(sizes, chips=1, seed=0, out=sys.stdout):
                   on_tpu)
             phase("flash_two_widths", phase_flash_two_widths, sizes, seed,
                   on_tpu)
+            phase("routed_layer", phase_routed_layer, sizes, seed, on_tpu)
             net = phase("gluon_trainer", phase_gluon_trainer, sizes, seed)
             phase("serve", phase_serve, sizes, seed, net)
             phase("warm_start", phase_warm_start, sizes, seed, net)

@@ -18,11 +18,19 @@ returned alongside the output (scaled by E, per the paper).
 
 :func:`routed_ffn` is the other kind of layer: top-k of many small gated
 experts, no capacity and nothing dropped, told which of the router's
-experts it holds (one chip's share under expert parallelism). Its tokens
-are sorted by expert and go through grouped products over exactly the rows
-each expert was chosen for; on one chip it runs without an exchange.
+experts it holds (one chip's share under expert parallelism). The (token,
+slot) pairs routed here are sorted by expert and go through grouped
+products over exactly the rows each expert was chosen for; on one chip it
+runs without an exchange. What a step pays follows the rows routed HERE,
+not all T*k: the held part exists at a short ladder of static row counts
+and a ``lax.switch`` runs the lowest that holds the step's live rows
+(:func:`piece_plan` is that decision, public and pure); the last rung is
+all T*k rows, so the worst case still runs and no shape is dynamic.
 """
 from __future__ import annotations
+
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +38,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError
 
-__all__ = ["switch_ffn", "routed_ffn", "route_top_k", "shard_experts"]
+__all__ = ["switch_ffn", "routed_ffn", "route_top_k", "piece_plan",
+           "shard_experts"]
 
 
 def switch_ffn(x, router_w, w1, b1, w2, b2, capacity_factor=1.25):
@@ -99,44 +108,74 @@ def route_top_k(x, router_w, score_bias, top_k, scale=1.0):
     return idx, w
 
 
-@jax.custom_vjp
-def _dispatch(x, order, inverse):
-    """Row ``i`` of the result is token ``order[i] // k``: the (token,
-    slot) pairs in sorted order. ``order`` permutes the T*k pairs and
-    ``inverse`` undoes it, so the transpose is a gather too (then a sum
-    over each token's k slots), not a scatter-add with repeated rows."""
-    return x[order // (order.shape[0] // x.shape[0])]
+# rows a grid step of XLA:TPU's grouped matmul kernel takes (its tile
+# table at [T*k, D] has ceil(T*k / 512) + groups - 1 entries)
+_ROW_TILE = 512
 
 
-def _dispatch_fwd(x, order, inverse):
-    return _dispatch(x, order, inverse), (inverse, x.shape[0])
+class PiecePlan(NamedTuple):
+    """What :func:`piece_plan` decides for one batch. ``rungs`` is static
+    (Python ints); the rest are device values."""
+    order: jax.Array     # (T*k,) the (token, slot) pairs, live ones first
+    sizes: jax.Array     # (held,) rows of each expert held
+    n_live: jax.Array    # () pairs routed here: sum(sizes)
+    rung: jax.Array      # () index into ``rungs`` of the row count run
+    rungs: tuple         # the row counts the held part exists at
+
+    @property
+    def rows(self):
+        """() the rows the held part runs over: ``rungs[rung]``."""
+        return jnp.asarray(self.rungs, jnp.int32)[self.rung]
 
 
-def _dispatch_bwd(kept, g):
-    inverse, t = kept
-    return g[inverse].reshape(t, -1, g.shape[-1]).sum(axis=1), None, None
+def _rungs(pairs, held, total):
+    """The static row counts the held part is built at, smallest first:
+    a third above the share of the ``pairs`` (token, slot) rows that
+    ``held`` of ``total`` equally loaded experts draw, in whole row tiles
+    of the grouped kernel, then doubling while a rung still saves half the
+    rows, then all of them. Every rung is compiled (each its own grouped
+    kernels: about 5 s of a cold compile and 7 MB of code a rung and layer
+    at the kanana cell's widths), so the ladder is short. All experts held
+    is the one rung ``pairs``."""
+    rung = -(-pairs * held * 4 // (total * 3 * _ROW_TILE)) * _ROW_TILE
+    rungs = []
+    while rung * 2 <= pairs:    # a rung above half would save under half
+        rungs.append(rung)
+        rung *= 2
+    return tuple(rungs) + (pairs,)
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def _unpermute(y, order, inverse):
-    """``y[inverse]``: sorted rows back into (token, slot) order; its
-    transpose is ``g[order]``."""
-    return y[inverse]
-
-
-_unpermute.defvjp(lambda y, order, inverse: (y[inverse], order),
-                  lambda order, g: (g[order], None, None))
+def piece_plan(idx, first_expert, held, total):
+    """Which rows the held part of :func:`routed_ffn` runs over, from the
+    router's choices ``idx`` (T, k) alone: the pairs that chose one of the
+    ``held`` experts from ``first_expert`` on, sorted by expert (stable, so
+    a group's tokens ascend), ahead of the pairs routed elsewhere; each
+    expert's rows; and the smallest of the static row counts
+    (:func:`_rungs`) that holds every live row. The layer runs exactly
+    ``rungs[rung]`` rows: this function is the whole of that decision."""
+    rungs = _rungs(idx.size, held, total)
+    with jax.named_scope("moe.dispatch"):
+        local = idx.reshape(-1) - first_expert
+        # pairs of experts held elsewhere sort behind the last group
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                        dtype=jnp.int32)
+        n_live = jnp.sum(sizes)
+        rung = jnp.sum(n_live > jnp.asarray(rungs[:-1], jnp.int32),
+                       dtype=jnp.int32)
+    return PiecePlan(order, sizes, n_live, rung, rungs)
 
 
 def _grouped(xs, w, sizes):
     """``xs[rows of group e] @ w[e]`` for every group, rows sorted by
     group: ``jax.lax.ragged_dot``, which XLA:TPU lowers to one grouped
-    matmul kernel that visits only the row tiles the groups cover (rows
-    past the last group cost nothing and hold nothing defined), under the
-    framework's MXU policy (``contract_acc``)."""
+    matmul kernel that visits only the row tiles the groups cover, under
+    the framework's MXU policy (``contract_acc``). Rows past the last group
+    cost nothing and are NOT WRITTEN, in the product and in both
+    transposes: they hold what the memory held (NaN, on a chip that has run
+    anything else), so a caller selects them away (``where``) before any
+    arithmetic and never multiplies them by zero."""
     from ..ops.precision_util import contract_acc
     return contract_acc(
         lambda a, b, **kw: jax.lax.ragged_dot(a, b, sizes, **kw), xs, w)
@@ -151,12 +190,21 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
     Every token is routed over ALL ``router_w.shape[0]`` experts and keeps
     every one of its ``top_k`` choices: there is no capacity and nothing is
     dropped. The (token, slot) pairs that chose an expert held here are
-    sorted by expert (`moe.dispatch`), run through ``w_down[e](silu(x
-    w_gate[e]) * (x w_up[e]))`` as grouped products over exactly the rows
-    of each expert (`moe.experts`), and summed back per token under the
-    router's weights (`moe.combine`). A choice of an expert that is not
-    held adds nothing; its weight still counts in the normalisation, so
-    the shares of all holders add up to the whole layer.
+    sorted by expert (:func:`piece_plan`; ``moe.dispatch`` gathers their
+    tokens), run through ``w_down[e](silu(x w_gate[e]) * (x w_up[e]))`` as
+    grouped products over exactly the rows of each expert
+    (``moe.experts``), and added into their tokens' rows in float32 under
+    the router's weights (``moe.combine``: a scatter-add by token id, no
+    un-permute over every pair). A choice of an expert that is not held
+    adds nothing; its weight still counts in the normalisation, so the
+    shares of all holders add up to the whole layer.
+
+    All of it runs over ``rungs[rung]`` sorted rows, the lowest of the
+    static row counts of :func:`_rungs` that holds this step's live rows:
+    a chip that holds 16 of 128 experts lays out 8,192 rows of the 49,152
+    pairs while its experts draw up to a third over their even share, and
+    the last rung is every pair. The ladder follows from T, k, held, total
+    and the grouped kernel's row tile; nothing sets it from outside.
 
     Parameters
     ----------
@@ -171,6 +219,10 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
         ``moe.grouped_mm.dense``.
 
     Returns (T, D): the held experts' part of the layer's output.
+
+    Counted at trace time (``telemetry``), beside ``moe.layers``:
+    ``moe.rows_total`` (T*k, what the grouped path lays out at most) and
+    ``moe.piece_rows`` (its lowest rung, what it lays out at least).
     """
     from .. import telemetry
     t, d = x.shape
@@ -186,48 +238,98 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
     else:
         telemetry.inc("moe.grouped_mm.dense")
     idx, w = route_top_k(x, router_w, score_bias, top_k, scale)
-    local = idx - first_expert
-    mine = (local >= 0) & (local < held)                     # (T, k)
-
-    def expert(xs, e_gate, e_up, e_down, mm):
-        return mm(jax.nn.silu(mm(xs, e_gate)) * mm(xs, e_up), e_down)
-
     if not grouped:
         from ..ops.precision_util import contract_acc
+        local = idx - first_expert
         out = jnp.zeros((t, d), jnp.float32)
         for e in range(held):
             w_e = jnp.sum(jnp.where(local == e, w, 0.0), axis=-1)
-            y = expert(x, w_gate[e], w_up[e], w_down[e],
-                       lambda a, b: contract_acc(jnp.matmul, a, b))
+            y = _expert(x, w_gate[e], w_up[e], w_down[e],
+                        lambda a, b: contract_acc(jnp.matmul, a, b))
             out = out + w_e[:, None] * y.astype(jnp.float32)
         return out.astype(x.dtype)
 
-    def held_part(x, w, w_gate, w_up, w_down):
-        with jax.named_scope("moe.dispatch"):
-            # pairs of experts held elsewhere sort behind the last group
-            key = jnp.where(mine, local, held).reshape(-1)   # (T*k,)
-            order = jnp.argsort(key, stable=True).astype(jnp.int32)
-            inverse = jnp.zeros_like(order).at[order].set(
-                jnp.arange(order.shape[0], dtype=jnp.int32))
-            sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :],
-                            axis=0, dtype=jnp.int32)
-            live = jnp.arange(order.shape[0]) < jnp.sum(sizes)
-            xs = jnp.where(live[:, None], _dispatch(x, order, inverse), 0)
-        with jax.named_scope("moe.experts"):
-            ys = expert(xs, w_gate, w_up, w_down,
-                        lambda a, b: _grouped(a, b, sizes))
-        with jax.named_scope("moe.combine"):
-            y = jnp.where(mine.reshape(-1, 1),
-                          _unpermute(ys, order, inverse), 0)
-            # one multiply-and-reduce fusion: no float32 copy of y
-            return jnp.sum(w[:, :, None] * y.reshape(t, top_k, d).astype(
-                jnp.float32), axis=1)
-
-    # T*k rows are laid out whatever share of them is routed here (the
-    # worst case is all), so nothing of this part is kept for the backward
-    # pass: it is computed again from x, the weights and the choices
-    out = jax.checkpoint(held_part)(x, w, w_gate, w_up, w_down)
+    plan = piece_plan(idx, first_expert, held, total)
+    telemetry.inc("moe.rows_total", t * top_k)
+    telemetry.inc("moe.piece_rows", plan.rungs[0])
+    out = _held_part(top_k, plan.rungs, x, w, w_gate, w_up, w_down,
+                     plan.order, plan.sizes, plan.rung)
     return out.astype(x.dtype)
+
+
+def _expert(xs, e_gate, e_up, e_down, mm):
+    return mm(jax.nn.silu(mm(xs, e_gate)) * mm(xs, e_up), e_down)
+
+
+@jax.custom_vjp
+def _token_rows(x, tok):
+    """``x[tok]``; its transpose sums each token's rows in float32 and
+    rounds once (a scatter-add in ``x``'s bf16 would round after every
+    one of a token's up to k rows)."""
+    return x[tok]
+
+
+_token_rows.defvjp(
+    lambda x, tok: (x[tok], (tok, x.shape[0])),
+    lambda kept, g: (jnp.zeros((kept[1],) + g.shape[1:], jnp.float32)
+                     .at[kept[0]].add(g.astype(jnp.float32)).astype(g.dtype),
+                     None))
+
+
+def _held_rows(rows, top_k, x, w, w_gate, w_up, w_down, order, sizes):
+    """The held experts' part over the first ``rows`` sorted pairs (every
+    live one is among them): (T, D) float32."""
+    with jax.named_scope("moe.dispatch"):
+        pairs = order[:rows]
+        tok = pairs // top_k
+        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        xs = jnp.where(live, _token_rows(x, tok), 0)
+    with jax.named_scope("moe.experts"):
+        ys = _expert(xs, w_gate, w_up, w_down,
+                     lambda a, b: _grouped(a, b, sizes))
+    with jax.named_scope("moe.combine"):
+        # float32 under the router's weights, summed by token. A row past
+        # the live ones holds nothing defined: it is zeroed BEFORE it meets
+        # its weight, or the weight's gradient would be 0 * whatever it held
+        w_row = w.reshape(-1).at[pairs].get(unique_indices=True)[:, None]
+        y = w_row * jnp.where(live, ys, 0).astype(jnp.float32)
+        return jnp.zeros(x.shape, jnp.float32).at[tok].add(y)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_part(top_k, rungs, x, w, w_gate, w_up, w_down, order, sizes, rung):
+    """:func:`_held_rows` at the row count ``rungs[rung]``: one
+    ``lax.switch`` over the static counts, so a step pays for the rung its
+    live rows fit and the worst case (every pair live) still runs them all.
+
+    The backward pass is a second switch that computes the chosen rung's
+    part again from x, the weights and the plan, then its transpose.
+    Nothing but those inputs is kept: what one rung would keep has another
+    shape in the next, and a residual that crosses the switch would be laid
+    out (and zero-filled) at every rung's size, the largest included."""
+    return jax.lax.switch(
+        rung, [functools.partial(_held_rows, rows, top_k) for rows in rungs],
+        x, w, w_gate, w_up, w_down, order, sizes)
+
+
+def _held_part_fwd(top_k, rungs, *args):
+    return _held_part(top_k, rungs, *args), args
+
+
+def _held_rows_transposed(rows, top_k, g, order, sizes, *operands):
+    return jax.vjp(lambda *a: _held_rows(rows, top_k, *a, order, sizes),
+                   *operands)[1](g)
+
+
+def _held_part_bwd(top_k, rungs, kept, g):
+    *operands, order, sizes, rung = kept
+    return jax.lax.switch(
+        rung, [functools.partial(_held_rows_transposed, rows, top_k)
+               for rows in rungs],
+        g, order, sizes, *operands) + (None, None, None)
+
+
+_held_part.defvjp(_held_part_fwd, _held_part_bwd)
 
 
 def shard_experts(params, mesh, num_experts, expert_axis="expert"):

@@ -45,8 +45,12 @@ def test_rehearsal_one_chip_phases(tmp_path):
     phases = _phases(proc.stdout)
     assert list(phases) == ["device", "sync", "train_resnet50",
                             "train_bert_base", "flash_two_widths",
-                            "gluon_trainer", "serve", "warm_start", "total"]
+                            "routed_layer", "gluon_trainer", "serve",
+                            "warm_start", "total"]
     assert max(phases["flash_two_widths"]["gaps"].values()) <= 2e-2
+    rows = phases["routed_layer"]["rows"]
+    assert 0 < rows["live"] <= rows["run"] < rows["total"]
+    assert max(phases["routed_layer"]["gaps"].values()) <= 3e-2
     for rec in phases.values():
         assert rec["seconds"] >= 0 and rec["compile_seconds"] >= 0
     assert phases["device"]["platform"] == "cpu"

@@ -246,14 +246,208 @@ def test_routed_gradients_match_the_masked_form():
         assert _gap(a, b) <= 1e-5
 
 
+# ---- the rows routed here: the ladder of row counts the held part runs at
+T_, HELD, FIRST = 64, 2, 4      # 192 pairs; rungs 32, 64, 192 at a tile of 8
+
+
+@pytest.fixture()
+def small_tile(monkeypatch):
+    """The grouped kernel's row tile is 512 on the chip; at 8 the toy
+    layer has the same ladder of three rungs as the cell's."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    assert moe._rungs(T_ * K, HELD, E) == (32, 64, 192)
+    assert moe._rungs(T_ * K, E, E) == (192,)
+
+
+def _steered(n_live, held, first, dtype, seed=11):
+    """A layer whose router reads its choices off x: token t chooses
+    ``n_live`` experts held in all (spread as evenly as its held experts
+    allow, first tokens first) and fills its k with experts held
+    elsewhere, at three distinct scores."""
+    x, leaves = _layer(seed, t=T_)
+    leaves[0] = jnp.eye(E, D)
+    leaves[1] = jnp.zeros(E)
+    mine = list(range(first, first + held))
+    other = [e for e in range(E) if e not in mine]
+    head = np.full((T_, E), -6.0, np.float32)
+    most = min(K, held)
+    for t in range(T_):
+        c = min(most, n_live // T_ + (t < n_live % T_))
+        chosen = [mine[(t + j) % held] for j in range(c)] \
+            + [other[(t + j) % len(other)] for j in range(K - c)]
+        head[t, chosen] = [6.0, 5.0, 4.0]
+    x = x.at[:, :E].set(head).astype(dtype)
+    return x, leaves
+
+
+def _routed_grads(x, leaves, held, first, grouped):
+    part = slice(first, first + held)
+
+    def f(x, router, eg, eu, ed):
+        out = moe.routed_ffn(x, router, leaves[1], eg, eu, ed, top_k=K,
+                             first_expert=first, scale=2.448,
+                             grouped=grouped)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+
+    args = (x, leaves[0].astype(x.dtype)) + tuple(
+        w[part].astype(x.dtype) for w in leaves[2:5])
+    (_, out), grads = jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4),
+                                         has_aux=True)(*args)
+    return (out,) + grads
+
+
+def _reference_grads(x, leaves, held, first):
+    part = slice(first, first + held)
+
+    def f(x, router, eg, eu, ed):
+        out = ref.expert_layer(_cfg(held, first), x,
+                               [router, leaves[1], eg, eu, ed] + leaves[5:])
+        out = out - _shared(x, leaves)
+        return jnp.sum(jnp.sin(out)), out
+
+    f32 = lambda a: a.astype(jnp.float32)
+    args = (f32(x), f32(leaves[0].astype(x.dtype))) + tuple(
+        f32(w[part].astype(x.dtype)) for w in leaves[2:5])
+    (_, out), grads = jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4),
+                                         has_aux=True)(*args)
+    return (out,) + grads
+
+
+LIVE = {  # name: (pairs routed here, experts held, the first, rows run)
+    "none": (0, HELD, FIRST, 32), "eighth": (24, HELD, FIRST, 32),
+    "one_under_the_edge": (31, HELD, FIRST, 32),
+    "on_the_edge": (32, HELD, FIRST, 32),
+    "one_over_the_edge": (33, HELD, FIRST, 64),
+    "on_the_second_edge": (64, HELD, FIRST, 64),
+    "over_the_second_edge": (65, HELD, FIRST, 192),
+    "every_token_both_held": (128, HELD, FIRST, 192),
+    "all_choose_the_same_three": (192, 3, 5, 192),     # experts 5, 6, 7
+    "all_held": (192, E, 0, 192),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(LIVE))
+def test_rows_routed_here_forward_and_gradients(small_tile, case, dtype):
+    """The output and the gradients of x, the router and the three expert
+    leaves, at every share of live rows and on both sides of each rung's
+    edge, against the masked form (the same arithmetic on every token) and
+    the float32 reference; the plan runs the rung expected."""
+    n_live, held, first, rows = LIVE[case]
+    x, leaves = _steered(n_live, held, first, jnp.dtype(dtype))
+    idx, _ = moe.route_top_k(x, leaves[0], leaves[1], K)
+    plan = moe.piece_plan(idx, first, held, E)
+    assert int(plan.n_live) == n_live
+    assert int(plan.rows) == rows
+    got = _routed_grads(x, leaves, held, first, True)
+    plain = _routed_grads(x, leaves, held, first, False)
+    want = _reference_grads(x, leaves, held, first)
+    tol = 1e-5 if dtype == "float32" else 3e-2
+    for a, b, c in zip(got, plain, want):
+        assert a.dtype == b.dtype == jnp.dtype(dtype)
+        assert bool(jnp.all(jnp.isfinite(a.astype(jnp.float32))))
+        if n_live == 0:         # nothing routed here: exactly nothing
+            assert not np.any(np.asarray(a, np.float32))
+            assert not np.any(np.asarray(b, np.float32))
+            assert float(jnp.max(jnp.abs(c))) <= 1e-5
+        else:
+            assert _gap(a, b) <= tol
+            # in bf16 the reference is as far from the masked form
+            assert _gap(a, c) <= (2e-4 if dtype == "float32"
+                                  else max(tol, 1.5 * _gap(b, c)))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("held,first", [(HELD, FIRST), (4, 12), (E, 0)])
+def test_piece_plan_against_a_count(small_tile, seed, held, first):
+    """``piece_plan`` on a seeded router against NumPy: each expert's
+    rows, the live pairs sorted by (expert, token), and the smallest rung
+    that holds them."""
+    x, leaves = _layer(seed, t=T_)
+    idx, _ = moe.route_top_k(x, leaves[0], leaves[1], K)
+    plan = moe.piece_plan(idx, first, held, E)
+    flat = np.asarray(idx).reshape(-1) - first
+    live = np.flatnonzero((flat >= 0) & (flat < held))
+    np.testing.assert_array_equal(np.asarray(plan.sizes),
+                                  np.bincount(flat[live], minlength=held))
+    assert int(plan.n_live) == live.size > 0
+    want = live[np.argsort(flat[live], kind="stable")]
+    np.testing.assert_array_equal(np.asarray(plan.order)[:live.size], want)
+    assert sorted(np.asarray(plan.order)) == list(range(T_ * K))
+    assert plan.rungs == moe._rungs(T_ * K, held, E)
+    assert int(plan.rows) == min(r for r in plan.rungs if r >= live.size)
+
+
+@pytest.mark.parametrize("case", ["none", "eighth", "on_the_edge"])
+def test_rows_past_the_last_group_hold_nothing_defined(small_tile,
+                                                       monkeypatch, case):
+    """XLA:TPU's grouped kernel leaves the rows past the last group
+    unwritten, forward and transposed (on the chip they held NaN once
+    other buffers had used the memory; XLA:CPU zeroes them). With those
+    rows poisoned in both directions the layer gives what it gave."""
+    n_live, held, first, _ = LIVE[case]
+    x, leaves = _steered(n_live, held, first, jnp.float32)
+    want = _routed_grads(x, leaves, held, first, True)
+    grouped = moe._grouped
+
+    def poison(a, sizes):
+        dead = jnp.arange(a.shape[0])[:, None] >= jnp.sum(sizes)
+        return jnp.where(dead, jnp.nan, a)
+
+    @jax.custom_vjp
+    def poisoned(xs, w, sizes):
+        return poison(grouped(xs, w, sizes), sizes)
+
+    def fwd(xs, w, sizes):
+        return poisoned(xs, w, sizes), (xs, w, sizes)
+
+    def bwd(kept, g):
+        xs, w, sizes = kept
+        dxs, dw = jax.vjp(lambda a, b: grouped(a, b, sizes), xs, w)[1](g)
+        return poison(dxs, sizes), dw, None
+
+    poisoned.defvjp(fwd, bwd)
+    monkeypatch.setattr(moe, "_grouped", poisoned)
+    got = _routed_grads(x, leaves, held, first, True)
+    for a, b in zip(got, want):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        assert _gap(a, b) <= 1e-6 or not np.any(np.asarray(b))
+
+
+def test_no_array_of_every_pair_outside_the_switch(small_tile):
+    """The guard that the dead rows do not come back: in the compiled
+    gradient of the layer at held < total, every array of T*k rows by D (or
+    by the experts' width) lives under the conditional's last branch."""
+    from _hlo_text import arrays_outside_control_flow, computations
+    x, leaves = _steered(24, HELD, FIRST, jnp.float32)
+
+    def loss(x, router, eg, eu, ed):
+        return jnp.sum(moe.routed_ffn(x, router, leaves[1], eg, eu, ed,
+                                      top_k=K, first_expert=FIRST))
+
+    args = (x, leaves[0]) + tuple(w[FIRST:FIRST + HELD] for w in leaves[2:5])
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    assert any("conditional(" in line
+               for lines in computations(text).values() for line in lines)
+    assert "[%d,%d]" % (T_ * K, D) in text          # the last rung has them
+    for cols in (D, F_):
+        assert arrays_outside_control_flow(text, T_ * K, cols) == []
+
+
 def test_the_layer_counts_what_it_traced():
     from mxtpu import telemetry
     for name in ("moe.layers", "moe.experts_held", "moe.experts_total",
-                 "moe.grouped_mm.grouped", "moe.grouped_mm.dense"):
+                 "moe.grouped_mm.grouped", "moe.grouped_mm.dense",
+                 "moe.rows_total", "moe.piece_rows"):
         telemetry.reset_metric(name)
-    x, leaves = _layer(7)
+    x, leaves = _layer(7, t=1024)
     _routed(x, leaves, first=4, held=4)
     _routed(x, leaves, grouped=False)
+    # laid out at most and at least, by the grouped layer alone: all T*k
+    # pairs, and the lowest rung (a third over T*k * 4/16, in tiles of 512)
+    assert telemetry.value("moe.rows_total") == 1024 * K
+    assert telemetry.value("moe.piece_rows") == 1024
     assert telemetry.value("moe.layers") == 2
     assert telemetry.value("moe.experts_held") == 4 + E
     assert telemetry.value("moe.experts_total") == 2 * E

@@ -192,7 +192,11 @@ def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
     """The expert layer at the cell's widths (16 of 128 experts held, 6
     choices a token, 8,192 tokens): XLA:TPU takes ``ragged_dot`` as its
     grouped matmul kernel, forward and both transposes; nothing is expanded
-    into a product over every expert held."""
+    into a product over every expert held; and no array of all T*k =
+    49,152 (token, slot) rows by the model's or the experts' width is
+    computed unconditionally: they exist under the switch's last branch
+    alone, which a step whose live rows fit a lower rung does not run."""
+    from _hlo_text import arrays_outside_control_flow
     from mxtpu.parallel import moe
     x = _spec((8192, 2048), one_chip)
     specs = (x, _spec((128, 2048), one_chip), _spec((128,), one_chip),
@@ -208,3 +212,7 @@ def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
     assert "ragged-dot" in text and "tpu_custom_call" in text
     # no [T*k, held, .] or [held, T*k, .] expansion of the products
     assert "49152,16,768" not in text and "16,49152,768" not in text
+    assert moe._rungs(49152, 16, 128) == (8192, 16384, 49152)
+    assert "[8192,768]" in text and "[49152,768]" in text
+    for cols in (2048, 768):
+        assert arrays_outside_control_flow(text, 49152, cols) == []
