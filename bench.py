@@ -14,12 +14,10 @@ JSON line each, so the driver artifact captures all three):
 
 Every config prints ONE JSON line {"metric", "value", "unit", "vs_baseline",
 "mfu", "hfu"} (resnet50 adds "pct_of_achievable" — per-chip fraction of the
-measured 140 TFLOP/s achievable rate, the PERF.md gap statement; the
-``conv_class`` config additionally emits one line per conv class x impl —
-XLA vs the Pallas implicit-GEMM kernel). EVERY printed line is stamped with
-the resolved ``platform`` and active ``policy_key``; ``main()`` refuses to
-run at all off the TPU, and any config that errors makes the run exit
-non-zero:
+measured 140 TFLOP/s achievable rate, the PERF.md gap statement). EVERY
+printed line is stamped with the resolved ``platform`` and active
+``policy_key``; ``main()`` refuses to run at all off the TPU, and any
+config that errors makes the run exit non-zero:
 
 * ``mfu`` — *model*-flops utilization in THE one convention used across
   BASELINE.md / PERF.md / this file (reconciled round 4): an analytic
@@ -173,9 +171,8 @@ def build_resnet50(batch, dtype="bfloat16", layout="NHWC",
         # lever (round 7): the wrap is unconditional and mode None defers
         # the variant choice to MXTPU_S2D_STEM at trace time (0 = the
         # plain stem, so the wrap is free). The env rides
-        # registry.policy_key, so it recompiles per run and composes with
-        # the Pallas conv gate in one jit cache key. mode 1 = 4x4 conv on
-        # 12 channels; mode 2 = double s2d -> MXU-shaped 3x3 conv on
+        # registry.policy_key, so it recompiles per run. mode 1 = 4x4 conv
+        # on 12 channels; mode 2 = double s2d -> MXU-shaped 3x3 conv on
         # 48->256 channels + depth-to-space (contrib/s2d_stem.py)
         from mxtpu.contrib import s2d_stem
         s2d_stem.apply_to_resnet(net)
@@ -934,196 +931,21 @@ def _platform_name():
         return "unknown"
 
 
-def _tune_verdict(tune_rows, key):
-    """Fold per-class A/B rows into a summary verdict: None when the A/B
-    didn't run (BENCH_AUTOTUNE=0 or every search errored), else
-    any(improved) / all(not_worse)."""
-    rows = [r for r in tune_rows if r and "error" not in r]
-    if not rows:
-        return None
-    if key == "improved":
-        return any(r.get("improved") for r in rows)
-    return all(r.get(key) for r in rows)
-
-
-def _autotune_ab(emit, ptune, kernel_id, metric, sc, host_tier,
-                 host_scale=None):
-    """One autotuned-vs-default A/B line for a bench class: a bounded
-    measured search (install=False — the bench must not mutate the
-    serving table) whose default candidate is always timed first by the
-    same warmup-discarded median-of-rounds harness, so default_s/best_s
-    is a like-for-like ratio. ``not_worse`` is the gate: the tuner may
-    fail to beat the hand default but must never regress it (the best
-    candidate can only be the default itself then). The host tier
-    shrinks the problem so interpret-mode candidates stay inside the CI
-    budget — same machinery, smaller buffers."""
-    sc = dict(sc)
-    if host_tier and host_scale:
-        sc.update(host_scale)
-    try:
-        res = ptune.search(kernel_id, sc, install=False, persist=False)
-    except Exception as e:  # noqa: BLE001 — keep the sweep
-        rec = {"metric": metric + "_autotune", "impl": "autotune_ab",
-               "error": str(e)}
-        emit(rec)
-        return rec
-    rec = {"metric": metric + "_autotune", "impl": "autotune_ab",
-           "class": res["class"],
-           "default_plan": res["default_plan_id"],
-           "best_plan": res["best_plan_id"],
-           "default_ms": round(res["default_s"] * 1e3, 3),
-           "best_ms": round(res["best_s"] * 1e3, 3),
-           "value": round(res["speedup_vs_default"], 4),
-           "unit": "x_vs_default",
-           "candidates": res["candidates"], "timed": res["timed"],
-           "budget_exhausted": res["budget_exhausted"],
-           "improved": res["improved"],
-           # best is argmin over a set containing the default, so worse
-           # only by timing noise; 5% bounds that noise
-           "not_worse": res["best_s"] <= res["default_s"] * 1.05}
-    emit(rec)
-    return rec
-
-
-def bench_conv_class(emit=None):
-    """Per-conv-class TFLOP/s, XLA vs the Pallas implicit-GEMM kernel
-    (mxtpu/ops/pallas/conv.py) — the kernel-level numbers that previously
-    lived only in builders' tool logs, now a
-    bench config so the driver artifact records them. One JSON line per
-    (class, impl); classes are the PERF.md sinks: the 7x7s2 stem, a 1x1
-    bottleneck pointwise, a stage-2 3x3 spatial, plus an MXU-filled 1x1
-    control the gate must LEAVE on XLA. Scan-fused K-step timing with
-    host-fetch sync (methodology section). Returns a summary record in
-    the standard schema."""
-    import jax
-    import jax.numpy as jnp
-    from mxtpu.ops.conv_acc import conv_fast
-    from mxtpu.ops.pallas import autotune as ptune
-    from mxtpu.ops.pallas import conv as pconv
-
-    pcommon = _perf_common()
-    if emit is None:
-        emit = _emit
-    batch = int(os.environ.get("BENCH_CONV_BATCH",
-                               os.environ.get("BENCH_BATCH", "128")))
-    k_steps = int(os.environ.get("BENCH_CONV_STEPS", "16"))
-    dtype = (jnp.float32 if os.environ.get("BENCH_DTYPE") == "float32"
-             else jnp.bfloat16)
-    dn = ("NHWC", "HWIO", "NHWC")
-    do_tune = os.environ.get("BENCH_AUTOTUNE", "1") == "1"
-    host_tier = _platform_name() != "tpu"
-    # (label, HW_in, Cin, Cout, k, stride); the last is the XLA control —
-    # K=1024 and C_out=256 both fill the MXU, so Pallas must decline it
-    classes = [
-        ("stem_7x7s2", 224, 3, 64, 7, 2),
-        ("pw_1x1_256to64", 56, 256, 64, 1, 1),
-        ("spatial_3x3_64", 56, 64, 64, 3, 1),
-        ("pw_1x1_1024to256_xla_control", 14, 1024, 256, 1, 1),
-    ]
-    lines = []
-    tune_rows = []
-    saved = os.environ.get("MXTPU_PALLAS_CONV")
-    try:
-        for label, hw, cin, cout, k, s in classes:
-            x = jax.random.normal(jax.random.PRNGKey(0),
-                                  (batch, hw, hw, cin), dtype)
-            w = jax.random.normal(jax.random.PRNGKey(1),
-                                  (k, k, cin, cout), dtype) * 0.1
-            pad = [(k // 2, k // 2), (k // 2, k // 2)]
-            hw_out = (hw + 2 * (k // 2) - k) // s + 1
-            fl = 2 * batch * hw_out * hw_out * cin * cout * k * k
-            # the autotuner's shape class for this (conv_fast routes the
-            # plain conv: no scale/residual epilogue)
-            sc = {"n": batch, "h": hw, "w": hw, "cin": cin, "kh": k,
-                  "kw": k, "cout": cout, "sh": s, "sw": s,
-                  "p0": k // 2, "p1": k // 2, "q0": k // 2, "q1": k // 2,
-                  "dtype": jnp.dtype(dtype).name, "scale": 0, "res": 0}
-            pid, prov = ptune.active_plan("pallas_conv", sc)
-            if pid is None:  # no tuned plan: name the hand-picked default
-                pid = ptune.plan_id_of(pconv._tune_default(sc))
-            by_impl = {}
-            for impl in ("xla", "pallas"):
-                os.environ["MXTPU_PALLAS_CONV"] = \
-                    "1" if impl == "pallas" else "0"
-                pconv.reset_dispatch_stats()
-
-                f = pcommon.reinject(
-                    lambda xd, w=w, s=s, pad=pad: conv_fast(
-                        xd, w, (s, s), pad, (1, 1), (1, 1), dn, 1))
-                try:
-                    dt = pcommon.timed_scan(f, x, K=k_steps)
-                except Exception as e:  # noqa: BLE001 — keep the sweep
-                    emit({"metric": "conv_class_%s" % label, "impl": impl,
-                          "error": str(e)})
-                    continue
-                # dispatch routing now reads from the telemetry registry
-                # (the DISPATCH_STATS dict is a thin view over it)
-                from mxtpu import telemetry
-                if telemetry.value("pallas_conv.pallas"):
-                    used = "pallas"
-                elif impl == "pallas":
-                    reasons = telemetry.tagged("pallas_conv.fallback")
-                    used = "xla_fallback(%s)" % "; ".join(sorted(reasons)) \
-                        if reasons else "xla_gate_declined"
-                else:
-                    used = "xla"
-                rec = {"metric": "conv_class_%s" % label, "impl": impl,
-                       "impl_used": used, "ms": round(dt * 1e3, 3),
-                       # 4 decimals: a CPU-fallback line must not round to
-                       # a flat 0.00 (the chip numbers are 1-100 TFLOP/s)
-                       "value": round(fl / dt / 1e12, 4),
-                       "unit": "TFLOP/s",
-                       "plan": pid, "plan_provenance": prov}
-                by_impl[impl] = dt
-                if impl == "pallas" and "xla" in by_impl:
-                    rec["speedup_vs_xla"] = round(by_impl["xla"] / dt, 3)
-                emit(rec)
-                lines.append(rec)
-            if do_tune and "xla_control" not in label:
-                tune_rows.append(_autotune_ab(
-                    emit, ptune, "pallas_conv",
-                    "conv_class_%s" % label, sc, host_tier,
-                    host_scale={"n": min(batch, 2), "h": min(hw, 64),
-                                "w": min(hw, 64)}))
-    finally:
-        if saved is None:
-            os.environ.pop("MXTPU_PALLAS_CONV", None)
-        else:
-            os.environ["MXTPU_PALLAS_CONV"] = saved
-    pallas_lines = [r for r in lines if r.get("impl") == "pallas"
-                    and r.get("impl_used") == "pallas"]
-    return {
-        "metric": "conv_class",
-        "value": len(lines),
-        "unit": "json_lines",
-        "vs_baseline": None,
-        "mfu": None,
-        "hfu": None,
-        "pallas_kernel_lines": len(pallas_lines),
-        "classes": [r["metric"] for r in lines],
-        "autotune_beats_default": _tune_verdict(tune_rows, "improved"),
-        "autotune_not_worse": _tune_verdict(tune_rows, "not_worse"),
-    }
-
-
 def bench_flash_class(emit=None):
     """Per-attention-class TFLOP/s, XLA softmax path vs the Pallas flash
-    kernel (mxtpu/ops/pallas/flash_attention.py) — conv_class's sibling
-    for the transformer hot path. One JSON line per (class, impl);
+    kernel (mxtpu/ops/pallas/flash_attention.py) for the transformer hot
+    path. One JSON line per (class, impl);
     classes cover the decoder/encoder shapes plus an odd length the
     block picker must still tile (768 → 384-blocks). Off-TPU the kernel
     runs through the Pallas interpreter (MXTPU_FLASH_INTERPRET) on
     host-scaled shapes — slower absolute numbers, but the dispatch
-    routing, plan stamping, and autotune A/B exercise the real kernel.
-    Scan-fused K-step timing with host-fetch sync; every line carries
-    the active plan id + tuned|default provenance; summary gates
-    autotuned-vs-default not-worse."""
+    routing exercises the real kernel. Scan-fused K-step timing with
+    host-fetch sync."""
     import importlib
 
     import jax
     import jax.numpy as jnp
     fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
-    from mxtpu.ops.pallas import autotune as ptune
 
     pcommon = _perf_common()
     if emit is None:
@@ -1131,7 +953,6 @@ def bench_flash_class(emit=None):
     k_steps = int(os.environ.get("BENCH_FLASH_STEPS", "8"))
     dtype = (jnp.float32 if os.environ.get("BENCH_DTYPE") == "float32"
              else jnp.bfloat16)
-    do_tune = os.environ.get("BENCH_AUTOTUNE", "1") == "1"
     host_tier = _platform_name() != "tpu"
     # (label, batch, heads, T, D, host_T) — host_T keeps interpret-mode
     # lines inside the battery budget while preserving each class's
@@ -1143,7 +964,6 @@ def bench_flash_class(emit=None):
     ]
     causal = os.environ.get("BENCH_FLASH_CAUSAL", "0") == "1"
     lines = []
-    tune_rows = []
     saved = os.environ.get("MXTPU_FLASH_INTERPRET")
     try:
         if host_tier:
@@ -1161,11 +981,6 @@ def bench_flash_class(emit=None):
                                    dtype)
             # 2 matmuls (scores + values), 2 FLOPs each: 4*b*h*t*tk*d
             fl = 4 * b * h * t * t * d
-            sc = {"b": b, "h": h, "t": t, "tk": t, "d": d,
-                  "dtype": jnp.dtype(dtype).name}
-            pid, prov = ptune.active_plan("pallas_flash", sc)
-            if pid is None:  # no tuned plan: name the hand-picked default
-                pid = ptune.plan_id_of(fa._tune_default(sc))
             by_impl = {}
             for impl in ("xla", "pallas"):
                 fa.reset_dispatch_stats()
@@ -1197,17 +1012,12 @@ def bench_flash_class(emit=None):
                 rec = {"metric": "flash_class_%s" % label, "impl": impl,
                        "impl_used": used, "ms": round(dt * 1e3, 3),
                        "value": round(fl / dt / 1e12, 4),
-                       "unit": "TFLOP/s",
-                       "plan": pid, "plan_provenance": prov}
+                       "unit": "TFLOP/s"}
                 by_impl[impl] = dt
                 if impl == "pallas" and "xla" in by_impl:
                     rec["speedup_vs_xla"] = round(by_impl["xla"] / dt, 3)
                 emit(rec)
                 lines.append(rec)
-            if do_tune:
-                tune_rows.append(_autotune_ab(
-                    emit, ptune, "pallas_flash",
-                    "flash_class_%s" % label, sc, host_tier))
     finally:
         if saved is None:
             os.environ.pop("MXTPU_FLASH_INTERPRET", None)
@@ -1224,8 +1034,6 @@ def bench_flash_class(emit=None):
         "hfu": None,
         "pallas_kernel_lines": len(pallas_lines),
         "classes": [r["metric"] for r in lines],
-        "autotune_beats_default": _tune_verdict(tune_rows, "improved"),
-        "autotune_not_worse": _tune_verdict(tune_rows, "not_worse"),
     }
 
 
@@ -1473,7 +1281,7 @@ def bench_multichip_resnet(emit=None):
     meaningful). One JSON line per device count (items/s, ``vs_baseline``
     = speedup over the 1-device plain-Trainer run) plus a summary line.
 
-    Tiered gating, like conv_class: on a real multi-chip platform the
+    Tiered gating: on a real multi-chip platform the
     summary's ``vs_baseline`` is the max-count scaling efficiency
     (speedup / devices — the ROADMAP item 1 acceptance number). On the
     forced-host-device tier the N "devices" share one socket, so scaling
@@ -1802,7 +1610,6 @@ CONFIGS = {
     "guard_overhead": bench_guard_overhead,
     "telemetry_overhead": bench_telemetry_overhead,
     "integrity_overhead": bench_integrity_overhead,
-    "conv_class": bench_conv_class,
     "flash_class": bench_flash_class,
     "serving": bench_serving,
     "serving_decode": bench_serving_decode,

@@ -795,15 +795,6 @@ def warmup(entries, threads=None):
     silently)."""
     entries = list(entries)
     t0 = time.perf_counter()
-    # preload tuned Pallas block plans BEFORE any entry traces: warmup is
-    # how ReplicaSet/Trainer ship executables fleet-wide, and the traced
-    # programs must bake the plans a serving process will run under
-    # (no-op unless MXTPU_AUTOTUNE=1)
-    try:
-        from .ops.pallas import autotune as _autotune
-        _autotune.ensure_loaded()
-    except Exception:  # noqa: BLE001 — plan preload must never block warmup
-        pass
     summary = {"entries": len(entries), "built": 0, "disk": 0,
                "cached": 0, "errors": 0, "wall_s": 0.0}
     if not entries:

@@ -40,8 +40,8 @@ def stem_mode():
     policy-mode ``_StemFn`` (mode=None), and part of
     ``registry.policy_key`` — so a per-run flip recompiles every jit
     cache (CachedOp, executors) instead of silently reusing the other
-    stem's executable, and it composes with the MXTPU_PALLAS_CONV gate in
-    one cache key. bench.py maps its BENCH_S2D_STEM knob onto this env."""
+    stem's executable. bench.py maps its BENCH_S2D_STEM knob onto this
+    env."""
     v = os.environ.get("MXTPU_S2D_STEM", "0")
     if v not in ("0", "1", "2"):
         raise MXNetError("MXTPU_S2D_STEM=%r: valid values are 0 (plain "
@@ -151,11 +151,10 @@ class _StemFn:
         self._mode = mode
 
     def __call__(self, x):
-        from ..ops.conv_acc import conv_fast
+        from ..ops.nn import conv_fast
         mode = self._mode if self._mode is not None else stem_mode()
         if mode == 0:
-            # the untransformed stem (the conv the wrap replaced) — bias
-            # rides conv_fast so the Pallas gate can fuse it
+            # the untransformed stem (the conv the wrap replaced)
             return conv_fast(x, self._w, strides=(2, 2),
                              padding=[(3, 3), (3, 3)],
                              lhs_dilation=(1, 1), rhs_dilation=(1, 1),

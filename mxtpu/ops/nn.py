@@ -19,7 +19,6 @@ from jax import lax
 
 from .. import autograd
 from ..random import next_key
-from .conv_acc import conv_fast
 from .precision_util import dot_acc, mxu_precision
 from .registry import register
 
@@ -81,6 +80,23 @@ def _conv_dims(ndim, layout):
     raise ValueError("unsupported conv ndim %d" % ndim)
 
 
+def conv_fast(x, w, strides, padding, lhs_dilation, rhs_dilation, dims,
+              groups, bias=None):
+    """The one route every convolution takes: ``lax.conv_general_dilated``
+    under the package precision policy, then the per-channel ``bias``
+    (a [C_out] vector) as a broadcast add."""
+    out = lax.conv_general_dilated(
+        x, w, window_strides=strides, padding=padding,
+        lhs_dilation=lhs_dilation, rhs_dilation=rhs_dilation,
+        dimension_numbers=dims, feature_group_count=groups,
+        precision=mxu_precision(x, w))
+    if bias is None:
+        return out
+    if dims[2][-1] == "C":          # channels-last: trailing broadcast
+        return out + bias
+    return out + jnp.reshape(bias, (1, -1) + (1,) * (out.ndim - 2))
+
+
 @register("Convolution", aliases=("convolution",))
 def Convolution(data, weight, bias=None, kernel=None, stride=None, dilate=None,
                 pad=None, num_filter=None, num_group=1, no_bias=False, layout=None,
@@ -89,12 +105,10 @@ def Convolution(data, weight, bias=None, kernel=None, stride=None, dilate=None,
     src/operator/nn/convolution.cu + cudnn wrappers). One HLO ConvGeneralDilated;
     grouped/depthwise via feature_group_count (the reference needed a dedicated
     TF-derived depthwise kernel, depthwise_convolution_tf.cuh — here it's the same
-    HLO and XLA picks the kernel). bf16 operands take the f32-accumulate
-    custom-vjp fast path (conv_acc.py); MXU-underfilled NHWC shapes (the
-    stem/1x1/small-C classes PERF.md attributes ~78%% of the ResNet step
-    to) route to the Pallas implicit-GEMM kernel when MXTPU_PALLAS_CONV
-    is on (pallas/conv.py), with the bias riding its fused epilogue —
-    the bias is handed to conv_fast so every dispatch path owns it."""
+    HLO and XLA picks the kernel). Operands that are all bf16/f16 ask for
+    one MXU pass (``precision_util.mxu_precision``), float32 ones keep the
+    package's full-precision default; the output takes the operands'
+    promoted dtype and the gradients are autodiff's."""
     ndim = data.ndim - 2
     kernel = _pair(kernel, ndim)
     stride = _pair(stride, ndim)
@@ -354,29 +368,13 @@ def _leaky_impl(x, gamma, act_type, slope):
     raise ValueError("unknown act_type " + act_type)
 
 
-def _bn_onepass():
-    """Single-read batch statistics, DEFAULT ON as of round 5: the
-    same-session on-chip A/B measured +7.8% end-to-end ResNet-50
-    throughput (2331.7 -> 2512.7 img/s, round-5 builder chip session) and -9.4%
-    on the conv+BN microbench; numerics are pinned eager+hybridized both
-    ways (tests/test_precision.py). MXTPU_BN_ONEPASS=0 restores two-pass
-    jnp.var stats. Baked into compiled executables: registry.policy_key()
-    puts it in jit cache keys so mid-process flips recompile."""
-    import os
-    return os.environ.get("MXTPU_BN_ONEPASS", "1") == "1"
-
-
 def bn_batch_stats(xf, red):
-    """(mean, var) over axes ``red`` under the active stats policy — THE
-    implementation BatchNorm compiles and tools/perf_bn.py measures.
-    One-pass mode: E[x] and E[x^2] in one fused read, var clamped >= 0
-    (catastrophic-cancellation floor; BN's eps covers the residue)."""
+    """(mean, var) over axes ``red``, as BatchNorm compiles them: E[x] and
+    E[x^2] in one fused read, var clamped >= 0 (catastrophic-cancellation
+    floor; BN's eps covers the residue)."""
     mean = jnp.mean(xf, axis=red)
-    if _bn_onepass():
-        var = jnp.maximum(
-            jnp.mean(jnp.square(xf), axis=red) - jnp.square(mean), 0.0)
-    else:
-        var = jnp.var(xf, axis=red)
+    var = jnp.maximum(
+        jnp.mean(jnp.square(xf), axis=red) - jnp.square(mean), 0.0)
     return mean, var
 
 

@@ -5,7 +5,6 @@ fused rnn_impl.h, attention helpers); here the escape hatch below XLA is
 Pallas. Kernels fall back to pure-XLA implementations when shapes or platform
 don't fit, so numerics are always available on CPU test runs.
 """
-from .conv import fused_conv
 from .flash_attention import flash_attention
 
-__all__ = ["flash_attention", "fused_conv"]
+__all__ = ["flash_attention"]

@@ -35,13 +35,12 @@ Design (TPU-first):
 * fallback: non-TPU platforms or non-divisible shapes use the XLA softmax
   path with the same signature (its backward is XLA's own). Why each
   fallback happened is counted in the reason-tagged
-  ``pallas_flash.{pallas,xla,fallback}`` telemetry family (the conv
-  kernel's dispatch-stats discipline); the backward of a kernel forward
-  counts in ``pallas_flash.{bwd_pallas,bwd_xla,bwd_fallback}``.
+  ``pallas_flash.{pallas,xla,fallback}`` telemetry family; the backward
+  of a kernel forward counts in
+  ``pallas_flash.{bwd_pallas,bwd_xla,bwd_fallback}``.
 * parity off-chip: ``MXTPU_FLASH_INTERPRET=1`` runs the kernel through
   the Pallas interpreter, so tier-1 pins the real online-softmax kernel
-  against the XLA path on CPU without a chip (and the autotuner can
-  measure block plans on the host tier).
+  against the XLA path on CPU without a chip.
 """
 from __future__ import annotations
 
@@ -51,8 +50,6 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from . import autotune
 
 _NEG_INF = -1e30
 
@@ -82,9 +79,9 @@ def _interpret():
     return _interpret_flag("MXTPU_FLASH_INTERPRET")
 
 
-# observability: how often the hand kernel ran vs why it fell back — the
-# same dict-shaped view over the telemetry registry conv.py exposes, so
-# bench/report/JSONL read one copy of the truth.
+# observability: how often the hand kernel ran vs why it fell back — a
+# dict-shaped view over the telemetry registry, so bench/report/JSONL read
+# one copy of the truth.
 class _DispatchStatsView:
     """Read-only dict-shaped view over the telemetry counters."""
 
@@ -405,6 +402,23 @@ def _first_q_block(j, i, block_q, block_k, n_q):
     return jnp.minimum(jnp.maximum(i, (j * block_k) // block_q), n_q - 1)
 
 
+# VMEM the backward kernel may plan for: v5e's scoped default is 16 MiB of
+# 128 MiB; the kernel asks for what _bwd_vmem reckons, up to this
+_BWD_VMEM_BUDGET = 64 * 1024 * 1024
+
+
+def _bwd_vmem(bq, bk, t, d, dv, itm):
+    """Bytes one grid step of the backward kernel holds, with dq of the
+    whole head resident (``t`` rows). ``d`` is the width of queries and
+    keys, ``dv`` of values and the cotangent."""
+    dp, dvp = -(-d // 128) * 128, -(-dv // 128) * 128
+    return (2 * (bq + bk) * (dp + dvp) * itm     # q, g, k, v blocks (dbuf)
+            + 2 * 2 * 8 * bq * 4                 # lse, delta rows (dbuf)
+            + bq * bk * (4 * 4 + 2 * itm)        # s^T, P^T, dP^T, ds^T + casts
+            + bk * (dp + dvp) * (4 + 2 * itm)    # dk, dv: scratch + out (dbuf)
+            + t * dp * (4 + 2 * itm))            # dq of the head: scratch + out
+
+
 def _resolve_bwd_blocks(q, k, v, block_q, block_k):
     """``((block_q, block_k), None)`` for the backward kernel, or ``(None,
     reason)`` where it refuses. The tile is transposed against the
@@ -531,18 +545,6 @@ def _pick_block(n, want, mult):
 _warned_fallbacks = set()
 
 
-def shape_class_of(q, k):
-    """The autotuner's shape class for this attention call: problem
-    geometry + dtype. Causal is deliberately absent — the block plan is
-    launch geometry, and a plan that wins on the full score grid also
-    serves the causal-skip variant of the same shape. Works on tracers
-    (shape/dtype only)."""
-    b, h, t, d = q.shape
-    return {"b": int(b), "h": int(h), "t": int(t),
-            "tk": int(k.shape[2]), "d": int(d),
-            "dtype": jnp.dtype(q.dtype).name}
-
-
 def _resolve_blocks(q, k, block_q, block_k):
     """(block_q, block_k) for the Pallas kernel, or None → XLA fallback.
 
@@ -550,10 +552,7 @@ def _resolve_blocks(q, k, block_q, block_k):
     materializes in HBM), so it warns ONCE per offending shape instead of
     silently absorbing it (VERDICT r4 weak #7). Every outcome is counted
     in ``pallas_flash.{pallas,xla}`` / reason-tagged
-    ``pallas_flash.fallback``. A tuned plan (autotune.lookup) may
-    override the q/k block wants, but only after revalidating against
-    the SAME granule/divisor gates — a stale artifact degrades to the
-    defaults with a counted drop."""
+    ``pallas_flash.fallback``."""
     t, tk, d = q.shape[2], k.shape[2], q.shape[3]
     on_tpu = _platform() == "tpu"
     from ... import telemetry
@@ -577,24 +576,9 @@ def _resolve_blocks(q, k, block_q, block_k):
     if not on_tpu and not _interpret():
         # expected off-TPU; counted but not a cliff worth warning about
         return _fallback("platform is not tpu")
-    # head dims off the 128-lane granule (64 for BERT-base et al.) are
-    # zero-padded to the next multiple by _pad_head_dim — scores and lse
-    # are invariant to zero columns, so no fallback needed.
-    # MXTPU_FLASH_PAD_D=0 restores the old fallback (perf A/B only).
-    # default mirrors the registry.policy_key entry — a bare .get() here
-    # would alias unset (None) and "1" onto one compiled-cache key
-    if d % 128 != 0 and os.environ.get("MXTPU_FLASH_PAD_D", "1") == "0":
-        return _fallback("head dim not a multiple of 128 (padding "
-                         "disabled by MXTPU_FLASH_PAD_D=0)")
-    tuned = autotune.lookup("pallas_flash", shape_class_of(q, k))
-    if tuned is not None:
-        tbq = int(tuned.get("block_q", 0))
-        tbk = int(tuned.get("block_k", 0))
-        if (_pick_block(t, tbq, 8) == tbq
-                and _pick_block(tk, tbk, 128) == tbk):
-            block_q, block_k = tbq, tbk
-        else:
-            autotune.plan_infeasible("pallas_flash")
+    # a head dim off the 128-lane granule (64 for BERT-base et al.) is no
+    # reason to fall back: _pad_head_dim zero-pads it, and scores and lse
+    # are invariant to zero columns
     bq = _pick_block(t, block_q, 8)       # sublane granularity
     bk = _pick_block(tk, block_k, 128)    # lane granularity
     if bq is None or bk is None:
@@ -729,117 +713,3 @@ def _fa_lse_bwd(causal, scale, block_q, block_k, res, cots):
 
 
 flash_attention_with_lse.defvjp(_fa_lse_fwd, _fa_lse_bwd)
-
-
-# ------------------------------------------------------- autotune descriptor
-# candidate q/k block wants the space sweeps; each realizes through
-# _pick_block (8-sublane / 128-lane granules), so every emitted plan is a
-# block pair the kernel can actually launch
-_TUNE_WANTS = (128, 256, 512, 1024, 2048)
-# VMEM the feasibility gate lets a candidate plan for (same headroom
-# philosophy as conv's _VMEM_BUDGET; flash has no serving-side VMEM gate
-# because its default blocks are bounded, but the tuner's space is not)
-_TUNE_VMEM_BUDGET = 10 * 1024 * 1024
-
-
-def _tune_space(sc):
-    plans = []
-    for wq in _TUNE_WANTS:
-        for wk in _TUNE_WANTS:
-            bq = _pick_block(sc["t"], wq, 8)
-            bk = _pick_block(sc["tk"], wk, 128)
-            if bq is not None and bk is not None:
-                plans.append({"block_q": bq, "block_k": bk})
-    return plans
-
-
-def _tune_default(sc):
-    return {"block_q": _pick_block(sc["t"], 512, 8),
-            "block_k": _pick_block(sc["tk"], 512, 128)}
-
-
-def _tune_vmem(bq, bk, d, itm):
-    dp = -(-d // 128) * 128
-    return (2 * (bq * dp + dp * bk + bk * dp) * itm  # q/kT/v blocks (dbuf)
-            + bq * bk * 4                            # score/p tile (f32)
-            + 2 * bq * 128 * 4 + bq * dp * 4         # m, l, acc scratch
-            + 2 * (bq * dp * itm + bq * 128 * 4))    # out + lse tiles
-
-
-# VMEM the backward kernel may plan for: v5e's scoped default is 16 MiB of
-# 128 MiB; the kernel asks for what _bwd_vmem reckons, up to this
-_BWD_VMEM_BUDGET = 64 * 1024 * 1024
-
-
-def _bwd_vmem(bq, bk, t, d, dv, itm):
-    """The backward kernel's :func:`_tune_vmem`: bytes one grid step
-    holds, with dq of the whole head resident (``t`` rows). ``d`` is the
-    width of queries and keys, ``dv`` of values and the cotangent."""
-    dp, dvp = -(-d // 128) * 128, -(-dv // 128) * 128
-    return (2 * (bq + bk) * (dp + dvp) * itm     # q, g, k, v blocks (dbuf)
-            + 2 * 2 * 8 * bq * 4                 # lse, delta rows (dbuf)
-            + bq * bk * (4 * 4 + 2 * itm)        # s^T, P^T, dP^T, ds^T + casts
-            + bk * (dp + dvp) * (4 + 2 * itm)    # dk, dv: scratch + out (dbuf)
-            + t * dp * (4 + 2 * itm))            # dq of the head: scratch + out
-
-
-def _tune_feasible(plan, sc):
-    bq = int(plan.get("block_q", 0))
-    bk = int(plan.get("block_k", 0))
-    if _pick_block(sc["t"], bq, 8) != bq:
-        return False, ("block_q=%d is not an 8-multiple divisor of t=%d"
-                       % (bq, sc["t"]))
-    if _pick_block(sc["tk"], bk, 128) != bk:
-        return False, ("block_k=%d is not a 128-multiple divisor of tk=%d"
-                       % (bk, sc["tk"]))
-    itm = jnp.dtype(sc["dtype"]).itemsize
-    vmem = _tune_vmem(bq, bk, sc["d"], itm)
-    if vmem > _TUNE_VMEM_BUDGET:
-        return False, ("VMEM budget: %dx%d blocks need ~%.1f MB > %.1f MB"
-                       % (bq, bk, vmem / 2**20,
-                          _TUNE_VMEM_BUDGET / 2**20))
-    return True, None
-
-
-def _tune_runner(sc):
-    """Real buffers + a dispatch through flash_attention's public entry.
-    causal=False times the full score grid — the plan also serves the
-    causal variant of the shape class (see shape_class_of)."""
-    import numpy as np
-    rng = np.random.default_rng(0)
-    dt = jnp.dtype(sc["dtype"])
-    shp_q = (sc["b"], sc["h"], sc["t"], sc["d"])
-    shp_k = (sc["b"], sc["h"], sc["tk"], sc["d"])
-    q = jnp.asarray(rng.standard_normal(shp_q), dt)
-    k = jnp.asarray(rng.standard_normal(shp_k), dt)
-    v = jnp.asarray(rng.standard_normal(shp_k), dt)
-
-    def fn(q_, k_, v_):
-        return flash_attention(q_, k_, v_, causal=False)
-
-    return fn, (q, k, v)
-
-
-def _tune_classes(host_tier):
-    """Representative shape classes a tuning session sweeps. The host
-    tier shrinks batch/heads/T so interpret-mode candidates stay inside
-    the perf-battery budget; on a chip the bench-transformer shapes run
-    as-is."""
-    if host_tier:
-        shapes = [(1, 2, 256, 256, 64), (1, 2, 512, 512, 64)]
-    else:
-        shapes = [(4, 8, 512, 512, 64), (2, 8, 1024, 1024, 128),
-                  (2, 8, 2048, 2048, 128)]
-    return [{"b": b, "h": h, "t": t, "tk": tk, "d": d, "dtype": "float32"}
-            for (b, h, t, tk, d) in shapes]
-
-
-autotune.register_kernel(autotune.TunableKernel(
-    kernel_id="pallas_flash",
-    space=_tune_space,
-    default=_tune_default,
-    feasible=_tune_feasible,
-    runner=_tune_runner,
-    classes=_tune_classes,
-    interpret_env="MXTPU_FLASH_INTERPRET",
-))

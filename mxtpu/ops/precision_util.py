@@ -12,8 +12,9 @@ see PERF.md "RETRACTED").
 
 What DOES move the MXU (PERF.md "achievable ceiling"): asking low-precision
 contractions for an **f32 accumulator output** (``preferred_element_type``)
-— 102 -> 140 TFLOP/s on an 8k matmul, +10% on conv stacks — implemented by
-``acc_dtype``/``dot_acc`` here and the conv custom-vjp in conv_acc.py.
+— 102 -> 140 TFLOP/s on an 8k matmul — implemented by ``acc_dtype``/
+``dot_acc`` here. Convolutions do not ask for it: end to end it lost
+(PERF.md §6, PR 28).
 """
 from __future__ import annotations
 
@@ -44,7 +45,7 @@ def acc_dtype(*operands):
     Returns jnp.float32 for low-precision operands, else None. jax 0.9
     supports preferred_element_type under autodiff for dot_general but NOT
     for conv_general_dilated (its transpose rule rejects the mixed-dtype
-    cotangent) — conv uses the custom-vjp wrapper in conv_acc.py instead.
+    cotangent), so this is a matmul policy only.
     """
     dtypes = [o.dtype for o in operands if hasattr(o, "dtype")]
     if dtypes and all(d in _LOW for d in dtypes):
@@ -67,8 +68,7 @@ def contract_acc(contraction, a, b, **kwargs):
     accumulator for low-precision operands with the result cast back to the
     operands' promoted dtype; full-precision operands inherit the honest-f32
     global. Used by FullyConnected, dot, batch_dot and the RNN gate matmuls
-    so the policy cannot drift between call sites (convs need the
-    custom-vjp variant in conv_acc.py instead)."""
+    so the policy cannot drift between call sites."""
     pet = acc_dtype(a, b)
     out = contraction(a, b, precision=mxu_precision(a, b),
                       preferred_element_type=pet, **kwargs)

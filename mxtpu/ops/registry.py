@@ -87,39 +87,18 @@ def register(name: Optional[str] = None, aliases=(), as_method: bool = False,
     return deco
 
 
-def _autotune_plans_entry():
-    """The tuned-plan-identity component of policy_key: a digest of the
-    installed autotune plan set (pallas/autotune.policy_token). "0"
-    whenever serving is off or no plans are installed, so the lever
-    being absent changes nothing; a plan flip changes the digest, so a
-    tuned-plan change can never alias an executable traced under the
-    old block geometry (the MeshPlan discipline)."""
-    try:
-        from .pallas import autotune
-        return autotune.policy_token()
-    except Exception:  # noqa: BLE001 — policy_key must never raise
-        return "0"
-
-
 def policy_key():
     """Trace-time env policies that get BAKED INTO compiled executables
-    (f32-accumulate convs, one-pass BN stats). Every jit cache keyed on
-    shapes/modes must include this tuple, or flipping a policy flag
-    mid-process silently reuses executables traced under the old policy
-    (an A/B measurement would then compare a lever with itself)."""
+    (the ring-flash route, the RNN hoist, the stem transform, the numerics
+    guard). Every jit cache keyed on shapes/modes must include this tuple,
+    or flipping a policy flag mid-process silently reuses executables
+    traced under the old policy (an A/B measurement would then compare a
+    lever with itself)."""
     import os
-    return (os.environ.get("MXTPU_CONV_ACC", "0"),
-            # defaults must MIRROR their read sites (ops/nn.py:_bn_onepass,
-            # pallas/flash_attention.py:_resolve_blocks) — a mismatch would
-            # alias unset and the non-default value onto one cache key
-            os.environ.get("MXTPU_BN_ONEPASS", "1"),
-            os.environ.get("MXTPU_RING_FLASH", "0"),
-            os.environ.get("MXTPU_FLASH_PAD_D", "1"),
-            os.environ.get("MXTPU_CONV_IM2COL", "0"),
+    # defaults must MIRROR their read sites — a mismatch would alias unset
+    # and the non-default value onto one cache key
+    return (os.environ.get("MXTPU_RING_FLASH", "0"),
             os.environ.get("MXTPU_RNN_HOIST", "1"),
-            # conv_acc.py:_pallas_enabled / pallas/conv.py:_interpret
-            os.environ.get("MXTPU_PALLAS_CONV", "0"),
-            os.environ.get("MXTPU_PALLAS_CONV_INTERPRET", "0"),
             # contrib/s2d_stem.py:stem_mode (policy-mode _StemFn)
             os.environ.get("MXTPU_S2D_STEM", "0"),
             # resilience.guard_enabled: the in-jit numerics sentinel — the
@@ -138,12 +117,9 @@ def policy_key():
             # executable that never contained the fingerprint
             "0" if os.environ.get("MXTPU_DIVERGENCE_EVERY", "0")
             in ("", "0") else "1",
-            # pallas/autotune.enabled / flash_attention._interpret —
-            # tuned-plan serving and the flash interpret path change the
-            # traced program, so both ride the key
-            os.environ.get("MXTPU_AUTOTUNE", "0"),
-            os.environ.get("MXTPU_FLASH_INTERPRET", "0"),
-            _autotune_plans_entry())
+            # flash_attention._interpret: the interpret path changes the
+            # traced program
+            os.environ.get("MXTPU_FLASH_INTERPRET", "0"))
 
 
 # canonical op name -> fn(attrs) -> int: STATIC output count for ops whose

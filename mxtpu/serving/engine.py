@@ -88,7 +88,7 @@ def serve_int8_default():
     the flag is baked per instance, so a mid-run env flip can never alias
     an executable — it only affects predictors built after it."""
     import os
-    # == "1" like every other boolean lever (MXTPU_PALLAS_CONV, ...):
+    # == "1" like every other boolean lever (MXTPU_RING_FLASH, ...):
     # "false"/"off" must not silently enable quantization
     return os.environ.get("MXTPU_SERVE_INT8", "0") == "1"
 

@@ -5,9 +5,9 @@ The reference framework's ops-facing surface is its engine-level profiler
 aggregate tables). On a jit-compiled TPU stack the signals that matter are
 different — recompiles, host syncs, kernel-dispatch routing, skip-steps,
 IO retries — and before this module they were scattered across five
-modules (``optimizer_fused.FUSED_STATS``, ``ops.pallas.conv.
-DISPATCH_STATS``, ``resilience.FAULT_STATS``, monitor logs, bench-only
-counters) with no common surface. This module is that surface:
+modules (``optimizer_fused.FUSED_STATS``, ``ops.pallas.
+flash_attention.DISPATCH_STATS``, ``resilience.FAULT_STATS``, monitor logs,
+bench-only counters) with no common surface. This module is that surface:
 
 * **Registry** — process-global counters / gauges / histograms with
   near-zero-overhead host-side updates (one short lock, no device work,

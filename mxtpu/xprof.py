@@ -142,8 +142,7 @@ def _capture(args, kwargs):
 
 def _shapes_of(spec_tree, limit=16):
     """Public shape summary of a captured signature: "dtype[d1,d2,...]"
-    per array leaf, bounded. This is what the ledger streams (the
-    ``--tuning-queue`` emitter keys tuning candidates on it); the full
+    per array leaf, bounded. This is what the ledger streams; the full
     ``_Spec`` tree stays private for AOT re-lowering."""
     import jax
     out = []

@@ -252,6 +252,31 @@ def test_head_dim_64_pads_instead_of_falling_back(monkeypatch):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("shape_q,width_v,padded", [
+    ((16, 12, 512, 64), 64, (128, 128)),      # bert_base.train_b16_s512
+    ((1, 32, 8192, 192), 128, (192, 128)),    # kanana2_30b_a3b, causal
+], ids=["bert_base", "kanana2_30b_a3b"])
+def test_the_cells_attention_runs_the_measured_blocks(monkeypatch, shape_q,
+                                                      width_v, padded):
+    """The blocks PRs 25-26 measured fastest on the chip are what the
+    kernels run at the benchmark's attention shapes: 512 x 512 forward and
+    backward, width 64 padded to the 128 lanes, 192 / 128 as they are, and
+    neither shape leaves the kernel for the fallback."""
+    import importlib
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    fa.reset_dispatch_stats()
+    q = jax.ShapeDtypeStruct(shape_q, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape_q[:3] + (width_v,), jnp.bfloat16)
+    assert fa._resolve_blocks(q, q, 512, 512) == (512, 512)
+    assert fa._resolve_bwd_blocks(q, q, v, 512, 512) == ((512, 512), None)
+    qp, vp = jax.eval_shape(fa._pad_head_dim, q, v)
+    assert (qp.shape[-1], vp.shape[-1]) == padded
+    stats = dict(fa.DISPATCH_STATS.items())
+    assert stats["pallas"] == 1 and stats["xla"] == 0
+    assert not stats["fallback_reasons"]
+
+
 def test_pad_head_dim_noop_on_granule():
     import importlib
     fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")

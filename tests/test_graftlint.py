@@ -344,16 +344,6 @@ def test_jit_surface_inventory_lists_all_six_caches():
     assert draft["allowlisted"] is True
     assert "policy_key" in draft["cache_key"], draft
     assert "spec_k" in draft["cache_key"], draft
-    # ISSUE 17: the autotuner's measurement probes are a declared jit
-    # surface too — ephemeral by design (the persisted artifact is the
-    # PLAN; plan identity reaches the real caches through the policy_key
-    # digest component), registered at its own record_retrace site so
-    # the xprof ledger covers it like every other inventory entry
-    assert "autotune.search" in sites, sites
-    tune = by_site["autotune.search"]
-    assert tune["file"] == "mxtpu/ops/pallas/autotune.py", tune
-    assert tune["service"] is True
-    assert tune["function"] == "_time_plan", tune
 
 
 # ------------------------------------------------------------------------ CLI

@@ -477,7 +477,7 @@ def test_sharded_train_step_policy_flip_recompiles(monkeypatch):
     step(mx.nd.array(x), mx.nd.array(y)).asnumpy()
     assert compiles() == before + 1  # steady state: one build, then cached
 
-    monkeypatch.setenv("MXTPU_BN_ONEPASS", "0")  # flip a policy_key lever
+    monkeypatch.setenv("MXTPU_NUMERICS_GUARD", "1")  # flip a policy_key lever
     step(mx.nd.array(x), mx.nd.array(y)).asnumpy()
     assert compiles() == before + 2  # exactly one rebuild per flip
 

@@ -1,9 +1,9 @@
-"""The round-5 measured-best defaults, pinned (PERF.md lever table):
-BN one-pass ON (+7.8% end-to-end), conv_acc custom-vjp OFF (-2.8%),
-flash head-dim padding ON (+8.9% BERT), RNN hoist ON, staged levers
-(im2col, ring-flash) OFF until their on-chip A/B. A default drifting
-here silently changes every user's performance — this test makes that
-a visible decision, not an accident."""
+"""The levers that remain, pinned at their defaults: RNN hoist ON, the
+staged ones (ring-flash, the s2d stem's policy mode) OFF until a cell
+stands on either side of them (ROADMAP.md Queue D1), the numerics guard
+and the divergence sentinel OFF. A default drifting here silently changes
+every user's performance — this test makes that a visible decision, not
+an accident."""
 import os
 
 import pytest
@@ -11,45 +11,32 @@ import pytest
 
 @pytest.fixture(autouse=True)
 def _clean_env(monkeypatch):
-    for var in ("MXTPU_CONV_ACC", "MXTPU_BN_ONEPASS", "MXTPU_RING_FLASH",
-                "MXTPU_FLASH_PAD_D", "MXTPU_CONV_IM2COL",
-                "MXTPU_RNN_HOIST", "BENCH_S2D_STEM", "BENCH_LAYOUT",
-                "MXTPU_FUSED_OPTIMIZER", "MXTPU_PALLAS_CONV",
-                "MXTPU_PALLAS_CONV_INTERPRET", "MXTPU_S2D_STEM",
+    for var in ("MXTPU_RING_FLASH", "MXTPU_RNN_HOIST", "BENCH_S2D_STEM",
+                "BENCH_LAYOUT", "MXTPU_FUSED_OPTIMIZER", "MXTPU_S2D_STEM",
                 "MXTPU_NUMERICS_GUARD", "MXTPU_LOSS_SCALE",
                 "MXTPU_FAULT_INJECT", "MXTPU_CKPT_RETRIES",
                 "MXTPU_DIVERGENCE_EVERY", "MXTPU_TRAIN_STEP_TIMEOUT_X",
                 "MXTPU_POISON_STREAK", "MXTPU_CKPT_KEEP",
-                "MXTPU_AUTOTUNE", "MXTPU_FLASH_INTERPRET"):
+                "MXTPU_FLASH_INTERPRET"):
         monkeypatch.delenv(var, raising=False)
 
 
 def test_policy_key_defaults_are_the_measured_best():
-    from mxtpu.ops.pallas import autotune
     from mxtpu.ops.registry import policy_key
-    autotune.reset()
-    # (conv_acc, bn_onepass, ring_flash, flash_pad_d, im2col, rnn_hoist,
-    #  pallas_conv, pallas_conv_interpret, s2d_stem, numerics_guard,
-    #  divergence_every, autotune, flash_interpret, autotune_plans)
-    assert policy_key() == ("0", "1", "0", "1", "0", "1", "0", "0", "0",
-                            "0", "0", "0", "0", "0")
+    # (ring_flash, rnn_hoist, s2d_stem, numerics_guard, divergence_every,
+    #  flash_interpret)
+    assert policy_key() == ("0", "1", "0", "0", "0", "0")
 
 
 def test_read_sites_mirror_policy_key():
     from mxtpu.contrib.s2d_stem import stem_mode
-    from mxtpu.ops.conv_acc import (_enabled, _im2col_enabled,
-                                    _pallas_enabled)
-    from mxtpu.ops.nn import _bn_onepass
-    from mxtpu.ops.pallas.conv import _interpret
+    from mxtpu.ops.pallas.flash_attention import _interpret
     from mxtpu.ops.rnn_ops import _hoist_enabled
-    from mxtpu.resilience import guard_enabled
-    assert _enabled() is False          # conv_acc: measured regression
-    assert _bn_onepass() is True        # measured +7.8%
-    assert _im2col_enabled() is False   # staged, awaiting on-chip A/B
+    from mxtpu.resilience import divergence_every, guard_enabled
     assert _hoist_enabled() is True
-    assert _pallas_enabled() is False   # staged: resnet_pallas battery
-    assert _interpret() is False        # test-only interpreter path
     assert stem_mode() == 0             # plain stem until measured
+    assert _interpret() is False        # test-only interpreter path
+    assert divergence_every() == 0
     # numerics sentinel OFF by default without a loss scaler: the guarded
     # jit is a different executable, so the default must be a decision
     # (guard_overhead bench tracks its <2% cost), not an accident
@@ -137,42 +124,6 @@ def test_optimizer_step_bench_emits_the_benchline_schema(monkeypatch):
     json.dumps(rec)  # one parseable JSON line
     # the measurement must restore the ambient default (fused on)
     assert os.environ.get("MXTPU_FUSED_OPTIMIZER") is None
-
-
-def test_conv_class_bench_emits_per_class_lines(monkeypatch):
-    """bench.py's conv_class config must emit one stamped JSON line per
-    (conv class, impl) — at least 3 classes, XLA vs Pallas — plus a
-    summary record in the standard schema. On the CPU tier the 'pallas'
-    impl lines must SAY they fell back (impl_used), which is exactly the
-    artifact-readability property the platform/policy stamp exists for."""
-    import json
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    import bench
-    assert "conv_class" in bench.CONFIGS
-    monkeypatch.setenv("BENCH_CONV_BATCH", "1")
-    monkeypatch.setenv("BENCH_CONV_STEPS", "2")
-    # autotune A/B off: the measured-search sweep (ISSUE 17) emits its own
-    # x_vs_default lines and costs real search time — it has its own test
-    # (test_autotune.py); this pin covers the per-class timing schema
-    monkeypatch.setenv("BENCH_AUTOTUNE", "0")
-    lines = []
-    rec = bench.bench_conv_class(emit=lambda r: lines.append(bench._stamp(r)))
-    assert {"metric", "value", "unit", "vs_baseline", "mfu", "hfu"} <= set(rec)
-    assert rec["unit"] == "json_lines"
-    classes = {l["metric"] for l in lines}
-    assert len(classes) >= 3
-    for l in lines:
-        json.dumps(l)                      # parseable artifact lines
-        # assert on ms, not the TFLOP/s rounding — a loaded CPU host can
-        # legitimately land below the value's printable resolution
-        assert l["unit"] == "TFLOP/s" and l["ms"] > 0 and l["value"] >= 0
-        assert l["impl"] in ("xla", "pallas")
-        assert "platform" in l and "policy_key" in l   # the round-7 stamp
-        if l["impl"] == "pallas" and l["platform"] != "tpu":
-            assert l["impl_used"].startswith("xla")    # honest fallback tag
-    # the A/B must restore the ambient default (lever off)
-    assert os.environ.get("MXTPU_PALLAS_CONV") is None
 
 
 def test_bench_lines_are_stamped_with_platform_and_policy(monkeypatch):

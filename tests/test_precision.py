@@ -78,50 +78,54 @@ def test_whole_resnet_step_precision():
                                                           len(convs))
 
 
-def test_bn_onepass_stats_match_twopass(monkeypatch):
-    """MXTPU_BN_ONEPASS=1 (single-read E[x^2]-mean^2 stats, the staged
-    round-4 HBM lever) must match the two-pass default to f32 tolerance
-    in training mode, eager AND hybridized (the policy is part of the
-    jit cache key — registry.policy_key — so the hybrid A/B genuinely
-    recompiles rather than reusing the first trace)."""
-    import numpy as np
-
-    import mxtpu as mx
+def _bn_training_output(x, hybridize):
     from mxtpu import autograd
     from mxtpu.gluon import nn
+    net = nn.BatchNorm(in_channels=x.shape[1])
+    net.initialize()
+    if hybridize:
+        net.hybridize()
+    with autograd.record():
+        out = net(mx.nd.array(x))
+    return net, out.asnumpy()
 
+
+@pytest.mark.parametrize("hybridize", [False, True])
+def test_bn_onepass_stats_match_twopass(hybridize):
+    """BatchNorm's single-read statistics (E[x^2] - mean^2, clamped at 0)
+    against the two-pass ``jnp.var`` form, to f32 tolerance, in training
+    mode, eager and hybridized."""
+    from mxtpu.ops.nn import bn_batch_stats
     x = np.random.RandomState(0).uniform(-2, 2, (8, 6, 5, 5)) \
         .astype(np.float32)
+    red = (0, 2, 3)
+    mean, var = bn_batch_stats(jnp.asarray(x), red)
+    np.testing.assert_allclose(mean, jnp.mean(x, axis=red), rtol=1e-6,
+                               atol=1e-6)
+    np.testing.assert_allclose(var, jnp.var(x, axis=red), rtol=1e-4,
+                               atol=1e-5)
+    # a constant channel: the cancellation floor holds the variance at 0
+    flat = np.full((8, 6, 5, 5), 3.1, np.float32)
+    assert float(jnp.min(bn_batch_stats(jnp.asarray(flat), red)[1])) >= 0.0
 
-    def run(hybridize):
-        mx.random.seed(0)
-        np.random.seed(0)
-        net = nn.BatchNorm(in_channels=6)
-        net.initialize()
-        if hybridize:
-            net.hybridize()
-        with autograd.record():
-            out = net(mx.nd.array(x))
-        return out.asnumpy()
+    net, one = _bn_training_output(x, hybridize)
+    shape = (1, 6, 1, 1)
+    two = (x - jnp.mean(x, axis=red).reshape(shape)) * jax.lax.rsqrt(
+        jnp.var(x, axis=red).reshape(shape) + net._kwargs["eps"])
+    np.testing.assert_allclose(one, two, rtol=1e-4, atol=1e-5)
 
-    for hyb in (False, True):
-        monkeypatch.setenv("MXTPU_BN_ONEPASS", "0")  # explicit two-pass
-        two = run(hyb)                               # (default is now 1)
-        monkeypatch.setenv("MXTPU_BN_ONEPASS", "1")
-        one = run(hyb)
-        np.testing.assert_allclose(one, two, rtol=1e-4, atol=1e-5)
 
-    # the cache-key guarantee itself: one SHARED hybridized net must
-    # recompile when the policy flips (a stale reuse would make A/B
-    # measurements vacuous)
-    net = nn.BatchNorm(in_channels=6)
-    net.initialize()
-    net.hybridize()
-    monkeypatch.setenv("MXTPU_BN_ONEPASS", "0")
-    with autograd.record():
-        net(mx.nd.array(x))
-    n_jits = len(net._cached_op._jits) if net._cached_op else 0
-    monkeypatch.setenv("MXTPU_BN_ONEPASS", "1")
+def test_cached_op_recompiles_on_policy_flip(monkeypatch):
+    """The cache-key guarantee itself: one SHARED hybridized net must
+    recompile when a ``registry.policy_key`` lever flips (a stale reuse
+    would make A/B measurements vacuous)."""
+    x = np.random.RandomState(0).uniform(-2, 2, (8, 6, 5, 5)) \
+        .astype(np.float32)
+    from mxtpu import autograd
+    monkeypatch.delenv("MXTPU_RNN_HOIST", raising=False)
+    net, _ = _bn_training_output(x, True)
+    n_jits = len(net._cached_op._jits)
+    monkeypatch.setenv("MXTPU_RNN_HOIST", "0")
     with autograd.record():
         net(mx.nd.array(x))
     assert len(net._cached_op._jits) > n_jits, \

@@ -255,26 +255,28 @@ def test_transfer_watchdog_warns_on_steady_state_sync(caplog):
 
 # ------------------------------------------------------- adopted stats
 def test_dispatch_stats_is_view_over_registry():
+    import importlib
     import jax.numpy as jnp
-    from mxtpu.ops.pallas import conv as pc
-    pc.reset_dispatch_stats()
-    w = jnp.zeros((3, 3, 4, 8), jnp.float32)
-    out = pc.fused_conv(jnp.ones((1, 5, 5, 4)), w, (1, 1), ((1, 1), (1, 1)))
-    assert out.shape == (1, 5, 5, 8)
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    fa.reset_dispatch_stats()
+    q = jnp.ones((1, 2, 128, 64), jnp.float32)
+    out = fa.flash_attention(q, q, q)
+    assert out.shape == q.shape
     # off-TPU without the interpreter: counted XLA fallback
-    assert telemetry.value("pallas_conv.xla") == 1
+    assert telemetry.value("pallas_flash.xla") == 1
     assert any("platform" in r
-               for r in telemetry.tagged("pallas_conv.fallback"))
+               for r in telemetry.tagged("pallas_flash.fallback"))
     # the module-level dict is a THIN VIEW over the same registry entries
-    assert pc.DISPATCH_STATS["xla"] == 1
-    assert pc.DISPATCH_STATS["pallas"] == 0
-    assert pc.DISPATCH_STATS["fallback_reasons"] == \
-        telemetry.tagged("pallas_conv.fallback")
-    assert set(pc.DISPATCH_STATS.keys()) == \
-        {"pallas", "xla", "fallback_reasons"}
-    pc.reset_dispatch_stats()
-    assert pc.DISPATCH_STATS["xla"] == 0
-    assert pc.DISPATCH_STATS["fallback_reasons"] == {}
+    assert fa.DISPATCH_STATS["xla"] == 1
+    assert fa.DISPATCH_STATS["pallas"] == 0
+    assert fa.DISPATCH_STATS["fallback_reasons"] == \
+        telemetry.tagged("pallas_flash.fallback")
+    assert set(fa.DISPATCH_STATS.keys()) == \
+        {"pallas", "xla", "fallback_reasons",
+         "bwd_pallas", "bwd_xla", "bwd_fallback_reasons"}
+    fa.reset_dispatch_stats()
+    assert fa.DISPATCH_STATS["xla"] == 0
+    assert fa.DISPATCH_STATS["fallback_reasons"] == {}
 
 
 def test_health_monitor_emits_through_telemetry(monkeypatch):

@@ -14,6 +14,7 @@ and the whole suite count 0. Keep these cases in this one file for the same
 reason (a second file can land on a worker that cannot load the library).
 """
 import importlib
+import re
 
 import pytest
 
@@ -22,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
-pc = importlib.import_module("mxtpu.ops.pallas.conv")
 
 
 @pytest.fixture(scope="module")
@@ -44,10 +44,7 @@ def as_tpu(monkeypatch):
     to it but cannot be read back without a chip, and warns."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
-    monkeypatch.setattr(pc, "_platform", lambda: "tpu")
-    for var in ("MXTPU_FLASH_INTERPRET", "MXTPU_PALLAS_CONV_INTERPRET",
-                "MXTPU_AUTOTUNE"):
-        monkeypatch.delenv(var, raising=False)
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -131,40 +128,33 @@ _CONVS = {
 
 
 @pytest.mark.parametrize("name", sorted(_CONVS))
-def test_pallas_conv_forward_compiles_to_mosaic(one_chip, as_tpu, name):
-    xs, ws, strides, padding = _CONVS[name]
-    pc.reset_dispatch_stats()
-    text = _compiled_text(
-        lambda x, w: pc.fused_conv(x, w, strides, padding, relu=True),
-        _spec(xs, one_chip), _spec(ws, one_chip))
-    assert "tpu_custom_call" in text
-    assert pc.DISPATCH_STATS["pallas"] >= 1
-    assert not pc.DISPATCH_STATS["fallback_reasons"]
-
-
-@pytest.mark.parametrize("name", sorted(_CONVS))
-def test_pallas_conv_gradients_compile(one_chip, as_tpu, name):
+def test_conv_route_compiles_to_xla_convolutions(one_chip, as_tpu, name):
+    """The one route (``ops.nn.conv_fast``), forward and both gradients at
+    the cell's batch: three XLA convolutions in bf16, no hand kernel."""
+    from mxtpu.ops.nn import conv_fast
     xs, ws, strides, padding = _CONVS[name]
 
     def loss(x, w):
-        y = pc.fused_conv(x, w, strides, padding, relu=True)
+        y = conv_fast(x, w, strides, padding, (1, 1), (1, 1),
+                      ("NHWC", "HWIO", "NHWC"), 1)
+        assert y.dtype == jnp.bfloat16
         return y.astype(jnp.float32).sum()
 
-    text = _compiled_text(jax.grad(loss, argnums=(0, 1)),
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)),
                           _spec(xs, one_chip), _spec(ws, one_chip))
-    assert "tpu_custom_call" in text     # the forward inside the vjp
+    assert "tpu_custom_call" not in text
+    assert len(re.findall(r" convolution\(", text)) == 3
+    assert "bf16[%s]" % ",".join(map(str, xs)) in text     # dx
+    assert "bf16[%s]" % ",".join(map(str, ws)) in text     # dw
 
 
 def test_interpret_flag_is_an_error_on_tpu(as_tpu, monkeypatch):
-    """No hidden slow path: the interpreter flags are the off-chip parity
-    route, and a TPU run that still carries one refuses to start."""
+    """No hidden slow path: the interpreter flag is the off-chip parity
+    route, and a TPU run that still carries it refuses to start."""
     from mxtpu.base import MXNetError
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     with pytest.raises(MXNetError, match="MXTPU_FLASH_INTERPRET"):
         fa._interpret()
-    monkeypatch.setenv("MXTPU_PALLAS_CONV_INTERPRET", "1")
-    with pytest.raises(MXNetError, match="MXTPU_PALLAS_CONV_INTERPRET"):
-        pc._interpret()
 
 
 # latent attention at the kanana2_30b_a3b cell's shape: keys and queries

@@ -1,7 +1,7 @@
 """Low-precision end-to-end training tier (ref: tests/python/train/
 test_dtype.py — the fp16 training accuracy asserts, mapped to bf16, the
-TPU design point). Exercises the f32-accumulate conv/dot fast paths
-(conv_acc.py, precision_util.py) through a REAL training run with an
+TPU design point). Exercises the one-pass bf16 convolution and the
+f32-accumulate dot policy (precision_util.py) through a REAL training run with an
 accuracy bar, not just op-level parity."""
 import numpy as np
 

@@ -274,17 +274,6 @@ def test_ledger_covers_graftlint_inventory():
                  page_tokens=4, draft_model=model, spec_k=2,
                  warmup=True, start=False)
 
-    # autotune.search: one ephemeral candidate probe (ISSUE 17) — the
-    # measured search's throwaway jits report to the same ledger site
-    # (a single-candidate class keeps it to exactly one compile)
-    from mxtpu.ops.pallas import autotune as ptune
-    from mxtpu.ops.pallas import conv as pconv
-    acfg = pconv._Cfg((1, 1), ((1, 1), (1, 1)), False, False, False, False)
-    asc = pconv.shape_class_of(jnp.zeros((1, 8, 8, 4), jnp.float32),
-                               jnp.zeros((3, 3, 4, 8), jnp.float32), acfg)
-    ptune.search("pallas_conv", asc, rounds=1, install=False,
-                 persist=False)
-
     runtime_sites = _sites_of(xprof.ledger(resolve=False))
     missing = {s for s in static_sites
                if not any(r == s or r.startswith(s + ".")

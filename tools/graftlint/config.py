@@ -127,29 +127,6 @@ JIT_ALLOWLIST: Dict[Tuple[str, str], Dict[str, str]] = {
                      "serving.draft are ZERO (watchdog-pinned by the "
                      "decode bench gate)",
     },
-    ("mxtpu/ops/pallas/autotune.py", "_time_plan"): {
-        "site": "autotune.search",
-        "service": True,
-        "reason": "the measured-search candidate probe: each tuning "
-                  "candidate compiles ONCE as a deliberately ephemeral "
-                  "throwaway jit (timed with warmup-discarded "
-                  "median-of-rounds dispatches, then dropped — caching "
-                  "a losing candidate's executable would be waste), "
-                  "registered via record_retrace('autotune.search') so "
-                  "the xprof ledger covers the site; probe volume is "
-                  "accounted by the autotune.searches counter and "
-                  "bounded by MXTPU_AUTOTUNE_BUDGET_S, far under the "
-                  "retrace watchdog budget per class. The persisted "
-                  "artifact is the PLAN, and the serving-path "
-                  "executables that embed a winning plan resolve "
-                  "through compile_service.get_or_build at their own "
-                  "sites with the plan digest riding "
-                  "registry.policy_key",
-        "cache_key": "none by design (ephemeral measurement probes, "
-                     "never cached, never served) — plan identity "
-                     "reaches real caches via the policy_key digest "
-                     "component (registry._autotune_plans_entry)",
-    },
     ("mxtpu/optimizer_fused.py", "_build_guarded"): {
         "site": "fused_optimizer",
         "service": True,

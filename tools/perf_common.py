@@ -50,7 +50,7 @@ def timed_scan(step_fn, x0, K=8):
     backend (chip_smoke.py's ``sync`` phase checks ``block_until_ready``
     against it on the attached chip). ``step_fn: carry -> carry``; returns
     seconds per step. The single copy behind the perf tools and bench.py's
-    conv_class config — a sync-idiom fix lands everywhere."""
+    flash_class config — a sync-idiom fix lands everywhere."""
     import jax
 
     @jax.jit

@@ -27,12 +27,6 @@ column folds in the site's own span p50 where one exists (e.g.
 ``serving.predict``) — an approximation (host dispatch wall time, not
 device occupancy), printed only where the span times the dispatch.
 
-``--tuning-queue <json>`` (implies ``--ledger``) writes the ranked
-memory-bound candidate list as the Pallas autotuner's work order —
-site, captured argument shapes, intensity, verdict, executed FLOPs —
-which ``tools/autotune_session.py`` consumes top-down (docs/autotune.md,
-the observe → tune → persist → serve loop).
-
 Multi-host runs produce one sink PER HOST: any path argument may be a
 directory (every ``*.jsonl`` inside) or a glob, and several paths are
 merged — counters fold per-file then sum, gauges take the freshest
@@ -49,8 +43,7 @@ payloads — which rank arrived last and which stage made it late.
 Usage::
 
     python tools/telemetry_report.py <jsonl|dir|glob>... [--json]
-        [--traces [K]] [--ledger] [--tuning-queue <json>]
-        [--fleet <board-dir>]
+        [--traces [K]] [--ledger] [--fleet <board-dir>]
 """
 from __future__ import annotations
 
@@ -246,24 +239,6 @@ def format_ledger_table(rows, cands):
     return "\n".join(lines)
 
 
-def tuning_queue(rows, cands):
-    """The ledger's memory-bound shortlist as the autotuner's work order:
-    ``{"format": 1, "queue": [{site, seq, shapes, intensity, verdict,
-    calls, executed_gflops}, ...]}`` ranked by executed FLOPs — the
-    order ``tools/autotune_session.py`` consumes top-down (tune where a
-    better block plan buys the most first)."""
-    queue = []
-    for r in cands:
-        queue.append({"site": r["site"], "seq": r["seq"],
-                      "shapes": r.get("shapes"),
-                      "intensity": r.get("intensity"),
-                      "verdict": r.get("verdict"),
-                      "calls": r["calls"],
-                      "executed_gflops": (r["flops"] * max(r["calls"], 1)
-                                          / 1e9)})
-    return {"format": 1, "queue": queue}
-
-
 def load(path):
     records = []
     with open(path) as f:
@@ -399,14 +374,6 @@ def main(argv):
             # consume the count token BY INDEX: a data file that happens
             # to be named like the number must not be dropped from paths
             top = int(argv.pop(nxt))
-    queue_path = None
-    if "--tuning-queue" in argv:
-        nxt = argv.index("--tuning-queue") + 1
-        if nxt >= len(argv):
-            print("--tuning-queue needs an output path", file=sys.stderr)
-            return 1
-        queue_path = argv.pop(nxt)   # consume BY INDEX, like --traces
-        with_ledger = True           # the queue IS a ledger product
     paths = [a for a in argv if not a.startswith("-")]
     if (not paths and fleet_dir is None) or "-h" in argv or "--help" in argv:
         print(__doc__)
@@ -425,12 +392,6 @@ def main(argv):
     summary = aggregate(records)
     traces = trace_summary(records, top=top) if top is not None else None
     ledger = ledger_summary(records) if with_ledger else None
-    if queue_path is not None:
-        q = tuning_queue(*ledger)
-        with open(queue_path, "w", encoding="utf-8") as f:
-            json.dump(q, f, sort_keys=True, indent=1)
-        print("tuning queue: %d site(s) -> %s"
-              % (len(q["queue"]), queue_path), file=sys.stderr)
     if as_json:
         out = dict(summary)
         if traces is not None:
