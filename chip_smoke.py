@@ -204,26 +204,31 @@ def phase_train_resnet50(sizes, seed):
             "losses": losses}
 
 
-def _flash_backward_gap(fa, seed):
-    """The flash backward where the BERT step does not take it: causal,
-    Tq != Tk, several q and k blocks, a cotangent on lse (what ring
-    attention differentiates). ``jax.vjp`` through the public function
-    against the float32 blockwise oracle on the same residuals; the
-    largest gap of dq, dk, dv, each over its tensor's largest entry."""
+def _flash_small_gaps(fa, seed):
+    """Both flash kernels where the BERT step does not take them: causal,
+    Tq != Tk, 3 x 4 blocks, a cotangent on lse (what ring attention
+    differentiates). ``jax.vjp`` through the public function: out and lse
+    against the plain float32 attention, dq, dk, dv against the float32
+    blockwise oracle on the same residuals; each gap over its tensor's
+    largest entry."""
     import jax
     import jax.numpy as jnp
     rng = np.random.RandomState(seed % (2 ** 31))
     q, k, v, g = (jnp.asarray(rng.randn(2, 4, t, 64), jnp.bfloat16)
-                  for t in (256, 384, 384, 256))
-    g_lse = jnp.asarray(rng.randn(2, 4, 256), jnp.float32)
+                  for t in (384, 512, 512, 384))
+    g_lse = jnp.asarray(rng.randn(2, 4, 384), jnp.float32)
     (out, lse), vjp = jax.vjp(
         lambda q_, k_, v_: fa.flash_attention_with_lse(
             q_, k_, v_, True, None, 128, 128), q, k, v)
     oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, True, 0.125,
                                        128, g_lse=g_lse)
     f32 = lambda x: np.asarray(x, np.float32)
-    return max(float(np.max(np.abs(f32(a) - f32(b))) / np.max(np.abs(f32(b))))
-               for a, b in zip(vjp((g, g_lse)), oracle))
+    want = fa._xla_attention_lse(*(x.astype(jnp.float32) for x in (q, k, v)),
+                                 True, 0.125)
+    return {name: float(np.max(np.abs(f32(a) - f32(b))) / np.max(np.abs(f32(b))))
+            for name, a, b in zip(("out", "lse", "dq", "dk", "dv"),
+                                  (out, lse) + vjp((g, g_lse)),
+                                  want + oracle)}
 
 
 def phase_train_bert_base(sizes, seed, on_tpu):
@@ -254,10 +259,10 @@ def phase_train_bert_base(sizes, seed, on_tpu):
     # bf16 against the float32 oracle: tier-1 measures 0.0084 at most
     # through the interpreter; a wrong mask or lse term reads 0.1 or more
     kernel_backwards = fa.DISPATCH_STATS["bwd_pallas"]
-    rec["flash_backward_gap"] = _flash_backward_gap(fa, seed)
-    _check(rec["flash_backward_gap"] <= 2e-2,
-           "flash backward (causal, Tq != Tk, g_lse) is %.4f from the "
-           "blockwise oracle" % rec["flash_backward_gap"])
+    rec["flash_small_gaps"] = _flash_small_gaps(fa, seed)
+    _check(max(rec["flash_small_gaps"].values()) <= 2e-2,
+           "flash attention (causal, Tq != Tk, 3 x 4 blocks, g_lse) is %s "
+           "from its float32 oracles" % rec["flash_small_gaps"])
     if on_tpu:
         after = dict(fa.DISPATCH_STATS.items())
         _check(after["bwd_pallas"] == kernel_backwards + 1
@@ -269,10 +274,12 @@ def phase_train_bert_base(sizes, seed, on_tpu):
 def phase_flash_two_widths(sizes, seed, on_tpu):
     """Both flash kernels, compiled, with keys and queries of one width and
     values of another (latent attention), causal, over many blocks:
-    ``jax.vjp`` through the public function against the plain float32
-    attention and its ``jax.vjp`` on the first ``check_heads`` heads (the
-    scores of all heads in float32 would not fit). Fails on the chip if
-    either kernel was left for XLA."""
+    ``jax.vjp`` through the public function, out and lse and a cotangent on
+    each, against the plain float32 attention and its ``jax.vjp`` on the
+    first ``check_heads`` heads (the scores of all heads in float32 would
+    not fit). Fails on the chip if either kernel was left for XLA. The
+    record's ``pallas_flash.block_pairs`` are the call's (q block, k
+    block) pairs a head: skipped / visible / crossed."""
     import importlib
 
     import jax
@@ -283,9 +290,11 @@ def phase_flash_two_widths(sizes, seed, on_tpu):
     shape = lambda d: (1, n["heads"], n["seq"], n[d])
     q, k, v, g = (jnp.asarray(rng.randn(*shape(d)), jnp.bfloat16)
                   for d in ("qk", "qk", "v", "v"))
+    g_lse = jnp.asarray(rng.randn(*shape("v")[:3]), jnp.float32)
     fa.reset_dispatch_stats()
-    out, vjp = jax.vjp(lambda *a: fa.flash_attention(*a, True), q, k, v)
-    got = (out,) + vjp(g)
+    out_lse, vjp = jax.vjp(
+        lambda *a: fa.flash_attention_with_lse(*a, True), q, k, v)
+    got = out_lse + vjp((g, g_lse))
     stats = dict(fa.DISPATCH_STATS.items())
     if on_tpu:
         _check(stats["pallas"] == 1 and stats["xla"] == 0,
@@ -294,11 +303,12 @@ def phase_flash_two_widths(sizes, seed, on_tpu):
                "the two-width flash backward took the XLA path: %s" % stats)
     f32 = lambda x: x[:, :n["check_heads"]].astype(jnp.float32)
     want, ref_vjp = jax.vjp(
-        lambda *a: fa._xla_attention(*a, True, n["qk"] ** -0.5),
+        lambda *a: fa._xla_attention_lse(*a, True, n["qk"] ** -0.5),
         f32(q), f32(k), f32(v))
-    want = (want,) + ref_vjp(f32(g))
+    want = want + ref_vjp((f32(g), f32(g_lse)))
     gaps = {name: float(jnp.max(jnp.abs(f32(a) - b)) / jnp.max(jnp.abs(b)))
-            for name, a, b in zip(("out", "dq", "dk", "dv"), got, want)}
+            for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got,
+                                  want)}
     # bf16 against float32: tier-1 measures 0.0084 at most through the
     # interpreter; a wrong mask, width or scale reads 0.1 or more
     _check(max(gaps.values()) <= 2e-2,

@@ -7,11 +7,31 @@ transformer.cc). This is the single-chip hot path under
 and the model zoo transformer.
 
 Design (TPU-first):
-* forward: one Pallas kernel, grid (batch*heads, Tq/bq, Tk/bk) — the k-block
-  axis is innermost so the online-softmax state (m, l, acc) lives in VMEM
-  scratch across k steps; the [T, T] score matrix never materializes in HBM.
-  Causal q/k block pairs above the diagonal are skipped (`pl.when`), saving
-  ~half the FLOPs.
+* forward: one Pallas kernel (``flash_attention_fwd``), grid (batch*heads,
+  Tq/bq, Tk/bk), the k-block axis innermost so the online-softmax state
+  lives in VMEM scratch across k steps; the [T, T] score matrix never
+  materializes in HBM. It works on the TRANSPOSED score tile ``s^T = k q^T``
+  [bk on sublanes, bq on lanes], as the backward does: K comes as the
+  caller holds it (no transposed copy in HBM; the MXU takes q transposed
+  as it loads it), the running max, running sum and rescale are [1, bq]
+  rows whose reductions run down the sublanes (no cross-lane step) and
+  which broadcast for nothing, the accumulator is kept transposed
+  (``acc^T [dv, bq] += v^T p^T``: only the small V block is turned a step)
+  and turned once a q block, and lse leaves as a (bh, 1, t) row, which is
+  what the backward kernel reads. A grid step walks its q block in slabs
+  of 256 columns (exact: the statistics are per q row), every slab's
+  scores first, so that one slab's exp runs beside another's matmul; with
+  the whole row in one k block nothing is carried and there is no
+  scratch. On the chip this took the kernel from 29% to 58% of the MXU's
+  peak at latent attention's 192 / 128 widths (PERF.md §6, PR 29).
+* the causal mask: ONE block-level predicate (:func:`_block_case`: a
+  (q block, k block) pair is skipped, wholly visible or crossed by the
+  diagonal) that both kernels ask. Skipped pairs compute nothing
+  (``pl.when``) and fetch nothing (the index maps name a block already
+  held), saving about half the work; every other pair runs one body with
+  the mask (a second body without it for the visible pairs read no
+  faster). ``pallas_flash.block_pairs{skipped,visible,crossed}`` counts
+  a call's pairs a head at trace time.
 * backward: custom_vjp, flash-attention-2 equations from the saved
   log-sum-exp. Where the forward ran the kernel, ONE fused Pallas kernel
   (``flash_attention_bwd``), grid (batch*heads, Tk/bk, Tq/bq) with the
@@ -32,6 +52,10 @@ Design (TPU-first):
   128). A width under 128 is zero-padded to 128, 192 runs as it is
   (:func:`_pad_head_dim`); every block and accumulator of both kernels
   takes the width of the operand it holds.
+* blocks: one rule for both kernels (:func:`_tile_blocks`): q on the 128
+  lanes (a length off that granule is one whole block), k in 128s, 1024 x
+  1024 asked for where no caller names a pair, halved while a grid step
+  does not fit the VMEM budget.
 * fallback: non-TPU platforms or non-divisible shapes use the XLA softmax
   path with the same signature (its backward is XLA's own). Why each
   fallback happened is counted in the reason-tagged
@@ -86,9 +110,10 @@ class _DispatchStatsView:
     """Read-only dict-shaped view over the telemetry counters."""
 
     _KEYS = ("pallas", "xla", "fallback_reasons",
-             "bwd_pallas", "bwd_xla", "bwd_fallback_reasons")
+             "bwd_pallas", "bwd_xla", "bwd_fallback_reasons", "block_pairs")
     _TAGGED = {"fallback_reasons": "pallas_flash.fallback",
-               "bwd_fallback_reasons": "pallas_flash.bwd_fallback"}
+               "bwd_fallback_reasons": "pallas_flash.bwd_fallback",
+               "block_pairs": "pallas_flash.block_pairs"}
 
     def __getitem__(self, key):
         from ... import telemetry
@@ -126,7 +151,7 @@ DISPATCH_STATS = _DispatchStatsView()
 def reset_dispatch_stats():
     from ... import telemetry
     for name in ("pallas", "xla", "fallback",
-                 "bwd_pallas", "bwd_xla", "bwd_fallback"):
+                 "bwd_pallas", "bwd_xla", "bwd_fallback", "block_pairs"):
         telemetry.reset_metric("pallas_flash." + name)
 
 
@@ -158,123 +183,219 @@ def _xla_attention_lse(q, k, v, causal, scale):
     return out.astype(q.dtype), lse
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-               *, scale, causal, block_q, block_k, n_k):
+def _block_case(qi, ki, block_q, block_k):
+    """What the causal mask does to block pair (q block ``qi``, k block
+    ``ki``): ``(visible, crossed)``. *Visible*: every key of the block lies
+    at or before every query, so no position is masked. *Crossed*: the
+    diagonal passes through, so some are. Neither: *skipped*, no query sees
+    a key. Plain arithmetic on the block indices, so it takes Python ints
+    and arrays (the counts) as well as a kernel's program ids; the one
+    place the mask's block geometry is written down: both kernels ask it
+    (:func:`_live`), and a further mask (a window, segment ids) would."""
+    first_q, first_k = qi * block_q, ki * block_k
+    last_q, last_k = first_q + block_q - 1, first_k + block_k - 1
+    return last_k <= first_q, (first_k <= last_q) & (last_k > first_q)
+
+
+def _live(causal, qi, ki, block_q, block_k):
+    """Whether step (qi, ki) of a kernel computes: every pair without a
+    mask, the visible and the crossed ones under the causal mask."""
+    if not causal:
+        return True
+    visible, crossed = _block_case(qi, ki, block_q, block_k)
+    return visible | crossed
+
+
+def _causal_mask(st, qi, ki, block_q, block_k, first_col=0):
+    """The causal mask on the transposed tile ``st`` [k rows, q columns
+    from ``first_col`` of the q block on] of a live block pair. It runs
+    on the visible pairs too, where it changes nothing: a second copy of
+    a kernel's body without it read no faster on the chip, forward or
+    backward (the mask's passes fill VALU slots the MXU-bound schedule
+    leaves empty; PERF.md §6, PR 29)."""
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+    q_pos = qi * block_q + first_col + jax.lax.broadcasted_iota(
+        jnp.int32, st.shape, 1)
+    return jnp.where(q_pos >= k_pos, st, _NEG_INF)
+
+
+def _count_block_pairs(n_q, n_k, block_q, block_k, causal):
+    """``pallas_flash.block_pairs{skipped,visible,crossed}``: one call's
+    (q block, k block) pairs a head, as :func:`_block_case` sorts them."""
+    import numpy as np
+    from ... import telemetry
+    visible, crossed = n_q * n_k, 0
+    if causal:
+        visible, crossed = (int(x.sum()) for x in _block_case(
+            np.arange(n_q)[:, None], np.arange(n_k)[None, :], block_q,
+            block_k))
+    for tag, n in (("skipped", n_q * n_k - visible - crossed),
+                   ("visible", visible), ("crossed", crossed)):
+        telemetry.inc("pallas_flash.block_pairs", n, tag=tag)
+
+
+def _dot(a, b, contract):
+    """A product of both kernels: operands in their input dtype (bf16: one
+    MXU pass, DEFAULT; the global jax_default_matmul_precision=float32
+    would ask for a multi-pass bf16 contraction Mosaic cannot lower),
+    float32 operands at HIGHEST so reference parity holds, float32 out."""
+    prec = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=prec)
+
+
+# q columns of the tile that one slab holds. A grid step walks its q block
+# slab by slab: row statistics are per q row, so the slabs share nothing and
+# need no rescale, and the scheduler runs one slab's exp beside the next
+# slab's matmul (whole, the tile's phases follow each other with the MXU
+# idle through the softmax; PERF.md §6, PR 29)
+_Q_SLAB = 256
+
+
+def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
+               block_q, block_k, n_k):
+    """One (head, q block, k block) step of the forward, on the TRANSPOSED
+    score tile ``s^T = k q^T`` [bk on sublanes, bq on lanes], the form of
+    :func:`_fa_bwd_kernel`: the row statistics (running max m, running sum
+    l, the rescale alpha) are [1, bq] rows, their reductions run down the
+    sublanes (vreg-to-vreg max / add, no cross-lane step), they broadcast
+    along sublanes for nothing, and the accumulator is kept transposed,
+    ``acc^T [dv, bq] += v^T p^T``, turned once a q block. With the whole
+    row in one k block (``n_k == 1``) nothing is carried: no scratch, no
+    rescale."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    carried = n_k > 1
+    if carried:
+        m_scr, l_scr, acc_scr = scratch
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        @pl.when(ki == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # causal: a k block strictly above the q block's diagonal is all-masked
-    run = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    def _store(cols, m, l, acc_t):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, cols, :] = jnp.transpose(acc_t / l).astype(o_ref.dtype)
+        lse_ref[0, :, cols] = m + jnp.log(l)      # a [1, bq] row of lse
 
-    @pl.when(run)
-    def _compute():
-        # operands stay in their input dtype (bf16 = single-pass MXU);
-        # accumulation is f32 via preferred_element_type. K arrives
-        # pre-transposed [d, bk] so both matmuls are plain (1,0)
-        # contractions (Mosaic's native MXU form).
-        q = q_ref[0]                              # [bq, d]
-        kt = k_ref[0]                             # [d, bk]
-        vb = v_ref[0]                             # [bk, d]
-        # bf16 inputs: single-pass MXU (DEFAULT) — the global
-        # jax_default_matmul_precision=float32 would request a multi-pass
-        # bf16 contraction Mosaic cannot lower. f32 inputs keep HIGHEST so
-        # reference-parity numerics hold.
-        prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
-                else jax.lax.Precision.DEFAULT)
-        s = jax.lax.dot_general(q, kt, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32,
-                                precision=prec) * scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_prev = m_scr[:, :1]                     # [bq, 1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                    # [bq, bk]
-        alpha = jnp.exp(m_prev - m_new)           # [bq, 1]
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # p cast to the value dtype for a single-pass MXU matmul (standard
-        # flash practice); accumulator stays f32
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=prec)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    @pl.when(_live(causal, qi, ki, block_q, block_k))
+    def _step():
+        k = k_ref[0]                              # [bk, d]
+        v = v_ref[0]                              # [bk, dv]
+        slab = _Q_SLAB if block_q % _Q_SLAB == 0 else block_q
+        slabs = [slice(c, c + slab) for c in range(0, block_q, slab)]
+        # every slab's scores first: the products do not wait for a softmax
+        tiles = [_dot(k, q_ref[0, cols, :], ((1,), (1,))) * scale
+                 for cols in slabs]               # [bk, slab] float32 each
+        for cols, st in zip(slabs, tiles):
+            if causal:
+                st = _causal_mask(st, qi, ki, block_q, block_k, cols.start)
+            m_new = jnp.max(st, axis=0, keepdims=True)          # [1, slab]
+            if carried:
+                m_prev = m_scr[:, cols]
+                m_new = jnp.maximum(m_prev, m_new)
+            pt = jnp.exp(st - m_new)              # float32 exp, P^T
+            l_new = jnp.sum(pt, axis=0, keepdims=True)
+            # p cast to the value dtype for a single-pass product (standard
+            # flash practice); the accumulator stays float32
+            pv = _dot(v, pt.astype(v.dtype), ((0,), (0,)))      # [dv, slab]
+            if not carried:
+                _store(cols, m_new, l_new, pv)
+                continue
+            alpha = jnp.exp(m_prev - m_new)
+            m_scr[:, cols] = m_new
+            l_scr[:, cols] = alpha * l_scr[:, cols] + l_new
+            acc_scr[:, cols] = alpha * acc_scr[:, cols] + pv
 
-    @pl.when(ki == n_k - 1)
-    def _finalize():
-        l = l_scr[:, :1]
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        # [bq, 128] lane-replicated (TPU tiling needs a 128 trailing dim);
-        # lane 0 is sliced out on the host side
-        lse_ref[0] = jnp.broadcast_to(
-            m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30)), lse_ref.shape[1:])
+    if carried:
+        @pl.when(ki == n_k - 1)
+        def _finalize():
+            _store(slice(None), m_scr[...], l_scr[...], acc_scr[...])
+
+
+def _last_k_block(i, j, block_q, block_k):
+    """The k block that step (q block ``i``, k block ``j``) of the causal
+    forward names: ``j`` where it computes, else the last block the q
+    block needed, so that nothing is fetched for a skipped step."""
+    return jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+
+
+def _lanes(d):
+    return -(-d // 128) * 128
+
+
+def _vmem_limit(reckoned):
+    """What a kernel asks Mosaic for: v5e's scoped default of 16 MiB, or a
+    quarter over what ``_fwd_vmem`` / ``_bwd_vmem`` reckons."""
+    return max(16 * 2**20, int(1.25 * reckoned))
+
+
+def _fwd_vmem(bq, bk, d, dv, itm):
+    """Bytes one grid step of the forward kernel holds."""
+    dp, dvp = _lanes(d), _lanes(dv)
+    return (2 * (bq * (dp + dvp) + bk * (dp + dvp)) * itm   # q, out, k, v
+            + 2 * 8 * bq * 4                     # the lse row (dbuf)
+            + bq * bk * (4 + 4 + itm)            # s^T, P^T and its cast
+            + (dvp + 2 * 8) * bq * 4)            # acc^T, m, l
 
 
 def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k):
     b, h, t, d = q.shape
     tk, dv = k.shape[2], v.shape[3]   # values may be narrower than keys
     bh = b * h
-    q3 = q.reshape(bh, t, d)
-    k3 = jnp.swapaxes(k.reshape(bh, tk, d), 1, 2)  # [bh, d, tk] for the MXU
-    v3 = v.reshape(bh, tk, dv)
     n_q = t // block_q
     n_k = tk // block_k
+    _count_block_pairs(n_q, n_k, block_q, block_k, causal)
     from jax.experimental.pallas import tpu as pltpu
     kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, n_k=n_k)
     interpret = _interpret()
     extra = {}
-    if not interpret:  # Mosaic-only hint: the interpreter takes none
+    if not interpret:  # Mosaic-only hints: the interpreter takes none
+        itm = jnp.dtype(q.dtype).itemsize
         extra["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(
+                _fwd_vmem(block_q, block_k, d, dv, itm)))
 
     def k_block(i, j):
-        # causal: the steps past a q block's diagonal compute nothing, and
-        # name the last block they needed, so that nothing is fetched
-        # for them either
-        if not causal:
-            return j
-        return jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+        return _last_k_block(i, j, block_q, block_k) if causal else j
 
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, d, block_k),
-                         lambda b_, i, j: (b_, 0, k_block(i, j))),
+            # K as the caller holds it: k q^T contracts both last axes on
+            # the MXU, no transposed copy in HBM
+            pl.BlockSpec((1, block_k, d),
+                         lambda b_, i, j: (b_, k_block(i, j), 0)),
             pl.BlockSpec((1, block_k, dv),
                          lambda b_, i, j: (b_, k_block(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b_, i, j: (b_, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, t, 128), jnp.float32),
+            # lse as (bh, 1, t) rows: what the backward kernel reads
+            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running max m
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running sum l
-            pltpu.VMEM((block_q, dv), jnp.float32),   # output accumulator
-        ],
+            pltpu.VMEM((1, block_q), jnp.float32),    # running max m
+            pltpu.VMEM((1, block_q), jnp.float32),    # running sum l
+            pltpu.VMEM((dv, block_q), jnp.float32),   # acc^T
+        ] if n_k > 1 else [],
         interpret=interpret,
         name="flash_attention_fwd",   # the kernel's name in a device trace
         **extra,
-    )(q3, k3, v3)
-    return out.reshape(b, h, t, dv), lse[:, :, 0].reshape(b, h, t)
+    )(q.reshape(bh, t, d), k.reshape(bh, tk, d), v.reshape(bh, tk, dv))
+    return out.reshape(b, h, t, dv), lse
 
 
 @jax.named_scope("flash_attention_bwd")   # plain XLA: found by this scope
@@ -349,40 +470,24 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     def _init_q():
         dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
 
-    # causal: a k block strictly above the q block's diagonal is all-masked
-    run = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
-
-    @pl.when(run)
-    def _compute():
+    @pl.when(_live(causal, qi, ki, block_q, block_k))
+    def _step():
         q = q_ref[0]                              # [bq, d]
         k = k_ref[0]                              # [bk, d]
         v = v_ref[0]                              # [bk, dv]
         g = g_ref[0]                              # [bq, dv]
-        # the forward kernel's precision policy: operands in their input
-        # dtype (bf16 = one MXU pass), float32 accumulation and statistics
-        prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
-                else jax.lax.Precision.DEFAULT)
-
-        def dot(a, b, contract):
-            return jax.lax.dot_general(
-                a, b, (contract, ((), ())),
-                preferred_element_type=jnp.float32, precision=prec)
-
-        st = dot(k, q, ((1,), (1,))) * scale      # [bk, bq]
+        # the forward kernel's precision policy (:func:`_dot`)
+        st = _dot(k, q, ((1,), (1,))) * scale     # [bk, bq]
         if causal:
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
-            st = jnp.where(q_pos >= k_pos, st, _NEG_INF)
+            st = _causal_mask(st, qi, ki, block_q, block_k)
         pt = jnp.exp(st - lse_ref[0])             # P^T, lse as a [1, bq] row
-        dv_acc[:] += dot(pt.astype(g.dtype), g, ((1,), (0,)))
-        dpt = dot(v, g, ((1,), (1,)))             # dP^T [bk, bq]
+        dv_acc[:] += _dot(pt.astype(g.dtype), g, ((1,), (0,)))
+        dpt = _dot(v, g, ((1,), (1,)))            # dP^T [bk, bq]
         # ds^T without its factor ``scale``: that is applied once to the
         # [*, d] results instead of the [bk, bq] tile
         dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
-        dk_acc[:] += dot(dst, q, ((1,), (0,)))
-        dq_acc[rows, :] += dot(dst, k, ((0,), (0,)))
+        dk_acc[:] += _dot(dst, q, ((1,), (0,)))
+        dq_acc[rows, :] += _dot(dst, k, ((0,), (0,)))
 
     @pl.when(qi == n_q - 1)
     def _store_kv():
@@ -402,16 +507,16 @@ def _first_q_block(j, i, block_q, block_k, n_q):
     return jnp.minimum(jnp.maximum(i, (j * block_k) // block_q), n_q - 1)
 
 
-# VMEM the backward kernel may plan for: v5e's scoped default is 16 MiB of
-# 128 MiB; the kernel asks for what _bwd_vmem reckons, up to this
-_BWD_VMEM_BUDGET = 64 * 1024 * 1024
+# VMEM a kernel may plan for: v5e's scoped default is 16 MiB of 128 MiB; a
+# kernel asks for what _fwd_vmem / _bwd_vmem reckons, up to this
+_VMEM_BUDGET = 64 * 1024 * 1024
 
 
 def _bwd_vmem(bq, bk, t, d, dv, itm):
     """Bytes one grid step of the backward kernel holds, with dq of the
     whole head resident (``t`` rows). ``d`` is the width of queries and
     keys, ``dv`` of values and the cotangent."""
-    dp, dvp = -(-d // 128) * 128, -(-dv // 128) * 128
+    dp, dvp = _lanes(d), _lanes(dv)
     return (2 * (bq + bk) * (dp + dvp) * itm     # q, g, k, v blocks (dbuf)
             + 2 * 2 * 8 * bq * 4                 # lse, delta rows (dbuf)
             + bq * bk * (4 * 4 + 2 * itm)        # s^T, P^T, dP^T, ds^T + casts
@@ -419,20 +524,20 @@ def _bwd_vmem(bq, bk, t, d, dv, itm):
             + t * dp * (4 + 2 * itm))            # dq of the head: scratch + out
 
 
-def _resolve_bwd_blocks(q, k, v, block_q, block_k):
-    """``((block_q, block_k), None)`` for the backward kernel, or ``(None,
-    reason)`` where it refuses. The tile is transposed against the
-    forward's: q lies on the 128 lanes (a length off that granule is one
-    whole block, which is always tileable) and k on the sublanes. The
-    larger side halves until :func:`_bwd_vmem` fits the budget."""
-    t, tk, d, dv = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
-    itm = jnp.dtype(q.dtype).itemsize
+def _tile_blocks(t, tk, block_q, block_k, vmem, too_big):
+    """``((block_q, block_k), None)`` for a kernel, or ``(None, reason)``
+    where it refuses: the one block rule of both kernels. They work on the
+    transposed tile [bk, bq]: q lies on the 128 lanes (a length off that
+    granule is one whole block, which is always tileable) and k on the
+    sublanes, in blocks of 128s. The larger side halves until
+    ``vmem(block_q, block_k)`` fits the budget; ``too_big`` is the reason
+    where nothing smaller is left."""
     while True:
         bq = _pick_block(t, block_q, 128) or (t if t % 8 == 0 else None)
         bk = _pick_block(tk, block_k, 128)
         if bq is None or bk is None:
             return None, "sequence length has no TPU-tileable block"
-        if _bwd_vmem(bq, bk, t, d, dv, itm) <= _BWD_VMEM_BUDGET:
+        if vmem(bq, bk) <= _VMEM_BUDGET:
             return (bq, bk), None
         smaller_q = _pick_block(t, bq // 2, 128) if bq > 128 else None
         smaller_k = _pick_block(tk, bk // 2, 128) if bk > 128 else None
@@ -441,7 +546,18 @@ def _resolve_bwd_blocks(q, k, v, block_q, block_k):
         elif smaller_k:
             block_k = smaller_k
         else:
-            return None, "dq of one head does not fit the VMEM budget"
+            return None, too_big
+
+
+def _resolve_bwd_blocks(q, k, v, block_q, block_k):
+    """:func:`_tile_blocks` for the backward kernel, which keeps dq of the
+    whole head in VMEM (:func:`_bwd_vmem`)."""
+    t, tk, d, dv = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
+    itm = jnp.dtype(q.dtype).itemsize
+    return _tile_blocks(
+        t, tk, block_q, block_k,
+        lambda bq, bk: _bwd_vmem(bq, bk, t, d, dv, itm),
+        "dq of one head does not fit the VMEM budget")
 
 
 @jax.named_scope("flash_attention_bwd")   # prologue, kernel and epilogue
@@ -477,9 +593,8 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
         itm = jnp.dtype(q.dtype).itemsize
         extra["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=max(
-                16 * 2**20,
-                int(1.25 * _bwd_vmem(block_q, block_k, t, d, dv, itm))))
+            vmem_limit_bytes=_vmem_limit(
+                _bwd_vmem(block_q, block_k, t, d, dv, itm)))
 
     def q_block(j, i):
         # causal: the q blocks above a k block's diagonal compute nothing,
@@ -545,7 +660,7 @@ def _pick_block(n, want, mult):
 _warned_fallbacks = set()
 
 
-def _resolve_blocks(q, k, block_q, block_k):
+def _resolve_blocks(q, k, v, block_q, block_k):
     """(block_q, block_k) for the Pallas kernel, or None → XLA fallback.
 
     On TPU the fallback is a real memory cliff (the [T, T] score matrix
@@ -553,7 +668,7 @@ def _resolve_blocks(q, k, block_q, block_k):
     silently absorbing it (VERDICT r4 weak #7). Every outcome is counted
     in ``pallas_flash.{pallas,xla}`` / reason-tagged
     ``pallas_flash.fallback``."""
-    t, tk, d = q.shape[2], k.shape[2], q.shape[3]
+    t, tk, d, dv = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
     on_tpu = _platform() == "tpu"
     from ... import telemetry
 
@@ -567,9 +682,10 @@ def _resolve_blocks(q, k, block_q, block_k):
                 warnings.warn(
                     "flash_attention falling back to the XLA softmax path "
                     "(%s; q[T=%d] k[T=%d] D=%d): the [T,T] score matrix "
-                    "will materialize in HBM — pad T to a multiple of 8 "
-                    "(q) / 128 (k) for the fused kernel (head dims are "
-                    "padded to the 128-lane granule automatically)"
+                    "will materialize in HBM — pad the keys' T to a "
+                    "multiple of 128 and the queries' to one of 128 (or, "
+                    "for one block, of 8) for the fused kernel (head dims "
+                    "are padded to the 128-lane granule automatically)"
                     % (reason, t, tk, d))
         return None
 
@@ -579,12 +695,15 @@ def _resolve_blocks(q, k, block_q, block_k):
     # a head dim off the 128-lane granule (64 for BERT-base et al.) is no
     # reason to fall back: _pad_head_dim zero-pads it, and scores and lse
     # are invariant to zero columns
-    bq = _pick_block(t, block_q, 8)       # sublane granularity
-    bk = _pick_block(tk, block_k, 128)    # lane granularity
-    if bq is None or bk is None:
-        return _fallback("sequence length has no TPU-tileable block")
+    itm = jnp.dtype(q.dtype).itemsize
+    blocks, refused = _tile_blocks(
+        t, tk, block_q, block_k,
+        lambda bq, bk: _fwd_vmem(bq, bk, d, dv, itm),
+        "one q block does not fit the VMEM budget")
+    if blocks is None:
+        return _fallback(refused)
     telemetry.inc("pallas_flash.pallas")
-    return bq, bk
+    return blocks
 
 
 def _pad_head_dim(*xs):
@@ -607,9 +726,15 @@ def _pad_head_dim(*xs):
     return tuple(pad(x) for x in xs)
 
 
+# the blocks both kernels ask for where the caller names none (a shorter
+# sequence is one block): read fastest on the chip, in the model, at 8,192
+# causal positions against {256, 512, 2048} each way (PERF.md §6, PR 29)
+_BLOCK_Q, _BLOCK_K = 1024, 1024
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
-                    block_k=512):
+def flash_attention(q, k, v, causal=False, scale=None, block_q=_BLOCK_Q,
+                    block_k=_BLOCK_K):
     """Fused attention [B, H, T, D] -> [B, H, T, D]; falls back to XLA softmax
     off-TPU or for non-divisible shapes."""
     out, _ = _fa_fwd(q, k, v, causal, scale, block_q, block_k)
@@ -617,17 +742,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
 
 
 def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    blocks = _resolve_blocks(q, k, block_q, block_k)
-    if blocks is None:
-        out = _xla_attention(q, k, v, causal, scale)
-        return out, (q, k, v, out, None)
-    out, lse = _fa_forward_pallas(*_pad_head_dim(q, k, v), causal, scale,
-                                  *blocks)
-    if out.shape[-1] != v.shape[-1]:
-        out = out[..., :v.shape[-1]]
-    return out, (q, k, v, out, lse)
+    out, _, res = _fa_lse_fwd_impl(q, k, v, causal, scale, block_q, block_k)
+    return out, res
 
 
 def _fa_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
@@ -643,8 +759,8 @@ def _fa_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         # plain jax (no lane constraint), but its k-block must DIVIDE tk —
         # the scan would silently drop a ragged tail otherwise
         block_k = _pick_block(k.shape[2], block_k, 1) or k.shape[2]
-        return _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale,
-                                      block_k, g_lse=g_lse)
+        return _fa_backward_blockwise(q, k, v, out, lse.reshape(q.shape[:3]),
+                                      g, causal, scale, block_k, g_lse=g_lse)
     telemetry.inc("pallas_flash.bwd_pallas")
     return _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, *blocks,
                                g_lse=g_lse)
@@ -667,8 +783,8 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention_with_lse(q, k, v, causal=False, scale=None, block_q=512,
-                             block_k=512):
+def flash_attention_with_lse(q, k, v, causal=False, scale=None,
+                             block_q=_BLOCK_Q, block_k=_BLOCK_K):
     """Like :func:`flash_attention` but ALSO returns the per-row
     log-sum-exp [B, H, T] — the quantity that lets partial attention
     results over disjoint key sets be merged exactly (ring attention's
@@ -681,7 +797,7 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None, block_q=512,
 def _fa_lse_fwd_impl(q, k, v, causal, scale, block_q, block_k):
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    blocks = _resolve_blocks(q, k, block_q, block_k)
+    blocks = _resolve_blocks(q, k, v, block_q, block_k)
     if blocks is None:
         out, lse = _xla_attention_lse(q, k, v, causal, scale)
         return out, lse, (q, k, v, out, None)
@@ -689,7 +805,9 @@ def _fa_lse_fwd_impl(q, k, v, causal, scale, block_q, block_k):
                                   *blocks)
     if out.shape[-1] != v.shape[-1]:
         out = out[..., :v.shape[-1]]
-    return out, lse, (q, k, v, out, lse)
+    # the residual is the kernel's own (bh, 1, t) rows, which the backward
+    # kernel reads as they are; the public lse is [B, H, T]
+    return out, lse.reshape(q.shape[:3]), (q, k, v, out, lse)
 
 
 def _fa_lse_fwd(q, k, v, causal, scale, block_q, block_k):

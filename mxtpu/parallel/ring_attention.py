@@ -86,7 +86,7 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None):
 
 
 def ring_flash_attention(q, k, v, axis_name, causal=False, scale=None,
-                         block_q=512, block_k=512):
+                         block_q=None, block_k=None):
     """Ring attention whose per-step block runs the FUSED flash kernel
     (Pallas on TPU; XLA fallback elsewhere) instead of materializing the
     [T_local, T_local] block scores. Per-step partial results merge
@@ -102,7 +102,10 @@ def ring_flash_attention(q, k, v, axis_name, causal=False, scale=None,
     behind MXTPU_RING_FLASH (see registry.policy_key) pending on-chip
     measurement; numerics are pinned against the dense path either way.
     """
-    from ..ops.pallas.flash_attention import flash_attention_with_lse
+    from ..ops.pallas.flash_attention import (_BLOCK_K, _BLOCK_Q,
+                                              flash_attention_with_lse)
+    # no blocks named: the kernels' own pair
+    block_q, block_k = block_q or _BLOCK_Q, block_k or _BLOCK_K
 
     n = jax.lax.psum(1, axis_name)  # concrete inside shard_map
     idx = jax.lax.axis_index(axis_name)

@@ -231,7 +231,7 @@ def test_head_dim_64_pads_instead_of_falling_back(monkeypatch):
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(1, 2, 512, 64), jnp.float32)
-    blocks = fa._resolve_blocks(q, q, 512, 512)
+    blocks = fa._resolve_blocks(q, q, q, 512, 512)
     assert blocks is not None  # no fallback for D=64
     # padding invariance of the attention math the kernel relies on:
     # zero-padded q/k leave scores unchanged, zero-padded v adds zero
@@ -252,29 +252,40 @@ def test_head_dim_64_pads_instead_of_falling_back(monkeypatch):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("shape_q,width_v,padded", [
-    ((16, 12, 512, 64), 64, (128, 128)),      # bert_base.train_b16_s512
-    ((1, 32, 8192, 192), 128, (192, 128)),    # kanana2_30b_a3b, causal
+@pytest.mark.parametrize("shape_q,width_v,padded,blocks,pairs", [
+    # bert_base.train_b16_s512: one block a head, no mask
+    ((16, 12, 512, 64), 64, (128, 128), (512, 512), (0, 1, 0)),
+    # kanana2_30b_a3b.train_b1_s8192: causal, 8 x 8 blocks
+    ((1, 32, 8192, 192), 128, (192, 128), (1024, 1024), (28, 28, 8)),
 ], ids=["bert_base", "kanana2_30b_a3b"])
 def test_the_cells_attention_runs_the_measured_blocks(monkeypatch, shape_q,
-                                                      width_v, padded):
-    """The blocks PRs 25-26 measured fastest on the chip are what the
-    kernels run at the benchmark's attention shapes: 512 x 512 forward and
-    backward, width 64 padded to the 128 lanes, 192 / 128 as they are, and
-    neither shape leaves the kernel for the fallback."""
+                                                      width_v, padded, blocks,
+                                                      pairs):
+    """The blocks read fastest on the chip (PR 29: 1024 x 1024 where the
+    sequence has them; PRs 25-26 had read 512 x 512 with the forward's
+    older tile) are what both kernels run at the benchmark's attention
+    shapes when no blocks are named: width 64 padded to the 128 lanes,
+    192 / 128 as they are, and neither shape leaves the kernel for the
+    fallback. ``pairs``: a head's skipped / visible / crossed block pairs."""
     import importlib
     fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
     fa.reset_dispatch_stats()
     q = jax.ShapeDtypeStruct(shape_q, jnp.bfloat16)
     v = jax.ShapeDtypeStruct(shape_q[:3] + (width_v,), jnp.bfloat16)
-    assert fa._resolve_blocks(q, q, 512, 512) == (512, 512)
-    assert fa._resolve_bwd_blocks(q, q, v, 512, 512) == ((512, 512), None)
+    asked = (fa._BLOCK_Q, fa._BLOCK_K)
+    assert fa._resolve_blocks(q, q, v, *asked) == blocks
+    assert fa._resolve_bwd_blocks(q, q, v, *asked) == (blocks, None)
     qp, vp = jax.eval_shape(fa._pad_head_dim, q, v)
     assert (qp.shape[-1], vp.shape[-1]) == padded
     stats = dict(fa.DISPATCH_STATS.items())
     assert stats["pallas"] == 1 and stats["xla"] == 0
     assert not stats["fallback_reasons"]
+    causal = shape_q[2] > 512
+    t = shape_q[2]
+    fa._count_block_pairs(t // blocks[0], t // blocks[1], *blocks, causal)
+    assert fa.DISPATCH_STATS["block_pairs"] == dict(
+        zip(("skipped", "visible", "crossed"), pairs))
 
 
 def test_pad_head_dim_noop_on_granule():
@@ -283,6 +294,101 @@ def test_pad_head_dim_noop_on_granule():
     q = jnp.zeros((1, 1, 8, 128), jnp.float32)
     qp, kp, vp = fa._pad_head_dim(q, q, q)
     assert qp is q and kp is q and vp is q
+
+
+# --------------------------------- the forward kernel, transposed tile
+def _fwd_case(causal, t, tk, d, dv, dtype, want, grads=False, heads=(1, 2),
+              name=None):
+    return pytest.param(
+        causal, heads, t, tk, d, dv, dtype, want, grads,
+        id=name or "%s-t%dx%d-qk%d-v%d-%s-blocks%d" % (
+            "causal" if causal else "full", t, tk, d, dv, dtype, want))
+
+
+_FWD_SHAPES = [
+    # t, tk, blocks asked for -> what the kernel walks
+    (128, 128, 512),      # one block: nothing carried, no scratch
+    (256, 256, 128),      # 2 x 2
+    (384, 256, 128),      # 3 x 2, Tq != Tk
+    (256, 384, 128),      # 2 x 3: the last k block is seen by no query
+    (768, 768, 512),      # 2 x 2 of 384: the slab is the whole block
+    (1536, 1536, 512),    # 3 x 3 of 512: two slabs of 256 a block
+]
+_FWD_CASES = [
+    _fwd_case(True, t, tk, d, dv, dtype, want,
+              grads=dtype == "float32" and t <= 384)
+    for t, tk, want in _FWD_SHAPES
+    for d, dv in ((64, 64), (128, 128), (192, 128))
+    for dtype in ("float32", "bfloat16")
+] + [
+    _fwd_case(False, t, tk, d, d, dtype, want)
+    for t, tk, want in _FWD_SHAPES[:1] + _FWD_SHAPES[2:3]
+    for d in (64, 128)
+    for dtype in ("float32", "bfloat16")
+] + [
+    # the attention of the two language-model cells at their rehearsal
+    # sizes (benchmark/configs/*.json): batch x heads x seq x widths
+    _fwd_case(False, 128, 128, 32, 32, "bfloat16", 512, True, heads=(4, 2),
+              name="bert_base-rehearsal"),
+    _fwd_case(True, 128, 128, 24, 16, "bfloat16", 512, True, heads=(2, 2),
+              name="kanana2_30b_a3b-rehearsal"),
+]
+
+
+@pytest.mark.parametrize("causal,heads,t,tk,d,dv,dtype,want,grads",
+                         _FWD_CASES)
+def test_pallas_forward_matches_xla(monkeypatch, causal, heads, t, tk, d, dv,
+                                    dtype, want, grads):
+    """out AND lse of the forward kernel (the transposed tile, lse leaving
+    as a row) through the interpreter against the plain float32 attention;
+    where ``grads``, the gradient through the custom_vjp too, the kernel's
+    lse handed on to the backward kernel, with a cotangent on lse. Keys no
+    query may see (causal, Tk > Tq) are NaN in K and V: a block the mask
+    skips is not read, and the oracle gets the keys without them."""
+    import importlib
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    rng = np.random.RandomState(3)
+    dt = jnp.dtype(dtype)
+    q = jnp.asarray(rng.randn(*heads, t, d), dt)
+    k = jnp.asarray(rng.randn(*heads, tk, d), dt)
+    v = jnp.asarray(rng.randn(*heads, tk, dv), dt)
+    seen = min(t, tk) if causal else tk
+    if seen < tk:
+        k = k.at[:, :, seen:].set(jnp.nan)
+        v = v.at[:, :, seen:].set(jnp.nan)
+    scale = d ** -0.5
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def kernel(q_, k_, v_):
+        return fa.flash_attention_with_lse(q_, k_, v_, causal, None, want,
+                                           want)
+
+    def oracle(q_, k_, v_):
+        return fa._xla_attention_lse(f32(q_), f32(k_[:, :, :seen]),
+                                     f32(v_[:, :, :seen]), causal, scale)
+
+    fa.reset_dispatch_stats()
+    (out, lse), vjp = jax.vjp(kernel, q, k, v)
+    assert fa.DISPATCH_STATS["pallas"] == 1 and fa.DISPATCH_STATS["xla"] == 0
+    (want_out, want_lse), ref_vjp = jax.vjp(oracle, q, k, v)
+    assert out.shape == heads + (t, dv) and out.dtype == dt
+    assert lse.shape == heads + (t,) and lse.dtype == jnp.float32
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    assert _gap(out, want_out) <= tol
+    assert _gap(lse, want_lse) <= (1e-5 if dtype == "float32" else 5e-3)
+    if not grads:
+        return
+    g = jnp.asarray(rng.randn(*out.shape), dt)
+    g_lse = jnp.asarray(rng.randn(*lse.shape), jnp.float32)
+    got = vjp((g, g_lse))
+    assert fa.DISPATCH_STATS["bwd_pallas"] == 1
+    ref = ref_vjp((f32(g), g_lse))
+    assert _gap(got[0], ref[0]) <= tol, "dq"
+    for name, a, b in zip(("dk", "dv"), got[1:], ref[1:]):
+        assert _gap(a[:, :, :seen], b[:, :, :seen]) <= tol, name
+        # an unseen key gets no gradient, and no NaN
+        assert not np.any(np.asarray(a[:, :, seen:], np.float32)), name
 
 
 # ------------------------------------------- the fused backward kernel
@@ -388,7 +494,7 @@ def test_backward_refusal_is_counted_and_takes_the_oracle(monkeypatch):
     import importlib
     fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
-    monkeypatch.setattr(fa, "_BWD_VMEM_BUDGET", 1024)
+    monkeypatch.setattr(fa, "_bwd_vmem", lambda *a: 2 * fa._VMEM_BUDGET)
     q, k, v = (_rand((1, 1, 128, 64), s) for s in range(3))
     fa.reset_dispatch_stats()
     got = jax.grad(lambda *a: jnp.sum(fa.flash_attention(*a) ** 2),
@@ -422,7 +528,7 @@ def test_backward_blocks_follow_the_shapes():
     (bq, bk), _ = resolve(spec(32768, 128), spec(32768, 128),
                                          2048, 2048)
     assert (bq, bk) == (1024, 1024)
-    assert fa._bwd_vmem(bq, bk, 32768, 128, 128, 2) <= fa._BWD_VMEM_BUDGET
+    assert fa._bwd_vmem(bq, bk, 32768, 128, 128, 2) <= fa._VMEM_BUDGET
     assert resolve(spec(2 ** 20, 128), spec(2 ** 20, 128),
                                   512, 512) == (
         None, "dq of one head does not fit the VMEM budget")

@@ -103,6 +103,65 @@ def test_causal_backward_names_only_blocks_that_exist(n_q, n_k, bq, bk):
                 assert got == i
 
 
+@pytest.mark.parametrize("n_q,n_k,bq,bk", [
+    (1, 1, 512, 512), (2, 2, 128, 128), (16, 16, 512, 512),    # equal
+    (8, 8, 1024, 1024), (4, 8, 256, 128), (2, 8, 512, 128),    # 2:1, 4:1
+    (8, 4, 128, 256), (8, 2, 128, 512),                        # 1:2, 1:4
+    (2, 3, 128, 128), (3, 2, 128, 128), (1, 4, 200, 128),      # Tq != Tk
+    (3, 5, 384, 128), (5, 3, 128, 384), (2, 8, 256, 128)])
+def test_block_predicate_sorts_every_pair(n_q, n_k, bq, bk):
+    """``_block_case`` against the mask itself, pair by pair: a pair called
+    visible has no masked position, a skipped pair no visible one, a
+    crossed pair both. The forward's index map names ``j`` wherever the
+    pair is not skipped, and only blocks inside the arrays (the chip halts
+    on a block index past the end; the interpreter clamps it and says
+    nothing); the ``pallas_flash.block_pairs`` counts are the brute-force
+    ones."""
+    want = {"skipped": 0, "visible": 0, "crossed": 0}
+    for i in range(n_q):
+        for j in range(n_k):
+            sees = (np.arange(i * bq, (i + 1) * bq)[:, None]
+                    >= np.arange(j * bk, (j + 1) * bk)[None, :])
+            visible, crossed = fa._block_case(i, j, bq, bk)
+            assert isinstance(visible, bool) and isinstance(crossed, bool)
+            assert not (visible and crossed)
+            assert visible == bool(sees.all()), (i, j)
+            assert crossed == bool(sees.any() and not sees.all()), (i, j)
+            kind = ("visible" if visible else
+                    "crossed" if crossed else "skipped")
+            want[kind] += 1
+            named = int(fa._last_k_block(i, j, bq, bk))
+            assert 0 <= named < n_k
+            if kind != "skipped":
+                assert named == j
+            else:          # the last block the q block needed, not j
+                assert named < j and any(fa._block_case(i, named, bq, bk))
+    # the same answers on arrays, which is how a kernel's ids arrive
+    visible, crossed = fa._block_case(
+        jnp.arange(n_q)[:, None], jnp.arange(n_k)[None, :], bq, bk)
+    assert int(visible.sum()) == want["visible"]
+    assert int(crossed.sum()) == want["crossed"]
+    for causal in (True, False):
+        fa.reset_dispatch_stats()
+        fa._count_block_pairs(n_q, n_k, bq, bk, causal)
+        assert fa.DISPATCH_STATS["block_pairs"] == (want if causal else {
+            "skipped": 0, "visible": n_q * n_k, "crossed": 0})
+
+
+def test_block_pairs_of_the_two_cells():
+    """What ``chip_smoke.py`` prints and PERF.md quotes: a head of the
+    kanana cell at 512 x 512 has 120 / 120 / 16 pairs, a head of BERT's
+    one visible pair."""
+    fa.reset_dispatch_stats()
+    fa._count_block_pairs(16, 16, 512, 512, True)
+    assert fa.DISPATCH_STATS["block_pairs"] == {
+        "skipped": 120, "visible": 120, "crossed": 16}
+    fa.reset_dispatch_stats()
+    fa._count_block_pairs(1, 1, 512, 512, False)
+    assert fa.DISPATCH_STATS["block_pairs"] == {
+        "skipped": 0, "visible": 1, "crossed": 0}
+
+
 def test_grad_two_widths_runs_both_kernels(monkeypatch):
     """``jax.grad`` through the public function at 192 / 128: the forward
     and the backward both run as kernels, and no fallback is counted."""

@@ -273,7 +273,9 @@ def test_dispatch_stats_is_view_over_registry():
         telemetry.tagged("pallas_flash.fallback")
     assert set(fa.DISPATCH_STATS.keys()) == \
         {"pallas", "xla", "fallback_reasons",
-         "bwd_pallas", "bwd_xla", "bwd_fallback_reasons"}
+         "bwd_pallas", "bwd_xla", "bwd_fallback_reasons", "block_pairs"}
+    # a forward that fell back counted no block pairs
+    assert fa.DISPATCH_STATS["block_pairs"] == {}
     fa.reset_dispatch_stats()
     assert fa.DISPATCH_STATS["xla"] == 0
     assert fa.DISPATCH_STATS["fallback_reasons"] == {}
