@@ -6,7 +6,9 @@ the two models ``bench.py`` is built around (ResNet-50 v1 at 224x224x3 /
 1000 classes / batch 128, BERT-base at 12 layers / 768 wide / 12 heads /
 seq 512 / batch 16 / vocab 30,522; bf16, weights random from ``--seed``),
 both flash kernels once more at latent attention's shape (32 heads of
-8,192 positions, keys and queries 192 wide, values 128, causal), and the
+8,192 positions, keys and queries 192 wide, values 128, causal) and at
+grouped heads (2 sequences, 32 query heads over 8 key/value heads of 64,
+8,192 positions, causal), and the
 routed expert layer at one chip's share of kanana-2-30b-a3b's (8,192 tokens
 of 2,048, top-6 of 128 experts of width 768, 16 held).
 
@@ -51,6 +53,9 @@ FULL = dict(
     bert=dict(batch=16, seq=512, vocab=30522, dim=768, heads=12, layers=12),
     # latent attention's shape: keys and queries 192 wide, values 128
     latent=dict(heads=32, seq=8192, qk=192, v=128, check_heads=2),
+    # grouped heads: 32 query heads over 8 key/value heads, two sequences
+    grouped=dict(batch=2, heads=32, kv_heads=8, seq=8192, qk=64, v=64,
+                 check_heads=4),
     # one chip's share of a routed expert layer: 16 of 128 experts, top-6
     routed=dict(tokens=8192, dim=2048, width=768, held=16, total=128,
                 top_k=6),
@@ -68,6 +73,8 @@ TINY = dict(
     serve_requests=(1, 3, 4, 2),
     bert=dict(batch=4, seq=128, vocab=512, dim=64, heads=2, layers=2),
     latent=dict(heads=2, seq=256, qk=24, v=16, check_heads=2),
+    grouped=dict(batch=2, heads=4, kv_heads=2, seq=256, qk=16, v=16,
+                 check_heads=2),
     routed=dict(tokens=512, dim=64, width=32, held=2, total=16, top_k=3),
     train_steps=5, gluon_steps=3,
     # the toy memorizes its 8 images in three steps (loss 3.4 -> 0.02),
@@ -271,13 +278,17 @@ def phase_train_bert_base(sizes, seed, on_tpu):
     return rec
 
 
-def phase_flash_two_widths(sizes, seed, on_tpu):
-    """Both flash kernels, compiled, with keys and queries of one width and
-    values of another (latent attention), causal, over many blocks:
+def phase_flash_kernels(n, seed, on_tpu):
+    """Both flash kernels, compiled, causal, over many blocks, at the shape
+    ``n``: keys and queries of one width and values of another (latent
+    attention), or ``heads`` query heads over ``kv_heads`` key/value heads
+    (grouped heads; K, V and their gradients stay at ``kv_heads``).
     ``jax.vjp`` through the public function, out and lse and a cotangent on
     each, against the plain float32 attention and its ``jax.vjp`` on the
-    first ``check_heads`` heads (the scores of all heads in float32 would
-    not fit). Fails on the chip if either kernel was left for XLA. The
+    first ``check_heads`` query heads with the key/value heads they read
+    (whole groups, so that dk and dv are sums over every reader; the scores
+    of all heads in float32 would not fit). Fails on the chip if either
+    kernel was left for XLA or K, V were repeated to the query heads. The
     record's ``pallas_flash.block_pairs`` are the call's (q block, k
     block) pairs a head: skipped / visible / crossed."""
     import importlib
@@ -285,12 +296,14 @@ def phase_flash_two_widths(sizes, seed, on_tpu):
     import jax
     import jax.numpy as jnp
     fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
-    n = sizes["latent"]
+    kv_heads = n.get("kv_heads", n["heads"])
+    group = n["heads"] // kv_heads
     rng = np.random.RandomState(seed % (2 ** 31))
-    shape = lambda d: (1, n["heads"], n["seq"], n[d])
-    q, k, v, g = (jnp.asarray(rng.randn(*shape(d)), jnp.bfloat16)
-                  for d in ("qk", "qk", "v", "v"))
-    g_lse = jnp.asarray(rng.randn(*shape("v")[:3]), jnp.float32)
+    shape = lambda heads, d: (n.get("batch", 1), heads, n["seq"], n[d])
+    q, k, v, g = (jnp.asarray(rng.randn(*shape(h, d)), jnp.bfloat16)
+                  for h, d in ((n["heads"], "qk"), (kv_heads, "qk"),
+                               (kv_heads, "v"), (n["heads"], "v")))
+    g_lse = jnp.asarray(rng.randn(*shape(n["heads"], "v")[:3]), jnp.float32)
     fa.reset_dispatch_stats()
     out_lse, vjp = jax.vjp(
         lambda *a: fa.flash_attention_with_lse(*a, True), q, k, v)
@@ -298,10 +311,16 @@ def phase_flash_two_widths(sizes, seed, on_tpu):
     stats = dict(fa.DISPATCH_STATS.items())
     if on_tpu:
         _check(stats["pallas"] == 1 and stats["xla"] == 0,
-               "the two-width flash forward fell back: %s" % stats)
+               "the flash forward fell back: %s" % stats)
         _check(stats["bwd_pallas"] == 1 and stats["bwd_xla"] == 0,
-               "the two-width flash backward took the XLA path: %s" % stats)
-    f32 = lambda x: x[:, :n["check_heads"]].astype(jnp.float32)
+               "the flash backward took the XLA path: %s" % stats)
+        _check(not stats["kv_repeated"],
+               "K and V were repeated to the query heads: %s" % stats)
+    _check(stats["grouped"] == (group > 1),
+           "grouped heads were not counted as such: %s" % stats)
+    checked = n["check_heads"]
+    f32 = lambda x: x[:, :checked if x.shape[1] == n["heads"]
+                      else checked // group].astype(jnp.float32)
     want, ref_vjp = jax.vjp(
         lambda *a: fa._xla_attention_lse(*a, True, n["qk"] ** -0.5),
         f32(q), f32(k), f32(v))
@@ -310,9 +329,9 @@ def phase_flash_two_widths(sizes, seed, on_tpu):
             for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got,
                                   want)}
     # bf16 against float32: tier-1 measures 0.0084 at most through the
-    # interpreter; a wrong mask, width or scale reads 0.1 or more
+    # interpreter; a wrong mask, width, scale or head reads 0.1 or more
     _check(max(gaps.values()) <= 2e-2,
-           "two-width flash attention is %s from the float32 oracle" % gaps)
+           "flash attention at %s is %s from the float32 oracle" % (n, gaps))
     return {"shape": n, "pallas_flash": stats, "gaps": gaps}
 
 
@@ -702,8 +721,10 @@ def run(sizes, chips=1, seed=0, out=sys.stdout):
             phase("train_resnet50", phase_train_resnet50, sizes, seed)
             phase("train_bert_base", phase_train_bert_base, sizes, seed,
                   on_tpu)
-            phase("flash_two_widths", phase_flash_two_widths, sizes, seed,
-                  on_tpu)
+            phase("flash_two_widths", phase_flash_kernels, sizes["latent"],
+                  seed, on_tpu)
+            phase("flash_grouped", phase_flash_kernels, sizes["grouped"],
+                  seed, on_tpu)
             phase("routed_layer", phase_routed_layer, sizes, seed, on_tpu)
             net = phase("gluon_trainer", phase_gluon_trainer, sizes, seed)
             phase("serve", phase_serve, sizes, seed, net)

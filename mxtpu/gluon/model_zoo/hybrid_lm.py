@@ -1,0 +1,145 @@
+"""Decoder-only language model whose layers differ in kind: each layer
+names its sequence operator and its feed-forward by position.
+
+No reference counterpart. Pre-norm blocks, every norm an RMSNorm, no bias
+anywhere: ``h = x + op(norm1(x)); y = h + ffn(norm2(h))``.
+
+* ``op`` is one of :data:`OPERATORS`: ``conv`` (the gated short
+  convolution of LFM2, :class:`~mxtpu.gluon.nn.ShortConv`),
+  ``full_attention`` (:class:`GroupedQueryAttention`: causal attention of
+  ``num_heads`` query heads over ``num_kv_heads`` key/value heads, an
+  RMSNorm over each query and key head, rotary over the whole head) or
+  ``latent_attention`` (:class:`~mxtpu.gluon.model_zoo.latent_moe.
+  MultiHeadLatentAttention`);
+* ``ffn`` is a gated MLP in the first ``dense_layers`` blocks and a
+  :class:`~mxtpu.gluon.contrib.nn.RoutedMoE` after them (which may hold one
+  chip's share of each layer's experts);
+* a final norm and a vocabulary head, tied to the embedding by default.
+
+``HybridLM(layers=["conv", "full_attention", "conv", ...])`` is LFM2's
+stack (``model_type: lfm2_moe``); :class:`~mxtpu.gluon.model_zoo.latent_moe.
+LatentMoELM` is the same model with ``latent_attention`` in every layer.
+Trains under :class:`mxtpu.parallel.ShardedTrainStep`.
+"""
+from __future__ import annotations
+
+from ..block import HybridBlock
+from .. import nn
+
+__all__ = ["HybridLM", "DecoderBlock", "GroupedQueryAttention", "OPERATORS"]
+
+
+class GroupedQueryAttention(HybridBlock):
+    """Causal grouped-query attention (Ainslie et al., arXiv:2305.13245):
+    ``num_heads`` query heads of ``dim // num_heads`` read ``num_kv_heads``
+    key / value heads, query head ``j`` the head ``j // (num_heads /
+    num_kv_heads)``; queries and keys go through an RMSNorm over a head's
+    entries (one learned scale each) and rotary over the whole head before
+    the flash kernels, which take K and V at their own heads."""
+
+    def __init__(self, dim, num_heads, num_kv_heads, rope_theta=10000.0,
+                 epsilon=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % num_kv_heads:
+            raise ValueError("%d query heads do not divide over %d key/value"
+                             " heads" % (num_heads, num_kv_heads))
+        self._head_dim = head_dim = dim // num_heads
+        self._rope_theta = rope_theta
+        with self.name_scope():
+            self.q = nn.Dense(num_heads * head_dim, use_bias=False,
+                              flatten=False, prefix="q_")
+            self.k = nn.Dense(num_kv_heads * head_dim, use_bias=False,
+                              flatten=False, prefix="k_")
+            self.v = nn.Dense(num_kv_heads * head_dim, use_bias=False,
+                              flatten=False, prefix="v_")
+            self.q_norm = nn.RMSNorm(epsilon=epsilon, prefix="qnorm_")
+            self.k_norm = nn.RMSNorm(epsilon=epsilon, prefix="knorm_")
+            self.proj = nn.Dense(dim, use_bias=False, flatten=False,
+                                 prefix="proj_")
+
+    def hybrid_forward(self, F, x):
+        heads = (0, 0, -1, self._head_dim)        # [B, T, H, head_dim]
+        q = self.q_norm(F.reshape(self.q(x), shape=heads))
+        k = self.k_norm(F.reshape(self.k(x), shape=heads))
+        return self.proj(F._contrib_grouped_attention(
+            q, k, self.v(x), rope_theta=self._rope_theta))
+
+
+def _latent_attention(dim, **kwargs):
+    from .latent_moe import MultiHeadLatentAttention
+    return MultiHeadLatentAttention(dim, **kwargs)
+
+
+# kind of sequence operator -> (its block's constructor after ``dim``, the
+# prefix of its parameters in a decoder block)
+OPERATORS = {
+    "conv": (nn.ShortConv, "conv_"),
+    "full_attention": (GroupedQueryAttention, "attn_"),
+    "latent_attention": (_latent_attention, "attn_"),
+}
+
+
+class DecoderBlock(HybridBlock):
+    """``h = x + op(norm1(x)); y = h + ffn(norm2(h))``. ``operator`` is
+    ``(kind, keyword arguments)`` of one of :data:`OPERATORS`; ``ffn`` is a
+    gated MLP (``moe=None``) or routed experts (``moe``: the keyword
+    arguments of :class:`~mxtpu.gluon.contrib.nn.RoutedMoE` after
+    ``dim``)."""
+
+    def __init__(self, dim, operator, dense_hidden=0, moe=None,
+                 epsilon=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        kind, op_kwargs = operator
+        make, prefix = OPERATORS[kind]
+        with self.name_scope():
+            self.norm1 = nn.RMSNorm(epsilon=epsilon, prefix="norm1_")
+            self.op = make(dim, prefix=prefix, **op_kwargs)
+            self.norm2 = nn.RMSNorm(epsilon=epsilon, prefix="norm2_")
+            if moe is None:
+                self.ffn = nn.GatedMLP(dim, dense_hidden, prefix="mlp_")
+            else:
+                from ..contrib.nn import RoutedMoE
+                self.ffn = RoutedMoE(dim, prefix="moe_", **moe)
+
+    def hybrid_forward(self, F, x):
+        x = x + self.op(self.norm1(x))
+        return x + self.ffn(self.norm2(x))
+
+
+class HybridLM(HybridBlock):
+    """Embed -> one :class:`DecoderBlock` a layer -> RMSNorm -> vocabulary
+    head. Input: int token ids [B, T]; output: logits [B, T, vocab].
+
+    ``layers``: each layer's operator kind, in order (``layer_types`` of an
+    ``lfm2_moe`` configuration). ``operators``: kind -> the keyword
+    arguments of its block (:data:`OPERATORS`), e.g. ``{"conv":
+    {"kernel_size": 3}, "full_attention": {"num_heads": 32,
+    "num_kv_heads": 8, "rope_theta": 1e6, "epsilon": 1e-5}}``. The first
+    ``dense_layers`` blocks have a gated MLP of ``dense_hidden``, the rest
+    ``moe`` (``hidden, num_experts, top_k`` and optionally ``experts_held,
+    first_expert, scale, shared_hidden``). ``tie_head``: the head reads the
+    embedding's weight, whose gradient is the sum of both uses.
+    """
+
+    def __init__(self, vocab_size, dim, layers, operators, dense_hidden, moe,
+                 dense_layers=1, epsilon=1e-6, tie_head=True, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.embed = nn.Embedding(vocab_size, dim, prefix="wte_")
+            self.blocks = nn.HybridSequential(prefix="h_")
+            with self.blocks.name_scope():
+                for i, kind in enumerate(layers):
+                    self.blocks.add(DecoderBlock(
+                        dim, (kind, operators.get(kind, {})),
+                        dense_hidden=dense_hidden,
+                        moe=None if i < dense_layers else moe,
+                        epsilon=epsilon))
+            self.norm_f = nn.RMSNorm(epsilon=epsilon, prefix="normf_")
+            # tied: the head is a Dense over the embedding's own weight
+            self.head = nn.Dense(
+                vocab_size, use_bias=False, flatten=False,
+                **({"in_units": dim, "params": self.embed.params}
+                   if tie_head else {"prefix": "head_"}))
+
+    def hybrid_forward(self, F, tokens):
+        return self.head(self.norm_f(self.blocks(self.embed(tokens))))

@@ -24,8 +24,9 @@ from __future__ import annotations
 
 from ..block import HybridBlock
 from .. import nn
+from .hybrid_lm import HybridLM
 
-__all__ = ["LatentMoELM", "LatentMoEBlock", "MultiHeadLatentAttention"]
+__all__ = ["LatentMoELM", "MultiHeadLatentAttention"]
 
 
 class MultiHeadLatentAttention(HybridBlock):
@@ -63,34 +64,13 @@ class MultiHeadLatentAttention(HybridBlock):
         return self.proj(out)
 
 
-class LatentMoEBlock(HybridBlock):
-    """``h = x + attn(norm1(x)); y = h + ffn(norm2(h))``; ``ffn`` is a gated
-    MLP (``moe=None``) or routed experts (``moe``: the keyword arguments of
-    :class:`~mxtpu.gluon.contrib.nn.RoutedMoE` after ``dim``)."""
-
-    def __init__(self, dim, attention, dense_hidden=0, moe=None,
-                 epsilon=1e-6, **kwargs):
-        super().__init__(**kwargs)
-        with self.name_scope():
-            self.norm1 = nn.RMSNorm(epsilon=epsilon, prefix="norm1_")
-            self.attn = MultiHeadLatentAttention(dim, epsilon=epsilon,
-                                                 prefix="attn_", **attention)
-            self.norm2 = nn.RMSNorm(epsilon=epsilon, prefix="norm2_")
-            if moe is None:
-                self.ffn = nn.GatedMLP(dim, dense_hidden, prefix="mlp_")
-            else:
-                from ..contrib.nn import RoutedMoE
-                self.ffn = RoutedMoE(dim, prefix="moe_", **moe)
-
-    def hybrid_forward(self, F, x):
-        x = x + self.attn(self.norm1(x))
-        return x + self.ffn(self.norm2(x))
-
-
-class LatentMoELM(HybridBlock):
+class LatentMoELM(HybridLM):
     """Embed → ``dense_layers`` dense blocks → routed-expert blocks → RMSNorm
-    → vocabulary head. Input: int token ids [B, T]; output: logits [B, T,
-    vocab].
+    → vocabulary head (untied), latent attention in every layer: a
+    :class:`~mxtpu.gluon.model_zoo.hybrid_lm.HybridLM` of one operator
+    kind (each layer a :class:`~mxtpu.gluon.model_zoo.hybrid_lm.DecoderBlock`
+    whose ``op`` is a :class:`MultiHeadLatentAttention`). Input: int token
+    ids [B, T]; output: logits [B, T, vocab].
 
     ``attention``: ``num_heads, kv_rank, nope_dim, rope_dim, v_dim`` and
     optionally ``rope_theta, rope_interleave``. ``moe``: ``hidden,
@@ -100,19 +80,8 @@ class LatentMoELM(HybridBlock):
 
     def __init__(self, vocab_size, dim, num_layers, attention, dense_hidden,
                  moe, dense_layers=1, epsilon=1e-6, **kwargs):
-        super().__init__(**kwargs)
-        with self.name_scope():
-            self.embed = nn.Embedding(vocab_size, dim, prefix="wte_")
-            self.blocks = nn.HybridSequential(prefix="h_")
-            with self.blocks.name_scope():
-                for i in range(num_layers):
-                    self.blocks.add(LatentMoEBlock(
-                        dim, attention, dense_hidden=dense_hidden,
-                        moe=None if i < dense_layers else moe,
-                        epsilon=epsilon))
-            self.norm_f = nn.RMSNorm(epsilon=epsilon, prefix="normf_")
-            self.head = nn.Dense(vocab_size, use_bias=False, flatten=False,
-                                 prefix="head_")
-
-    def hybrid_forward(self, F, tokens):
-        return self.head(self.norm_f(self.blocks(self.embed(tokens))))
+        super().__init__(
+            vocab_size, dim, ["latent_attention"] * num_layers,
+            {"latent_attention": dict(attention, epsilon=epsilon)},
+            dense_hidden, moe, dense_layers=dense_layers, epsilon=epsilon,
+            tie_head=False, **kwargs)

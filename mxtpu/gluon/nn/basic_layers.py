@@ -6,7 +6,7 @@ from ...base import MXNetError
 from ..block import Block, HybridBlock
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
-           "InstanceNorm", "LayerNorm", "RMSNorm", "GatedMLP", "Embedding", "Flatten", "Lambda",
+           "InstanceNorm", "LayerNorm", "RMSNorm", "GatedMLP", "ShortConv", "Embedding", "Flatten", "Lambda",
            "HybridLambda", "HybridConcurrent", "Concurrent", "Identity"]
 
 
@@ -322,6 +322,27 @@ class GatedMLP(HybridBlock):
     def hybrid_forward(self, F, x):
         return self.down(F.Activation(self.gate(x),
                                       act_type=self._activation) * self.up(x))
+
+
+class ShortConv(HybridBlock):
+    """The gated short-convolution operator of LFM2 (Liquid AI,
+    ``model_type: lfm2``): ``(B, C, x) = split3(in_proj(u))``; ``y =
+    out_proj(C * conv(B * x))`` with ``conv`` a depthwise causal filter of
+    ``kernel_size`` taps along the sequence axis (-2). No bias, no
+    activation (op ``_contrib_short_conv``; no reference counterpart)."""
+
+    def __init__(self, units, kernel_size=3, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.weight = self.params.get("weight",
+                                          shape=(units, kernel_size))
+            self.in_proj = Dense(3 * units, use_bias=False, flatten=False,
+                                 prefix="in_")
+            self.out_proj = Dense(units, use_bias=False, flatten=False,
+                                  prefix="out_")
+
+    def hybrid_forward(self, F, x, weight):
+        return self.out_proj(F._contrib_short_conv(self.in_proj(x), weight))
 
 
 class Embedding(HybridBlock):

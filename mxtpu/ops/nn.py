@@ -557,6 +557,75 @@ def latent_attention(q, kv, k_rope, num_heads=1, nope_dim=128, rope_dim=64,
         return out.transpose(0, 2, 1, 3).reshape(b, t, h * v_dim)
 
 
+@register("_contrib_grouped_attention", aliases=("grouped_attention",))
+def grouped_attention(q, k, v, rope_theta=10000.0):
+    """Causal grouped-query attention (Ainslie et al., arXiv:2305.13245)
+    after its projections and per-head norms: ``q`` [B, T, H, D], ``k`` [B,
+    T, H_kv, D] and ``v`` [B, T, H_kv * D] (or [B, T, H_kv, D]), ``H`` a
+    multiple of ``H_kv``; query head ``j`` reads key/value head ``j // (H /
+    H_kv)``. Rotary over the whole head (halves rotated) turns q and k;
+    both flash kernels take K and V at their ``H_kv`` heads and fetch them
+    by group, so no copy of them at the query heads exists. Scores are
+    scaled by ``1/sqrt(D)``. Returns [B, T, H * D]."""
+    from .pallas import flash_attention
+    b, t, h, d = q.shape
+    with jax.named_scope("gqa_attention"):
+        q = rotary(q, rope_theta)
+        k = rotary(k, rope_theta)
+        v = v.reshape(b, t, k.shape[2], -1)
+        out = flash_attention(q.transpose(0, 2, 1, 3),
+                              k.transpose(0, 2, 1, 3),
+                              v.transpose(0, 2, 1, 3), True)    # [B, H, T, D]
+        return out.transpose(0, 2, 1, 3).reshape(b, t, h * v.shape[-1])
+
+
+def _short_conv_plain(data, weight):
+    d, taps = weight.shape
+    t = data.shape[-2]
+    f32 = jnp.float32
+    gate_in, gate_out, x = (data[..., i * d:(i + 1) * d].astype(f32)
+                            for i in range(3))
+    w = weight.astype(f32)
+    z = gate_in * x
+    # c[t] = sum_j w[:, j] z[t - (taps - 1) + j], z zero before the start:
+    # shifted multiply-adds, which XLA fuses with both gates into passes
+    # over [B, T, D] (no grouped convolution: PERF.md §6)
+    c = w[:, taps - 1] * z
+    for j in range(taps - 1):
+        back = taps - 1 - j
+        pad = [(0, 0)] * (z.ndim - 2) + [(back, 0), (0, 0)]
+        c = c + w[:, j] * jnp.pad(z, pad)[..., :t, :]
+    return (gate_out * c).astype(data.dtype)
+
+
+@jax.custom_vjp
+def _short_conv(data, weight):
+    return _short_conv_plain(data, weight)
+
+
+# the backward computes the gated products again from the projected input:
+# kept, they would be five float32 [B, T, D] arrays a layer
+_short_conv.defvjp(
+    lambda data, weight: (_short_conv_plain(data, weight), (data, weight)),
+    lambda kept, g: jax.vjp(_short_conv_plain, *kept)[1](g))
+
+
+@register("_contrib_short_conv", aliases=("short_conv",))
+def short_conv(data, weight):
+    """The gated short convolution of LFM2 (Liquid AI, ``model_type:
+    lfm2``) after its input projection: ``data`` [..., T, 3D] is ``(B | C |
+    x)``, ``weight`` [D, L] a depthwise causal filter of ``L`` taps;
+    ``y = C * conv_L(B * x)`` with ``conv_L(z)[t] = sum_j weight[:, j]
+    z[t - (L - 1) + j]`` and ``z`` zero before the sequence's start
+    (``torch.nn.Conv1d(D, D, L, groups=D, padding=L - 1)`` cut to T). No
+    activation, no bias. float32 arithmetic, one rounding to ``data``'s
+    dtype. Returns [..., T, D]."""
+    from .. import telemetry
+    telemetry.inc("short_conv.layers")
+    with jax.named_scope("short_conv"):
+        return _short_conv(data, weight)
+
+
 @register("InstanceNorm")
 def InstanceNorm(data, gamma, beta, eps=1e-3):
     """Ref: src/operator/instance_norm.cc (NCHW; normalize over spatial dims)."""

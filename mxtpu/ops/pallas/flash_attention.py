@@ -109,7 +109,7 @@ def _interpret():
 class _DispatchStatsView:
     """Read-only dict-shaped view over the telemetry counters."""
 
-    _KEYS = ("pallas", "xla", "fallback_reasons",
+    _KEYS = ("pallas", "xla", "fallback_reasons", "grouped", "kv_repeated",
              "bwd_pallas", "bwd_xla", "bwd_fallback_reasons", "block_pairs")
     _TAGGED = {"fallback_reasons": "pallas_flash.fallback",
                "bwd_fallback_reasons": "pallas_flash.bwd_fallback",
@@ -150,7 +150,7 @@ DISPATCH_STATS = _DispatchStatsView()
 
 def reset_dispatch_stats():
     from ... import telemetry
-    for name in ("pallas", "xla", "fallback",
+    for name in ("pallas", "xla", "fallback", "grouped", "kv_repeated",
                  "bwd_pallas", "bwd_xla", "bwd_fallback", "block_pairs"):
         telemetry.reset_metric("pallas_flash." + name)
 
@@ -167,8 +167,8 @@ def _xla_attention(q, k, v, causal, scale):
 
 
 def _xla_attention_lse(q, k, v, causal, scale):
-    """Fallback attention returning (out, lse) — ONE copy of the XLA math
-    (softmax(s) == exp(s - lse) exactly); differentiable directly."""
+    """Fallback (out, lse): ONE copy of the XLA math; differentiable."""
+    k, v = _repeat_kv(q, k, v)     # grouped heads: K, V at the query heads
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * scale
@@ -346,7 +346,7 @@ def _fwd_vmem(bq, bk, d, dv, itm):
 def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k):
     b, h, t, d = q.shape
     tk, dv = k.shape[2], v.shape[3]   # values may be narrower than keys
-    bh = b * h
+    bh, kv_head = b * h, _kv_head_map(_group(q, k))
     n_q = t // block_q
     n_k = tk // block_k
     _count_block_pairs(n_q, n_k, block_q, block_k, causal)
@@ -373,9 +373,9 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k):
             # K as the caller holds it: k q^T contracts both last axes on
             # the MXU, no transposed copy in HBM
             pl.BlockSpec((1, block_k, d),
-                         lambda b_, i, j: (b_, k_block(i, j), 0)),
+                         lambda b_, i, j: (kv_head(b_), k_block(i, j), 0)),
             pl.BlockSpec((1, block_k, dv),
-                         lambda b_, i, j: (b_, k_block(i, j), 0)),
+                         lambda b_, i, j: (kv_head(b_), k_block(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0)),
@@ -394,8 +394,51 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k):
         interpret=interpret,
         name="flash_attention_fwd",   # the kernel's name in a device trace
         **extra,
-    )(q.reshape(bh, t, d), k.reshape(bh, tk, d), v.reshape(bh, tk, dv))
+    )(q.reshape(bh, t, d), k.reshape(-1, tk, d), v.reshape(-1, tk, dv))
     return out.reshape(b, h, t, dv), lse
+
+
+def _group(q, k):
+    """Query heads a key/value head: ``q`` is [B, H_q, T, D], ``k`` (and
+    ``v``) [B, H_kv, T, D] with ``H_q`` a multiple of ``H_kv``; query head
+    ``j`` reads key/value head ``j // group`` (grouped-query attention,
+    Ainslie et al., arXiv:2305.13245)."""
+    h, hk = q.shape[1], k.shape[1]
+    if h % hk:
+        from ...base import MXNetError
+        raise MXNetError("flash_attention: %d query heads do not divide "
+                         "over %d key/value heads" % (h, hk))
+    return h // hk
+
+
+def _repeat_kv(q, k, v):
+    """K and V at the query heads, for the plain paths: a [B, H_q, T, *]
+    copy of each, which the kernels never make. Counted in
+    ``pallas_flash.kv_repeated``."""
+    group = _group(q, k)
+    if group == 1:
+        return k, v
+    from ... import telemetry
+    telemetry.inc("pallas_flash.kv_repeated")
+    return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+
+
+def _sum_group(dx, like):
+    """[B, H_q, T, D] gradients of repeated K or V -> [B, H_kv, T, D]."""
+    b, hk, t, d = like.shape
+    if dx.shape[1] == hk:
+        return dx
+    return jnp.sum(dx.reshape(b, hk, -1, t, d), axis=2)
+
+
+def _kv_head_map(group):
+    """Row of the flattened [B * H_kv, T, D] keys and values that row
+    ``b_`` of the flattened [B * H_q, T, D] queries reads: ``(b * H_q +
+    j) // group = b * H_kv + j // group``. K and V blocks are fetched by
+    it in the index maps, so no copy of them at the query heads exists."""
+    if group == 1:
+        return lambda b_: b_
+    return lambda b_: b_ // group
 
 
 @jax.named_scope("flash_attention_bwd")   # plain XLA: found by this scope
@@ -408,7 +451,8 @@ def _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale, block_k,
     d lse_i / d s_ik = P_ik, so it adds ``P * g_lse`` to ds).
     """
     f32 = jnp.float32
-    q32, k32, v32 = q.astype(f32), k.astype(f32), v.astype(f32)
+    k_rep, v_rep = _repeat_kv(q, k, v)     # grouped heads: the plain way
+    q32, k32, v32 = q.astype(f32), k_rep.astype(f32), v_rep.astype(f32)
     g32, out32 = g.astype(f32), out.astype(f32)
     t, tk = q.shape[2], k.shape[2]
     delta = jnp.sum(out32 * g32, axis=-1)            # [b, h, t]
@@ -443,28 +487,42 @@ def _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale, block_k,
     dq, (dk_blocks, dv_blocks) = jax.lax.scan(
         body, jnp.zeros_like(q32), jnp.arange(n_k))
     # scan stacks [n_k, b, h, bk, d] -> [b, h, tk, d]
-    dk = jnp.moveaxis(dk_blocks, 0, 2).reshape(k.shape)
-    dv = jnp.moveaxis(dv_blocks, 0, 2).reshape(v.shape)
+    dk = _sum_group(jnp.moveaxis(dk_blocks, 0, 2).reshape(k32.shape), k)
+    dv = _sum_group(jnp.moveaxis(dv_blocks, 0, 2).reshape(v32.shape), v)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
-                   *, scale, causal, block_q, block_k, n_q, n_k):
+                   *, scale, causal, block_q, block_k, n_q, n_k, group=1):
     """One (head, k block, q block) step of the flash backward. Works on
     the TRANSPOSED score tile ``s^T = k q^T`` [bk, bq]: dv and dk are then
     plain matmuls with the tile on the left, lse and delta broadcast along
     sublanes from [1, bq] rows, and only dq contracts the tile's first
     axis. dk/dv accumulate over the inner (q) axis; dq for the whole head
-    stays in VMEM while the k blocks pass."""
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    stays in VMEM while the k blocks pass.
+
+    ``group`` > 1 (grouped heads, the grid (key/value head, query head of
+    its group, k block, q block)): dk and dv of the WHOLE key/value head
+    stay in VMEM while its query heads pass, so they leave the kernel once,
+    at the key/value heads, summed over the group in float32."""
+    if group == 1:
+        ki, qi, kv = pl.program_id(1), pl.program_id(2), slice(None)
+
+        def in_head(gi, step):        # one query head a key/value head
+            return step
+    else:
+        head, ki, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+        kv = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+
+        def in_head(gi, step):        # ``step``, in the group's head ``gi``
+            return step & (head == gi)
     rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
 
-    @pl.when(qi == 0)
+    @pl.when(in_head(0, qi == 0))
     def _init_kv():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[kv] = jnp.zeros((block_k, dk_acc.shape[1]), jnp.float32)
+        dv_acc[kv] = jnp.zeros((block_k, dv_acc.shape[1]), jnp.float32)
 
     @pl.when(ki == 0)
     def _init_q():
@@ -481,18 +539,18 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         if causal:
             st = _causal_mask(st, qi, ki, block_q, block_k)
         pt = jnp.exp(st - lse_ref[0])             # P^T, lse as a [1, bq] row
-        dv_acc[:] += _dot(pt.astype(g.dtype), g, ((1,), (0,)))
+        dv_acc[kv] += _dot(pt.astype(g.dtype), g, ((1,), (0,)))
         dpt = _dot(v, g, ((1,), (1,)))            # dP^T [bk, bq]
         # ds^T without its factor ``scale``: that is applied once to the
         # [*, d] results instead of the [bk, bq] tile
         dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
-        dk_acc[:] += _dot(dst, q, ((1,), (0,)))
+        dk_acc[kv] += _dot(dst, q, ((1,), (0,)))
         dq_acc[rows, :] += _dot(dst, k, ((0,), (0,)))
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(in_head(group - 1, qi == n_q - 1))
     def _store_kv():
-        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0, kv] = (dk_acc[kv] * scale).astype(dk_ref.dtype)
+        dv_ref[0, kv] = dv_acc[kv].astype(dv_ref.dtype)
 
     @pl.when(ki == n_k - 1)
     def _store_q():
@@ -512,15 +570,16 @@ def _first_q_block(j, i, block_q, block_k, n_q):
 _VMEM_BUDGET = 64 * 1024 * 1024
 
 
-def _bwd_vmem(bq, bk, t, d, dv, itm):
+def _bwd_vmem(bq, bk, t, d, dv, itm, tk=0):
     """Bytes one grid step of the backward kernel holds, with dq of the
     whole head resident (``t`` rows). ``d`` is the width of queries and
-    keys, ``dv`` of values and the cotangent."""
+    keys, ``dv`` of values and the cotangent. ``tk``: the rows of dk and dv
+    resident where they are whole heads (grouped heads), else a k block."""
     dp, dvp = _lanes(d), _lanes(dv)
     return (2 * (bq + bk) * (dp + dvp) * itm     # q, g, k, v blocks (dbuf)
             + 2 * 2 * 8 * bq * 4                 # lse, delta rows (dbuf)
             + bq * bk * (4 * 4 + 2 * itm)        # s^T, P^T, dP^T, ds^T + casts
-            + bk * (dp + dvp) * (4 + 2 * itm)    # dk, dv: scratch + out (dbuf)
+            + (tk or bk) * (dp + dvp) * (4 + 2 * itm)  # dk, dv: scratch + out
             + t * dp * (4 + 2 * itm))            # dq of the head: scratch + out
 
 
@@ -554,9 +613,10 @@ def _resolve_bwd_blocks(q, k, v, block_q, block_k):
     whole head in VMEM (:func:`_bwd_vmem`)."""
     t, tk, d, dv = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
     itm = jnp.dtype(q.dtype).itemsize
+    whole = tk if _group(q, k) > 1 else 0
     return _tile_blocks(
         t, tk, block_q, block_k,
-        lambda bq, bk: _bwd_vmem(bq, bk, t, d, dv, itm),
+        lambda bq, bk: _bwd_vmem(bq, bk, t, d, dv, itm, whole),
         "dq of one head does not fit the VMEM budget")
 
 
@@ -580,21 +640,25 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
     d_out, dv_out = q.shape[3], v.shape[3]
     q, k, v, g = _pad_head_dim(q, k, v, g.astype(q.dtype))
     b, h, t, d = q.shape
-    tk, dv = k.shape[2], v.shape[3]
+    hk, tk, dv = k.shape[1], k.shape[2], v.shape[3]
+    group = _group(q, k)
+    grouped = group > 1
     bh = b * h
     n_q = t // block_q
     n_k = tk // block_k
     kernel = functools.partial(_fa_bwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, n_q=n_q,
-                               n_k=n_k)
+                               n_k=n_k, group=group)
     interpret = _interpret()
     extra = {}
     if not interpret:  # Mosaic-only hints: the interpreter takes none
         itm = jnp.dtype(q.dtype).itemsize
         extra["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel",) + ("arbitrary",) * (
+                3 if grouped else 2),
             vmem_limit_bytes=_vmem_limit(
-                _bwd_vmem(block_q, block_k, t, d, dv, itm)))
+                _bwd_vmem(block_q, block_k, t, d, dv, itm,
+                          tk if grouped else 0)))
 
     def q_block(j, i):
         # causal: the q blocks above a k block's diagonal compute nothing,
@@ -604,40 +668,62 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
             return i
         return _first_q_block(j, i, block_q, block_k, n_q)
 
-    q_spec = pl.BlockSpec((1, block_q, d),
-                          lambda b_, j, i: (b_, q_block(j, i), 0))
-    g_spec = pl.BlockSpec((1, block_q, dv),
-                          lambda b_, j, i: (b_, q_block(j, i), 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
-    v_spec = pl.BlockSpec((1, block_k, dv), lambda b_, j, i: (b_, j, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q),
-                            lambda b_, j, i: (b_, 0, q_block(j, i)))
-    dq, dk, dv = pl.pallas_call(
+    # the grid's axes -> (query head, key/value head, k block, q block)
+    if grouped:
+        # a key/value head's query heads on a sequential axis: dk, dv of
+        # the whole head wait in VMEM for all of them, so they leave at the
+        # key/value heads, summed in float32 (as float32 partials a query
+        # head summed by XLA the call read 1.05 ms longer at the lfm2
+        # cell's shape, 18.58 against 19.63: PERF.md §6, PR 30)
+        def at(c, gi, j, i):
+            return c * group + gi, c, j, i
+        grid = (b * hk, group, n_k, n_q)
+        kv_rows = tk
+    else:
+        def at(b_, j, i):
+            return b_, b_, j, i
+        grid = (bh, n_k, n_q)
+        kv_rows = block_k
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda *ids: index(*at(*ids)))
+
+    q_spec = spec((1, block_q, d), lambda hq, hkv, j, i: (hq, q_block(j, i), 0))
+    g_spec = spec((1, block_q, dv),
+                  lambda hq, hkv, j, i: (hq, q_block(j, i), 0))
+    k_spec = spec((1, block_k, d), lambda hq, hkv, j, i: (hkv, j, 0))
+    v_spec = spec((1, block_k, dv), lambda hq, hkv, j, i: (hkv, j, 0))
+    row_spec = spec((1, 1, block_q),
+                    lambda hq, hkv, j, i: (hq, 0, q_block(j, i)))
+    # dk, dv: the k block of the step, or the key/value head whole
+    kv_block = (lambda hq, hkv, j, i: (hkv, 0, 0)) if grouped else (
+        lambda hq, hkv, j, i: (hkv, j, 0))
+    dq, dk, dv_ = pl.pallas_call(
         kernel,
-        grid=(bh, n_k, n_q),
+        grid=grid,
         in_specs=[q_spec, k_spec, v_spec, g_spec, row_spec, row_spec],
         out_specs=[
-            pl.BlockSpec((1, t, d), lambda b_, j, i: (b_, 0, 0)),
-            k_spec,
-            v_spec,
+            spec((1, t, d), lambda hq, hkv, j, i: (hq, 0, 0)),
+            spec((1, kv_rows, d), kv_block),
+            spec((1, kv_rows, dv), kv_block),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, dv), v.dtype),
+            jax.ShapeDtypeStruct((b * hk, tk, d), k.dtype),
+            jax.ShapeDtypeStruct((b * hk, tk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((t, d), f32),          # dq of the head
-            pltpu.VMEM((block_k, d), f32),    # dk of the k block
-            pltpu.VMEM((block_k, dv), f32),   # dv of the k block
+            pltpu.VMEM((kv_rows, d), f32),    # dk of the k block (or head)
+            pltpu.VMEM((kv_rows, dv), f32),   # dv of the k block (or head)
         ],
         interpret=interpret,
         name="flash_attention_bwd",   # the kernel's name in a device trace
         **extra,
-    )(q.reshape(bh, t, d), k.reshape(bh, tk, d), v.reshape(bh, tk, dv),
+    )(q.reshape(bh, t, d), k.reshape(-1, tk, d), v.reshape(-1, tk, dv),
       g.reshape(bh, t, dv), lse.reshape(bh, 1, t), delta.reshape(bh, 1, t))
     return (dq.reshape(q.shape)[..., :d_out], dk.reshape(k.shape)[..., :d_out],
-            dv.reshape(v.shape)[..., :dv_out])
+            dv_.reshape(v.shape)[..., :dv_out])
 
 
 def _pick_block(n, want, mult):
@@ -797,6 +883,9 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
 def _fa_lse_fwd_impl(q, k, v, causal, scale, block_q, block_k):
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
+    if _group(q, k) > 1:
+        from ... import telemetry
+        telemetry.inc("pallas_flash.grouped")
     blocks = _resolve_blocks(q, k, v, block_q, block_k)
     if blocks is None:
         out, lse = _xla_attention_lse(q, k, v, causal, scale)

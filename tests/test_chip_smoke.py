@@ -45,9 +45,13 @@ def test_rehearsal_one_chip_phases(tmp_path):
     phases = _phases(proc.stdout)
     assert list(phases) == ["device", "sync", "train_resnet50",
                             "train_bert_base", "flash_two_widths",
-                            "routed_layer", "gluon_trainer", "serve",
-                            "warm_start", "total"]
+                            "flash_grouped", "routed_layer", "gluon_trainer",
+                            "serve", "warm_start", "total"]
     assert max(phases["flash_two_widths"]["gaps"].values()) <= 2e-2
+    assert max(phases["flash_grouped"]["gaps"].values()) <= 2e-2
+    # off the chip the plain path runs, which repeats K and V and says so
+    assert phases["flash_grouped"]["pallas_flash"]["grouped"] == 1
+    assert phases["flash_grouped"]["pallas_flash"]["kv_repeated"] > 0
     rows = phases["routed_layer"]["rows"]
     assert 0 < rows["live"] <= rows["run"] < rows["total"]
     assert max(phases["routed_layer"]["gaps"].values()) <= 3e-2

@@ -983,6 +983,9 @@ COVERED_ELSEWHERE = {
     "_contrib_rotary_embedding": "test_latent_moe.py",
     "_contrib_latent_attention": "test_latent_moe.py",
     "_contrib_routed_moe": "test_latent_moe.py",
+    # the hybrid stack's operators: against the plain reference
+    "_contrib_short_conv": "test_short_conv.py",
+    "_contrib_grouped_attention": "test_hybrid_lm.py",
     "CTCLoss": "test_ctc.py",
     "Custom": "test_custom_op.py",
     "RNN": "test_operator.py",

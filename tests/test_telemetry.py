@@ -272,8 +272,11 @@ def test_dispatch_stats_is_view_over_registry():
     assert fa.DISPATCH_STATS["fallback_reasons"] == \
         telemetry.tagged("pallas_flash.fallback")
     assert set(fa.DISPATCH_STATS.keys()) == \
-        {"pallas", "xla", "fallback_reasons",
+        {"pallas", "xla", "fallback_reasons", "grouped", "kv_repeated",
          "bwd_pallas", "bwd_xla", "bwd_fallback_reasons", "block_pairs"}
+    # equal heads: not a grouped call, and nothing was repeated
+    assert fa.DISPATCH_STATS["grouped"] == 0
+    assert fa.DISPATCH_STATS["kv_repeated"] == 0
     # a forward that fell back counted no block pairs
     assert fa.DISPATCH_STATS["block_pairs"] == {}
     fa.reset_dispatch_stats()

@@ -178,6 +178,32 @@ def test_flash_two_widths_compile_to_mosaic(one_chip, as_tpu):
     assert stats["xla"] == 0 and stats["bwd_xla"] == 0
 
 
+# grouped heads at the lfm2_8b_a1b cell's shape: two sequences, 32 query
+# heads over 8 key/value heads of 64 (padded to the 128 lanes), 8,192
+# causal positions; dk and dv of a whole key/value head wait in VMEM
+def test_flash_grouped_heads_compile_to_mosaic(one_chip, as_tpu):
+    q = _spec((2, 32, 8192, 64), one_chip)
+    kv = _spec((2, 8, 8192, 64), one_chip)
+    fa.reset_dispatch_stats()
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, True).astype(
+            jnp.float32) ** 2)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") >= 2
+    # K and V enter both kernels padded at their own 8 heads (16 rows
+    # flattened); nothing of 64 rows flattened is float32 (no partials a
+    # query head), and K's and V's gradients leave at 8 heads
+    assert "bf16[16,8192,128]" in text
+    assert "f32[64,8192,128]" not in text
+    assert "bf16[2,8,8192,64]" in text
+    stats = dict(fa.DISPATCH_STATS.items())
+    assert (stats["pallas"], stats["bwd_pallas"]) == (1, 1)
+    assert stats["xla"] == 0 and stats["bwd_xla"] == 0
+    assert stats["grouped"] == 1 and stats["kv_repeated"] == 0
+
+
 def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
     """The expert layer at the cell's widths (16 of 128 experts held, 6
     choices a token, 8,192 tokens): XLA:TPU takes ``ragged_dot`` as its
