@@ -343,9 +343,13 @@ def phase_routed_layer(sizes, seed, on_tpu):
     every token by every expert held. On the chip the device's memory is
     filled with NaN and freed first: the grouped kernel leaves the rows
     past the last group unwritten, and whatever they hold must not reach a
-    result. Prints the plan: rows live / run / laid out at most."""
+    result. Prints the plan (rows live / run / laid out at most) and what
+    the traced gradient counted: the bytes the forward keeps for the
+    backward (its two up products at all T*k rows) and the grouped products
+    of one backward branch (six: none computed again)."""
     import jax
     import jax.numpy as jnp
+    from mxtpu import telemetry
     from mxtpu.parallel import moe
     n = sizes["routed"]
     if on_tpu:      # NaN over half of what is free, a GiB at a time
@@ -378,7 +382,16 @@ def phase_routed_layer(sizes, seed, on_tpu):
         return (out,) + g
 
     f32 = lambda a: a.astype(jnp.float32)
-    got, want = grads(True), grads(False)
+    counters = ("moe.kept_bytes", "moe.bwd_products")
+    for name in counters:
+        telemetry.reset_metric(name)
+    got = grads(True)
+    kept = {name: telemetry.value(name) for name in counters}
+    _check(kept["moe.bwd_products"] == 6 and kept["moe.kept_bytes"]
+           == 2 * n["tokens"] * n["top_k"] * n["width"]
+           * experts[0].dtype.itemsize,
+           "the routed layer's backward traced %s" % kept)
+    want = grads(False)
     _check(all(bool(jnp.all(jnp.isfinite(f32(a)))) for a in got),
            "the routed layer's grouped path gave a value that is not finite")
     gaps = {name: float(jnp.linalg.norm(f32(a) - f32(b))
@@ -397,7 +410,8 @@ def phase_routed_layer(sizes, seed, on_tpu):
         r for r in plan.rungs if r >= rows["live"]),
         "the plan runs %s of the rungs %s" % (rows, plan.rungs))
     return {"shape": n, "rows": rows, "rungs": list(plan.rungs),
-            "gaps": gaps}
+            "kept_bytes": kept["moe.kept_bytes"],
+            "bwd_products": kept["moe.bwd_products"], "gaps": gaps}
 
 
 def _gluon_loop(sizes, seed, mesh=None):

@@ -26,6 +26,14 @@ not all T*k: the held part exists at a short ladder of static row counts
 and a ``lax.switch`` runs the lowest that holds the step's live rows
 (:func:`piece_plan` is that decision, public and pure); the last rung is
 all T*k rows, so the worst case still runs and no shape is dynamic.
+
+Under differentiation the forward switch keeps its rung's two up products
+(``xs w_gate`` and ``xs w_up``, in the weights' dtype) for a hand-written
+backward switch that computes no grouped product twice: six a branch. A
+switch's results have one shape whatever branch ran, so the kept pair is
+laid out once, at the last rung's ``[T*k, F]``, and a lower rung fills its
+first rows and zero-fills the rest; everything else the backward reads
+(the gathered rows, ``silu * up``) it makes again from x and the pair.
 """
 from __future__ import annotations
 
@@ -167,18 +175,30 @@ def piece_plan(idx, first_expert, held, total):
     return PiecePlan(order, sizes, n_live, rung, rungs)
 
 
-def _grouped(xs, w, sizes):
-    """``xs[rows of group e] @ w[e]`` for every group, rows sorted by
-    group: ``jax.lax.ragged_dot``, which XLA:TPU lowers to one grouped
-    matmul kernel that visits only the row tiles the groups cover, under
-    the framework's MXU policy (``contract_acc``). Rows past the last group
-    cost nothing and are NOT WRITTEN, in the product and in both
-    transposes: they hold what the memory held (NaN, on a chip that has run
-    anything else), so a caller selects them away (``where``) before any
-    arithmetic and never multiplies them by zero."""
+# the grouped product over sorted rows, and the one over the sorted rows as
+# the contracted dimension that gives a weight's gradient: the two forms
+# XLA:TPU has a grouped kernel for
+_ROWS = jax.lax.RaggedDotDimensionNumbers(        # a[rows of e] @ b[e]
+    (((1,), (1,)), ((), ())), lhs_ragged_dimensions=(0,),
+    rhs_group_dimensions=(0,))
+_WEIGHTS = jax.lax.RaggedDotDimensionNumbers(     # a[rows of e].T @ b[rows of e]
+    (((0,), (0,)), ((), ())), lhs_ragged_dimensions=(0,),
+    rhs_group_dimensions=())
+
+
+def _grouped(a, b, sizes, dims=_ROWS):
+    """``a[rows of group e] @ b[e]`` for every group, rows sorted by group
+    (with ``_WEIGHTS``, every group's ``a[rows].T @ b[rows]``):
+    ``ragged_dot_general``, which XLA:TPU lowers to one grouped matmul
+    kernel that visits only the row tiles the groups cover, under the
+    framework's MXU policy (``contract_acc``). Rows past the last group
+    cost nothing and are NOT WRITTEN: they hold what the memory held (NaN,
+    on a chip that has run anything else), so a caller selects them away
+    (``where``) before any arithmetic and never multiplies them by zero."""
     from ..ops.precision_util import contract_acc
     return contract_acc(
-        lambda a, b, **kw: jax.lax.ragged_dot(a, b, sizes, **kw), xs, w)
+        lambda a, b, **kw: jax.lax.ragged_dot_general(a, b, sizes, dims, **kw),
+        a, b)
 
 
 def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
@@ -222,7 +242,10 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
 
     Counted at trace time (``telemetry``), beside ``moe.layers``:
     ``moe.rows_total`` (T*k, what the grouped path lays out at most) and
-    ``moe.piece_rows`` (its lowest rung, what it lays out at least).
+    ``moe.piece_rows`` (its lowest rung, what it lays out at least); under
+    differentiation ``moe.kept_bytes`` (what the forward keeps for the
+    backward: :func:`_held_part`) and ``moe.bwd_products`` (the grouped
+    products of one backward branch).
     """
     from .. import telemetry
     t, d = x.shape
@@ -261,39 +284,101 @@ def _expert(xs, e_gate, e_up, e_down, mm):
     return mm(jax.nn.silu(mm(xs, e_gate)) * mm(xs, e_up), e_down)
 
 
-@jax.custom_vjp
-def _token_rows(x, tok):
-    """``x[tok]``; its transpose sums each token's rows in float32 and
-    rounds once (a scatter-add in ``x``'s bf16 would round after every
-    one of a token's up to k rows)."""
-    return x[tok]
+def _sum_by_token(rows, tok, tokens):
+    """(tokens, D) float32: each token's rows of ``rows`` summed in float32
+    (a scatter-add in bf16 would round after every one of a token's up to k
+    rows)."""
+    return jnp.zeros((tokens,) + rows.shape[1:], jnp.float32).at[tok].add(
+        rows.astype(jnp.float32))
 
 
-_token_rows.defvjp(
-    lambda x, tok: (x[tok], (tok, x.shape[0])),
-    lambda kept, g: (jnp.zeros((kept[1],) + g.shape[1:], jnp.float32)
-                     .at[kept[0]].add(g.astype(jnp.float32)).astype(g.dtype),
-                     None))
+def _rows_here(rows, top_k, x, w, order, sizes):
+    """The first ``rows`` sorted pairs: their (token, slot) ids, tokens, the
+    mask of the live ones (rows, 1), their tokens' rows of x with the rows
+    past the live ones zero, and the router's weights (rows, 1)."""
+    pairs = order[:rows]
+    tok = pairs // top_k
+    live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+    w_row = w.reshape(-1).at[pairs].get(unique_indices=True)[:, None]
+    return pairs, tok, live, jnp.where(live, x[tok], 0), w_row
 
 
-def _held_rows(rows, top_k, x, w, w_gate, w_up, w_down, order, sizes):
+def _held_rows(rows, top_k, keep, x, w, w_gate, w_up, w_down, order, sizes):
     """The held experts' part over the first ``rows`` sorted pairs (every
-    live one is among them): (T, D) float32."""
+    live one is among them): (T, D) float32; with ``keep`` also the two up
+    products, padded to all T*k rows, for :func:`_held_rows_bwd`."""
     with jax.named_scope("moe.dispatch"):
-        pairs = order[:rows]
-        tok = pairs // top_k
-        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
-        xs = jnp.where(live, _token_rows(x, tok), 0)
+        _, tok, live, xs, w_row = _rows_here(rows, top_k, x, w, order, sizes)
     with jax.named_scope("moe.experts"):
-        ys = _expert(xs, w_gate, w_up, w_down,
-                     lambda a, b: _grouped(a, b, sizes))
+        gate = _grouped(xs, w_gate, sizes)
+        up = _grouped(xs, w_up, sizes)
+        ys = _grouped(jax.nn.silu(gate) * up, w_down, sizes)
     with jax.named_scope("moe.combine"):
         # float32 under the router's weights, summed by token. A row past
         # the live ones holds nothing defined: it is zeroed BEFORE it meets
-        # its weight, or the weight's gradient would be 0 * whatever it held
-        w_row = w.reshape(-1).at[pairs].get(unique_indices=True)[:, None]
+        # its weight (0 * NaN is NaN)
         y = w_row * jnp.where(live, ys, 0).astype(jnp.float32)
-        return jnp.zeros(x.shape, jnp.float32).at[tok].add(y)
+        out = _sum_by_token(y, tok, x.shape[0])
+    if not keep:
+        return out
+    tail = ((0, order.shape[0] - rows), (0, 0))
+    return out, jnp.pad(gate, tail), jnp.pad(up, tail)
+
+
+def _held_rows_bwd(rows, top_k, g, gate, up, x, w, w_gate, w_up, w_down,
+                   order, sizes):
+    """The transpose of :func:`_held_rows` at ``rows`` rows over the up
+    products its forward kept: the cotangents of x, w and the three expert
+    leaves from ``g``, (T, D) float32, in six grouped products. The
+    router weight's gradient ``<ys, gy>`` is had as ``<h, gy w_down^T>``,
+    the product the backward makes anyway, so ``ys = h w_down`` is never
+    formed again. Every row past the live ones is selected away before
+    any arithmetic, in what was kept too: the grouped kernel wrote none."""
+    from .. import telemetry
+    f32 = jnp.float32
+    last = rows == order.shape[0]       # the rung every ladder has
+
+    def mm(a, b, dims=_ROWS):
+        if last:
+            telemetry.inc("moe.bwd_products")
+        return _grouped(a, b, sizes, dims)
+
+    def back(a, b):         # a[rows of e] @ b[e].T
+        return jnp.where(live, mm(a, jnp.swapaxes(b, 1, 2)), 0).astype(f32)
+
+    with jax.named_scope("moe.dispatch"):
+        pairs, tok, live, xs, w_row = _rows_here(rows, top_k, x, w, order,
+                                                 sizes)
+        gy = jnp.where(live, g[tok], 0)
+    with jax.named_scope("moe.experts"):
+        gate = jnp.where(live, gate[:rows], 0)
+        up = jnp.where(live, up[:rows], 0)
+        dt = gate.dtype
+        h = jax.nn.silu(gate) * up
+        t = back(gy.astype(dt), w_down)
+        d_w_row = jnp.sum(h.astype(f32) * t, axis=-1)
+        dw_down = mm(h, (w_row * gy).astype(dt), _WEIGHTS)
+        dh, gate32 = w_row * t, gate.astype(f32)
+        sig = jax.nn.sigmoid(gate32)
+        d_gate = (dh * up.astype(f32) * sig
+                  * (1 + gate32 * (1 - sig))).astype(dt)
+        d_up = (dh * gate32 * sig).astype(dt)
+        dxs = back(d_gate, w_gate) + back(d_up, w_up)
+        dw_gate, dw_up = mm(xs, d_gate, _WEIGHTS), mm(xs, d_up, _WEIGHTS)
+    with jax.named_scope("moe.dispatch"):
+        dx = _sum_by_token(dxs, tok, x.shape[0]).astype(x.dtype)
+    with jax.named_scope("moe.combine"):
+        dw = jnp.zeros(w.size, w.dtype).at[pairs].set(
+            d_w_row.astype(w.dtype), unique_indices=True).reshape(w.shape)
+    return (dx, dw, dw_gate.astype(w_gate.dtype), dw_up.astype(w_up.dtype),
+            dw_down.astype(w_down.dtype))
+
+
+def _switch(rung, rungs, part, static, *operands):
+    """``part(rows, *static, *operands)`` at ``rows = rungs[rung]``."""
+    return jax.lax.switch(
+        rung, [functools.partial(part, rows, *static) for rows in rungs],
+        *operands)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -302,31 +387,35 @@ def _held_part(top_k, rungs, x, w, w_gate, w_up, w_down, order, sizes, rung):
     ``lax.switch`` over the static counts, so a step pays for the rung its
     live rows fit and the worst case (every pair live) still runs them all.
 
-    The backward pass is a second switch that computes the chosen rung's
-    part again from x, the weights and the plan, then its transpose.
-    Nothing but those inputs is kept: what one rung would keep has another
-    shape in the next, and a residual that crosses the switch would be laid
-    out (and zero-filled) at every rung's size, the largest included."""
-    return jax.lax.switch(
-        rung, [functools.partial(_held_rows, rows, top_k) for rows in rungs],
-        x, w, w_gate, w_up, w_down, order, sizes)
+    Under differentiation the forward switch also returns its rung's two up
+    products, ``xs w_gate`` and ``xs w_up``, in the weights' dtype, and the
+    backward is a second switch over :func:`_held_rows_bwd`, written by
+    hand so that no grouped product is computed twice (six a branch; the
+    transpose of a recomputed forward is nine). A switch's result has ONE
+    shape, whichever branch ran, so the pair is laid out at the last rung's
+    ``[T*k, F]``: a lower rung fills its first ``rows`` rows and zero-fills
+    the rest, at most one write of the pair. That is why only the pair
+    crosses (2F a row, bf16 in the cells): the gathered rows, ``silu * up``
+    and everything float32 or D wide are cheaper to make again in the
+    branch than to lay out at T*k rows. Without a gradient nothing is kept.
+    Counted at trace time: ``moe.kept_bytes``, ``moe.bwd_products``."""
+    return _switch(rung, rungs, _held_rows, (top_k, False),
+                   x, w, w_gate, w_up, w_down, order, sizes)
 
 
 def _held_part_fwd(top_k, rungs, *args):
-    return _held_part(top_k, rungs, *args), args
-
-
-def _held_rows_transposed(rows, top_k, g, order, sizes, *operands):
-    return jax.vjp(lambda *a: _held_rows(rows, top_k, *a, order, sizes),
-                   *operands)[1](g)
+    from .. import telemetry
+    *operands, rung = args
+    out, gate, up = _switch(rung, rungs, _held_rows, (top_k, True),
+                            *operands)
+    telemetry.inc("moe.kept_bytes", gate.nbytes + up.nbytes)
+    return out, (gate, up) + args
 
 
 def _held_part_bwd(top_k, rungs, kept, g):
-    *operands, order, sizes, rung = kept
-    return jax.lax.switch(
-        rung, [functools.partial(_held_rows_transposed, rows, top_k)
-               for rows in rungs],
-        g, order, sizes, *operands) + (None, None, None)
+    *operands, rung = kept
+    return _switch(rung, rungs, _held_rows_bwd, (top_k,),
+                   g, *operands) + (None, None, None)
 
 
 _held_part.defvjp(_held_part_fwd, _held_part_bwd)

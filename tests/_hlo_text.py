@@ -28,6 +28,21 @@ def computations(text):
     return out
 
 
+def conditionals(text):
+    """The ``conditional`` instruction lines of the module."""
+    return [line for lines in computations(text).values() for line in lines
+            if " conditional(" in line]
+
+
+def grouped_kernels(text):
+    """XLA:TPU's grouped matmul kernels (``ragged-dot`` custom calls, their
+    metadata call apart) in each computation that holds any, sorted."""
+    counts = (sum("custom-call(" in line and "ragged-dot" in line
+                  and "ragged-dot-metadata" not in line for line in lines)
+              for lines in computations(text).values())
+    return sorted(n for n in counts if n)
+
+
 def _names(found):
     return [n.strip().lstrip("%") for n in found.split(",") if n.strip()]
 

@@ -378,59 +378,132 @@ def test_piece_plan_against_a_count(small_tile, seed, held, first):
     assert int(plan.rows) == min(r for r in plan.rungs if r >= live.size)
 
 
+def _dead_rows_poisoned(a, n_live):
+    dead = jnp.arange(a.shape[0])[:, None] >= n_live
+    return jnp.where(dead, jnp.nan, a)
+
+
+@pytest.mark.parametrize("poisoned", ["products", "kept"])
 @pytest.mark.parametrize("case", ["none", "eighth", "on_the_edge"])
 def test_rows_past_the_last_group_hold_nothing_defined(small_tile,
-                                                       monkeypatch, case):
+                                                       monkeypatch, case,
+                                                       poisoned):
     """XLA:TPU's grouped kernel leaves the rows past the last group
-    unwritten, forward and transposed (on the chip they held NaN once
-    other buffers had used the memory; XLA:CPU zeroes them). With those
-    rows poisoned in both directions the layer gives what it gave."""
+    unwritten, in the product and in its transpose (on the chip they held
+    NaN once other buffers had used the memory; XLA:CPU zeroes them). With
+    those rows poisoned in every grouped result, forward and backward, the
+    layer gives what it gave; and so it does with every row past the live
+    ones of the KEPT products poisoned between forward and backward, the
+    zero-filled tail included: the backward selects before it multiplies."""
     n_live, held, first, _ = LIVE[case]
     x, leaves = _steered(n_live, held, first, jnp.float32)
     want = _routed_grads(x, leaves, held, first, True)
-    grouped = moe._grouped
+    if poisoned == "products":
+        grouped = moe._grouped
 
-    def poison(a, sizes):
-        dead = jnp.arange(a.shape[0])[:, None] >= jnp.sum(sizes)
-        return jnp.where(dead, jnp.nan, a)
+        def poison(a, b, sizes, *dims):
+            out = grouped(a, b, sizes, *dims)   # (rows, .) or (E, K, N)
+            return _dead_rows_poisoned(out, jnp.sum(sizes)) \
+                if out.ndim == 2 else out
 
-    @jax.custom_vjp
-    def poisoned(xs, w, sizes):
-        return poison(grouped(xs, w, sizes), sizes)
+        monkeypatch.setattr(moe, "_grouped", poison)
+    else:
+        backward = moe._held_rows_bwd
 
-    def fwd(xs, w, sizes):
-        return poisoned(xs, w, sizes), (xs, w, sizes)
+        def poison(rows, top_k, g, gate, up, *operands):
+            n = jnp.sum(operands[-1])
+            assert gate.shape == up.shape == (T_ * K, F_)
+            return backward(rows, top_k, g, _dead_rows_poisoned(gate, n),
+                            _dead_rows_poisoned(up, n), *operands)
 
-    def bwd(kept, g):
-        xs, w, sizes = kept
-        dxs, dw = jax.vjp(lambda a, b: grouped(a, b, sizes), xs, w)[1](g)
-        return poison(dxs, sizes), dw, None
-
-    poisoned.defvjp(fwd, bwd)
-    monkeypatch.setattr(moe, "_grouped", poisoned)
+        monkeypatch.setattr(moe, "_held_rows_bwd", poison)
     got = _routed_grads(x, leaves, held, first, True)
     for a, b in zip(got, want):
         assert bool(jnp.all(jnp.isfinite(a)))
         assert _gap(a, b) <= 1e-6 or not np.any(np.asarray(b))
 
 
-def test_no_array_of_every_pair_outside_the_switch(small_tile):
-    """The guard that the dead rows do not come back: in the compiled
-    gradient of the layer at held < total, every array of T*k rows by D (or
-    by the experts' width) lives under the conditional's last branch."""
-    from _hlo_text import arrays_outside_control_flow, computations
-    x, leaves = _steered(24, HELD, FIRST, jnp.float32)
+def _held_operands(case, dtype):
+    """The operands of the held part for a case of ``LIVE``: (x, w, the
+    three expert leaves, order, sizes), the rung's rows, and a cotangent."""
+    n_live, held, first, rows = LIVE[case]
+    x, leaves = _steered(n_live, held, first, jnp.dtype(dtype))
+    idx, w = moe.route_top_k(x, leaves[0], leaves[1], K, 2.448)
+    plan = moe.piece_plan(idx, first, held, E)
+    assert int(plan.rows) == rows
+    experts = tuple(a[first:first + held].astype(x.dtype)
+                    for a in leaves[2:5])
+    g = jax.random.normal(jax.random.PRNGKey(13), x.shape, jnp.float32)
+    return (x, w) + experts + (plan.order, plan.sizes), rows, g
 
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(LIVE))
+def test_the_hand_written_backward_is_the_transpose(small_tile, case, dtype):
+    """``_held_rows_bwd`` over the two products the forward kept, against
+    ``jax.vjp`` of ``_held_rows`` at the same rung: the cotangents of x, the
+    router's weights and the three expert leaves, at every share of live
+    rows; what is kept is the rung's two up products in the leaves' dtype,
+    at all T*k rows, zero past the rung."""
+    (*diff, order, sizes), rows, g = _held_operands(case, dtype)
+    out, gate, up = moe._held_rows(rows, K, True, *diff, order, sizes)
+    want_out, transpose = jax.vjp(
+        lambda *a: moe._held_rows(rows, K, False, *a, order, sizes), *diff)
+    assert _gap(out, want_out) <= 1e-6 or not np.any(np.asarray(want_out))
+    for kept in (gate, up):
+        assert kept.shape == (T_ * K, F_) and kept.dtype == jnp.dtype(dtype)
+        assert not np.any(np.asarray(kept[rows:], np.float32))
+    got = moe._held_rows_bwd(rows, K, g, gate, up, *diff, order, sizes)
+    tol = 1e-5 if dtype == "float32" else 3e-2
+    for a, b, operand in zip(got, transpose(g), diff):
+        assert a.shape == operand.shape and a.dtype == operand.dtype
+        assert bool(jnp.all(jnp.isfinite(a.astype(jnp.float32))))
+        assert _gap(a, b) <= tol or not np.any(np.asarray(b, np.float32))
+
+
+def _layer_loss(bias):
     def loss(x, router, eg, eu, ed):
-        return jnp.sum(moe.routed_ffn(x, router, leaves[1], eg, eu, ed,
-                                      top_k=K, first_expert=FIRST))
+        return jnp.sum(moe.routed_ffn(x, router, bias, eg, eu, ed, top_k=K,
+                                      first_expert=FIRST)
+                       .astype(jnp.float32))
+    return loss
 
-    args = (x, leaves[0]) + tuple(w[FIRST:FIRST + HELD] for w in leaves[2:5])
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_only_the_two_kept_products_leave_the_switch(small_tile, dtype):
+    """The guard that the dead rows do not come back: in the compiled
+    gradient of the layer at held < total, the arrays of T*k rows outside
+    control flow are the two kept products, [T*k, F] in the weights' dtype
+    (the forward conditional's results, the backward's operands); none is
+    [T*k, D], and in bf16 none is float32."""
+    from _hlo_text import arrays_outside_control_flow, conditionals
+    x, leaves = _steered(24, HELD, FIRST, jnp.dtype(dtype))
+    args = (x, leaves[0]) + tuple(w[FIRST:FIRST + HELD].astype(x.dtype)
+                                  for w in leaves[2:5])
+    text = jax.jit(jax.grad(_layer_loss(leaves[1]),
+                            argnums=(0, 1, 2, 3, 4))).lower(
         *args).compile().as_text()
-    assert any("conditional(" in line
-               for lines in computations(text).values() for line in lines)
-    assert "[%d,%d]" % (T_ * K, D) in text          # the last rung has them
+    switches = conditionals(text)
+    assert len(switches) == 2               # one forward, one backward
+    kept = "%s[%d,%d]" % ({"float32": "f32", "bfloat16": "bf16"}[dtype],
+                          T_ * K, F_)
+    results = [line.split(" conditional(")[0] for line in switches]
+    assert sorted(r.count(kept) for r in results) == [0, 2]
+    assert arrays_outside_control_flow(text, T_ * K, D) == []
+    outside = arrays_outside_control_flow(text, T_ * K, F_)
+    assert outside and all(
+        line.count("[%d,%d]" % (T_ * K, F_)) == line.count(kept)
+        for line in outside), outside
+
+
+def test_a_forward_without_a_gradient_keeps_nothing(small_tile):
+    """``jax.jit`` of the layer alone (as ``Predictor`` runs it): one
+    conditional, and no array of T*k rows by F or D outside it."""
+    from _hlo_text import arrays_outside_control_flow, conditionals
+    x, leaves = _steered(24, HELD, FIRST, jnp.float32)
+    args = (x, leaves[0]) + tuple(w[FIRST:FIRST + HELD] for w in leaves[2:5])
+    text = jax.jit(_layer_loss(leaves[1])).lower(*args).compile().as_text()
+    assert len(conditionals(text)) == 1
     for cols in (D, F_):
         assert arrays_outside_control_flow(text, T_ * K, cols) == []
 
@@ -439,7 +512,8 @@ def test_the_layer_counts_what_it_traced():
     from mxtpu import telemetry
     for name in ("moe.layers", "moe.experts_held", "moe.experts_total",
                  "moe.grouped_mm.grouped", "moe.grouped_mm.dense",
-                 "moe.rows_total", "moe.piece_rows"):
+                 "moe.rows_total", "moe.piece_rows", "moe.kept_bytes",
+                 "moe.bwd_products"):
         telemetry.reset_metric(name)
     x, leaves = _layer(7, t=1024)
     _routed(x, leaves, first=4, held=4)
@@ -453,6 +527,18 @@ def test_the_layer_counts_what_it_traced():
     assert telemetry.value("moe.experts_total") == 2 * E
     assert telemetry.value("moe.grouped_mm.grouped") == 1
     assert telemetry.value("moe.grouped_mm.dense") == 1
+    # a forward without a gradient keeps nothing and traces no backward
+    assert telemetry.value("moe.kept_bytes") == 0
+    assert telemetry.value("moe.bwd_products") == 0
+    # under a gradient: the two up products at all T*k rows in the leaves'
+    # dtype, and six grouped products a backward branch, once a layer
+    jax.grad(lambda x: jnp.sum(_routed(x.astype(jnp.bfloat16),
+                                       [w.astype(jnp.bfloat16)
+                                        for w in leaves], first=4, held=4)
+                               .astype(jnp.float32)))(x)
+    assert telemetry.value("moe.kept_bytes") == 2 * 1024 * K * F_ * 2
+    assert telemetry.value("moe.bwd_products") == 6
+    assert telemetry.value("moe.layers") == 3
 
 
 def test_a_range_outside_the_router_is_refused():

@@ -208,11 +208,13 @@ def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
     """The expert layer at the cell's widths (16 of 128 experts held, 6
     choices a token, 8,192 tokens): XLA:TPU takes ``ragged_dot`` as its
     grouped matmul kernel, forward and both transposes; nothing is expanded
-    into a product over every expert held; and no array of all T*k =
-    49,152 (token, slot) rows by the model's or the experts' width is
-    computed unconditionally: they exist under the switch's last branch
-    alone, which a step whose live rows fit a lower rung does not run."""
-    from _hlo_text import arrays_outside_control_flow
+    into a product over every expert held; a forward branch holds three
+    grouped kernels and a backward branch six (the hand-written backward
+    computes none again: nine before); and the only arrays of all T*k =
+    49,152 (token, slot) rows computed unconditionally are the two up
+    products the forward keeps for the backward, bf16 by the experts'
+    width: none by the model's width, none float32."""
+    from _hlo_text import arrays_outside_control_flow, grouped_kernels
     from mxtpu.parallel import moe
     x = _spec((8192, 2048), one_chip)
     specs = (x, _spec((128, 2048), one_chip), _spec((128,), one_chip),
@@ -220,9 +222,10 @@ def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
              _spec((16, 2048, 768), one_chip),
              _spec((16, 768, 2048), one_chip))
 
-    def loss(x, router, bias, eg, eu, ed):
-        return jnp.sum(moe.routed_ffn(x, router, bias, eg, eu, ed, top_k=6,
-                                      scale=2.448).astype(jnp.float32))
+    def loss(x, router, bias, eg, eu, ed):     # one that needs the output
+        return jnp.sum(jnp.sin(moe.routed_ffn(
+            x, router, bias, eg, eu, ed, top_k=6,
+            scale=2.448).astype(jnp.float32)))
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 3, 4, 5)), *specs)
     assert "ragged-dot" in text and "tpu_custom_call" in text
@@ -230,5 +233,9 @@ def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
     assert "49152,16,768" not in text and "16,49152,768" not in text
     assert moe._rungs(49152, 16, 128) == (8192, 16384, 49152)
     assert "[8192,768]" in text and "[49152,768]" in text
-    for cols in (2048, 768):
-        assert arrays_outside_control_flow(text, 49152, cols) == []
+    # three rungs, forward | backward
+    assert grouped_kernels(text) == [3, 3, 3, 6, 6, 6]
+    assert arrays_outside_control_flow(text, 49152, 2048) == []
+    kept = arrays_outside_control_flow(text, 49152, 768)
+    assert kept and all(line.count("[49152,768]")
+                        == line.count("bf16[49152,768]") for line in kept)
