@@ -8,7 +8,9 @@ seq 512 / batch 16 / vocab 30,522; bf16, weights random from ``--seed``),
 both flash kernels once more at latent attention's shape (32 heads of
 8,192 positions, keys and queries 192 wide, values 128, causal) and at
 grouped heads (2 sequences, 32 query heads over 8 key/value heads of 64,
-8,192 positions, causal), and the
+8,192 positions, causal), and at a window / global stack's attention (28
+query heads over 4 key/value heads of 128, 16,384 positions: once with a
+sliding window of 4,096 keys, once without), and the
 routed expert layer at one chip's share of kanana-2-30b-a3b's (8,192 tokens
 of 2,048, top-6 of 128 experts of width 768, 16 held).
 
@@ -56,6 +58,10 @@ FULL = dict(
     # grouped heads: 32 query heads over 8 key/value heads, two sequences
     grouped=dict(batch=2, heads=32, kv_heads=8, seq=8192, qk=64, v=64,
                  check_heads=4),
+    # a window / global stack's attention: 28 query heads over 4 key/value
+    # heads of 128, the model's whole context, 4,096 keys seen
+    window=dict(heads=28, kv_heads=4, seq=16384, width=128, window=4096,
+                check_heads=7),
     # one chip's share of a routed expert layer: 16 of 128 experts, top-6
     routed=dict(tokens=8192, dim=2048, width=768, held=16, total=128,
                 top_k=6),
@@ -75,6 +81,8 @@ TINY = dict(
     latent=dict(heads=2, seq=256, qk=24, v=16, check_heads=2),
     grouped=dict(batch=2, heads=4, kv_heads=2, seq=256, qk=16, v=16,
                  check_heads=2),
+    window=dict(heads=14, kv_heads=2, seq=256, width=16, window=72,
+                check_heads=7),
     routed=dict(tokens=512, dim=64, width=32, held=2, total=16, top_k=3),
     train_steps=5, gluon_steps=3,
     # the toy memorizes its 8 images in three steps (loss 3.4 -> 0.02),
@@ -333,6 +341,98 @@ def phase_flash_kernels(n, seed, on_tpu):
     _check(max(gaps.values()) <= 2e-2,
            "flash attention at %s is %s from the float32 oracle" % (n, gaps))
     return {"shape": n, "pallas_flash": stats, "gaps": gaps}
+
+
+def _attention_by_rows(q, k, v, window, rows=1024):
+    """Plain float32 causal attention (out, lse), K and V repeated to the
+    query heads, masked position by position (key j visible to query i iff
+    ``i - window < j <= i``), ``rows`` queries at a time against all keys:
+    the whole score matrix of 16,384 positions would not fit."""
+    import jax
+    import jax.numpy as jnp
+    group, t = q.shape[1] // k.shape[1], q.shape[2]
+    rows = rows if t % rows == 0 else t
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    j = jnp.arange(t)[None, :]
+
+    def block(at):
+        i = at + jnp.arange(rows)[:, None]
+        seen = (j <= i) & (j > i - window) if window else j <= i
+        s = jnp.einsum("bhqd,bhkd->bhqk",
+                       jax.lax.dynamic_slice_in_dim(q, at, rows, 2), k,
+                       precision="highest") * q.shape[-1] ** -0.5
+        s = jnp.where(seen, s, -jnp.inf)
+        lse = jax.scipy.special.logsumexp(s, axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v,
+                          precision="highest"), lse
+
+    out, lse = jax.lax.map(block, jnp.arange(0, t, rows))
+    merge = lambda x: jnp.moveaxis(x, 0, 2).reshape(
+        x.shape[1:3] + (t,) + x.shape[4:])
+    return merge(out), merge(lse)
+
+
+def phase_window_attention(n, seed, on_tpu):
+    """Both flash kernels, compiled, at a window / global stack's shape
+    ``n`` (``heads`` query heads over ``kv_heads`` key/value heads, the
+    whole context): once with the sliding window (``flash_window_fwd`` /
+    ``_bwd``: the grid's sequential axis holds only the steps a block can
+    need) and once without (the global layer's call, which in such a model
+    also gets q and k unturned: rotary is the operator's, not the
+    kernels'). ``jax.vjp`` through the public function; out against plain
+    float32 attention masked position by position, dq, dk, dv against the
+    float32 blockwise oracle on that reference's out and lse, on the first
+    ``check_heads`` query heads with the key/value heads they read. Fails
+    on the chip if a call was left for XLA, repeated K or V, or visited
+    the pairs left of its window. Each record's ``block_pairs`` are the
+    call's (q block, k block) pairs a head: skipped / visible / crossed."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    group = n["heads"] // n["kv_heads"]
+    rng = np.random.RandomState(seed % (2 ** 31))
+    q, k, v, g = (jnp.asarray(rng.randn(1, h, n["seq"], n["width"]),
+                              jnp.bfloat16)
+                  for h in (n["heads"], n["kv_heads"], n["kv_heads"],
+                            n["heads"]))
+    checked = n["check_heads"]
+    f32 = lambda x: x[:, :checked if x.shape[1] == n["heads"]
+                      else checked // group].astype(jnp.float32)
+    rec = {"shape": n}
+    for name, window in (("windowed", n["window"]), ("global", 0)):
+        fa.reset_dispatch_stats()
+        out, vjp = jax.vjp(
+            lambda *a: fa.flash_attention(*a, True, window=window), q, k, v)
+        got = (out,) + vjp(g)
+        stats = dict(fa.DISPATCH_STATS.items())
+        if on_tpu:
+            _check(stats["pallas"] == 1 and stats["xla"] == 0
+                   and stats["bwd_pallas"] == 1 and stats["bwd_xla"] == 0,
+                   "a flash kernel (%s) was left for XLA: %s" % (name, stats))
+            _check(not stats["kv_repeated"] and not stats["window_unskipped"],
+                   "the %s call repeated K, V or visited the pairs left of "
+                   "its window: %s" % (name, stats))
+        _check(stats["windowed"] == (1 if window else 0)
+               and stats["grouped"] == 1,
+               "the %s call was not counted as such: %s" % (name, stats))
+        want_out, lse = jax.jit(
+            lambda *a: _attention_by_rows(*a, window))(f32(q), f32(k), f32(v))
+        want = (want_out,) + jax.jit(
+            lambda *a: fa._fa_backward_blockwise(
+                *a, True, n["width"] ** -0.5,
+                min(1024, n["seq"]), window=window))(
+            f32(q), f32(k), f32(v), want_out, lse, f32(g))
+        gaps = {what: float(jnp.max(jnp.abs(f32(a) - b)) / jnp.max(jnp.abs(b)))
+                for what, a, b in zip(("out", "dq", "dk", "dv"), got, want)}
+        # bf16 against float32: a wrong edge of the mask, a block skipped
+        # that a query sees, or rows of dq never opened read 0.1 or more
+        _check(max(gaps.values()) <= 2e-2,
+               "flash attention (%s) at %s is %s from the float32 oracles"
+               % (name, n, gaps))
+        rec[name] = {"pallas_flash": stats, "gaps": gaps}
+    return rec
 
 
 def phase_routed_layer(sizes, seed, on_tpu):
@@ -739,6 +839,8 @@ def run(sizes, chips=1, seed=0, out=sys.stdout):
                   seed, on_tpu)
             phase("flash_grouped", phase_flash_kernels, sizes["grouped"],
                   seed, on_tpu)
+            phase("window_attention", phase_window_attention,
+                  sizes["window"], seed, on_tpu)
             phase("routed_layer", phase_routed_layer, sizes, seed, on_tpu)
             net = phase("gluon_trainer", phase_gluon_trainer, sizes, seed)
             phase("serve", phase_serve, sizes, seed, net)

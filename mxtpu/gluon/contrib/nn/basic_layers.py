@@ -135,7 +135,12 @@ class RoutedMoE(HybridBlock):
     """Top-k routed gated experts with optional shared experts, as
     DeepSeek-V3 has them (arXiv:2412.19437 §2.1.2): sigmoid scores, a
     selection bias that takes no gradient, weights normalised over the
-    chosen experts and scaled; no capacity, no token dropped.
+    chosen experts and scaled; no capacity, no token dropped. ``score``
+    (``"softmax"``: the softmax over all experts, its chosen renormalised)
+    and ``activation`` (``"relu"``: ReGLU experts) name another published
+    layer (mxtpu.parallel.moe.route_top_k, routed_ffn). Called with a
+    second input, the router scores that and the experts read the first
+    (a router placed ahead of attention reads its layer's input).
 
     ``experts_held`` of the router's ``num_experts`` live in this block,
     starting at ``first_expert``: one chip's share of the layer under
@@ -150,13 +155,14 @@ class RoutedMoE(HybridBlock):
 
     def __init__(self, dim, hidden, num_experts, top_k, experts_held=None,
                  first_expert=0, scale=1.0, shared_hidden=0, grouped=True,
-                 **kwargs):
+                 score="sigmoid", activation="silu", **kwargs):
         super().__init__(**kwargs)
         held = num_experts if experts_held is None else experts_held
         self._dim, self._hidden = dim, hidden
         self._num_experts, self._held = num_experts, held
         self._attrs = {"top_k": top_k, "first_expert": first_expert,
-                       "scale": scale, "grouped": grouped}
+                       "scale": scale, "grouped": grouped, "score": score,
+                       "activation": activation}
         with self.name_scope():
             self.router = self.params.get("router_weight",
                                           shape=(num_experts, dim))
@@ -170,12 +176,14 @@ class RoutedMoE(HybridBlock):
             self.shared = GatedMLP(dim, shared_hidden, prefix="shared_") \
                 if shared_hidden else None
 
-    def hybrid_forward(self, F, x, router, score_bias, w_gate, w_up, w_down):
+    def hybrid_forward(self, F, x, router_x=None, *, router, score_bias,
+                       w_gate, w_up, w_down):
         if x.shape[-1] != self._dim:
             raise ValueError("RoutedMoE(dim=%d) got input with last axis %d"
                              % (self._dim, x.shape[-1]))
+        ahead = {} if router_x is None else {"router_data": router_x}
         out = F._contrib_routed_moe(x, router, score_bias, w_gate, w_up,
-                                    w_down, **self._attrs)
+                                    w_down, **ahead, **self._attrs)
         if self.shared is None:
             return out
         with jax.named_scope("moe.shared"):

@@ -7,19 +7,26 @@ anywhere: ``h = x + op(norm1(x)); y = h + ffn(norm2(h))``.
 * ``op`` is one of :data:`OPERATORS`: ``conv`` (the gated short
   convolution of LFM2, :class:`~mxtpu.gluon.nn.ShortConv`),
   ``full_attention`` (:class:`GroupedQueryAttention`: causal attention of
-  ``num_heads`` query heads over ``num_kv_heads`` key/value heads, an
-  RMSNorm over each query and key head, rotary over the whole head) or
-  ``latent_attention`` (:class:`~mxtpu.gluon.model_zoo.latent_moe.
-  MultiHeadLatentAttention`);
-* ``ffn`` is a gated MLP in the first ``dense_layers`` blocks and a
-  :class:`~mxtpu.gluon.contrib.nn.RoutedMoE` after them (which may hold one
-  chip's share of each layer's experts);
+  ``num_heads`` query heads over ``num_kv_heads`` key/value heads; by
+  default an RMSNorm over each query and key head and rotary over the
+  whole head), ``window_attention`` (the same block, for the layers of a
+  window / global stack that see a sliding window: its keyword arguments
+  carry the ``window``) or ``latent_attention``
+  (:class:`~mxtpu.gluon.model_zoo.latent_moe.MultiHeadLatentAttention`);
+* ``ffn`` is a gated MLP in the first ``dense_layers`` blocks (there may
+  be none) and a :class:`~mxtpu.gluon.contrib.nn.RoutedMoE` after them
+  (which may hold one chip's share of each layer's experts); with
+  ``router_ahead`` its router reads the layer's input ``x``, ahead of the
+  operator, while its experts read ``norm2(h)``;
 * a final norm and a vocabulary head, tied to the embedding by default.
 
 ``HybridLM(layers=["conv", "full_attention", "conv", ...])`` is LFM2's
-stack (``model_type: lfm2_moe``); :class:`~mxtpu.gluon.model_zoo.latent_moe.
-LatentMoELM` is the same model with ``latent_attention`` in every layer.
-Trains under :class:`mxtpu.parallel.ShardedTrainStep`.
+stack (``model_type: lfm2_moe``); ``layers=["full_attention",
+"window_attention", "window_attention", "window_attention"]`` with
+``router_ahead`` and no dense layer a period of SmallThinker's;
+:class:`~mxtpu.gluon.model_zoo.latent_moe.LatentMoELM` is the same model
+with ``latent_attention`` in every layer. Trains under
+:class:`mxtpu.parallel.ShardedTrainStep`.
 """
 from __future__ import annotations
 
@@ -31,20 +38,30 @@ __all__ = ["HybridLM", "DecoderBlock", "GroupedQueryAttention", "OPERATORS"]
 
 class GroupedQueryAttention(HybridBlock):
     """Causal grouped-query attention (Ainslie et al., arXiv:2305.13245):
-    ``num_heads`` query heads of ``dim // num_heads`` read ``num_kv_heads``
-    key / value heads, query head ``j`` the head ``j // (num_heads /
-    num_kv_heads)``; queries and keys go through an RMSNorm over a head's
-    entries (one learned scale each) and rotary over the whole head before
-    the flash kernels, which take K and V at their own heads."""
+    ``num_heads`` query heads of ``head_dim`` (``dim // num_heads`` unless
+    given) read ``num_kv_heads`` key / value heads, query head ``j`` the
+    head ``j // (num_heads / num_kv_heads)``, before the flash kernels,
+    which take K and V at their own heads.
+
+    The mask: key ``j`` is visible to query ``i`` iff ``j <= i``; with
+    ``window = W > 0`` iff ``i - W < j <= i`` (``W`` keys, the query's own
+    among them). ``qk_norm``: queries and keys go through an RMSNorm over a
+    head's entries (one learned scale each; without it the block has no
+    such leaves). ``rope``: rotary over the whole head turns them; without
+    it the layer carries no position encoding at all. The defaults are
+    LFM2's attention layer."""
 
     def __init__(self, dim, num_heads, num_kv_heads, rope_theta=10000.0,
-                 epsilon=1e-6, **kwargs):
+                 epsilon=1e-6, head_dim=None, qk_norm=True, rope=True,
+                 window=0, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError("%d query heads do not divide over %d key/value"
                              " heads" % (num_heads, num_kv_heads))
-        self._head_dim = head_dim = dim // num_heads
-        self._rope_theta = rope_theta
+        self._head_dim = head_dim = head_dim or dim // num_heads
+        self._attrs = {"rope_theta": rope_theta, "window": window,
+                       "rope": rope}
+        self._qk_norm = qk_norm
         with self.name_scope():
             self.q = nn.Dense(num_heads * head_dim, use_bias=False,
                               flatten=False, prefix="q_")
@@ -52,17 +69,22 @@ class GroupedQueryAttention(HybridBlock):
                               flatten=False, prefix="k_")
             self.v = nn.Dense(num_kv_heads * head_dim, use_bias=False,
                               flatten=False, prefix="v_")
-            self.q_norm = nn.RMSNorm(epsilon=epsilon, prefix="qnorm_")
-            self.k_norm = nn.RMSNorm(epsilon=epsilon, prefix="knorm_")
+            if qk_norm:
+                self.q_norm = nn.RMSNorm(epsilon=epsilon, prefix="qnorm_")
+                self.k_norm = nn.RMSNorm(epsilon=epsilon, prefix="knorm_")
             self.proj = nn.Dense(dim, use_bias=False, flatten=False,
                                  prefix="proj_")
 
     def hybrid_forward(self, F, x):
         heads = (0, 0, -1, self._head_dim)        # [B, T, H, head_dim]
-        q = self.q_norm(F.reshape(self.q(x), shape=heads))
-        k = self.k_norm(F.reshape(self.k(x), shape=heads))
+        q = F.reshape(self.q(x), shape=heads)
+        if self._qk_norm:
+            q = self.q_norm(q)
+        k = F.reshape(self.k(x), shape=heads)
+        if self._qk_norm:
+            k = self.k_norm(k)
         return self.proj(F._contrib_grouped_attention(
-            q, k, self.v(x), rope_theta=self._rope_theta))
+            q, k, self.v(x), **self._attrs))
 
 
 def _latent_attention(dim, **kwargs):
@@ -75,6 +97,9 @@ def _latent_attention(dim, **kwargs):
 OPERATORS = {
     "conv": (nn.ShortConv, "conv_"),
     "full_attention": (GroupedQueryAttention, "attn_"),
+    # the same block under its own name, so that a stack can give its
+    # windowed layers other keyword arguments than its global ones
+    "window_attention": (GroupedQueryAttention, "attn_"),
     "latent_attention": (_latent_attention, "attn_"),
 }
 
@@ -84,13 +109,16 @@ class DecoderBlock(HybridBlock):
     ``(kind, keyword arguments)`` of one of :data:`OPERATORS`; ``ffn`` is a
     gated MLP (``moe=None``) or routed experts (``moe``: the keyword
     arguments of :class:`~mxtpu.gluon.contrib.nn.RoutedMoE` after
-    ``dim``)."""
+    ``dim``). ``router_ahead`` (routed experts only): the router reads the
+    layer's own input, ``y = h + ffn(norm2(h), router_x=x)``: a router
+    placed before attention, whose choice does not wait for it."""
 
     def __init__(self, dim, operator, dense_hidden=0, moe=None,
-                 epsilon=1e-6, **kwargs):
+                 epsilon=1e-6, router_ahead=False, **kwargs):
         super().__init__(**kwargs)
         kind, op_kwargs = operator
         make, prefix = OPERATORS[kind]
+        self._router_ahead = router_ahead and moe is not None
         with self.name_scope():
             self.norm1 = nn.RMSNorm(epsilon=epsilon, prefix="norm1_")
             self.op = make(dim, prefix=prefix, **op_kwargs)
@@ -102,8 +130,10 @@ class DecoderBlock(HybridBlock):
                 self.ffn = RoutedMoE(dim, prefix="moe_", **moe)
 
     def hybrid_forward(self, F, x):
-        x = x + self.op(self.norm1(x))
-        return x + self.ffn(self.norm2(x))
+        h = x + self.op(self.norm1(x))
+        if self._router_ahead:
+            return h + self.ffn(self.norm2(h), x)
+        return h + self.ffn(self.norm2(h))
 
 
 class HybridLM(HybridBlock):
@@ -115,14 +145,17 @@ class HybridLM(HybridBlock):
     arguments of its block (:data:`OPERATORS`), e.g. ``{"conv":
     {"kernel_size": 3}, "full_attention": {"num_heads": 32,
     "num_kv_heads": 8, "rope_theta": 1e6, "epsilon": 1e-5}}``. The first
-    ``dense_layers`` blocks have a gated MLP of ``dense_hidden``, the rest
-    ``moe`` (``hidden, num_experts, top_k`` and optionally ``experts_held,
-    first_expert, scale, shared_hidden``). ``tie_head``: the head reads the
-    embedding's weight, whose gradient is the sum of both uses.
+    ``dense_layers`` blocks (0: none) have a gated MLP of ``dense_hidden``,
+    the rest ``moe`` (``hidden, num_experts, top_k`` and optionally
+    ``experts_held, first_expert, scale, shared_hidden, score,
+    activation``), whose routers read their layer's input with
+    ``router_ahead``. ``tie_head``: the head reads the embedding's weight,
+    whose gradient is the sum of both uses.
     """
 
     def __init__(self, vocab_size, dim, layers, operators, dense_hidden, moe,
-                 dense_layers=1, epsilon=1e-6, tie_head=True, **kwargs):
+                 dense_layers=1, epsilon=1e-6, tie_head=True,
+                 router_ahead=False, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
             self.embed = nn.Embedding(vocab_size, dim, prefix="wte_")
@@ -133,7 +166,7 @@ class HybridLM(HybridBlock):
                         dim, (kind, operators.get(kind, {})),
                         dense_hidden=dense_hidden,
                         moe=None if i < dense_layers else moe,
-                        epsilon=epsilon))
+                        epsilon=epsilon, router_ahead=router_ahead))
             self.norm_f = nn.RMSNorm(epsilon=epsilon, prefix="normf_")
             # tied: the head is a Dense over the embedding's own weight
             self.head = nn.Dense(

@@ -633,13 +633,18 @@ def switch_moe(data, router, w1, b1, w2, b2, capacity_factor=1.25):
 
 @register("_contrib_routed_moe", aliases=("routed_moe",))
 def routed_moe(data, router, score_bias, w_gate, w_up, w_down, top_k=1,
-               first_expert=0, scale=1.0, grouped=True):
+               first_expert=0, scale=1.0, grouped=True, router_data=None,
+               score="sigmoid", activation="silu"):
     """Top-k routed gated experts without a capacity, over the experts held
     here (backs gluon.contrib.nn.RoutedMoE; mxtpu.parallel.moe.routed_ffn).
-    data (..., D) is flattened to tokens."""
+    data (..., D) is flattened to tokens; ``router_data`` (the same shape),
+    where given, is what the router scores in ``data``'s place."""
     from ..parallel.moe import routed_ffn
     toks = data.reshape(-1, data.shape[-1])
+    if router_data is not None:
+        router_data = router_data.reshape(toks.shape)
     out = routed_ffn(toks, router, score_bias, w_gate, w_up, w_down,
                      top_k=top_k, first_expert=first_expert, scale=scale,
-                     grouped=grouped)
+                     grouped=grouped, router_x=router_data, score=score,
+                     activation=activation)
     return out.reshape(data.shape)

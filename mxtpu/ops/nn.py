@@ -558,24 +558,33 @@ def latent_attention(q, kv, k_rope, num_heads=1, nope_dim=128, rope_dim=64,
 
 
 @register("_contrib_grouped_attention", aliases=("grouped_attention",))
-def grouped_attention(q, k, v, rope_theta=10000.0):
+def grouped_attention(q, k, v, rope_theta=10000.0, window=0, rope=True):
     """Causal grouped-query attention (Ainslie et al., arXiv:2305.13245)
     after its projections and per-head norms: ``q`` [B, T, H, D], ``k`` [B,
     T, H_kv, D] and ``v`` [B, T, H_kv * D] (or [B, T, H_kv, D]), ``H`` a
     multiple of ``H_kv``; query head ``j`` reads key/value head ``j // (H /
-    H_kv)``. Rotary over the whole head (halves rotated) turns q and k;
-    both flash kernels take K and V at their ``H_kv`` heads and fetch them
-    by group, so no copy of them at the query heads exists. Scores are
-    scaled by ``1/sqrt(D)``. Returns [B, T, H * D]."""
+    H_kv)``. The mask: key ``j`` is visible to query ``i`` iff ``j <= i``,
+    and with ``window = W > 0`` iff ``i - W < j <= i`` (a sliding window of
+    ``W`` keys, the query's own among them: transformers' convention).
+    Rotary over the whole head (halves rotated) turns q and k; with
+    ``rope=False`` nothing turns and nothing is added: the layer carries no
+    position encoding (the global layers of a window / global stack, whose
+    only order is the mask's). Both flash kernels take K and V at their
+    ``H_kv`` heads and fetch them by group, so no copy of them at the query
+    heads exists. Scores are scaled by ``1/sqrt(D)``. Returns [B, T, H *
+    D]. The call sits under the scope ``window_attention`` with a window,
+    ``gqa_attention`` without."""
     from .pallas import flash_attention
     b, t, h, d = q.shape
-    with jax.named_scope("gqa_attention"):
-        q = rotary(q, rope_theta)
-        k = rotary(k, rope_theta)
+    with jax.named_scope("window_attention" if window else "gqa_attention"):
+        if rope:
+            q = rotary(q, rope_theta)
+            k = rotary(k, rope_theta)
         v = v.reshape(b, t, k.shape[2], -1)
         out = flash_attention(q.transpose(0, 2, 1, 3),
                               k.transpose(0, 2, 1, 3),
-                              v.transpose(0, 2, 1, 3), True)    # [B, H, T, D]
+                              v.transpose(0, 2, 1, 3), True,
+                              window=window)                    # [B, H, T, D]
         return out.transpose(0, 2, 1, 3).reshape(b, t, h * v.shape[-1])
 
 

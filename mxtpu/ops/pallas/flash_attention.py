@@ -32,6 +32,17 @@ Design (TPU-first):
   the mask (a second body without it for the visible pairs read no
   faster). ``pallas_flash.block_pairs{skipped,visible,crossed}`` counts
   a call's pairs a head at trace time.
+* a sliding window (``window = W > 0`` beside ``causal``): key ``j`` is
+  visible to query ``i`` iff ``i - W < j <= i``, the query's own key among
+  the ``W``. The same predicate gets its second bound (a pair wholly left
+  of the window is skipped, one the window's edge crosses is masked), and
+  the sequential axis of each kernel's grid holds only the steps a block
+  can need (:func:`_window_steps`: five of sixteen at blocks of 1,024, a
+  window of 4,096 and 16,384 positions), counted from the first block the
+  window reaches; the index maps clamp on both sides, so nothing is
+  fetched for a step outside. Such a call names its kernels
+  ``flash_window_fwd`` / ``flash_window_bwd``, so a trace tells the two
+  kinds of call apart. ``W >= T`` masks nothing and IS the causal call.
 * backward: custom_vjp, flash-attention-2 equations from the saved
   log-sum-exp. Where the forward ran the kernel, ONE fused Pallas kernel
   (``flash_attention_bwd``), grid (batch*heads, Tk/bk, Tq/bq) with the
@@ -110,7 +121,8 @@ class _DispatchStatsView:
     """Read-only dict-shaped view over the telemetry counters."""
 
     _KEYS = ("pallas", "xla", "fallback_reasons", "grouped", "kv_repeated",
-             "bwd_pallas", "bwd_xla", "bwd_fallback_reasons", "block_pairs")
+             "bwd_pallas", "bwd_xla", "bwd_fallback_reasons", "block_pairs",
+             "windowed", "window_unskipped")
     _TAGGED = {"fallback_reasons": "pallas_flash.fallback",
                "bwd_fallback_reasons": "pallas_flash.bwd_fallback",
                "block_pairs": "pallas_flash.block_pairs"}
@@ -151,7 +163,8 @@ DISPATCH_STATS = _DispatchStatsView()
 def reset_dispatch_stats():
     from ... import telemetry
     for name in ("pallas", "xla", "fallback", "grouped", "kv_repeated",
-                 "bwd_pallas", "bwd_xla", "bwd_fallback", "block_pairs"):
+                 "bwd_pallas", "bwd_xla", "bwd_fallback", "block_pairs",
+                 "windowed", "window_unskipped"):
         telemetry.reset_metric("pallas_flash." + name)
 
 
@@ -161,12 +174,21 @@ def _count_fallback(reason):
     telemetry.inc("pallas_flash.fallback", tag=reason)
 
 
-def _xla_attention(q, k, v, causal, scale):
-    out, _ = _xla_attention_lse(q, k, v, causal, scale)
+def _xla_attention(q, k, v, causal, scale, window=0):
+    out, _ = _xla_attention_lse(q, k, v, causal, scale, window)
     return out
 
 
-def _xla_attention_lse(q, k, v, causal, scale):
+def _seen(q_pos, k_pos, window):
+    """The mask, position by position: key ``k_pos`` is visible to query
+    ``q_pos`` at or before it and, under a window, fewer than ``window``
+    positions back. The plain paths' own copy (the kernels ask
+    :func:`_block_case` and :func:`_causal_mask`)."""
+    seen = q_pos >= k_pos
+    return seen & (q_pos - k_pos < window) if window else seen
+
+
+def _xla_attention_lse(q, k, v, causal, scale, window=0):
     """Fallback (out, lse): ONE copy of the XLA math; differentiable."""
     k, v = _repeat_kv(q, k, v)     # grouped heads: K, V at the query heads
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
@@ -174,7 +196,7 @@ def _xla_attention_lse(q, k, v, causal, scale):
                    preferred_element_type=jnp.float32) * scale
     if causal:
         tq, tk = s.shape[-2:]
-        mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        mask = _seen(jnp.arange(tq)[:, None], jnp.arange(tk)[None, :], window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
@@ -183,32 +205,47 @@ def _xla_attention_lse(q, k, v, causal, scale):
     return out.astype(q.dtype), lse
 
 
-def _block_case(qi, ki, block_q, block_k):
-    """What the causal mask does to block pair (q block ``qi``, k block
-    ``ki``): ``(visible, crossed)``. *Visible*: every key of the block lies
-    at or before every query, so no position is masked. *Crossed*: the
-    diagonal passes through, so some are. Neither: *skipped*, no query sees
-    a key. Plain arithmetic on the block indices, so it takes Python ints
-    and arrays (the counts) as well as a kernel's program ids; the one
-    place the mask's block geometry is written down: both kernels ask it
-    (:func:`_live`), and a further mask (a window, segment ids) would."""
+def _block_case(qi, ki, block_q, block_k, window=0):
+    """What the mask does to block pair (q block ``qi``, k block ``ki``):
+    ``(visible, crossed)``. *Visible*: every query of the block sees every
+    key, so no position is masked. *Crossed*: an edge of the mask passes
+    through, so some are. Neither: *skipped*, no query sees a key. Plain
+    arithmetic on the block indices, so it takes Python ints and arrays
+    (the counts) as well as a kernel's program ids; the one place the
+    mask's block geometry is written down: both kernels ask it
+    (:func:`_live`).
+
+    The causal mask has one edge, the diagonal. A window of ``window`` keys
+    (key ``j`` visible to query ``i`` iff ``i - window < j <= i``) added
+    the second: a pair whose last key lies left of the FIRST query's window
+    is skipped, and a pair is visible only if its first key lies inside the
+    LAST query's window, so a pair may be crossed by the diagonal, by the
+    window's edge, or (a block wider than the window) by both. A further
+    mask (segment ids) would bound the same two sets."""
     first_q, first_k = qi * block_q, ki * block_k
     last_q, last_k = first_q + block_q - 1, first_k + block_k - 1
-    return last_k <= first_q, (first_k <= last_q) & (last_k > first_q)
+    visible = last_k <= first_q
+    crossed = (first_k <= last_q) & (last_k > first_q)
+    if not window:
+        return visible, crossed
+    live = (visible | crossed) & (last_k > first_q - window)
+    visible = visible & (first_k > last_q - window)
+    return visible, live ^ visible      # visible implies live
 
 
-def _live(causal, qi, ki, block_q, block_k):
+def _live(causal, qi, ki, block_q, block_k, window=0):
     """Whether step (qi, ki) of a kernel computes: every pair without a
-    mask, the visible and the crossed ones under the causal mask."""
+    mask, the visible and the crossed ones under one."""
     if not causal:
         return True
-    visible, crossed = _block_case(qi, ki, block_q, block_k)
+    visible, crossed = _block_case(qi, ki, block_q, block_k, window)
     return visible | crossed
 
 
-def _causal_mask(st, qi, ki, block_q, block_k, first_col=0):
-    """The causal mask on the transposed tile ``st`` [k rows, q columns
-    from ``first_col`` of the q block on] of a live block pair. It runs
+def _causal_mask(st, qi, ki, block_q, block_k, first_col=0, window=0):
+    """The mask on the transposed tile ``st`` [k rows, q columns from
+    ``first_col`` of the q block on] of a live block pair: the diagonal
+    and, under a window, its far edge. It runs
     on the visible pairs too, where it changes nothing: a second copy of
     a kernel's body without it read no faster on the chip, forward or
     backward (the mask's passes fill VALU slots the MXU-bound schedule
@@ -216,10 +253,40 @@ def _causal_mask(st, qi, ki, block_q, block_k, first_col=0):
     k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
     q_pos = qi * block_q + first_col + jax.lax.broadcasted_iota(
         jnp.int32, st.shape, 1)
-    return jnp.where(q_pos >= k_pos, st, _NEG_INF)
+    seen = q_pos >= k_pos
+    if window:
+        seen = seen & (q_pos - k_pos < window)
+    return jnp.where(seen, st, _NEG_INF)
 
 
-def _count_block_pairs(n_q, n_k, block_q, block_k, causal):
+def _first_k_block(i, block_q, block_k, window, xp=jnp):
+    """The first k block that q block ``i``'s window reaches: where its
+    steps of the forward start, and where its rows of dq open in the
+    backward. (``xp``: ``numpy`` for the static counts.)"""
+    return xp.maximum(i * block_q - (window - 1), 0) // block_k
+
+
+def _last_q_block(j, block_q, block_k, n_q, window, xp=jnp):
+    """The last q block whose window still reaches k block ``j``."""
+    return xp.minimum((j * block_k + block_k + window - 2) // block_q,
+                      n_q - 1)
+
+
+def _window_steps(n_q, n_k, block_q, block_k, window):
+    """Steps of each kernel's sequential axis under a window, static:
+    ``(k steps a q block needs at most, q steps a k block needs at
+    most)``, each counted from the first block the window reaches
+    (:func:`_first_k_block`; ``j * block_k // block_q`` for a k block)."""
+    import numpy as np
+    i, j = np.arange(n_q), np.arange(n_k)
+    k_steps = _last_k_block(i, n_k - 1, block_q, block_k, np) \
+        - _first_k_block(i, block_q, block_k, window, np)
+    q_steps = _last_q_block(j, block_q, block_k, n_q, window, np) \
+        - j * block_k // block_q
+    return int(k_steps.max()) + 1, int(q_steps.max()) + 1
+
+
+def _count_block_pairs(n_q, n_k, block_q, block_k, causal, window=0):
     """``pallas_flash.block_pairs{skipped,visible,crossed}``: one call's
     (q block, k block) pairs a head, as :func:`_block_case` sorts them."""
     import numpy as np
@@ -228,7 +295,7 @@ def _count_block_pairs(n_q, n_k, block_q, block_k, causal):
     if causal:
         visible, crossed = (int(x.sum()) for x in _block_case(
             np.arange(n_q)[:, None], np.arange(n_k)[None, :], block_q,
-            block_k))
+            block_k, window))
     for tag, n in (("skipped", n_q * n_k - visible - crossed),
                    ("visible", visible), ("crossed", crossed)):
         telemetry.inc("pallas_flash.block_pairs", n, tag=tag)
@@ -255,7 +322,7 @@ _Q_SLAB = 256
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
-               block_q, block_k, n_k):
+               block_q, block_k, n_k, window=0):
     """One (head, q block, k block) step of the forward, on the TRANSPOSED
     score tile ``s^T = k q^T`` [bk on sublanes, bq on lanes], the form of
     :func:`_fa_bwd_kernel`: the row statistics (running max m, running sum
@@ -264,14 +331,18 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
     along sublanes for nothing, and the accumulator is kept transposed,
     ``acc^T [dv, bq] += v^T p^T``, turned once a q block. With the whole
     row in one k block (``n_k == 1``) nothing is carried: no scratch, no
-    rescale."""
+    rescale. ``n_k`` is the grid's k steps: every k block, or under a
+    window the steps a q block can need, counted from the first block its
+    window reaches."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
+    ki = step + _first_k_block(qi, block_q, block_k, window) if window \
+        else step
     carried = n_k > 1
     if carried:
         m_scr, l_scr, acc_scr = scratch
 
-        @pl.when(ki == 0)
+        @pl.when(step == 0)
         def _init():
             m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
@@ -282,7 +353,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
         o_ref[0, cols, :] = jnp.transpose(acc_t / l).astype(o_ref.dtype)
         lse_ref[0, :, cols] = m + jnp.log(l)      # a [1, bq] row of lse
 
-    @pl.when(_live(causal, qi, ki, block_q, block_k))
+    @pl.when(_live(causal, qi, ki, block_q, block_k, window))
     def _step():
         k = k_ref[0]                              # [bk, d]
         v = v_ref[0]                              # [bk, dv]
@@ -293,7 +364,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
                  for cols in slabs]               # [bk, slab] float32 each
         for cols, st in zip(slabs, tiles):
             if causal:
-                st = _causal_mask(st, qi, ki, block_q, block_k, cols.start)
+                st = _causal_mask(st, qi, ki, block_q, block_k, cols.start,
+                                  window)
             m_new = jnp.max(st, axis=0, keepdims=True)          # [1, slab]
             if carried:
                 m_prev = m_scr[:, cols]
@@ -312,16 +384,16 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
             acc_scr[:, cols] = alpha * acc_scr[:, cols] + pv
 
     if carried:
-        @pl.when(ki == n_k - 1)
+        @pl.when(step == n_k - 1)
         def _finalize():
             _store(slice(None), m_scr[...], l_scr[...], acc_scr[...])
 
 
-def _last_k_block(i, j, block_q, block_k):
+def _last_k_block(i, j, block_q, block_k, xp=jnp):
     """The k block that step (q block ``i``, k block ``j``) of the causal
     forward names: ``j`` where it computes, else the last block the q
     block needed, so that nothing is fetched for a skipped step."""
-    return jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+    return xp.minimum(j, (i * block_q + block_q - 1) // block_k)
 
 
 def _lanes(d):
@@ -343,16 +415,19 @@ def _fwd_vmem(bq, bk, d, dv, itm):
             + (dvp + 2 * 8) * bq * 4)            # acc^T, m, l
 
 
-def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k):
+def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k, window=0):
     b, h, t, d = q.shape
     tk, dv = k.shape[2], v.shape[3]   # values may be narrower than keys
     bh, kv_head = b * h, _kv_head_map(_group(q, k))
     n_q = t // block_q
     n_k = tk // block_k
-    _count_block_pairs(n_q, n_k, block_q, block_k, causal)
+    _count_block_pairs(n_q, n_k, block_q, block_k, causal, window)
+    if window:      # the grid's k axis: the steps a q block can need
+        n_k = _window_steps(n_q, n_k, block_q, block_k, window)[0]
     from jax.experimental.pallas import tpu as pltpu
     kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, n_k=n_k)
+                               block_q=block_q, block_k=block_k, n_k=n_k,
+                               window=window)
     interpret = _interpret()
     extra = {}
     if not interpret:  # Mosaic-only hints: the interpreter takes none
@@ -363,6 +438,8 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k):
                 _fwd_vmem(block_q, block_k, d, dv, itm)))
 
     def k_block(i, j):
+        if window:      # step j of q block i, from its window's first block
+            j = j + _first_k_block(i, block_q, block_k, window)
         return _last_k_block(i, j, block_q, block_k) if causal else j
 
     out, lse = pl.pallas_call(
@@ -392,7 +469,8 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k):
             pltpu.VMEM((dv, block_q), jnp.float32),   # acc^T
         ] if n_k > 1 else [],
         interpret=interpret,
-        name="flash_attention_fwd",   # the kernel's name in a device trace
+        # the kernel's name in a device trace
+        name="flash_window_fwd" if window else "flash_attention_fwd",
         **extra,
     )(q.reshape(bh, t, d), k.reshape(-1, tk, d), v.reshape(-1, tk, dv))
     return out.reshape(b, h, t, dv), lse
@@ -443,7 +521,7 @@ def _kv_head_map(group):
 
 @jax.named_scope("flash_attention_bwd")   # plain XLA: found by this scope
 def _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale, block_k,
-                           g_lse=None):
+                           g_lse=None, window=0):
     """Flash-attention-2 backward, blockwise over k in plain jax:
     P = exp(S - lse); dv = P^T g; ds = P * (g v^T - D); dq += ds k; dk += ds^T q.
 
@@ -470,7 +548,7 @@ def _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale, block_k,
                        preferred_element_type=f32) * scale
         if causal:
             k_pos = j * block_k + jnp.arange(block_k)
-            mask = q_pos[:, None] >= k_pos[None, :]
+            mask = _seen(q_pos[:, None], k_pos[None, :], window)
             s = jnp.where(mask[None, None], s, _NEG_INF)
         p = jnp.exp(s - lse[..., None])              # [b,h,t,bk]
         dv = jnp.einsum("bhqk,bhqd->bhkd", p, g32,
@@ -494,7 +572,8 @@ def _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale, block_k,
 
 def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
-                   *, scale, causal, block_q, block_k, n_q, n_k, group=1):
+                   *, scale, causal, block_q, block_k, n_q, n_k, group=1,
+                   window=0, q_steps):
     """One (head, k block, q block) step of the flash backward. Works on
     the TRANSPOSED score tile ``s^T = k q^T`` [bk, bq]: dv and dk are then
     plain matmuls with the tile on the left, lse and delta broadcast along
@@ -505,30 +584,53 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     ``group`` > 1 (grouped heads, the grid (key/value head, query head of
     its group, k block, q block)): dk and dv of the WHOLE key/value head
     stay in VMEM while its query heads pass, so they leave the kernel once,
-    at the key/value heads, summed over the group in float32."""
-    if group == 1:
-        ki, qi, kv = pl.program_id(1), pl.program_id(2), slice(None)
+    at the key/value heads, summed over the group in float32.
 
-        def in_head(gi, step):        # one query head a key/value head
-            return step
+    ``q_steps`` is the grid's q axis under a window: the steps a k block
+    can need, counted from its diagonal's q block, in place of all ``n_q``
+    q blocks; a q block's rows of dq then open at the first k block its
+    window reaches and leave at its diagonal's, not at the first and last
+    k block."""
+    if group == 1:
+        ki, step, kv = pl.program_id(1), pl.program_id(2), slice(None)
+
+        def in_head(gi, cond):        # one query head a key/value head
+            return cond
     else:
-        head, ki, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+        head, ki, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
         kv = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
 
-        def in_head(gi, step):        # ``step``, in the group's head ``gi``
-            return step & (head == gi)
+        def in_head(gi, cond):        # ``cond``, in the group's head ``gi``
+            return cond & (head == gi)
+    # the k blocks at which q block ``qi``'s rows of dq open and leave
+    if window:
+        qi = step + ki * block_k // block_q
+        first_k = functools.partial(_first_k_block, qi, block_q, block_k,
+                                    window)
+        last_k = functools.partial(_last_k_block, qi, n_k - 1, block_q,
+                                   block_k)
+
+        def here(cond):
+            # the last k blocks' steps run past the last q block: the mask
+            # would let such queries see these keys, the array has none
+            return cond & (qi < n_q)
+    else:
+        qi, first_k, last_k = step, lambda: 0, lambda: n_k - 1
+
+        def here(cond):
+            return cond
     rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
 
-    @pl.when(in_head(0, qi == 0))
+    @pl.when(in_head(0, step == 0))
     def _init_kv():
         dk_acc[kv] = jnp.zeros((block_k, dk_acc.shape[1]), jnp.float32)
         dv_acc[kv] = jnp.zeros((block_k, dv_acc.shape[1]), jnp.float32)
 
-    @pl.when(ki == 0)
+    @pl.when(here(ki == first_k()))
     def _init_q():
         dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
 
-    @pl.when(_live(causal, qi, ki, block_q, block_k))
+    @pl.when(here(_live(causal, qi, ki, block_q, block_k, window)))
     def _step():
         q = q_ref[0]                              # [bq, d]
         k = k_ref[0]                              # [bk, d]
@@ -537,7 +639,7 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         # the forward kernel's precision policy (:func:`_dot`)
         st = _dot(k, q, ((1,), (1,))) * scale     # [bk, bq]
         if causal:
-            st = _causal_mask(st, qi, ki, block_q, block_k)
+            st = _causal_mask(st, qi, ki, block_q, block_k, window=window)
         pt = jnp.exp(st - lse_ref[0])             # P^T, lse as a [1, bq] row
         dv_acc[kv] += _dot(pt.astype(g.dtype), g, ((1,), (0,)))
         dpt = _dot(v, g, ((1,), (1,)))            # dP^T [bk, bq]
@@ -547,12 +649,12 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk_acc[kv] += _dot(dst, q, ((1,), (0,)))
         dq_acc[rows, :] += _dot(dst, k, ((0,), (0,)))
 
-    @pl.when(in_head(group - 1, qi == n_q - 1))
+    @pl.when(in_head(group - 1, step == q_steps - 1))
     def _store_kv():
         dk_ref[0, kv] = (dk_acc[kv] * scale).astype(dk_ref.dtype)
         dv_ref[0, kv] = dv_acc[kv].astype(dv_ref.dtype)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(here(ki == last_k()))
     def _store_q():
         dq_ref[0, rows, :] = (dq_acc[rows, :] * scale).astype(dq_ref.dtype)
 
@@ -622,7 +724,7 @@ def _resolve_bwd_blocks(q, k, v, block_q, block_k):
 
 @jax.named_scope("flash_attention_bwd")   # prologue, kernel and epilogue
 def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
-                        block_k, g_lse=None):
+                        block_k, g_lse=None, window=0):
     """The flash backward as ONE fused Pallas kernel: P is recomputed per
     (k block, q block) from the saved ``lse``; s, p, dp and ds never leave
     VMEM. Same contract as :func:`_fa_backward_blockwise`."""
@@ -646,9 +748,13 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
     bh = b * h
     n_q = t // block_q
     n_k = tk // block_k
+    # the grid's q axis: every q block, or the steps a k block can need
+    q_steps = _window_steps(n_q, n_k, block_q, block_k, window)[1] \
+        if window else n_q
     kernel = functools.partial(_fa_bwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, n_q=n_q,
-                               n_k=n_k, group=group)
+                               n_k=n_k, group=group, window=window,
+                               q_steps=q_steps)
     interpret = _interpret()
     extra = {}
     if not interpret:  # Mosaic-only hints: the interpreter takes none
@@ -666,6 +772,10 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
         # (the last q block where no row sees this k block: tk > t)
         if not causal:
             return i
+        if window:      # step i of k block j, from its diagonal's q block
+            return jnp.minimum(
+                i + j * block_k // block_q,
+                _last_q_block(j, block_q, block_k, n_q, window))
         return _first_q_block(j, i, block_q, block_k, n_q)
 
     # the grid's axes -> (query head, key/value head, k block, q block)
@@ -677,12 +787,12 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
         # cell's shape, 18.58 against 19.63: PERF.md §6, PR 30)
         def at(c, gi, j, i):
             return c * group + gi, c, j, i
-        grid = (b * hk, group, n_k, n_q)
+        grid = (b * hk, group, n_k, q_steps)
         kv_rows = tk
     else:
         def at(b_, j, i):
             return b_, b_, j, i
-        grid = (bh, n_k, n_q)
+        grid = (bh, n_k, q_steps)
         kv_rows = block_k
 
     def spec(block, index):
@@ -718,7 +828,8 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
             pltpu.VMEM((kv_rows, dv), f32),   # dv of the k block (or head)
         ],
         interpret=interpret,
-        name="flash_attention_bwd",   # the kernel's name in a device trace
+        # the kernel's name in a device trace
+        name="flash_window_bwd" if window else "flash_attention_bwd",
         **extra,
     )(q.reshape(bh, t, d), k.reshape(-1, tk, d), v.reshape(-1, tk, dv),
       g.reshape(bh, t, dv), lse.reshape(bh, 1, t), delta.reshape(bh, 1, t))
@@ -818,22 +929,42 @@ def _pad_head_dim(*xs):
 _BLOCK_Q, _BLOCK_K = 1024, 1024
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _window(q, k, causal, window):
+    """``window`` as both kernels take it: 0 for none, and for one that
+    masks nothing (``window >= T``: the call then IS the causal call, bit
+    for bit). Refuses a window the mask is not defined for."""
+    if not window:
+        return 0
+    if window < 0 or not causal or q.shape[2] != k.shape[2]:
+        from ...base import MXNetError
+        raise MXNetError(
+            "flash_attention: window=%r needs causal=True, a positive "
+            "width and as many keys as queries (key j is visible to query "
+            "i iff i - window < j <= i); got causal=%r, %d queries, %d keys"
+            % (window, causal, q.shape[2], k.shape[2]))
+    return 0 if window >= q.shape[2] else int(window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=_BLOCK_Q,
-                    block_k=_BLOCK_K):
+                    block_k=_BLOCK_K, window=0):
     """Fused attention [B, H, T, D] -> [B, H, T, D]; falls back to XLA softmax
-    off-TPU or for non-divisible shapes."""
-    out, _ = _fa_fwd(q, k, v, causal, scale, block_q, block_k)
+    off-TPU or for non-divisible shapes. ``causal``: key ``j`` is visible
+    to query ``i`` iff ``j <= i``; with ``window = W > 0`` (causal only)
+    iff ``i - W < j <= i``, the query's own key among the ``W``
+    (transformers' sliding-window mask)."""
+    out, _ = _fa_fwd(q, k, v, causal, scale, block_q, block_k, window)
     return out
 
 
-def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
-    out, _, res = _fa_lse_fwd_impl(q, k, v, causal, scale, block_q, block_k)
+def _fa_fwd(q, k, v, causal, scale, block_q, block_k, window=0):
+    out, _, res = _fa_lse_fwd_impl(q, k, v, causal, scale, block_q, block_k,
+                                   window)
     return out, res
 
 
 def _fa_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
-                 g_lse=None):
+                 g_lse=None, window=0):
     """The backward of a forward that ran the Pallas kernel (``lse`` was
     saved): the fused kernel, or the blockwise XLA path for a case the
     kernel refuses, counted with its reason like the forward's fallbacks."""
@@ -842,27 +973,31 @@ def _fa_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     if blocks is None:
         telemetry.inc("pallas_flash.bwd_xla")
         telemetry.inc("pallas_flash.bwd_fallback", tag=refused)
+        if window:      # the plain path visits the pairs left of the window
+            telemetry.inc("pallas_flash.window_unskipped")
         # plain jax (no lane constraint), but its k-block must DIVIDE tk —
         # the scan would silently drop a ragged tail otherwise
         block_k = _pick_block(k.shape[2], block_k, 1) or k.shape[2]
         return _fa_backward_blockwise(q, k, v, out, lse.reshape(q.shape[:3]),
-                                      g, causal, scale, block_k, g_lse=g_lse)
+                                      g, causal, scale, block_k, g_lse=g_lse,
+                                      window=window)
     telemetry.inc("pallas_flash.bwd_pallas")
     return _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, *blocks,
-                               g_lse=g_lse)
+                               g_lse=g_lse, window=window)
 
 
-def _fa_bwd(causal, scale, block_q, block_k, res, g):
+def _fa_bwd(causal, scale, block_q, block_k, window, res, g):
     q, k, v, out, lse = res
+    window = _window(q, k, causal, window)
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if lse is None:
         # fallback path: differentiate the XLA implementation directly
-        _, vjp = jax.vjp(lambda q_, k_, v_:
-                         _xla_attention(q_, k_, v_, causal, scale), q, k, v)
+        _, vjp = jax.vjp(lambda q_, k_, v_: _xla_attention(
+            q_, k_, v_, causal, scale, window), q, k, v)
         return vjp(g)
     return _fa_backward(q, k, v, out, lse, g, causal, scale, block_q,
-                        block_k)
+                        block_k, window=window)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
@@ -880,18 +1015,23 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     return out, lse
 
 
-def _fa_lse_fwd_impl(q, k, v, causal, scale, block_q, block_k):
+def _fa_lse_fwd_impl(q, k, v, causal, scale, block_q, block_k, window=0):
+    from ... import telemetry
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
+    window = _window(q, k, causal, window)
     if _group(q, k) > 1:
-        from ... import telemetry
         telemetry.inc("pallas_flash.grouped")
+    if window:
+        telemetry.inc("pallas_flash.windowed")
     blocks = _resolve_blocks(q, k, v, block_q, block_k)
     if blocks is None:
-        out, lse = _xla_attention_lse(q, k, v, causal, scale)
+        if window:      # the plain path visits the pairs left of the window
+            telemetry.inc("pallas_flash.window_unskipped")
+        out, lse = _xla_attention_lse(q, k, v, causal, scale, window)
         return out, lse, (q, k, v, out, None)
     out, lse = _fa_forward_pallas(*_pad_head_dim(q, k, v), causal, scale,
-                                  *blocks)
+                                  *blocks, window)
     if out.shape[-1] != v.shape[-1]:
         out = out[..., :v.shape[-1]]
     # the residual is the kernel's own (bh, 1, t) rows, which the backward

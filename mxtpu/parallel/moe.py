@@ -33,7 +33,11 @@ backward switch that computes no grouped product twice: six a branch. A
 switch's results have one shape whatever branch ran, so the kept pair is
 laid out once, at the last rung's ``[T*k, F]``, and a lower rung fills its
 first rows and zero-fills the rest; everything else the backward reads
-(the gathered rows, ``silu * up``) it makes again from x and the pair.
+(the gathered rows, ``act(gate) * up``) it makes again from x and the pair.
+The gate's activation (``silu`` or ``relu``) and the router's score
+(``sigmoid`` or ``softmax``) are properties of a model, named by its
+configuration; a router may read another tensor than the experts do
+(``router_x``: a router placed ahead of attention).
 """
 from __future__ import annotations
 
@@ -97,18 +101,30 @@ def switch_ffn(x, router_w, w1, b1, w2, b2, capacity_factor=1.25):
     return out, aux
 
 
-def route_top_k(x, router_w, score_bias, top_k, scale=1.0):
-    """The router of DeepSeek-V3 (arXiv:2412.19437 §2.1.2) without its
-    group limit: ``s = sigmoid(x W^T)`` with the product in float32
+def route_top_k(x, router_w, score_bias, top_k, scale=1.0, score="sigmoid"):
+    """A top-k router: ``s = score(x W^T)`` with the product in float32
     (``router_w`` is (E, D), a Dense weight); the ``top_k`` experts are
     chosen by ``s + score_bias`` (the selection bias steers the choice
     only and takes no gradient); their weights are ``s`` at the chosen
     experts, normalised to sum to one, times ``scale``. Returns ``(idx,
-    w)``, both (T, top_k): int32 experts and float32 weights."""
+    w)``, both (T, top_k): int32 experts and float32 weights.
+
+    ``score`` names the published router, a property of the model:
+    ``"sigmoid"`` is DeepSeek-V3's (arXiv:2412.19437 §2.1.2) without its
+    group limit, each expert scored alone (kanana-2, LFM2 with its expert
+    bias); ``"softmax"`` is the softmax over all E logits in float32, its
+    ``top_k`` largest renormalised, which equals the softmax over the
+    chosen logits alone (Mixtral's, and SmallThinker's with
+    ``moe_primary_router_apply_softmax`` and ``norm_topk_prob``); a model
+    without a selection bias holds that leaf at zero."""
+    if score not in ("sigmoid", "softmax"):
+        raise MXNetError("route_top_k: score %r is neither 'sigmoid' nor "
+                         "'softmax'" % (score,))
     with jax.named_scope("moe.route"):
-        s = jax.nn.sigmoid(jnp.einsum(
+        s = jnp.einsum(
             "td,ed->te", x.astype(jnp.float32), router_w.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
+            precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(s) if score == "sigmoid" else jax.nn.softmax(s, -1)
         _, idx = jax.lax.top_k(
             s + jax.lax.stop_gradient(score_bias.astype(jnp.float32)), top_k)
         w = jnp.take_along_axis(s, idx, axis=-1)
@@ -202,7 +218,8 @@ def _grouped(a, b, sizes, dims=_ROWS):
 
 
 def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
-               first_expert=0, scale=1.0, grouped=True):
+               first_expert=0, scale=1.0, grouped=True, router_x=None,
+               score="sigmoid", activation="silu"):
     """Top-k routed gated FFN over the experts HELD here: a contiguous
     range of the router's experts (expert parallelism's share of a layer;
     all of them when ``w_gate`` holds as many as the router scores).
@@ -211,7 +228,7 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
     every one of its ``top_k`` choices: there is no capacity and nothing is
     dropped. The (token, slot) pairs that chose an expert held here are
     sorted by expert (:func:`piece_plan`; ``moe.dispatch`` gathers their
-    tokens), run through ``w_down[e](silu(x w_gate[e]) * (x w_up[e]))`` as
+    tokens), run through ``w_down[e](act(x w_gate[e]) * (x w_up[e]))`` as
     grouped products over exactly the rows of each expert
     (``moe.experts``), and added into their tokens' rows in float32 under
     the router's weights (``moe.combine``: a scatter-add by token id, no
@@ -233,6 +250,15 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
     w_gate, w_up : (H, D, F) — the held experts' gate and up projections.
     w_down : (H, F, D).
     first_expert : index, among the router's E, of the first expert held.
+    router_x : (T, D) or None — what the router scores, where that is not
+        what the experts read (a router placed ahead of attention reads
+        the layer's input; the experts the normalised state after it). The
+        weights' gradient then goes through the router to ``router_x``,
+        not to ``x``. Counted in ``moe.router_ahead``.
+    score : the router's score function (:func:`route_top_k`).
+    activation : the gate's: ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU:
+        ``relu(x w_gate) * (x w_up)``). Like ``score`` a property of the
+        model, named by its configuration.
     grouped : False computes every held expert on every token under a
         mask (T*H expert passes instead of the T*k/E*H chosen): the plain
         form, kept as the in-program check of the grouped one. Counted in
@@ -253,6 +279,9 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
     if not 0 <= first_expert <= total - held:
         raise MXNetError("experts %d..%d are not among the router's %d"
                          % (first_expert, first_expert + held - 1, total))
+    if activation not in _GATES:
+        raise MXNetError("routed_ffn: activation %r is none of %s"
+                         % (activation, sorted(_GATES)))
     telemetry.inc("moe.layers")
     telemetry.inc("moe.experts_held", held)
     telemetry.inc("moe.experts_total", total)
@@ -260,7 +289,12 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
         telemetry.inc("moe.grouped_mm.grouped")
     else:
         telemetry.inc("moe.grouped_mm.dense")
-    idx, w = route_top_k(x, router_w, score_bias, top_k, scale)
+    if router_x is not None:
+        telemetry.inc("moe.router_ahead")
+    if score == "softmax":
+        telemetry.inc("moe.score.softmax")
+    idx, w = route_top_k(x if router_x is None else router_x, router_w,
+                         score_bias, top_k, scale, score)
     if not grouped:
         from ..ops.precision_util import contract_acc
         local = idx - first_expert
@@ -268,20 +302,25 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
         for e in range(held):
             w_e = jnp.sum(jnp.where(local == e, w, 0.0), axis=-1)
             y = _expert(x, w_gate[e], w_up[e], w_down[e],
-                        lambda a, b: contract_acc(jnp.matmul, a, b))
+                        lambda a, b: contract_acc(jnp.matmul, a, b),
+                        _GATES[activation])
             out = out + w_e[:, None] * y.astype(jnp.float32)
         return out.astype(x.dtype)
 
     plan = piece_plan(idx, first_expert, held, total)
     telemetry.inc("moe.rows_total", t * top_k)
     telemetry.inc("moe.piece_rows", plan.rungs[0])
-    out = _held_part(top_k, plan.rungs, x, w, w_gate, w_up, w_down,
-                     plan.order, plan.sizes, plan.rung)
+    out = _held_part(top_k, plan.rungs, activation, x, w, w_gate, w_up,
+                     w_down, plan.order, plan.sizes, plan.rung)
     return out.astype(x.dtype)
 
 
-def _expert(xs, e_gate, e_up, e_down, mm):
-    return mm(jax.nn.silu(mm(xs, e_gate)) * mm(xs, e_up), e_down)
+# the gate's activation by the name a configuration gives it
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _expert(xs, e_gate, e_up, e_down, mm, act):
+    return mm(act(mm(xs, e_gate)) * mm(xs, e_up), e_down)
 
 
 def _sum_by_token(rows, tok, tokens):
@@ -303,7 +342,8 @@ def _rows_here(rows, top_k, x, w, order, sizes):
     return pairs, tok, live, jnp.where(live, x[tok], 0), w_row
 
 
-def _held_rows(rows, top_k, keep, x, w, w_gate, w_up, w_down, order, sizes):
+def _held_rows(rows, top_k, keep, x, w, w_gate, w_up, w_down, order, sizes,
+               activation="silu"):
     """The held experts' part over the first ``rows`` sorted pairs (every
     live one is among them): (T, D) float32; with ``keep`` also the two up
     products, padded to all T*k rows, for :func:`_held_rows_bwd`."""
@@ -312,7 +352,7 @@ def _held_rows(rows, top_k, keep, x, w, w_gate, w_up, w_down, order, sizes):
     with jax.named_scope("moe.experts"):
         gate = _grouped(xs, w_gate, sizes)
         up = _grouped(xs, w_up, sizes)
-        ys = _grouped(jax.nn.silu(gate) * up, w_down, sizes)
+        ys = _grouped(_GATES[activation](gate) * up, w_down, sizes)
     with jax.named_scope("moe.combine"):
         # float32 under the router's weights, summed by token. A row past
         # the live ones holds nothing defined: it is zeroed BEFORE it meets
@@ -326,7 +366,7 @@ def _held_rows(rows, top_k, keep, x, w, w_gate, w_up, w_down, order, sizes):
 
 
 def _held_rows_bwd(rows, top_k, g, gate, up, x, w, w_gate, w_up, w_down,
-                   order, sizes):
+                   order, sizes, activation="silu"):
     """The transpose of :func:`_held_rows` at ``rows`` rows over the up
     products its forward kept: the cotangents of x, w and the three expert
     leaves from ``g``, (T, D) float32, in six grouped products. The
@@ -354,15 +394,19 @@ def _held_rows_bwd(rows, top_k, g, gate, up, x, w, w_gate, w_up, w_down,
         gate = jnp.where(live, gate[:rows], 0)
         up = jnp.where(live, up[:rows], 0)
         dt = gate.dtype
-        h = jax.nn.silu(gate) * up
+        h = _GATES[activation](gate) * up
         t = back(gy.astype(dt), w_down)
         d_w_row = jnp.sum(h.astype(f32) * t, axis=-1)
         dw_down = mm(h, (w_row * gy).astype(dt), _WEIGHTS)
         dh, gate32 = w_row * t, gate.astype(f32)
-        sig = jax.nn.sigmoid(gate32)
-        d_gate = (dh * up.astype(f32) * sig
-                  * (1 + gate32 * (1 - sig))).astype(dt)
-        d_up = (dh * gate32 * sig).astype(dt)
+        if activation == "silu":
+            sig = jax.nn.sigmoid(gate32)
+            d_gate = (dh * up.astype(f32) * sig
+                      * (1 + gate32 * (1 - sig))).astype(dt)
+            d_up = (dh * gate32 * sig).astype(dt)
+        else:       # relu: its slope is the gate's sign, 0 at 0 as jax's
+            d_gate = jnp.where(gate32 > 0, dh * up.astype(f32), 0).astype(dt)
+            d_up = (dh * jnp.maximum(gate32, 0)).astype(dt)
         dxs = back(d_gate, w_gate) + back(d_up, w_up)
         dw_gate, dw_up = mm(xs, d_gate, _WEIGHTS), mm(xs, d_up, _WEIGHTS)
     with jax.named_scope("moe.dispatch"):
@@ -374,15 +418,17 @@ def _held_rows_bwd(rows, top_k, g, gate, up, x, w, w_gate, w_up, w_down,
             dw_down.astype(w_down.dtype))
 
 
-def _switch(rung, rungs, part, static, *operands):
-    """``part(rows, *static, *operands)`` at ``rows = rungs[rung]``."""
+def _switch(rung, rungs, part, static, *operands, **named):
+    """``part(rows, *static, *operands, **named)`` at ``rows =
+    rungs[rung]``."""
     return jax.lax.switch(
-        rung, [functools.partial(part, rows, *static) for rows in rungs],
-        *operands)
+        rung, [functools.partial(part, rows, *static, **named)
+               for rows in rungs], *operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_part(top_k, rungs, x, w, w_gate, w_up, w_down, order, sizes, rung):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_part(top_k, rungs, activation, x, w, w_gate, w_up, w_down, order,
+               sizes, rung):
     """:func:`_held_rows` at the row count ``rungs[rung]``: one
     ``lax.switch`` over the static counts, so a step pays for the rung its
     live rows fit and the worst case (every pair live) still runs them all.
@@ -395,27 +441,27 @@ def _held_part(top_k, rungs, x, w, w_gate, w_up, w_down, order, sizes, rung):
     shape, whichever branch ran, so the pair is laid out at the last rung's
     ``[T*k, F]``: a lower rung fills its first ``rows`` rows and zero-fills
     the rest, at most one write of the pair. That is why only the pair
-    crosses (2F a row, bf16 in the cells): the gathered rows, ``silu * up``
+    crosses (2F a row, bf16 in the cells): the gathered rows, ``act * up``
     and everything float32 or D wide are cheaper to make again in the
     branch than to lay out at T*k rows. Without a gradient nothing is kept.
     Counted at trace time: ``moe.kept_bytes``, ``moe.bwd_products``."""
-    return _switch(rung, rungs, _held_rows, (top_k, False),
-                   x, w, w_gate, w_up, w_down, order, sizes)
+    return _switch(rung, rungs, _held_rows, (top_k, False), x, w, w_gate,
+                   w_up, w_down, order, sizes, activation=activation)
 
 
-def _held_part_fwd(top_k, rungs, *args):
+def _held_part_fwd(top_k, rungs, activation, *args):
     from .. import telemetry
     *operands, rung = args
     out, gate, up = _switch(rung, rungs, _held_rows, (top_k, True),
-                            *operands)
+                            *operands, activation=activation)
     telemetry.inc("moe.kept_bytes", gate.nbytes + up.nbytes)
     return out, (gate, up) + args
 
 
-def _held_part_bwd(top_k, rungs, kept, g):
+def _held_part_bwd(top_k, rungs, activation, kept, g):
     *operands, rung = kept
-    return _switch(rung, rungs, _held_rows_bwd, (top_k,),
-                   g, *operands) + (None, None, None)
+    return _switch(rung, rungs, _held_rows_bwd, (top_k,), g, *operands,
+                   activation=activation) + (None, None, None)
 
 
 _held_part.defvjp(_held_part_fwd, _held_part_bwd)

@@ -45,13 +45,21 @@ def test_rehearsal_one_chip_phases(tmp_path):
     phases = _phases(proc.stdout)
     assert list(phases) == ["device", "sync", "train_resnet50",
                             "train_bert_base", "flash_two_widths",
-                            "flash_grouped", "routed_layer", "gluon_trainer",
+                            "flash_grouped", "window_attention",
+                            "routed_layer", "gluon_trainer",
                             "serve", "warm_start", "total"]
     assert max(phases["flash_two_widths"]["gaps"].values()) <= 2e-2
     assert max(phases["flash_grouped"]["gaps"].values()) <= 2e-2
     # off the chip the plain path runs, which repeats K and V and says so
     assert phases["flash_grouped"]["pallas_flash"]["grouped"] == 1
     assert phases["flash_grouped"]["pallas_flash"]["kv_repeated"] > 0
+    # both calls of a window / global stack's attention; off the chip the
+    # windowed one takes the plain path, which skips nothing, and says so
+    for name, windowed in (("windowed", 1), ("global", 0)):
+        call = phases["window_attention"][name]
+        assert max(call["gaps"].values()) <= 2e-2
+        assert call["pallas_flash"]["windowed"] == windowed
+        assert call["pallas_flash"]["window_unskipped"] == windowed
     rows = phases["routed_layer"]["rows"]
     assert 0 < rows["live"] <= rows["run"] < rows["total"]
     assert max(phases["routed_layer"]["gaps"].values()) <= 3e-2

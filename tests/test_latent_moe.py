@@ -410,11 +410,11 @@ def test_rows_past_the_last_group_hold_nothing_defined(small_tile,
     else:
         backward = moe._held_rows_bwd
 
-        def poison(rows, top_k, g, gate, up, *operands):
+        def poison(rows, top_k, g, gate, up, *operands, **named):
             n = jnp.sum(operands[-1])
             assert gate.shape == up.shape == (T_ * K, F_)
             return backward(rows, top_k, g, _dead_rows_poisoned(gate, n),
-                            _dead_rows_poisoned(up, n), *operands)
+                            _dead_rows_poisoned(up, n), *operands, **named)
 
         monkeypatch.setattr(moe, "_held_rows_bwd", poison)
     got = _routed_grads(x, leaves, held, first, True)

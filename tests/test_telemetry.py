@@ -273,10 +273,14 @@ def test_dispatch_stats_is_view_over_registry():
         telemetry.tagged("pallas_flash.fallback")
     assert set(fa.DISPATCH_STATS.keys()) == \
         {"pallas", "xla", "fallback_reasons", "grouped", "kv_repeated",
-         "bwd_pallas", "bwd_xla", "bwd_fallback_reasons", "block_pairs"}
+         "bwd_pallas", "bwd_xla", "bwd_fallback_reasons", "block_pairs",
+         "windowed", "window_unskipped"}
     # equal heads: not a grouped call, and nothing was repeated
     assert fa.DISPATCH_STATS["grouped"] == 0
     assert fa.DISPATCH_STATS["kv_repeated"] == 0
+    # no window: not a windowed call
+    assert fa.DISPATCH_STATS["windowed"] == 0
+    assert fa.DISPATCH_STATS["window_unskipped"] == 0
     # a forward that fell back counted no block pairs
     assert fa.DISPATCH_STATS["block_pairs"] == {}
     fa.reset_dispatch_stats()

@@ -204,6 +204,44 @@ def test_flash_grouped_heads_compile_to_mosaic(one_chip, as_tpu):
     assert stats["grouped"] == 1 and stats["kv_repeated"] == 0
 
 
+# a window / global stack's attention at the smallthinker_21b_a3b cell's
+# shape: 28 query heads over 4 key/value heads of 128, unpadded, one
+# sequence of 16,384; dq of a head and dk, dv of a whole key/value head over
+# its seven query heads wait in VMEM, so the backward's q block halves
+@pytest.mark.parametrize("window", [4096, 0], ids=["windowed", "global"])
+def test_flash_window_compiles_to_mosaic(one_chip, as_tpu, window):
+    q = _spec((1, 28, 16384, 128), one_chip)
+    kv = _spec((1, 4, 16384, 128), one_chip)
+    fa.reset_dispatch_stats()
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, True, window=window)
+                       .astype(jnp.float32) ** 2)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") >= 2
+    # a kernel's ``name=`` is its instruction's name (the scope
+    # ``flash_attention_bwd`` is on both calls' prologue and epilogue)
+    kernels = set(re.findall(
+        r"%\w*?(flash_(?:window|attention)_(?:fwd|bwd))[_.\d]* = \(", text))
+    assert kernels == ({"flash_window_fwd", "flash_window_bwd"} if window
+                       else {"flash_attention_fwd", "flash_attention_bwd"})
+    assert "bf16[1,4,16384,128]" in text        # dk, dv at the 4 heads
+    assert fa._resolve_bwd_blocks(q, kv, kv, fa._BLOCK_Q, fa._BLOCK_K) == (
+        (512, 1024), None)
+    stats = dict(fa.DISPATCH_STATS.items())
+    assert (stats["pallas"], stats["bwd_pallas"]) == (1, 1)
+    assert stats["xla"] == 0 and stats["bwd_xla"] == 0
+    assert stats["grouped"] == 1 and stats["kv_repeated"] == 0
+    assert stats["windowed"] == (1 if window else 0)
+    assert stats["window_unskipped"] == 0
+    # the forward's blocks of 1,024: 70 live pairs a windowed head where
+    # the causal one has 136, of 256
+    pairs = stats["block_pairs"]
+    assert pairs["visible"] + pairs["crossed"] == (70 if window else 136)
+    assert pairs["skipped"] == 256 - (70 if window else 136)
+
+
 def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
     """The expert layer at the cell's widths (16 of 128 experts held, 6
     choices a token, 8,192 tokens): XLA:TPU takes ``ragged_dot`` as its
