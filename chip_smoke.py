@@ -250,15 +250,25 @@ def phase_train_bert_base(sizes, seed, on_tpu):
     import importlib
 
     import bench
+    from mxtpu import telemetry
     fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
     _seed(seed)
     fa.reset_dispatch_stats()
+    ce = {path: "loss.softmax_ce." + path
+          for path in ("one_pass", "materialized")}
+    for name in ce.values():
+        telemetry.reset_metric(name)
     step, (tokens, labels) = bench.build_bert_base_step(
         dtype="bfloat16", **sizes["bert"])
     losses = _losses(lambda: step(tokens, labels), sizes["train_steps"])
     _check_training(losses, "train_bert_base")
     stats = dict(fa.DISPATCH_STATS.items())
-    rec = {"model": sizes["bert"], "losses": losses, "pallas_flash": stats}
+    rec = {"model": sizes["bert"], "losses": losses, "pallas_flash": stats,
+           "softmax_ce": {path: telemetry.value(name)
+                          for path, name in ce.items()}}
+    # the loss read the logits once: no log-softmax array of their size
+    _check(rec["softmax_ce"] == {"one_pass": 1, "materialized": 0},
+           "the traced step's loss calls by path: %s" % rec["softmax_ce"])
     if on_tpu:
         # the flash kernels ran compiled, forward and backward: not
         # interpreted (the flag is an error on the chip), not replaced by

@@ -2,6 +2,7 @@
 KLDiv, CTC, Huber, Hinge/SquaredHinge, Logistic, Triplet, Poisson NLL)."""
 from __future__ import annotations
 
+from .. import telemetry
 from ..base import MXNetError, numeric_types
 from .block import HybridBlock
 
@@ -99,11 +100,17 @@ class SoftmaxCrossEntropyLoss(Loss):
         self._from_logits = from_logits
 
     def hybrid_forward(self, F, pred, label, sample_weight=None):
-        if not self._from_logits:
-            pred = F.log_softmax(pred, self._axis)
-        if self._sparse_label:
+        if self._sparse_label and not self._from_logits:
+            # one pass over the logits, no log-softmax array
+            # (ops/nn.py:log_softmax_at)
+            loss = -F._contrib_log_softmax_pick(pred, label, axis=self._axis,
+                                                keepdims=True)
+        elif self._sparse_label:
             loss = -F.pick(pred, label, axis=self._axis, keepdims=True)
         else:
+            if not self._from_logits:
+                telemetry.inc("loss.softmax_ce.materialized")
+                pred = F.log_softmax(pred, self._axis)
             label = _reshape_like(F, label, pred)
             loss = -F.sum(pred * label, axis=self._axis, keepdims=True)
         loss = _apply_weighting(F, loss, self._weight, sample_weight)

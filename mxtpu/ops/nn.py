@@ -261,6 +261,38 @@ def log_softmax(x, axis=-1, temperature=None, **_ig):
     return jax.nn.log_softmax(x, axis=axis)
 
 
+def log_softmax_at(data, label, axis=-1):
+    """``log_softmax(data)[label]`` along ``axis`` (kept, at size 1) as
+    ``data[label] - logsumexp(data)``: both terms reduced in float32 from one
+    read of ``data``, the labelled element by a one-hot select. Spelled as
+    ``pick(log_softmax(data), label)`` XLA writes the whole log-softmax array
+    (and, for logits reshaped from [B, T, V], a relayout copy of them) to
+    gather one element a row; this form's gradient, ``onehot - softmax``,
+    fuses into whatever reads it."""
+    from .. import telemetry
+    telemetry.inc("loss.softmax_ce.one_pass")
+    with jax.named_scope("softmax_ce"):
+        idx = jnp.clip(label.astype(jnp.int32), 0, data.shape[axis] - 1)
+        hit = (lax.broadcasted_iota(jnp.int32, data.shape, axis % data.ndim)
+               == jnp.expand_dims(idx, axis))
+        x = data.astype(jnp.float32)
+        top = lax.stop_gradient(jnp.max(x, axis=axis, keepdims=True))
+        lse = top + jnp.log(jnp.sum(jnp.exp(x - top), axis=axis, keepdims=True))
+        at = jnp.sum(jnp.where(hit, x, 0.0), axis=axis, keepdims=True)
+        return (at - lse).astype(data.dtype)
+
+
+@register("_contrib_log_softmax_pick", aliases=("log_softmax_pick",))
+def log_softmax_pick(data, label, axis=-1, keepdims=True):
+    """``pick(log_softmax(data, axis), label, axis, keepdims)`` without the
+    log-softmax array (``log_softmax_at``). ``label`` holds class ids (any
+    numeric dtype, clipped to the axis as ``pick``'s ``mode="clip"``) in
+    ``data``'s shape less ``axis``. float32 arithmetic whatever ``data``'s
+    dtype, one rounding to it."""
+    out = log_softmax_at(data, label, axis)
+    return out if keepdims else jnp.squeeze(out, axis=axis)
+
+
 @register("softmin")
 def softmin(x, axis=-1, **_ig):
     return jax.nn.softmax(-x, axis=axis)

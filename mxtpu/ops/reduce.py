@@ -9,7 +9,6 @@ MXNet reduce semantics: ``axis`` may be int/tuple/None, ``keepdims`` bool, and
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .registry import register
@@ -168,7 +167,5 @@ def L2Normalization(x, eps=1e-10, mode="instance"):
 @register("softmax_cross_entropy")
 def softmax_cross_entropy(data, label):
     """Fused CE (ref: src/operator/loss_binary_op.cc). Returns scalar sum."""
-    logp = jax.nn.log_softmax(data, axis=-1)
-    lab = label.astype(jnp.int32)
-    picked = jnp.take_along_axis(logp, lab[:, None], axis=-1)
-    return -jnp.sum(picked)
+    from .nn import log_softmax_at
+    return -jnp.sum(log_softmax_at(data, label))

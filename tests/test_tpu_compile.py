@@ -277,3 +277,39 @@ def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
     kept = arrays_outside_control_flow(text, 49152, 768)
     assert kept and all(line.count("[49152,768]")
                         == line.count("bf16[49152,768]") for line in kept)
+
+
+def test_vocabulary_head_loss_reads_the_logits_once(one_chip, as_tpu):
+    """BERT's head and loss with their gradient (``[16,512,768] x
+    [30522,768]`` through ``nn.Dense(flatten=False)`` and
+    ``SoftmaxCrossEntropyLoss``): the only result of 8,192 x 30,522 elements
+    is the forward product's logits. Spelled ``pick(log_softmax(pred))`` the
+    loss cost a relayout ``copy`` of them, a second ``bf16[8192,30522]``
+    (the log-softmax) and a gather over it: 1.00 GB of temporaries, three
+    operations and 3 ms of a 51 ms step (PERF.md, PR 33)."""
+    from mxtpu import gluon
+    from mxtpu.parallel.train import pure_forward
+    head = gluon.nn.Dense(30522, use_bias=False, flatten=False, in_units=768)
+    head.initialize()
+    head.cast("bfloat16")
+    head_fn, _ = pure_forward(head, train=True)
+    loss_fn, _ = pure_forward(gluon.loss.SoftmaxCrossEntropyLoss(),
+                              train=True)
+
+    def loss(w, x, y):
+        logits = head_fn([w], x).reshape((-1, 30522))
+        return jnp.mean(loss_fn([], logits, y.reshape((-1,)))
+                        .astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        _spec((30522, 768), one_chip), _spec((16, 512, 768), one_chip),
+        _spec((16, 512), one_chip, jnp.float32)).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):].splitlines()
+    whole = [line for line in entry
+             if re.search(r"\[(16,512|8192),30522\]", line)
+             and "get-tuple-element(" not in line]
+    assert len(whole) == 1, whole
+    assert " fusion(" in whole[0] and "kind=kOutput" in whole[0]
+    assert " gather(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
