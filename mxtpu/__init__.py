@@ -10,8 +10,13 @@ Use ``import mxtpu as mx`` — the namespace mirrors ``import mxnet as mx``.
 """
 
 import os as _os
+import time as _time
 
-import jax as _jax
+# the start of ``mxtpu.import``, the restart's first span: recorded at the
+# last line of this file, once the telemetry module is there to take it
+_T0_NS = _time.perf_counter_ns()
+
+import jax as _jax  # noqa: E402
 
 # float32 contractions stay honest f32 (without this, JAX's default silently
 # downcasts f32 matmuls to one-pass bf16, breaking reference-parity numerics —
@@ -28,6 +33,10 @@ from .base import Context, MXNetError, cpu, current_context, gpu, num_gpus, tpu
 # stdlib-only, imported FIRST among the framework modules: every later
 # module (ndarray's d2h counter, the trainer's step phases) may hook it
 from . import telemetry
+# JAX's compile events reach the ring and the ``compile.*`` counters from
+# here on: the programs a restart compiles before its first span (parameter
+# load, optimizer state) are part of its account
+telemetry.watch_compiles()
 from . import perf_model
 from . import xprof
 from . import autograd
@@ -95,3 +104,5 @@ from . import torch_interop
 # reference import hook (kvstore_server.py:75): a DMLC_ROLE=server process
 # must fail fast with the migration note, not silently join as a worker
 kvstore_server._init_kvstore_server_module()
+# JAX's own import is inside only where the caller had not imported it yet
+telemetry.record_interval("mxtpu.import", _T0_NS, cat="setup")

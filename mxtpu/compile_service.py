@@ -507,7 +507,6 @@ def _disk_load(key):
     if path is None:
         return None
     if not os.path.exists(path):
-        telemetry.inc("compile.disk.misses", tag=key.site)
         return None
     if _known_unloadable(path):
         return _drop_blob("unloadable", key.site, path)
@@ -830,7 +829,6 @@ def warmup(entries, threads=None):
     if len(entries) > 1 and n > 1:
         pool.shutdown(wait=True)
     summary["wall_s"] = time.perf_counter() - t0
-    telemetry.observe("compile.warmup_s", summary["wall_s"])
     if first_err is not None:
         raise first_err
     return summary

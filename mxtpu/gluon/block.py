@@ -30,6 +30,7 @@ analog of ``CachedOp::Backward`` executing the cached backward graph.
 """
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 from collections import OrderedDict
@@ -273,10 +274,15 @@ class Block:
             child.hybridize(active, **kwargs)
 
     def cast(self, dtype):
-        for child in self._children.values():
-            child.cast(dtype)
-        for p in self.params.values():
-            p.cast(dtype)
+        # ``gluon.cast`` is the root of the walk alone: a child's cast,
+        # called from inside it, opens none of its own
+        root = telemetry.open_span() != "gluon.cast"
+        with telemetry.span("gluon.cast", cat="setup") if root \
+                else contextlib.nullcontext():
+            for child in self._children.values():
+                child.cast(dtype)
+            for p in self.params.values():
+                p.cast(dtype)
 
     def save_parameters(self, filename):
         """Ref: block.py:save_parameters — strips this block's prefix so files are

@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from .. import initializer as init_mod
+from .. import telemetry
 from ..base import MXNetError, current_context
 from ..ndarray import NDArray
 from ..ndarray.ndarray import _as_jax_dtype
@@ -103,20 +104,26 @@ class Parameter:
         self._finish_init(init, default_init)
 
     def _finish_init(self, init, default_init):
-        data = NDArray(jnp.zeros(self.shape, _as_jax_dtype(self.dtype)))
-        chosen = init or self.init
-        if chosen is not None:
-            # reference mechanism (gluon/parameter.py _finish_deferred_init):
-            # an explicitly-chosen initializer rides the InitDesc attrs and
-            # the dispatcher forces it through _init_weight — otherwise the
-            # name dispatch would send e.g. bias_initializer=Constant(3)
-            # through the *bias → zeros rule and silently ignore it
-            desc = init_mod.InitDesc(self.name, attrs={"__init__": chosen})
-        else:
-            desc = init_mod.InitDesc(self.name)
-        init_mod.create(default_init)(desc, data)
-        self._load_init_data(data)
-        self._deferred_init = None
+        # where the array is made, for ``initialize`` and a deferred init
+        # alike: one ``gluon.param.init`` span a leaf (a restart's account,
+        # docs/observability.md)
+        with telemetry.span("gluon.param.init", cat="setup"):
+            data = NDArray(jnp.zeros(self.shape, _as_jax_dtype(self.dtype)))
+            chosen = init or self.init
+            if chosen is not None:
+                # reference mechanism (gluon/parameter.py
+                # _finish_deferred_init): an explicitly-chosen initializer
+                # rides the InitDesc attrs and the dispatcher forces it
+                # through _init_weight — otherwise the name dispatch would
+                # send e.g. bias_initializer=Constant(3) through the *bias →
+                # zeros rule and silently ignore it
+                desc = init_mod.InitDesc(self.name,
+                                         attrs={"__init__": chosen})
+            else:
+                desc = init_mod.InitDesc(self.name)
+            init_mod.create(default_init)(desc, data)
+            self._load_init_data(data)
+            self._deferred_init = None
 
     def _load_init_data(self, data: NDArray):
         self._data = data
@@ -182,20 +189,25 @@ class Parameter:
             d._grad._set_data(jnp.zeros_like(d._grad._data))
 
     def set_data(self, data):
-        if self._data is None:
-            if self.shape is None or any(s == 0 for s in self.shape):
-                self._shape_resolved(data.shape)
-            self._load_init_data(NDArray(data._data if isinstance(data, NDArray) else data))
-        else:
-            src = data._data if isinstance(data, NDArray) else data
-            d = jnp.asarray(src, dtype=self._data._data.dtype)
-            if d is src:
-                # matching dtype aliases the caller's buffer zero-copy; the
-                # fused optimizer step DONATES parameter buffers in place
-                # (optimizer_fused.py), which would delete the caller's
-                # array on the next Trainer.step — take our own copy
-                d = d.copy()
-            self._data._set_data(d)
+        # one span a leaf: a restart loads some hundreds, and where its
+        # seconds went is read off these (docs/observability.md)
+        with telemetry.span("gluon.param.set_data", cat="setup"):
+            if self._data is None:
+                if self.shape is None or any(s == 0 for s in self.shape):
+                    self._shape_resolved(data.shape)
+                self._load_init_data(NDArray(
+                    data._data if isinstance(data, NDArray) else data))
+            else:
+                src = data._data if isinstance(data, NDArray) else data
+                d = jnp.asarray(src, dtype=self._data._data.dtype)
+                if d is src:
+                    # matching dtype aliases the caller's buffer zero-copy;
+                    # the fused optimizer step DONATES parameter buffers in
+                    # place (optimizer_fused.py), which would delete the
+                    # caller's array on the next Trainer.step — take our
+                    # own copy
+                    d = d.copy()
+                self._data._set_data(d)
 
     def _update_aux(self, new_data):
         """Write mutable aux state (moving stats). Under a hybrid trace the update

@@ -125,6 +125,19 @@ class ShardedTrainStep:
                  optimizer_params=None, data_axis="data", param_specs=(),
                  batch_specs=None, forward=None, donate=True,
                  shard_weight_update=False):
+        # one ``train_step.init`` trace (mxtpu/telemetry.py) with the
+        # children ``.place_params`` / ``.create_states`` / ``.place_states``:
+        # where a restart's seconds go before the first step. What the
+        # children leave of the root is the rule lookup and the sharding
+        # plans
+        with telemetry.span("train_step.init", cat="setup", new_trace=True):
+            self._init(block, loss, mesh, optimizer, optimizer_params,
+                       data_axis, param_specs, batch_specs, forward, donate,
+                       shard_weight_update)
+
+    def _init(self, block, loss, mesh, optimizer, optimizer_params,
+              data_axis, param_specs, batch_specs, forward, donate,
+              shard_weight_update):
         self._block = block
         self._loss = loss
         self._mesh = mesh
@@ -189,19 +202,21 @@ class ShardedTrainStep:
         rules = [(re.compile(pat), spec) for pat, spec in param_specs]
         self._param_shardings = [
             NamedSharding(mesh, self._spec_for(p, rules)) for p in params]
-        self._param_datas = [
-            self._place(p.data()._data, s)
-            for p, s in zip(params, self._param_shardings)]
-        for p, d in zip(params, self._param_datas):
-            p.data()._set_data(d)
+        with telemetry.span("train_step.init.place_params", cat="setup"):
+            self._param_datas = [
+                self._place(p.data()._data, s)
+                for p, s in zip(params, self._param_shardings)]
+            for p, d in zip(params, self._param_datas):
+                p.data()._set_data(d)
         # optimizer state in the RULE's structure (None | array | tuple —
         # exactly what the optimizer's create_state builds and the shared
         # step fn consumes), materialized up front and placed on the mesh
-        raw_states = [
-            _tree_data(self._opt.create_state_multi_precision(
-                i, NDArray(d))) if t else None
-            for i, (d, t) in enumerate(zip(self._param_datas,
-                                           self._trainable))]
+        with telemetry.span("train_step.init.create_states", cat="setup"):
+            raw_states = [
+                _tree_data(self._opt.create_state_multi_precision(
+                    i, NDArray(d))) if t else None
+                for i, (d, t) in enumerate(zip(self._param_datas,
+                                               self._trainable))]
 
         # ZeRO-1 / cross-replica weight-update sharding (Xu et al. 2020,
         # arXiv:2004.13336 — PAPERS.md): optimizer state of replicated
@@ -226,10 +241,11 @@ class ShardedTrainStep:
             _state_sharding(sh, d, st)
             for d, st, sh in zip(self._param_datas, raw_states,
                                  self._param_shardings)]
-        self._opt_states = [
-            jax.tree_util.tree_map(lambda s, _pl=plan: self._place(s, _pl),
-                                   st)
-            for st, plan in zip(raw_states, state_plans)]
+        with telemetry.span("train_step.init.place_states", cat="setup"):
+            self._opt_states = [
+                jax.tree_util.tree_map(
+                    lambda s, _pl=plan: self._place(s, _pl), st)
+                for st, plan in zip(raw_states, state_plans)]
         self._state_shardings = [
             jax.tree_util.tree_map(lambda _s, _pl=plan: _pl, st)
             for st, plan in zip(raw_states, state_plans)]

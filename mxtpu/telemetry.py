@@ -23,7 +23,11 @@ bench-only counters) with no common surface. This module is that surface:
 * **Compile events** — :func:`watch_compiles`: JAX's own account of every
   Python trace, lowering and backend compile, as ring events
   (``jax.trace`` / ``jax.lower`` / ``jax.backend_compile``) and
-  ``compile.*_s`` counters tagged with the span that was open.
+  ``compile.*_s`` counters tagged with the span that was open. ``import
+  mxtpu`` registers the listeners, so the ring holds every compile of the
+  process, a restart's among them: ``mxtpu.import`` (:func:`record_interval`),
+  ``gluon.param.*`` / ``gluon.cast`` and ``train_step.init`` with its three
+  children are the restart's own spans (docs/observability.md).
 * **Retrace watchdog** — jit-cache owners (``optimizer_fused.
   FusedUpdater``, gluon ``CachedOp``) report every compile with its
   cache-key / ``registry.policy_key`` provenance via
@@ -75,6 +79,7 @@ import time
 
 __all__ = ["enabled", "retrace_budget", "inc", "gauge", "observe", "value",
            "tagged", "gauge_value", "reset_metric", "span",
+           "record_interval", "open_span",
            "record_d2h", "d2h_count",
            "record_retrace", "retrace_stats", "snapshot", "report",
            "events", "flush", "jsonl_path", "reset",
@@ -129,9 +134,10 @@ class _OpenLocal(threading.local):
 
 _OPEN = _OpenLocal()
 
-# jax.profiler's (TraceAnnotation, StepTraceAnnotation) once the first span
-# imported them: this module imports nothing of JAX when it is imported
-# (the flight recorder runs in processes that are dying)
+# jax.profiler's (TraceAnnotation, StepTraceAnnotation) once
+# ``watch_compiles`` imported them (``import mxtpu`` calls it): this module
+# itself imports nothing of JAX when it is imported (the flight recorder
+# runs in processes that are dying)
 _TRACE_ME = None
 # jax.monitoring's compile events -> (ring event, counter compile.<x>_s)
 _JAX_DURATIONS = {
@@ -612,12 +618,36 @@ class span:
             "(docs/observability.md)", delta, self.name, occurrences)
 
 
+def record_interval(name, t0_ns, cat="phase"):
+    """Record the interval from ``t0_ns`` (a ``time.perf_counter_ns()``
+    read the caller took earlier) to now as if a span ``name`` had been
+    open over it: one histogram observation, one ring event and, under an
+    active trace, one trace-ring event below the current context. For a
+    region no ``with`` can wrap: ``import mxtpu`` from its first line to
+    its last (``mxtpu.import``). No ``TraceAnnotation``: a profiler cannot
+    be told of an interval that has already begun."""
+    dur_ns = time.perf_counter_ns() - t0_ns
+    if not enabled():
+        return
+    tid = threading.get_ident() & 0xFFFF
+    ctx = _TRACE_CV.get()
+    if ctx is not None:
+        _TRACE_EVENTS.append(("span", ctx.trace_id, next(_SPAN_IDS),
+                              ctx.span_id, name, t0_ns // 1000,
+                              dur_ns // 1000, tid))
+    observe(name, dur_ns * 1e-9)
+    with _LOCK:
+        _EVENTS.append((name, cat, t0_ns // 1000, dur_ns // 1000, tid))
+
+
 # ------------------------------------------------------- JAX compile events
 def watch_compiles():
     """Import ``jax.profiler`` and register this module's ONE pair of
-    ``jax.monitoring`` listeners; idempotent. The first span does it; an
-    entry point that reads the ``compile.*`` counters without having
-    opened a span calls it itself. Every Python trace, lowering and
+    ``jax.monitoring`` listeners; idempotent. ``import mxtpu`` calls it as
+    soon as this module is there, so that the ring and the counters hold
+    every compile of the process, those before the first span too (a
+    restart's parameter load and optimizer state); a span or an entry
+    point may call it again at no cost. Every Python trace, lowering and
     backend compile JAX reports then lands, where it happens:
 
     * in the event ring (and, with tracing on, the trace ring, under the
@@ -645,16 +675,17 @@ def watch_compiles():
     return _TRACE_ME
 
 
-def _open_span_name():
+def open_span():
+    """Name of the innermost span open on this thread, None outside any."""
     sp = _OPEN.span
-    return "untraced" if sp is None else sp.name
+    return None if sp is None else sp.name
 
 
 def _on_jax_duration(event, secs, **_):
     names = _JAX_DURATIONS.get(event)
     if names is None:
         return
-    inc("compile.%s_s" % names[1], secs, tag=_open_span_name())
+    inc("compile.%s_s" % names[1], secs, tag=open_span() or "untraced")
     if not enabled():
         return
     dur_us = int(secs * 1e6)
@@ -672,7 +703,7 @@ def _on_jax_duration(event, secs, **_):
 
 def _on_jax_event(event, **_):
     if event == _JAX_CACHE_HIT:
-        inc("compile.xla_cache_hits", tag=_open_span_name())
+        inc("compile.xla_cache_hits", tag=open_span() or "untraced")
 
 
 # ----------------------------------------------------------- causal tracing

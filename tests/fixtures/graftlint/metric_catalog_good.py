@@ -13,6 +13,7 @@ def documented(i):
     telemetry.inc("dyn.r%d" % i)
     telemetry.inc("tagged.thing", tag="why")
     telemetry.record_retrace("fixture_site")
+    telemetry.record_interval("good.interval", 0)
 
 
 def stale_is_actually_recorded_here():

@@ -138,8 +138,10 @@ def test_no_device_to_host_sync_in_a_step():
 
 
 def test_telemetry_off_records_nothing_and_the_step_still_runs(monkeypatch):
-    step, batch = _step(), _batch()
+    # off before the step is built: ``train_step.init`` and the parameter
+    # load's spans (tests/test_setup_spans.py) are spans like the call's
     monkeypatch.setenv("MXTPU_TELEMETRY", "0")
+    step, batch = _step(), _batch()
     first = float(step(*batch).asnumpy())
     second = float(step(*batch).asnumpy())
     assert second < first

@@ -2,7 +2,7 @@
 directions — the env-var-catalog rule's twin for the telemetry registry.
 
 Every counter/gauge/histogram/span/stage name LITERAL recorded through
-``telemetry.{inc,gauge,observe,span,add_stage}`` (and
+``telemetry.{inc,gauge,observe,span,record_interval,add_stage}`` (and
 ``record_retrace(site)``, counted as ``retrace.<site>``) in the metric
 scopes (``mxtpu/``) must have a table row in the observability catalog
 (first cell, backticked), and every cataloged row must have a surviving
@@ -30,7 +30,7 @@ from ..core import Rule
 
 # writer -> index of the name argument
 _WRITERS = {"inc": 0, "gauge": 0, "observe": 0, "span": 0,
-            "add_stage": 1}
+            "record_interval": 0, "add_stage": 1}
 # declared metric-writing WRAPPERS (any receiver): the name literal lives
 # at the given positional index of the wrapper call, not in a direct
 # telemetry.* call — MicroBatcher._share_stage fans one stage duration
