@@ -453,7 +453,10 @@ def phase_routed_layer(sizes, seed, on_tpu):
     every token by every expert held. On the chip the device's memory is
     filled with NaN and freed first: the grouped kernel leaves the rows
     past the last group unwritten, and whatever they hold must not reach a
-    result. Prints the plan (rows live / run / laid out at most) and what
+    result. Prints the plan (rows live / run / laid out at most), the form
+    each rung's sum by token takes in each direction (``gather`` over every
+    pair or ``scatter``-add of the rung's rows: ``moe._sums_by_gather``, the
+    predicate the layer asks, and what the trace counted of each), and what
     the traced gradient counted: the bytes the forward keeps for the
     backward (its two up products at all T*k rows) and the grouped products
     of one backward branch (six: none computed again)."""
@@ -492,7 +495,8 @@ def phase_routed_layer(sizes, seed, on_tpu):
         return (out,) + g
 
     f32 = lambda a: a.astype(jnp.float32)
-    counters = ("moe.kept_bytes", "moe.bwd_products")
+    counters = ("moe.kept_bytes", "moe.bwd_products",
+                "moe.sum_by_token.gather", "moe.sum_by_token.scatter")
     for name in counters:
         telemetry.reset_metric(name)
     got = grads(True)
@@ -519,8 +523,18 @@ def phase_routed_layer(sizes, seed, on_tpu):
     _check(rows["live"] <= rows["run"] == min(
         r for r in plan.rungs if r >= rows["live"]),
         "the plan runs %s of the rungs %s" % (rows, plan.rungs))
+    # the forward sums rows of the experts' dtype, the backward float32
+    sums = {way: {str(r): "gather" if moe._sums_by_gather(
+        r, rows["total"], size) else "scatter" for r in plan.rungs}
+        for way, size in (("fwd", x.dtype.itemsize), ("bwd", 4))}
+    built = [form for way in sums.values() for form in way.values()]
+    _check(all(kept["moe.sum_by_token." + form] == built.count(form)
+               for form in ("gather", "scatter"))
+           and sums["fwd"][str(rows["total"])] == "gather"
+           and sums["bwd"][str(rows["total"])] == "gather",
+           "the routed layer's sums by token traced %s for %s" % (kept, sums))
     return {"shape": n, "rows": rows, "rungs": list(plan.rungs),
-            "kept_bytes": kept["moe.kept_bytes"],
+            "sums_by_token": sums, "kept_bytes": kept["moe.kept_bytes"],
             "bwd_products": kept["moe.bwd_products"], "gaps": gaps}
 
 

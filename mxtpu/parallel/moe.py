@@ -27,6 +27,18 @@ and a ``lax.switch`` runs the lowest that holds the step's live rows
 (:func:`piece_plan` is that decision, public and pure); the last rung is
 all T*k rows, so the worst case still runs and no shape is dynamic.
 
+A rung's rows are summed into their tokens, forward (``moe.combine``) and in
+the backward's transpose of the dispatch, in float32, in one of two
+spellings (:func:`_sum_by_token`). A scatter-add by token id costs by the
+rung's rows (its indices may collide, so XLA:TPU adds a row at a time); a
+gather of every token's row a slot, summed over the k slots, costs by the
+pairs T*k, whatever the rung, at a fifth to a half of a scatter-added
+row. Both counts are static for a branch of the switch, so each branch and
+direction takes the cheaper by :func:`_sums_by_gather`: the float32 rows of
+a backward scatter-add on a rung under four ninths of the pairs, the bf16
+rows of a forward only under a seventh, and the last rung of every ladder
+(and a layer that holds every expert) gathers both ways.
+
 Under differentiation the forward switch keeps its rung's two up products
 (``xs w_gate`` and ``xs w_up``, in the weights' dtype) for a hand-written
 backward switch that computes no grouped product twice: six a branch. A
@@ -136,6 +148,28 @@ def route_top_k(x, router_w, score_bias, top_k, scale=1.0, score="sigmoid"):
 # table at [T*k, D] has ceil(T*k / 512) + groups - 1 entries)
 _ROW_TILE = 512
 
+# what a sum by token costs on one v5e at 2,048-wide rows, in microseconds,
+# on a LOWER rung, a sixth to a third of the pairs, where the choice is open
+# (the last rung's gather wins two to one whatever these say): each of the
+# rung's float32 rows scatter-added by token id (XLA:TPU sorts the ids and
+# adds a row at a time: 0.114-0.146), and each (token, slot) pair gathered,
+# by the bytes of its element: 0.020-0.023 in bf16, 0.030-0.063 in float32.
+# A gather is not paid by the byte, and it is at these rates only while the
+# rung's rows take under 128 MiB (PERF.md section 6, PR 35). The forms cross
+# at rows / pairs = 0.15 for bf16 rows and 0.44 for float32 ones
+_SCATTER_ADD_ROW_US = 0.135
+_GATHER_PAIR_US = {2: 0.020, 4: 0.060}
+
+
+def _sums_by_gather(rows, pairs, itemsize):
+    """Whether a rung of ``rows`` rows sums them into their tokens by a
+    gather over all ``pairs`` (token, slot) pairs of ``itemsize``-byte
+    elements rather than by a scatter-add of its rows: the scatter-add's
+    cost follows the rung, the gather's the pairs, and both are static for
+    a branch, so this is the whole decision (:func:`_sum_by_token`)."""
+    pair_us = _GATHER_PAIR_US[2 if itemsize <= 2 else 4]
+    return rows * _SCATTER_ADD_ROW_US > pairs * pair_us
+
 
 class PiecePlan(NamedTuple):
     """What :func:`piece_plan` decides for one batch. ``rungs`` is static
@@ -231,10 +265,12 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
     tokens), run through ``w_down[e](act(x w_gate[e]) * (x w_up[e]))`` as
     grouped products over exactly the rows of each expert
     (``moe.experts``), and added into their tokens' rows in float32 under
-    the router's weights (``moe.combine``: a scatter-add by token id, no
-    un-permute over every pair). A choice of an expert that is not held
-    adds nothing; its weight still counts in the normalisation, so the
-    shares of all holders add up to the whole layer.
+    the router's weights (``moe.combine``: on a rung that lays out under a
+    seventh of the pairs a scatter-add by token id, no un-permute over
+    every pair; on a larger one a gather of each token's row a slot and a
+    sum over the k slots: :func:`_sum_by_token`). A choice of an expert that
+    is not held adds nothing; its weight still counts in the normalisation,
+    so the shares of all holders add up to the whole layer.
 
     All of it runs over ``rungs[rung]`` sorted rows, the lowest of the
     static row counts of :func:`_rungs` that holds this step's live rows:
@@ -271,7 +307,9 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
     ``moe.piece_rows`` (its lowest rung, what it lays out at least); under
     differentiation ``moe.kept_bytes`` (what the forward keeps for the
     backward: :func:`_held_part`) and ``moe.bwd_products`` (the grouped
-    products of one backward branch).
+    products of one backward branch); ``moe.sum_by_token.gather`` /
+    ``moe.sum_by_token.scatter``, one for each branch and direction built
+    (:func:`_sum_by_token`).
     """
     from .. import telemetry
     t, d = x.shape
@@ -323,23 +361,83 @@ def _expert(xs, e_gate, e_up, e_down, mm, act):
     return mm(act(mm(xs, e_gate)) * mm(xs, e_up), e_down)
 
 
-def _sum_by_token(rows, tok, tokens):
-    """(tokens, D) float32: each token's rows of ``rows`` summed in float32
-    (a scatter-add in bf16 would round after every one of a token's up to k
-    rows)."""
-    return jnp.zeros((tokens,) + rows.shape[1:], jnp.float32).at[tok].add(
-        rows.astype(jnp.float32))
+def _sum_by_token(ys, top_k, order, sizes, w=None):
+    """(T, D) float32: each token's rows of ``ys`` (the first sorted pairs'
+    rows; those past the live ones hold nothing defined and are selected
+    away before any arithmetic), under the router's weights ``w`` (T, k) if
+    given, summed in float32 (a sum in bf16 would round after every one of
+    a token's up to k rows). One sum in two spellings, chosen for each rung
+    and direction from static shapes alone (:func:`_sums_by_gather`):
+
+    * a scatter-add of the rung's rows by token id. Its indices may
+      collide, so XLA:TPU adds a row at a time and the cost follows the
+      rung's rows: the cheaper form where a rung lays out a small share of
+      the pairs (16 of 128 experts held: the float32 rows of the backward
+      at 8,192 and at 16,384 rows of 49,152 pairs);
+    * a gather a slot: for each of the k slots the row of every token's
+      pair, (T, D), added up slot by slot (k gathers summed as they come
+      ran 2 ms a layer faster in lfm2's backward than one (k, T, D) gather
+      and a sum over it), from ``pos``, the inverse of ``order`` (one unique
+      int32 scatter of T*k scalars, built only in the branches that take
+      this form). A pair is live where ``pos < sum(sizes)``; a dead pair's
+      clipped index lands on some row of the rung and is masked. The cost
+      follows the pairs whatever the rung, at a fifth to a half of a
+      scatter-added row: the cheaper form where the rung IS most of the
+      pairs (the last rung of every ladder; every expert held). ``ys`` is
+      gathered in its own dtype and widened after the select.
+
+    Counted at trace time, one for each branch and direction built:
+    ``moe.sum_by_token.gather`` / ``moe.sum_by_token.scatter``."""
+    from .. import telemetry
+    f32 = jnp.float32
+    rows, pairs = ys.shape[0], order.shape[0]
+    n_live = jnp.sum(sizes)
+    if _sums_by_gather(rows, pairs, ys.dtype.itemsize):
+        telemetry.inc("moe.sum_by_token.gather")
+        return _gathered_sum(ys, top_k, order, n_live, w)
+    telemetry.inc("moe.sum_by_token.scatter")
+    here = order[:rows]
+    y = jnp.where((jnp.arange(rows) < n_live)[:, None], ys, 0).astype(f32)
+    if w is not None:
+        y = _weights_here(w, here) * y
+    return jnp.zeros((pairs // top_k,) + ys.shape[1:], f32).at[
+        here // top_k].add(y)
 
 
-def _rows_here(rows, top_k, x, w, order, sizes):
+@functools.partial(jax.jit, static_argnums=1, inline=True)
+def _gathered_sum(ys, top_k, order, n_live, w):
+    """The gathering form of :func:`_sum_by_token`. Jitted (and inlined
+    where it is called) so that a step traces it once for each rung's
+    shape and not once a layer: the k slots' worth of small array
+    operations are some forty nested traces a call, each an event for
+    every ``jax.monitoring`` listener, and cost lfm2's set-up 1.5 s and
+    kanana's 4 before."""
+    rows, pairs = ys.shape[0], order.shape[0]
+    pos = jnp.zeros(pairs, jnp.int32).at[order].set(
+        jnp.arange(pairs, dtype=jnp.int32), unique_indices=True)
+    pos = pos.reshape(-1, top_k)
+    out = 0.
+    for slot in range(top_k):
+        at = pos[:, slot]
+        y = ys.at[jnp.minimum(at, rows - 1)].get(mode="promise_in_bounds")
+        y = jnp.where((at < n_live)[:, None], y, 0).astype(jnp.float32)
+        out = out + (y if w is None else w[:, slot, None] * y)
+    return out
+
+
+def _weights_here(w, pairs):
+    """(rows, 1): the router's weights of the sorted ``pairs``."""
+    return w.reshape(-1).at[pairs].get(unique_indices=True)[:, None]
+
+
+def _rows_here(rows, top_k, x, order, sizes):
     """The first ``rows`` sorted pairs: their (token, slot) ids, tokens, the
-    mask of the live ones (rows, 1), their tokens' rows of x with the rows
-    past the live ones zero, and the router's weights (rows, 1)."""
+    mask of the live ones (rows, 1), and their tokens' rows of x with the
+    rows past the live ones zero."""
     pairs = order[:rows]
     tok = pairs // top_k
     live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
-    w_row = w.reshape(-1).at[pairs].get(unique_indices=True)[:, None]
-    return pairs, tok, live, jnp.where(live, x[tok], 0), w_row
+    return pairs, tok, live, jnp.where(live, x[tok], 0)
 
 
 def _held_rows(rows, top_k, keep, x, w, w_gate, w_up, w_down, order, sizes,
@@ -348,7 +446,7 @@ def _held_rows(rows, top_k, keep, x, w, w_gate, w_up, w_down, order, sizes,
     live one is among them): (T, D) float32; with ``keep`` also the two up
     products, padded to all T*k rows, for :func:`_held_rows_bwd`."""
     with jax.named_scope("moe.dispatch"):
-        _, tok, live, xs, w_row = _rows_here(rows, top_k, x, w, order, sizes)
+        xs = _rows_here(rows, top_k, x, order, sizes)[-1]
     with jax.named_scope("moe.experts"):
         gate = _grouped(xs, w_gate, sizes)
         up = _grouped(xs, w_up, sizes)
@@ -357,8 +455,7 @@ def _held_rows(rows, top_k, keep, x, w, w_gate, w_up, w_down, order, sizes,
         # float32 under the router's weights, summed by token. A row past
         # the live ones holds nothing defined: it is zeroed BEFORE it meets
         # its weight (0 * NaN is NaN)
-        y = w_row * jnp.where(live, ys, 0).astype(jnp.float32)
-        out = _sum_by_token(y, tok, x.shape[0])
+        out = _sum_by_token(ys, top_k, order, sizes, w)
     if not keep:
         return out
     tail = ((0, order.shape[0] - rows), (0, 0))
@@ -387,8 +484,8 @@ def _held_rows_bwd(rows, top_k, g, gate, up, x, w, w_gate, w_up, w_down,
         return jnp.where(live, mm(a, jnp.swapaxes(b, 1, 2)), 0).astype(f32)
 
     with jax.named_scope("moe.dispatch"):
-        pairs, tok, live, xs, w_row = _rows_here(rows, top_k, x, w, order,
-                                                 sizes)
+        pairs, tok, live, xs = _rows_here(rows, top_k, x, order, sizes)
+        w_row = _weights_here(w, pairs)
         gy = jnp.where(live, g[tok], 0)
     with jax.named_scope("moe.experts"):
         gate = jnp.where(live, gate[:rows], 0)
@@ -410,7 +507,7 @@ def _held_rows_bwd(rows, top_k, g, gate, up, x, w, w_gate, w_up, w_down,
         dxs = back(d_gate, w_gate) + back(d_up, w_up)
         dw_gate, dw_up = mm(xs, d_gate, _WEIGHTS), mm(xs, d_up, _WEIGHTS)
     with jax.named_scope("moe.dispatch"):
-        dx = _sum_by_token(dxs, tok, x.shape[0]).astype(x.dtype)
+        dx = _sum_by_token(dxs, top_k, order, sizes).astype(x.dtype)
     with jax.named_scope("moe.combine"):
         dw = jnp.zeros(w.size, w.dtype).at[pairs].set(
             d_w_row.astype(w.dtype), unique_indices=True).reshape(w.shape)

@@ -43,6 +43,31 @@ def grouped_kernels(text):
     return sorted(n for n in counts if n)
 
 
+def branch_computations(text):
+    """For each ``conditional`` of the module, in the text's order: for each
+    of its branches, in the switch's order, the instruction lines of the
+    branch's computation and of everything it calls (its fusions' bodies
+    among them)."""
+    comps = computations(text)
+    calls = {name: {c for line in lines for c in _CALLEE.findall(line)}
+             for name, lines in comps.items()}
+    return [[[line for name in sorted(_reachable(calls, [branch]))
+              for line in comps.get(name, ())] for branch in _names(found)]
+            for line in conditionals(text)
+            for found in _BRANCHES.findall(line)]
+
+
+def _reachable(calls, roots):
+    """The computations ``roots`` and whatever they call."""
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo.extend(calls.get(name, ()))
+    return seen
+
+
 def _names(found):
     return [n.strip().lstrip("%") for n in found.split(",") if n.strip()]
 
@@ -59,13 +84,7 @@ def under_control_flow(comps):
             for found in _BRANCHES.findall(line):
                 calls[name].update(_names(found))
                 roots.update(_names(found))
-    seen, todo = set(), list(roots)
-    while todo:
-        name = todo.pop()
-        if name not in seen:
-            seen.add(name)
-            todo.extend(calls.get(name, ()))
-    return seen
+    return _reachable(calls, roots)
 
 
 def arrays_outside_control_flow(text, rows, cols):

@@ -2,6 +2,7 @@
 gated MLP) and ``parallel.moe.routed_ffn`` against the plain reference the
 benchmark keeps (``benchmark/reference/kanana2_30b_a3b.py``), at the
 configuration's rehearsal sizes, in float32 on seeded weights."""
+import functools
 import json
 import os
 
@@ -259,6 +260,21 @@ def small_tile(monkeypatch):
     assert moe._rungs(T_ * K, E, E) == (192,)
 
 
+FORMS = {"predicate": None, "gather": True, "scatter": False}
+
+
+@pytest.fixture(params=sorted(FORMS))
+def sum_form(request, monkeypatch):
+    """How the rungs sum their rows by token: as ``_sums_by_gather`` says
+    (at the toy ladder, 32 / 64 / 192 of 192 pairs, the last rung gathers
+    in both directions and bf16 rows at every rung), or every rung
+    and direction forced to the gather, or to the scatter-add."""
+    forced = FORMS[request.param]
+    if forced is not None:
+        monkeypatch.setattr(moe, "_sums_by_gather", lambda *shape: forced)
+    return request.param
+
+
 def _steered(n_live, held, first, dtype, seed=11):
     """A layer whose router reads its choices off x: token t chooses
     ``n_live`` experts held in all (spread as evenly as its held experts
@@ -326,13 +342,26 @@ LIVE = {  # name: (pairs routed here, experts held, the first, rows run)
 }
 
 
+@functools.lru_cache(maxsize=None)
+def _plain_and_reference(case, dtype):
+    """A case's masked form and float32 reference: the same whatever form
+    the grouped path's sums take, so made once."""
+    n_live, held, first, _ = LIVE[case]
+    x, leaves = _steered(n_live, held, first, jnp.dtype(dtype))
+    return (_routed_grads(x, leaves, held, first, False),
+            _reference_grads(x, leaves, held, first))
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("case", sorted(LIVE))
-def test_rows_routed_here_forward_and_gradients(small_tile, case, dtype):
+def test_rows_routed_here_forward_and_gradients(small_tile, sum_form, case,
+                                                dtype):
     """The output and the gradients of x, the router and the three expert
     leaves, at every share of live rows and on both sides of each rung's
     edge, against the masked form (the same arithmetic on every token) and
-    the float32 reference; the plan runs the rung expected."""
+    the float32 reference; the plan runs the rung expected. Under the form
+    of the sum by token the predicate picks, and under each form forced on
+    every rung and direction."""
     n_live, held, first, rows = LIVE[case]
     x, leaves = _steered(n_live, held, first, jnp.dtype(dtype))
     idx, _ = moe.route_top_k(x, leaves[0], leaves[1], K)
@@ -340,8 +369,7 @@ def test_rows_routed_here_forward_and_gradients(small_tile, case, dtype):
     assert int(plan.n_live) == n_live
     assert int(plan.rows) == rows
     got = _routed_grads(x, leaves, held, first, True)
-    plain = _routed_grads(x, leaves, held, first, False)
-    want = _reference_grads(x, leaves, held, first)
+    plain, want = _plain_and_reference(case, dtype)
     tol = 1e-5 if dtype == "float32" else 3e-2
     for a, b, c in zip(got, plain, want):
         assert a.dtype == b.dtype == jnp.dtype(dtype)
@@ -385,7 +413,7 @@ def _dead_rows_poisoned(a, n_live):
 
 @pytest.mark.parametrize("poisoned", ["products", "kept"])
 @pytest.mark.parametrize("case", ["none", "eighth", "on_the_edge"])
-def test_rows_past_the_last_group_hold_nothing_defined(small_tile,
+def test_rows_past_the_last_group_hold_nothing_defined(small_tile, sum_form,
                                                        monkeypatch, case,
                                                        poisoned):
     """XLA:TPU's grouped kernel leaves the rows past the last group
@@ -394,7 +422,9 @@ def test_rows_past_the_last_group_hold_nothing_defined(small_tile,
     those rows poisoned in every grouped result, forward and backward, the
     layer gives what it gave; and so it does with every row past the live
     ones of the KEPT products poisoned between forward and backward, the
-    zero-filled tail included: the backward selects before it multiplies."""
+    zero-filled tail included: the backward selects before it multiplies.
+    Under both forms of the sum by token, in both directions: a dead pair's
+    clipped index lands on a poisoned row and is masked."""
     n_live, held, first, _ = LIVE[case]
     x, leaves = _steered(n_live, held, first, jnp.float32)
     want = _routed_grads(x, leaves, held, first, True)
@@ -539,6 +569,71 @@ def test_the_layer_counts_what_it_traced():
     assert telemetry.value("moe.kept_bytes") == 2 * 1024 * K * F_ * 2
     assert telemetry.value("moe.bwd_products") == 6
     assert telemetry.value("moe.layers") == 3
+
+
+# (pairs, the ladder's rungs): lfm2's 8 of 32 experts at two sequences of
+# 8,192, kanana's 16 of 128 at 8,192 tokens, smallthinker's 8 of 64 at
+# 16,384, and every expert held (one rung: the pairs)
+LADDERS = {"lfm2": (16384 * 4, 8, 32), "kanana": (8192 * 6, 16, 128),
+           "smallthinker": (16384 * 6, 8, 64), "all_held": (8192 * 6, 16, 16)}
+
+
+@pytest.mark.parametrize("cell", sorted(LADDERS))
+def test_which_rungs_sum_by_a_gather(cell):
+    """``_sums_by_gather`` at the expert cells' ladders, for the forward's
+    bf16 rows and the backward's float32 ones: the last rung of every
+    ladder (and so every expert held) gathers in both directions; a first
+    rung a sixth of the pairs (kanana's, smallthinker's) gathers its bf16
+    rows and scatter-adds its float32 ones, and so do their second rungs
+    and lfm2's 22,016 of 65,536, a third. A form taken at a rung is taken
+    at every larger one, and a gather of float32 rows implies the gather of
+    bf16 ones."""
+    pairs, held, total = LADDERS[cell]
+    rungs = moe._rungs(pairs, held, total)
+    assert rungs == {"lfm2": (22016, 65536), "kanana": (8192, 16384, 49152),
+                     "smallthinker": (16384, 32768, 98304),
+                     "all_held": (49152,)}[cell]
+    forms = {size: [moe._sums_by_gather(rows, pairs, size) for rows in rungs]
+             for size in (2, 4)}
+    for size in (2, 4):
+        assert forms[size][-1] is True
+        assert forms[size] == sorted(forms[size])
+    assert all(a or not b for a, b in zip(forms[2], forms[4]))
+    assert all(forms[2])                        # from a sixth of the pairs
+    assert not any(forms[4][:-1])               # up to a third of them
+    # a rung an eighth of the pairs (1 of 16 experts held by the even
+    # share: 3 of 32 by the ladder's rule) scatter-adds both ways
+    assert not moe._sums_by_gather(pairs // 8, pairs, 2)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_each_branch_and_direction_counts_its_form(small_tile, dtype):
+    """``moe.sum_by_token.gather`` / ``.scatter``: one increment for each
+    branch and direction built. The toy ladder is 32, 64, 192 of 192 pairs:
+    a forward alone builds three branches, a gradient three more; the last
+    rung gathers in both directions, the first scatter-adds its float32
+    rows and gathers bf16 ones, and both counters say so."""
+    from mxtpu import telemetry
+    names = ("moe.sum_by_token.gather", "moe.sum_by_token.scatter")
+    size = jnp.dtype(dtype).itemsize
+    x, leaves = _steered(24, HELD, FIRST, jnp.dtype(dtype))
+    args = (x, leaves[0]) + tuple(w[FIRST:FIRST + HELD].astype(x.dtype)
+                                  for w in leaves[2:5])
+    fwd = [moe._sums_by_gather(rows, T_ * K, size) for rows in (32, 64, 192)]
+    bwd = [moe._sums_by_gather(rows, T_ * K, 4) for rows in (32, 64, 192)]
+    assert fwd[-1] and bwd[-1] and not bwd[0]
+    assert fwd[0] == (dtype == "bfloat16")
+
+    def counted(fn):
+        for name in names:
+            telemetry.reset_metric(name)
+        jax.block_until_ready(fn(*args))
+        return tuple(telemetry.value(name) for name in names)
+
+    loss = _layer_loss(leaves[1])
+    assert counted(loss) == (sum(fwd), 3 - sum(fwd))
+    assert counted(jax.grad(loss, argnums=(0, 1, 2, 3, 4))) == (
+        sum(fwd) + sum(bwd), 6 - sum(fwd) - sum(bwd))
 
 
 def test_a_range_outside_the_router_is_refused():

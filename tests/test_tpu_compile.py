@@ -279,6 +279,65 @@ def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
                         == line.count("bf16[49152,768]") for line in kept)
 
 
+def test_last_rung_sums_by_token_with_a_gather(one_chip, as_tpu):
+    """The expert layer at lfm2's widths (8 of 32 experts held, 4 choices a
+    token, two sequences of 8,192; the ladder 22,016 / 65,536 of 65,536
+    pairs) with its gradient: the last rung's two branches hold no float32
+    ``[*, 2048]`` scatter with an add combiner (each sums by token with a
+    gather a slot, four of ``[16384,2048]``, bf16 forward and float32
+    backward, from ``pos``, an ``s32[65536]`` unique scatter built inside
+    the branch); the low rung's backward, a third of the pairs,
+    still scatter-adds its 22,016 float32 rows. Forced to the scatter-add
+    everywhere (the parent's form) all four branches hold one. Prints
+    ``memory_analysis()``'s temporaries of both."""
+    from _hlo_text import branch_computations, grouped_kernels
+    from mxtpu.parallel import moe
+    specs = (_spec((16384, 2048), one_chip), _spec((32, 2048), one_chip),
+             _spec((32,), one_chip), _spec((8, 2048, 1792), one_chip),
+             _spec((8, 2048, 1792), one_chip),
+             _spec((8, 1792, 2048), one_chip))
+    assert moe._rungs(65536, 8, 32) == (22016, 65536)
+
+    def compiled():
+        def loss(x, router, bias, eg, eu, ed):  # a new function a form
+            return jnp.sum(jnp.sin(moe.routed_ffn(
+                x, router, bias, eg, eu, ed, top_k=4).astype(jnp.float32)))
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+            *specs).compile()
+
+    def forms(text):
+        """[forward, backward] x [low rung, last rung]: (float32 [*, 2048]
+        scatter-adds, gathers of a slot's rows, unique scatters of ``pos``)."""
+        switches = branch_computations(text)
+        assert [len(branches) for branches in switches] == [2, 2]
+        # the backward's branches hold six grouped kernels, the forward's 3
+        switches.sort(key=lambda branches: sum(
+            "ragged-dot" in line for line in branches[0]))
+        return [[(sum(" scatter(" in line and "f32[16384,2048]" in
+                      line.split(" scatter(")[0] for line in lines),
+                  sum(" gather(" in line and "[16384,2048]" in
+                      line.split(" gather(")[0] for line in lines),
+                  sum(" scatter(" in line and "s32[65536]" in
+                      line.split(" scatter(")[0] for line in lines))
+                 for lines in branches] for branches in switches]
+
+    change = compiled()
+    assert grouped_kernels(change.as_text()) == [3, 3, 6, 6]
+    fwd, bwd = forms(change.as_text())
+    assert fwd[1] == (0, 4, 1) and bwd[1] == (0, 4, 1)
+    assert bwd[0] == (1, 0, 0)
+    assert fwd[0] in ((1, 0, 0), (0, 4, 1))     # as the constants say
+    assert bool(fwd[0][1]) == moe._sums_by_gather(22016, 65536, 2)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "_sums_by_gather", lambda *shape: False)
+        parent = compiled()
+    assert forms(parent.as_text()) == [[(1, 0, 0)] * 2] * 2
+    temps = [c.memory_analysis().temp_size_in_bytes for c in (parent, change)]
+    print("lfm2 layer's gradient, temporaries: scatter-add everywhere %d B, "
+          "the predicate's forms %d B" % tuple(temps))
+    assert temps[1] <= temps[0] + (64 << 20)
+
+
 def test_vocabulary_head_loss_reads_the_logits_once(one_chip, as_tpu):
     """BERT's head and loss with their gradient (``[16,512,768] x
     [30522,768]`` through ``nn.Dense(flatten=False)`` and
