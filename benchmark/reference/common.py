@@ -10,6 +10,25 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
+def _sharding(devices, *axes):
+    """Over ``devices`` (the cell's) on one axis, ``data``; where none are
+    given, nothing is said and JAX places as it does by default."""
+    if devices is None:
+        return None
+    return jax.sharding.NamedSharding(
+        jax.sharding.Mesh(np.array(devices), ("data",)),
+        jax.sharding.PartitionSpec(*axes))
+
+
+def replicated(devices=None):
+    return _sharding(devices)
+
+
+def by_batch(devices=None):
+    """Along the leading axis, over the devices."""
+    return _sharding(devices, "data")
+
+
 def draw(key, shape, dtype, kind, arg):
     """One leaf of seeded weights. ``kind`` says how it is drawn:
     ``normal`` (std ``arg``), ``uniform`` (``arg`` = (lo, hi))."""
@@ -22,14 +41,15 @@ def draw(key, shape, dtype, kind, arg):
     return v.astype(dtype)
 
 
-def init_params(specs, seed):
-    """Every leaf on the device, in the dtype it is stored in, in ONE jitted
-    call from the seed. ``specs``: [(name, shape, dtype, trainable, kind,
-    arg)]."""
+def init_params(specs, seed, devices=None):
+    """Every leaf on every one of ``devices``, in the dtype it is stored in,
+    in ONE jitted call from the seed. ``specs``: [(name, shape, dtype,
+    trainable, kind, arg)]."""
     def make(key):
         keys = jax.random.split(key, len(specs))
         return [draw(k, s[1], s[2], s[4], s[5]) for k, s in zip(keys, specs)]
-    return jax.jit(make)(jax.random.PRNGKey(np.uint32(seed % (2 ** 32))))
+    return jax.jit(make, out_shardings=replicated(devices))(
+        jax.random.PRNGKey(np.uint32(seed % (2 ** 32))))
 
 
 def _round(x, dtype, top):
@@ -142,39 +162,80 @@ def leaf_distances(got, ref):
         float(np.sqrt(np.sum(far ** 2) / np.sum(size ** 2)))
 
 
-def train_reference(forward_loss, specs, opt, seed, batches, precision):
+def _nbytes(leaves):
+    """Bytes ONE device holds of ``leaves``: a shard of each."""
+    return sum(int(np.prod(x.sharding.shard_shape(x.shape)))
+               * x.dtype.itemsize for x in jax.tree_util.tree_leaves(leaves))
+
+
+def train_reference(forward_loss, specs, opt, seed, batches, precision,
+                    devices=None):
     """Follow ``len(batches)`` steps from the seeded weights. ``forward_loss
     (params, x, y, precision) -> (loss, aux)`` where ``aux`` maps the index
     of a non-trainable leaf to its new value. Returns host numbers: each
     step's loss, the first gradient per trainable leaf (from the state
-    after one step), the norm of every leaf's change at the end."""
-    t_idx = [i for i, s in enumerate(specs) if s[3]]
+    after one step), the norm of every leaf's change at the end, and the
+    bytes one device held while the gradient program ran (the leaves and
+    the batch it was given; the compiler's count of the program's arguments,
+    outputs, the gradients among them, and temporaries).
 
-    def step(params, states, t, x, y):
+    Two programs a step, so that the check fits where the program fits. The
+    gradient program takes the leaves (replicated over ``devices``, the
+    cell's) and a batch (sharded along its leading axis: an annotation, the
+    arithmetic is over the whole batch) and gives each trainable leaf's
+    gradient, in the leaf's dtype as JAX has it. The optimizer's rule then
+    runs leaf by leaf, each call given that leaf's weight and state to
+    overwrite; between steps the state waits on the host. While the
+    backward runs a device therefore holds the weights and their gradients,
+    4 bytes a bf16 parameter, and neither the start leaves nor the moments
+    (12 with those, in one program)."""
+    t_idx = [i for i, s in enumerate(specs) if s[3]]
+    rest_idx = [i for i, s in enumerate(specs) if not s[3]]
+
+    def loss_and_grads(train, rest, x, y):
         def of(train):
-            full = list(params)
+            full = [None] * len(specs)
             for i, w in zip(t_idx, train):
                 full[i] = w
+            for i, w in zip(rest_idx, rest):
+                full[i] = w
             return forward_loss(full, x, y, precision)
-        (loss, aux), grads = jax.value_and_grad(of, has_aux=True)(
-            [params[i] for i in t_idx])
-        new, new_states = list(params), []
-        for j, i in enumerate(t_idx):
-            new[i], st = opt_update(opt, t, params[i], grads[j], states[j])
-            new_states.append(st)
-        for i, a in aux.items():
-            new[i] = a.astype(params[i].dtype)
-        return new, new_states, loss
+        return jax.value_and_grad(of, has_aux=True)(train)
 
-    step = jax.jit(step, donate_argnums=(1,))
-    start = init_params(specs, seed)
-    params = start
-    states = [opt_init(opt, start[i]) for i in t_idx]
-    losses, grad = [], None
+    update = jax.jit(lambda t, w, g, state: opt_update(opt, t, w, g, state),
+                     donate_argnums=(1, 3))
+    rep, rows = replicated(devices), by_batch(devices)
+    params = init_params(specs, seed, devices)
+    states = [opt_init(opt, params[i]) for i in t_idx]
+    losses, grad, program, held = [], None, None, None
     for t, (x, y) in enumerate(batches, 1):
-        params, states, loss = step(params, states, jnp.float32(t), x, y)
+        args = ([params[i] for i in t_idx], [params[i] for i in rest_idx],
+                jax.device_put(x, rows), jax.device_put(y, rows))
+        if program is None:
+            program = jax.jit(loss_and_grads, out_shardings=rep).lower(
+                *args).compile()
+            m = program.memory_analysis()
+            held = {"parameters": sum(x.size for x in params),
+                    "leaves": _nbytes(params), "batch": _nbytes(args[2:]),
+                    "program_arguments": int(m.argument_size_in_bytes),
+                    "program_outputs": int(m.output_size_in_bytes),
+                    "program_temporaries": int(m.temp_size_in_bytes)}
+        (loss, aux), grads = program(*args)
+        del args
+        for j, i in enumerate(t_idx):
+            state = states[j] if t == 1 else jax.device_put(states[j], rep)
+            params[i], state = update(jnp.float32(t), params[i], grads[j],
+                                      state)
+            grads[j] = None
+            # to the host: the next backward runs without the moments
+            states[j] = state if t == len(batches) else jax.device_get(state)
+        for i, a in aux.items():
+            params[i] = a.astype(params[i].dtype)
         losses.append(float(loss))
         if t == 1:
             grad = [first_grad(opt, s) for s in states]
-    delta = np.asarray(jax.jit(delta_norms)(params, start))
-    return {"losses": losses, "grads": grad, "delta_norms": delta}
+    del states
+    delta = np.asarray(jax.jit(delta_norms)(params,
+                                            init_params(specs, seed, devices)))
+    return {"losses": losses, "grads": grad, "delta_norms": delta,
+            "bytes": held}

@@ -146,6 +146,10 @@ def run_cell(cell, seed, seconds, trace, out=print):
         if ctx["trace"] is not None:
             device["busy_s"] = ctx["trace"]["busy_s"]
             device["window_s"] = ctx["trace"]["window_s"]
+            # the whole operation table, too long for the result's line
+            with open(os.path.join(cell.out_dir, "trace_ops.json"), "w") as f:
+                json.dump({k: ctx["trace"][k] for k in (
+                    "busy_s", "window_s", "planes", "modules", "ops")}, f)
 
     # the reference runs with the program's state freed, after the device's
     # peak was read, and outside both set-up and the window

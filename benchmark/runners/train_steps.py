@@ -20,13 +20,22 @@ from benchmark.models import common as model_common
 from benchmark.reference import common as ref_common
 
 
-def _pool(ref, cfg, traffic, seed):
-    """Every batch of the pool in one jitted call from the seed."""
+def _devices(cell):
+    """The cell's chips (a rehearsal takes what there is, up to as many)."""
+    return jax.devices()[:cell.chips]
+
+
+def _pool(cell, seed):
+    """Every batch of the pool in one jitted call from the seed, each put
+    out along its leading axis over the cell's devices."""
+    ref, cfg, traffic = cell.module("reference"), cell.cfg, cell.traffic
+
     def make(key):
         return [ref.sample_inputs(cfg, k, traffic["batch"])
                 for k in jax.random.split(key, traffic["pool"])]
     key = jax.random.fold_in(jax.random.PRNGKey(np.uint32(seed % 2 ** 32)), 1)
-    return jax.jit(make)(key)
+    return jax.jit(make, out_shardings=ref_common.by_batch(
+        _devices(cell)))(key)
 
 
 def setup(cell, seed):
@@ -34,11 +43,11 @@ def setup(cell, seed):
     ref, model = cell.module("reference"), cell.module("models")
     opt, n_ref = traffic["optimizer"], traffic["reference_steps"]
     specs = ref.param_specs(cfg)
-    leaves = ref_common.init_params(specs, seed)
+    leaves = ref_common.init_params(specs, seed, _devices(cell))
     start = [jnp.copy(x) for x in leaves]
     net = model.build(cfg, specs, leaves)
     step = model.train_step(cfg, net, opt)
-    pool = [model.batch(cfg, x, y) for x, y in _pool(ref, cfg, traffic, seed)]
+    pool = [model.batch(cfg, x, y) for x, y in _pool(cell, seed)]
 
     # the first steps, through the window's own call and feed
     losses, grad = [], None
@@ -47,11 +56,13 @@ def setup(cell, seed):
         if t == 0:     # to the host: the next step donates these buffers
             grad = [ref_common.first_grad(opt, s) for s in
                     model_common.optimizer_state(step, specs)]
+    after = model_common.trained_leaves(net)
+    # where the program put its leaves (its mesh is its own business)
     delta = jax.jit(ref_common.delta_norms)(
-        model_common.trained_leaves(net), start)
+        after, jax.device_put(start, [a.sharding for a in after]))
     first = {"losses": [float(x.asnumpy()) for x in losses],
              "grads": grad, "delta_norms": np.asarray(delta)}
-    del start, leaves
+    del start, leaves, after
     return {"cell": cell, "seed": seed, "step": step, "net": net,
             "pool": pool, "first": first, "steps_done": n_ref}
 
@@ -119,8 +130,12 @@ def compare(cell, seed, first, precision, tag="run"):
     reference at ``precision``: [(name, value, limit)]."""
     cfg, traffic = cell.cfg, cell.traffic
     ref = cell.module("reference")
-    batches = _pool(ref, cfg, traffic, seed)[:traffic["reference_steps"]]
+    batches = _pool(cell, seed)[:traffic["reference_steps"]]
     want = reference_steps(cell, seed, batches, precision)
+    # what a device held while the reference's backward ran: the compiler's
+    # own count of that program, and the leaves kept outside it
+    for key, value in sorted(want["bytes"].items()):
+        print("note reference_bytes.%s = %r" % (key, value))
     lim = cell.limits
     names = [s[0] for s in ref.param_specs(cfg)]
     trainable = [s[0] for s in ref.param_specs(cfg) if s[3]]
@@ -161,14 +176,12 @@ def reference_steps(cell, seed, batches, precision):
     ref = cell.module("reference")
     return ref_common.train_reference(
         ref.forward_loss(cell.cfg), ref.param_specs(cell.cfg),
-        cell.traffic["optimizer"], seed, batches, precision)
+        cell.traffic["optimizer"], seed, batches, precision, _devices(cell))
 
 
 def control(cell, seed, precision):
     """The reference at ``precision`` in the program's place."""
-    ref = cell.module("reference")
-    batches = _pool(ref, cell.cfg, cell.traffic, seed)[
-        :cell.traffic["reference_steps"]]
+    batches = _pool(cell, seed)[:cell.traffic["reference_steps"]]
     return compare(cell, seed,
                    reference_steps(cell, seed, batches, precision), "float32",
                    tag="control")

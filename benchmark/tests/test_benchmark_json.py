@@ -2,9 +2,11 @@
 traffic, limits, runner, model, reference and flops files exist and load,
 every metric has its reader, and a made-up cell needs one new traffic file,
 one limits file and one entry: no edit of a file that is there."""
+import contextlib
 import copy
 import json
 import os
+import shutil
 
 import pytest
 
@@ -66,10 +68,10 @@ def test_metrics_name_known_things():
     assert json.dumps(SPEC).count("MXTPU_") == 0
 
 
-def test_a_made_up_cell_needs_only_new_files():
-    """What PERF.md's first Open-questions cell will take: a traffic file
-    and a limits file of its own, and an entry. No file that is there is
-    edited, and nothing in the harness names the cell."""
+@contextlib.contextmanager
+def _made_up_cell():
+    """What a four-chip cell over files that exist takes: a traffic file and
+    a limits file of its own, and its entries. -> (record, name)"""
     new = {"traffic": os.path.join(run.HERE, "traffic", "made_up_b256.json"),
            "limits": os.path.join(run.HERE, "limits",
                                   "resnet50_v1.made_up.json")}
@@ -77,8 +79,8 @@ def test_a_made_up_cell_needs_only_new_files():
     try:
         with open(new["traffic"], "w") as f:
             json.dump(dict(base.traffic, batch=256), f)
-        with open(new["limits"], "w") as f:
-            json.dump({"limits": base.limits}, f)
+        shutil.copy(os.path.join(run.HERE, "limits",
+                                 "resnet50_v1.train_b128.json"), new["limits"])
         spec = copy.deepcopy(SPEC)
         spec["workloads"].append({
             "name": "resnet50_v1.made_up", "config": "resnet50_v1",
@@ -86,14 +88,42 @@ def test_a_made_up_cell_needs_only_new_files():
         for m in spec["end_to_end"] + spec["per_layer"]:
             if "resnet50_v1.train_b128" in m.get("workloads", []):
                 m["workloads"].append("resnet50_v1.made_up")
-        made = run.Cell("resnet50_v1.made_up", spec=spec)
+        yield spec, "resnet50_v1.made_up"
+    finally:
+        for path in new.values():
+            if os.path.exists(path):
+                os.remove(path)
+
+
+def test_a_made_up_cell_needs_only_new_files():
+    """No file that is there is edited, and nothing in the harness names
+    the cell."""
+    base = cell("resnet50_v1.train_b128")
+    with _made_up_cell() as (spec, name):
+        made = run.Cell(name, spec=spec)
         assert made.traffic["batch"] == 256 and made.chips == 4
         assert [m["name"] for m in made.end_to_end] == \
             [m["name"] for m in base.end_to_end]
         assert [m["name"] for m in made.per_layer] == \
             [m["name"] for m in base.per_layer]
         assert made.module("runners") is base.module("runners")
-    finally:
-        for path in new.values():
-            if os.path.exists(path):
-                os.remove(path)
+
+
+def test_a_made_up_cell_on_four_chips_runs_through_the_check():
+    """Not the loader alone: set-up, a window and the check, the reference
+    over the four devices the cell names (of the eight the tier-1 run has;
+    of one, a mesh of one)."""
+    import jax
+    with _made_up_cell() as (spec, name):
+        lines = []
+        result = run.run_cell(run.Cell(name, rehearse=True, spec=spec), 7,
+                              1.0, 0, out=lines.append)
+    assert result["correct"] is True, lines
+    assert result["attempted"] > 0 and result["failed"] == 0
+    assert sum(line.startswith("check ") for line in lines) == 4
+    assert result["device"]["count"] == len(jax.devices())
+
+
+# the reference's own cases, collected here so that the tier-1 run, which
+# takes this file whole (tests/test_benchmark_suite.py), takes them too
+from benchmark.tests.reference_checks import *      # noqa: E402,F401,F403

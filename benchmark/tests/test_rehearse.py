@@ -77,18 +77,35 @@ def test_a_step_that_keeps_its_state_is_not_correct(name, monkeypatch):
     assert result["correct"] is False, lines
 
 
-@pytest.mark.parametrize("name", TRAIN)
-def test_a_part_of_the_batch_left_out_is_not_correct(name, monkeypatch):
-    """The second half of every batch replaced by its first half."""
+def _fed_the_first_part(monkeypatch, name, parts):
+    """Every batch of the cell's program made of its first ``1 / parts``,
+    repeated: the rows where there are as many, else (one sequence a step)
+    the positions of each row."""
     model = cell(name, rehearse=True).module("models")
     batch = model.batch
 
-    def halved(cfg, x, y):
-        h = x.shape[0] // 2
-        return batch(cfg, jnp.concatenate([x[:h], x[:h]]),
-                     jnp.concatenate([y[:h], y[:h]]))
+    def cut(cfg, x, y):
+        axis = 0 if x.shape[0] >= parts else 1
+        keep = x.shape[axis] // parts
+        return batch(cfg, *(jnp.concatenate(
+            [jax.lax.slice_in_dim(a, 0, keep, axis=axis)] * parts, axis)
+            for a in (x, y)))
 
-    monkeypatch.setattr(model, "batch", halved)
-    result, lines = _rehearse(name)
+    monkeypatch.setattr(model, "batch", cut)
+    return _rehearse(name)
+
+
+@pytest.mark.parametrize("name", TRAIN)
+def test_a_part_of_the_batch_left_out_is_not_correct(name, monkeypatch):
+    """The second half of every batch replaced by its first half."""
+    result, lines = _fed_the_first_part(monkeypatch, name, 2)
     assert result["correct"] is False, lines
 
+
+@pytest.mark.parametrize("name", [c for c in TRAIN if cell(c).chips > 1])
+def test_the_exchange_between_chips_left_out_is_not_correct(name,
+                                                            monkeypatch):
+    """What a data-parallel step without its exchange computes on chip 0:
+    the step of chip 0's rows alone. Every chip is given those rows."""
+    result, lines = _fed_the_first_part(monkeypatch, name, cell(name).chips)
+    assert result["correct"] is False, lines

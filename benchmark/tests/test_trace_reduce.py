@@ -53,6 +53,80 @@ def test_reduce_on_hand_made_planes():
         20 / 2 / 1e9)
 
 
+def _kernel_table(planes=1):
+    """A step that ran twice a chip: ten operations longer than the flash
+    forward kernel's two calls (5 and 7 ns a run), which rank 11th and
+    12th, and on a second chip the same again."""
+    ops, at = [], 0
+    for _run in range(2):
+        for k in range(10):
+            ops.append(("%%big.%d = f32[] fusion()" % k, at, 100 + k))
+            at += 100 + k
+        for n, d in ((1, 5), (2, 7)):
+            ops.append(("%%flash_attention_fwd.%d = bf16[] custom-call()" % n,
+                        at, d))
+            at += d
+        ops.append(("%all-reduce.3 = f32[] all-reduce()", at, 24))
+        at += 24
+    half = at // 2
+    plane = _plane(ops, [("jit_step(1)", 0, half), ("jit_step(1)", half,
+                                                    half)])
+    return {"/device:TPU:%d" % i: plane for i in range(planes)}, at
+
+
+@pytest.mark.parametrize("planes", [1, 4])
+def test_readers_see_a_kernel_under_the_ten_longest(planes):
+    """The readers get the whole operation table: a kernel's time a call is
+    read wherever it ranks, over the step's runs a chip, on one plane or
+    the mean of four; ``top_ops`` stays the ten longest."""
+    from benchmark import kernel_share, run
+    table, busy = _kernel_table(planes)
+    out = tr.reduce(table)
+    assert out["planes"] == planes and len(out["top_ops"]) == 10
+    assert not any(n.startswith("flash") for n, _s in out["top_ops"])
+    assert out["ops"]["flash_attention_fwd.2"] == pytest.approx(14e-9)
+    assert dict(out["top_ops"]) == {
+        k: v for k, v in out["ops"].items() if k.startswith("big.")}
+    # (5 + 7) ns a run over two calls: 6 ns a call
+    assert kernel_share.seconds_a_call(out, "flash_attention_fwd") == \
+        pytest.approx(6e-9)
+    assert kernel_share.seconds_a_call(out, "flash_attention") is None
+    flops = type("F", (), {"flash_fwd_flops": staticmethod(lambda cfg: 3.0)})
+    ctx = {"trace": out, "peak": {"bf16_flops_per_s": 1e9},
+           "cell": type("C", (), {"cfg": {}, "module": lambda self, k: flops})()}
+    assert run.reader("flash_fwd_mxu_pct.train")(ctx) == pytest.approx(50.0)
+    # a configuration without the windowed count, a trace without the
+    # kernel: nothing, never 0
+    assert run.reader("flash_window_fwd_mxu_pct.train")(ctx) is None
+    assert run.reader("flash_bwd_mxu_pct.train")(ctx) is None
+    assert run.reader("collective_pct.train")(ctx) == pytest.approx(
+        100.0 * 48 / busy)
+    out["ops"].pop("all-reduce.3")
+    assert run.reader("collective_pct.train")(ctx) is None
+
+
+with open(os.path.join(os.path.dirname(DATA), "recorded_ops.json")) as _f:
+    RECORDED_OPS = json.load(_f)["cells"]
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED_OPS))
+def test_kernel_readers_on_a_recorded_operation_table(name):
+    """Two traced chip runs' tables. In kanana's the forward kernel ranks
+    12th (under six backward kernels and five backward switches), which is
+    why its share read ``null`` while the readers saw ten operations; in
+    smallthinker's the windowed forward ranks under ten too. The readers
+    give what those runs' lines gave."""
+    from benchmark import run
+    rec = RECORDED_OPS[name]
+    assert (name.startswith("kanana")) == (
+        rec["first_forward_kernel_rank"] > 10)
+    ctx = {"trace": rec["trace"], "cell": run.Cell(name),
+           "peak": run.peak("TPU v5 lite")}
+    for metric, value in rec["expected"].items():
+        assert run.reader(metric)(ctx) == pytest.approx(value, rel=1e-9)
+        assert 0 < value < 100
+
+
 def test_no_device_plane_reduces_to_nothing():
     assert tr.reduce({}) is None
     assert tr.reduce({"/device:TPU:0": {"Steps": [["0", 0, 5]]}}) is None

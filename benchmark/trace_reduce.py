@@ -71,7 +71,10 @@ def reduce(planes, top=10):
     second core view, say) are left out; what remains is averaged. A gap
     is named by the module it lies inside, or by the modules on either
     side of it (what the host was doing in it needs the program's spans on
-    this clock), and gaps of one name are added up."""
+    this clock), and gaps of one name are added up. ``ops`` is the whole
+    operation table, seconds by name (mean over the planes, like
+    ``top_ops``, which is its ``top`` longest); ``modules`` holds every
+    plane's runs, so a module's runs a chip are its runs over ``planes``."""
     per_chip, modules, ops, gaps = [], {}, {}, {}
     for _name, lines in sorted(planes.items()):
         events = _line(lines, "XLA Ops")
@@ -108,8 +111,9 @@ def reduce(planes, top=10):
         return [[k, v / n / 1e9] for k, v in sorted(
             table.items(), key=lambda kv: -kv[1])[:top]]
 
-    return {"busy_s": busy, "window_s": window,
+    return {"busy_s": busy, "window_s": window, "planes": n,
             "idle_share": 1.0 - busy / window, "modules": modules,
+            "ops": {k: v / n / 1e9 for k, v in ops.items()},
             "top_ops": ranked(ops), "top_gaps": ranked(gaps)}
 
 
