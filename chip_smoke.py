@@ -10,7 +10,10 @@ both flash kernels once more at latent attention's shape (32 heads of
 grouped heads (2 sequences, 32 query heads over 8 key/value heads of 64,
 8,192 positions, causal), and at a window / global stack's attention (28
 query heads over 4 key/value heads of 128, 16,384 positions: once with a
-sliding window of 4,096 keys, once without), and the
+sliding window of 4,096 keys, once without), one layer of sparse attention
+(an indexer of 16 heads of 64 picks 2,048 of up to 16,384 keys a query,
+exactly; 32 query heads over 4 key/value heads of 128 through the sparse
+pair of kernels over those sets), and the
 routed expert layer at one chip's share of kanana-2-30b-a3b's (8,192 tokens
 of 2,048, top-6 of 128 experts of width 768, 16 held).
 
@@ -62,6 +65,10 @@ FULL = dict(
     # heads of 128, the model's whole context, 4,096 keys seen
     window=dict(heads=28, kv_heads=4, seq=16384, width=128, window=4096,
                 check_heads=7),
+    # one layer of sparse attention: 32 query heads over 4 key/value heads
+    # of 128, the 2,048 keys a query an indexer of 16 heads of 64 picks
+    sparse=dict(heads=32, kv_heads=4, seq=16384, width=128, dim=2048,
+                index_heads=16, index_width=64, topk=2048, check_heads=8),
     # one chip's share of a routed expert layer: 16 of 128 experts, top-6
     routed=dict(tokens=8192, dim=2048, width=768, held=16, total=128,
                 top_k=6),
@@ -83,6 +90,8 @@ TINY = dict(
                  check_heads=2),
     window=dict(heads=14, kv_heads=2, seq=256, width=16, window=72,
                 check_heads=7),
+    sparse=dict(heads=8, kv_heads=2, seq=256, width=16, dim=64,
+                index_heads=4, index_width=8, topk=32, check_heads=4),
     routed=dict(tokens=512, dim=64, width=32, held=2, total=16, top_k=3),
     train_steps=5, gluon_steps=3,
     # the toy memorizes its 8 images in three steps (loss 3.4 -> 0.02),
@@ -353,11 +362,12 @@ def phase_flash_kernels(n, seed, on_tpu):
     return {"shape": n, "pallas_flash": stats, "gaps": gaps}
 
 
-def _attention_by_rows(q, k, v, window, rows=1024):
+def _attention_by_rows(q, k, v, window, rows=1024, mask_t=None):
     """Plain float32 causal attention (out, lse), K and V repeated to the
     query heads, masked position by position (key j visible to query i iff
-    ``i - window < j <= i``), ``rows`` queries at a time against all keys:
-    the whole score matrix of 16,384 positions would not fit."""
+    ``i - window < j <= i``; or, with ``mask_t`` [B, T, T] int8, keys first,
+    iff it is in the query's set), ``rows`` queries at a time against all
+    keys: the whole score matrix of 16,384 positions would not fit."""
     import jax
     import jax.numpy as jnp
     group, t = q.shape[1] // k.shape[1], q.shape[2]
@@ -367,7 +377,11 @@ def _attention_by_rows(q, k, v, window, rows=1024):
 
     def block(at):
         i = at + jnp.arange(rows)[:, None]
-        seen = (j <= i) & (j > i - window) if window else j <= i
+        if mask_t is not None:
+            seen = jnp.swapaxes(jax.lax.dynamic_slice_in_dim(
+                mask_t, at, rows, 2), 1, 2)[:, None] != 0
+        else:
+            seen = (j <= i) & (j > i - window) if window else j <= i
         s = jnp.einsum("bhqd,bhkd->bhqk",
                        jax.lax.dynamic_slice_in_dim(q, at, rows, 2), k,
                        precision="highest") * q.shape[-1] ** -0.5
@@ -443,6 +457,80 @@ def phase_window_attention(n, seed, on_tpu):
                % (name, n, gaps))
         rec[name] = {"pallas_flash": stats, "gaps": gaps}
     return rec
+
+
+def phase_sparse_attention(n, seed, on_tpu):
+    """One layer of sparse attention, compiled, at shape ``n``: the
+    indexer's selection (op ``_contrib_index_select``: ``topk`` keys a
+    query of ``seq``, exactly ``min(t + 1, topk)`` each and none ahead,
+    counted here on the device's own result), then both sparse kernels
+    (``sparse_attention_fwd`` / ``_bwd``) over those sets through
+    ``jax.vjp`` of the public function: out against plain float32
+    attention over the sets, dq, dk, dv against the float32 blockwise
+    oracle on that reference's out and lse, on the first ``check_heads``
+    query heads with the key/value heads they read. Fails on the chip if
+    a call took a plain path, which holds [H, T, T]. ``pairs`` are the
+    (query, key) pairs a head the sets hold and the masked form visits."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    from mxtpu import telemetry
+    from mxtpu.ops.registry import get_op
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    group, t, topk = n["heads"] // n["kv_heads"], n["seq"], n["topk"]
+    rng = np.random.RandomState(seed % (2 ** 31))
+    x = jnp.asarray(rng.randn(1, t, n["dim"]), jnp.bfloat16)
+    wq, wk, ww = (jnp.asarray(0.02 * rng.randn(rows, n["dim"]), jnp.bfloat16)
+                  for rows in (n["index_heads"] * n["index_width"],
+                               n["index_width"], n["index_heads"]))
+    q, k, v, g = (jnp.asarray(rng.randn(1, h, t, n["width"]), jnp.bfloat16)
+                  for h in (n["heads"], n["kv_heads"], n["kv_heads"],
+                            n["heads"]))
+    select = jax.jit(lambda *a: get_op("_contrib_index_select").fn(
+        *a, num_heads=n["index_heads"], topk=topk))
+    mask_t = select(x, wq, wk, ww)
+    kept = np.asarray(jnp.sum(mask_t.astype(jnp.int32), axis=1))[0]
+    ahead = int(jnp.sum(jnp.tril(mask_t[0].astype(jnp.int32), -1)))
+    _check(np.array_equal(kept, np.minimum(np.arange(t) + 1, topk))
+           and ahead == 0,
+           "the selection kept %s keys a query (first rows), %d ahead"
+           % (kept[:4].tolist(), ahead))
+    names = ("calls", "fallbacks", "bwd_pallas", "pairs_selected",
+             "pairs_visited")
+    for name in names:
+        telemetry.reset_metric("sparse_attention." + name)
+    out, vjp = jax.vjp(
+        lambda *a: fa.sparse_attention(*a, mask_t, topk=topk), q, k, v)
+    got = (out,) + vjp(g)[:3]
+    stats = {name: telemetry.value("sparse_attention." + name)
+             for name in names}
+    if on_tpu:
+        _check(stats["calls"] == 1 and stats["fallbacks"] == 0
+               and stats["bwd_pallas"] == 1,
+               "a sparse kernel was left for a plain path: %s %s"
+               % (stats, telemetry.tagged("sparse_attention.fallbacks")))
+    _check(stats["pairs_selected"] == topk * t - topk * (topk - 1) // 2,
+           "the call counted %s" % stats)
+    checked = n["check_heads"]
+    f32 = lambda a: a[:, :checked if a.shape[1] == n["heads"]
+                      else checked // group].astype(jnp.float32)
+    want_out, lse = jax.jit(lambda *a: _attention_by_rows(
+        *a, 0, mask_t=mask_t))(f32(q), f32(k), f32(v))
+    want = (want_out,) + jax.jit(
+        lambda *a: fa._fa_backward_blockwise(
+            *a, True, n["width"] ** -0.5, min(1024, t), mask_t=mask_t))(
+        f32(q), f32(k), f32(v), want_out, lse, f32(g))
+    gaps = {what: float(jnp.max(jnp.abs(f32(a) - b)) / jnp.max(jnp.abs(b)))
+            for what, a, b in zip(("out", "dq", "dk", "dv"), got, want)}
+    # bf16 against float32: a set misread, a block skipped that holds a
+    # selected key, or a key/value head's sum left open reads 0.1 or more
+    _check(max(gaps.values()) <= 2e-2,
+           "sparse attention at %s is %s from the float32 oracles"
+           % (n, gaps))
+    return {"shape": n, "sparse_attention": stats, "gaps": gaps,
+            "pairs": {"selected": stats["pairs_selected"],
+                      "visited": stats["pairs_visited"]}}
 
 
 def phase_routed_layer(sizes, seed, on_tpu):
@@ -865,6 +953,8 @@ def run(sizes, chips=1, seed=0, out=sys.stdout):
                   seed, on_tpu)
             phase("window_attention", phase_window_attention,
                   sizes["window"], seed, on_tpu)
+            phase("sparse_attention", phase_sparse_attention,
+                  sizes["sparse"], seed, on_tpu)
             phase("routed_layer", phase_routed_layer, sizes, seed, on_tpu)
             net = phase("gluon_trainer", phase_gluon_trainer, sizes, seed)
             phase("serve", phase_serve, sizes, seed, net)

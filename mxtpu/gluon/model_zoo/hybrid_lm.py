@@ -11,7 +11,10 @@ anywhere: ``h = x + op(norm1(x)); y = h + ffn(norm2(h))``.
   default an RMSNorm over each query and key head and rotary over the
   whole head), ``window_attention`` (the same block, for the layers of a
   window / global stack that see a sliding window: its keyword arguments
-  carry the ``window``) or ``latent_attention``
+  carry the ``window``), ``sparse_attention`` (the same block with a
+  :class:`SparseIndexer` for a child: each query attends to the ``topk``
+  keys a learned indexer scores highest, DeepSeek-Sparse-Attention's
+  selection) or ``latent_attention``
   (:class:`~mxtpu.gluon.model_zoo.latent_moe.MultiHeadLatentAttention`);
 * ``ffn`` is a gated MLP in the first ``dense_layers`` blocks (there may
   be none) and a :class:`~mxtpu.gluon.contrib.nn.RoutedMoE` after them
@@ -33,7 +36,38 @@ from __future__ import annotations
 from ..block import HybridBlock
 from .. import nn
 
-__all__ = ["HybridLM", "DecoderBlock", "GroupedQueryAttention", "OPERATORS"]
+__all__ = ["HybridLM", "DecoderBlock", "GroupedQueryAttention",
+           "SparseIndexer", "OPERATORS"]
+
+
+class SparseIndexer(HybridBlock):
+    """The indexer of DeepSeek-Sparse-Attention (DeepSeek-V3.2-Exp report,
+    eq. 1-2): ``num_heads`` index heads of ``head_dim`` over ONE index key
+    head, and a weight an index head; query ``t`` keeps the ``min(t + 1,
+    topk)`` keys ``s <= t`` of largest ``I[t, s] = sum_j w[t, j] relu(qI[t,
+    j] . kI[s])`` (op ``_contrib_index_select``: float32, exact, of equal
+    scores the lower ``s``). No position encoding and no norm inside. Its
+    three leaves take no gradient (``grad_req="null"``): the sets are
+    integers, so no loss this block is trained under moves them; the
+    objective that would (an index aligned to the attention's scores, its
+    input detached) is a second loss this program does not run.
+
+    Input [B, T, dim]; output the sets as int8 [B, T, T], keys first."""
+
+    def __init__(self, dim, num_heads=16, head_dim=64, topk=2048, **kwargs):
+        super().__init__(**kwargs)
+        self._attrs = {"num_heads": num_heads, "topk": topk}
+        with self.name_scope():
+            self.q_weight, self.k_weight, self.w_weight = (
+                self.params.get(name, shape=(rows, dim), grad_req="null",
+                                differentiable=False)
+                for name, rows in (("q_weight", num_heads * head_dim),
+                                   ("k_weight", head_dim),
+                                   ("w_weight", num_heads)))
+
+    def hybrid_forward(self, F, x, *, q_weight, k_weight, w_weight):
+        return F._contrib_index_select(x, q_weight, k_weight, w_weight,
+                                       **self._attrs)
 
 
 class GroupedQueryAttention(HybridBlock):
@@ -49,11 +83,17 @@ class GroupedQueryAttention(HybridBlock):
     head's entries (one learned scale each; without it the block has no
     such leaves). ``rope``: rotary over the whole head turns them; without
     it the layer carries no position encoding at all. The defaults are
-    LFM2's attention layer."""
+    LFM2's attention layer.
+
+    ``topk = K > 0`` (with ``index_heads`` and ``index_head_dim``): sparse
+    attention. A :class:`SparseIndexer` reads the block's input and keeps
+    ``min(i + 1, K)`` keys a query, one set for all heads, and the softmax
+    runs over the set alone; projections, norms and rotary are unchanged."""
 
     def __init__(self, dim, num_heads, num_kv_heads, rope_theta=10000.0,
                  epsilon=1e-6, head_dim=None, qk_norm=True, rope=True,
-                 window=0, **kwargs):
+                 window=0, topk=0, index_heads=16, index_head_dim=64,
+                 **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError("%d query heads do not divide over %d key/value"
@@ -61,6 +101,10 @@ class GroupedQueryAttention(HybridBlock):
         self._head_dim = head_dim = head_dim or dim // num_heads
         self._attrs = {"rope_theta": rope_theta, "window": window,
                        "rope": rope}
+        if topk and (window or not rope):
+            raise ValueError("sparse attention (topk=%d) is written with "
+                             "rotary and without a window" % topk)
+        self._topk = topk
         self._qk_norm = qk_norm
         with self.name_scope():
             self.q = nn.Dense(num_heads * head_dim, use_bias=False,
@@ -74,6 +118,9 @@ class GroupedQueryAttention(HybridBlock):
                 self.k_norm = nn.RMSNorm(epsilon=epsilon, prefix="knorm_")
             self.proj = nn.Dense(dim, use_bias=False, flatten=False,
                                  prefix="proj_")
+            if topk:
+                self.indexer = SparseIndexer(dim, index_heads, index_head_dim,
+                                             topk, prefix="indexer_")
 
     def hybrid_forward(self, F, x):
         heads = (0, 0, -1, self._head_dim)        # [B, T, H, head_dim]
@@ -83,6 +130,10 @@ class GroupedQueryAttention(HybridBlock):
         k = F.reshape(self.k(x), shape=heads)
         if self._qk_norm:
             k = self.k_norm(k)
+        if self._topk:
+            return self.proj(F._contrib_sparse_attention(
+                q, k, self.v(x), self.indexer(x),
+                rope_theta=self._attrs["rope_theta"], topk=self._topk))
         return self.proj(F._contrib_grouped_attention(
             q, k, self.v(x), **self._attrs))
 
@@ -100,6 +151,8 @@ OPERATORS = {
     # the same block under its own name, so that a stack can give its
     # windowed layers other keyword arguments than its global ones
     "window_attention": (GroupedQueryAttention, "attn_"),
+    # and with a ``topk`` among them: each query's keys picked by an indexer
+    "sparse_attention": (GroupedQueryAttention, "attn_"),
     "latent_attention": (_latent_attention, "attn_"),
 }
 

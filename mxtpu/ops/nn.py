@@ -607,17 +607,166 @@ def grouped_attention(q, k, v, rope_theta=10000.0, window=0, rope=True):
     D]. The call sits under the scope ``window_attention`` with a window,
     ``gqa_attention`` without."""
     from .pallas import flash_attention
-    b, t, h, d = q.shape
     with jax.named_scope("window_attention" if window else "gqa_attention"):
-        if rope:
-            q = rotary(q, rope_theta)
-            k = rotary(k, rope_theta)
-        v = v.reshape(b, t, k.shape[2], -1)
-        out = flash_attention(q.transpose(0, 2, 1, 3),
-                              k.transpose(0, 2, 1, 3),
-                              v.transpose(0, 2, 1, 3), True,
-                              window=window)                    # [B, H, T, D]
-        return out.transpose(0, 2, 1, 3).reshape(b, t, h * v.shape[-1])
+        return _grouped_heads(
+            q, k, v, rope_theta if rope else None,
+            lambda *qkv: flash_attention(*qkv, True, window=window))
+
+
+def _grouped_heads(q, k, v, rope_theta, attend):
+    """What the grouped operators share around their kernels: rotary over
+    the whole head (none with ``rope_theta=None``), heads first, ``attend``
+    on q [B, H, T, D] and k, v [B, H_kv, T, D], and back to [B, T, H * D]."""
+    b, t, h, _ = q.shape
+    if rope_theta is not None:
+        q = rotary(q, rope_theta)
+        k = rotary(k, rope_theta)
+    v = v.reshape(b, t, k.shape[2], -1)
+    out = attend(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                 v.transpose(0, 2, 1, 3))                       # [B, H, T, D]
+    return out.transpose(0, 2, 1, 3).reshape(b, t, h * v.shape[-1])
+
+
+def _kth_largest(u, k, axis):
+    """The ``k``-th largest of the uint32 keys ``u`` along ``axis`` (kept),
+    exactly, from the top bits down: the largest ``v`` that at least ``k``
+    entries reach. Two bits a pass (the three candidates ``v | m << s``
+    are counted in one reading of ``u``): 16 counting passes, no sort."""
+    shape = list(u.shape)
+    shape[axis] = 1
+    marks = jnp.arange(1, 4, dtype=jnp.uint32).reshape([3] + [1] * u.ndim)
+
+    def two_bits(i, v):
+        cand = v[None] | (marks << (30 - 2 * i).astype(jnp.uint32))
+        reach = jnp.sum((u[None] >= cand).astype(jnp.int32), axis=axis + 1,
+                        keepdims=True)
+        # the counts fall as the candidate grows: the largest that k reach
+        best = jnp.sum((reach >= k).astype(jnp.uint32), axis=0)
+        return v | (best << (30 - 2 * i).astype(jnp.uint32))
+
+    return lax.fori_loop(0, 16, two_bits, jnp.zeros(shape, jnp.uint32))
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    bits = lax.bitcast_convert_type(x, jnp.int32)
+    flipped = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+    return lax.bitcast_convert_type(flipped, jnp.uint32) ^ jnp.uint32(1 << 31)
+
+
+def _index_score(q_idx, w_idx, k_idx):
+    """``I^T[s, t] = sum_j w[t, j] relu(qI[t, j] . kI[s])``, KEYS first:
+    float32 [B, Tk, rows] from ``q_idx`` [B, rows, Hi, Di], ``w_idx`` [B,
+    rows, Hi] and ``k_idx`` [B, Tk, Di], one index head at a time."""
+    def head(score, a):
+        qh, wh = a
+        s = jnp.einsum("bsd,bqd->bsq", k_idx, qh,
+                       precision=lax.Precision.HIGHEST)
+        return score + wh[:, None, :] * jax.nn.relu(s), None
+
+    zeros = jnp.zeros(k_idx.shape[:2] + q_idx.shape[1:2], jnp.float32)
+    return lax.scan(head, zeros, (jnp.moveaxis(q_idx, 2, 0),
+                                  jnp.moveaxis(w_idx, 2, 0)))[0]
+
+
+def _select_block(q_idx, w_idx, k_idx, at, topk):
+    """The sets of ``rows`` queries from position ``at`` on, against the
+    keys ``0 .. at + rows``: int8 [B, at + rows, rows], keys first."""
+    b, rows = q_idx.shape[:2]
+    tk = k_idx.shape[1]
+    causal = (jnp.arange(tk)[:, None] <= at + jnp.arange(rows)[None, :])[None]
+    if tk <= topk:                 # every query's set is all it can see
+        return jnp.broadcast_to(causal, (b, tk, rows)).astype(jnp.int8)
+    score = _index_score(q_idx, w_idx, k_idx)
+    # equal scores are one score (-0.0 is 0.0), a key ahead has none
+    score = jnp.where(score == 0, 0.0, score)
+    u = _ordered_bits(jnp.where(causal, score, -jnp.inf))
+    edge = _kth_largest(u, topk, 1)          # the set's smallest score
+    reach = jnp.sum((u >= edge).astype(jnp.int32), axis=1, keepdims=True)
+
+    def level_fits():              # every key level with an edge is in
+        return causal & (u >= edge)
+
+    def lowest_first():
+        # of the keys level with the edge, the lowest positions fill the
+        # set (and a query that sees fewer than topk keys keeps them all)
+        above, level = u > edge, u == edge
+        room = topk - jnp.sum(above.astype(jnp.int32), axis=1, keepdims=True)
+        first = jnp.cumsum(level.astype(jnp.int32), axis=1) <= room
+        return causal & (above | (level & first))
+
+    # a running count down 16,384 keys is a quarter of the block's time and
+    # decides something only where scores tie across a set's edge
+    return lax.cond(jnp.all(reach == topk), level_fits,
+                    lowest_first).astype(jnp.int8)
+
+
+# queries a block of the selection: 2,048 against 16,384 keys are 134 MB
+_SELECT_BLOCK = 2048
+
+
+@register("_contrib_index_select")
+def index_select(data, q_weight, k_weight, w_weight, num_heads=16, topk=2048):
+    """The selection of DeepSeek-Sparse-Attention's indexer
+    (DeepSeek-V3.2-Exp report, eq. 1-2), without position encoding or norm:
+    from ``data`` [B, T, d], ``qI = data WqI^T`` as [B, T, Hi, Di], ``kI =
+    data WkI^T`` [B, T, Di] and ``w = data WwI^T`` [B, T, Hi], all three
+    products in float32 from the operands as they are stored; the score of
+    key ``s`` for query ``t`` is ``I[t, s] = sum_j w[t, j] relu(qI[t, j] .
+    kI[s])``, float32; query ``t``'s set is the ``min(t + 1, topk)`` keys
+    ``s <= t`` of largest score, of equal scores the lower ``s``
+    (``jax.lax.top_k``'s order). Exact, and causal by construction: a key
+    ahead of the query is never chosen, whatever its score.
+
+    Returns the sets as int8 [B, T, T] with KEYS FIRST ([b, s, t] = 1 iff
+    key ``s`` is in query ``t``'s set): what
+    :func:`~mxtpu.ops.pallas.flash_attention.sparse_attention` reads,
+    forward and backward. Computed ``_SELECT_BLOCK`` queries at a time
+    against the keys up to the block's end, so no [T, T] float32 array is
+    alive whole; the edge of a set is found by counting, two bits a pass,
+    not by a sort. Takes no gradient: the sets are integers, and the three
+    weights are leaves no loss of this program moves."""
+    b, t, _ = data.shape
+    with jax.named_scope("index_select"):
+        x = lax.stop_gradient(data).astype(jnp.float32)
+
+        def proj(w):
+            return jnp.einsum("btd,od->bto", x, w.astype(jnp.float32),
+                              precision=lax.Precision.HIGHEST)
+
+        q_idx = proj(q_weight).reshape(b, t, num_heads, -1)
+        k_idx, w_idx = proj(k_weight), proj(w_weight)
+        rows = _SELECT_BLOCK if t % _SELECT_BLOCK == 0 else t
+        parts = []
+        for at in range(0, t, rows):
+            part = _select_block(q_idx[:, at:at + rows],
+                                 w_idx[:, at:at + rows],
+                                 k_idx[:, :at + rows], at, topk)
+            parts.append(jnp.pad(part, [(0, 0), (0, t - at - rows), (0, 0)]))
+        return jnp.concatenate(parts, axis=2)
+
+
+@register("_contrib_sparse_attention")
+def sparse_grouped_attention(q, k, v, selected, rope_theta=10000.0, topk=0):
+    """:func:`grouped_attention` over the keys each query selected
+    (``selected``: :func:`index_select`'s int8 [B, T, T], keys first; one
+    set a query, shared by all heads): ``q`` [B, T, H, D], ``k`` [B, T,
+    H_kv, D], ``v`` [B, T, H_kv * D]; rotary over the whole head turns q
+    and k; the softmax runs over the set alone. ``topk`` is what built the
+    sets: with ``topk >= T`` every set is all the query can see and the
+    call IS the causal call of ``grouped_attention`` (``selected`` unread,
+    counted under ``pallas_flash.*``). Returns [B, T, H * D]; scope
+    ``sparse_attention``."""
+    from .. import telemetry
+    from .pallas.flash_attention import flash_attention, sparse_attention
+    def attend(*qkv):
+        if topk >= q.shape[1]:
+            return flash_attention(*qkv, True)
+        return sparse_attention(*qkv, selected, topk=topk)
+
+    with telemetry.span("sparse_attention.trace"), \
+            jax.named_scope("sparse_attention"):
+        return _grouped_heads(q, k, v, rope_theta, attend)
 
 
 def _short_conv_plain(data, weight):

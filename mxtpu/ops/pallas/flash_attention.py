@@ -43,6 +43,13 @@ Design (TPU-first):
   fetched for a step outside. Such a call names its kernels
   ``flash_window_fwd`` / ``flash_window_bwd``, so a trace tells the two
   kinds of call apart. ``W >= T`` masks nothing and IS the causal call.
+* a set of keys chosen query by query (:func:`sparse_attention`, the
+  third kind of mask: data, where the diagonal and the window are
+  geometry): the same two kernel bodies, blocks and grids as a causal
+  call, with an int8 tile of the sets, keys first, fetched beside each k
+  block and applied where the diagonal's mask would be; kernels
+  ``sparse_attention_fwd`` / ``sparse_attention_bwd``, counters
+  ``sparse_attention.*`` (the section at the end of this file).
 * backward: custom_vjp, flash-attention-2 equations from the saved
   log-sum-exp. Where the forward ran the kernel, ONE fused Pallas kernel
   (``flash_attention_bwd``), grid (batch*heads, Tk/bk, Tq/bq) with the
@@ -188,13 +195,17 @@ def _seen(q_pos, k_pos, window):
     return seen & (q_pos - k_pos < window) if window else seen
 
 
-def _xla_attention_lse(q, k, v, causal, scale, window=0):
-    """Fallback (out, lse): ONE copy of the XLA math; differentiable."""
+def _xla_attention_lse(q, k, v, causal, scale, window=0, mask_t=None):
+    """Fallback (out, lse): ONE copy of the XLA math; differentiable.
+    ``mask_t`` ([B, Tk, T] int8, keys first): a sparse call's selection,
+    in the causal mask's place."""
     k, v = _repeat_kv(q, k, v)     # grouped heads: K, V at the query heads
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * scale
-    if causal:
+    if mask_t is not None:
+        s = jnp.where(jnp.swapaxes(mask_t, 1, 2)[:, None] != 0, s, _NEG_INF)
+    elif causal:
         tq, tk = s.shape[-2:]
         mask = _seen(jnp.arange(tq)[:, None], jnp.arange(tk)[None, :], window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
@@ -257,6 +268,13 @@ def _causal_mask(st, qi, ki, block_q, block_k, first_col=0, window=0):
     if window:
         seen = seen & (q_pos - k_pos < window)
     return jnp.where(seen, st, _NEG_INF)
+
+
+def _selected(st, tile):
+    """The selection's mask on the transposed tile ``st`` [k rows, q
+    columns]: ``tile`` is the int8 block of the same shape, non-zero where
+    the key is in the query's set."""
+    return jnp.where(tile.astype(jnp.int32) != 0, st, _NEG_INF)
 
 
 def _first_k_block(i, block_q, block_k, window, xp=jnp):
@@ -322,7 +340,7 @@ _Q_SLAB = 256
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
-               block_q, block_k, n_k, window=0):
+               block_q, block_k, n_k, window=0, mask_ref=None):
     """One (head, q block, k block) step of the forward, on the TRANSPOSED
     score tile ``s^T = k q^T`` [bk on sublanes, bq on lanes], the form of
     :func:`_fa_bwd_kernel`: the row statistics (running max m, running sum
@@ -333,7 +351,10 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
     row in one k block (``n_k == 1``) nothing is carried: no scratch, no
     rescale. ``n_k`` is the grid's k steps: every k block, or under a
     window the steps a q block can need, counted from the first block its
-    window reaches."""
+    window reaches. ``mask_ref`` (a sparse call): the int8 tile [bk, bq] of
+    the selection, keys on sublanes as the scores are; where it is zero the
+    pair is masked, in the diagonal's place (a selection is causal by
+    construction, so the block predicate stays the causal one)."""
     qi = pl.program_id(1)
     step = pl.program_id(2)
     ki = step + _first_k_block(qi, block_q, block_k, window) if window \
@@ -363,7 +384,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
         tiles = [_dot(k, q_ref[0, cols, :], ((1,), (1,))) * scale
                  for cols in slabs]               # [bk, slab] float32 each
         for cols, st in zip(slabs, tiles):
-            if causal:
+            if mask_ref is not None:
+                st = _selected(st, mask_ref[0, :, cols])
+            elif causal:
                 st = _causal_mask(st, qi, ki, block_q, block_k, cols.start,
                                   window)
             m_new = jnp.max(st, axis=0, keepdims=True)          # [1, slab]
@@ -387,6 +410,17 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
         @pl.when(step == n_k - 1)
         def _finalize():
             _store(slice(None), m_scr[...], l_scr[...], acc_scr[...])
+
+
+def _with_mask(kernel, at, *refs, **kwargs):
+    """``kernel`` of a sparse call: the selection's tile is the operand at
+    position ``at``, after the dense call's inputs, and goes in by name."""
+    return kernel(*refs[:at], *refs[at + 1:], mask_ref=refs[at], **kwargs)
+
+
+def _mask_vmem(bq, bk):
+    """Bytes of a sparse call's int8 selection tile, double-buffered."""
+    return 2 * bq * bk
 
 
 def _last_k_block(i, j, block_q, block_k, xp=jnp):
@@ -415,19 +449,27 @@ def _fwd_vmem(bq, bk, d, dv, itm):
             + (dvp + 2 * 8) * bq * 4)            # acc^T, m, l
 
 
-def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k, window=0):
+def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k, window=0,
+                       mask_t=None):
+    """``mask_t`` ([B, Tk, T] int8, keys first): a sparse call, the
+    selection's tile fetched beside each k block (:func:`sparse_attention`),
+    under the causal block predicate."""
     b, h, t, d = q.shape
     tk, dv = k.shape[2], v.shape[3]   # values may be narrower than keys
     bh, kv_head = b * h, _kv_head_map(_group(q, k))
     n_q = t // block_q
     n_k = tk // block_k
-    _count_block_pairs(n_q, n_k, block_q, block_k, causal, window)
+    sparse = mask_t is not None
+    if not sparse:
+        _count_block_pairs(n_q, n_k, block_q, block_k, causal, window)
     if window:      # the grid's k axis: the steps a q block can need
         n_k = _window_steps(n_q, n_k, block_q, block_k, window)[0]
     from jax.experimental.pallas import tpu as pltpu
     kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, n_k=n_k,
                                window=window)
+    if sparse:
+        kernel = functools.partial(_with_mask, kernel, 3)
     interpret = _interpret()
     extra = {}
     if not interpret:  # Mosaic-only hints: the interpreter takes none
@@ -435,7 +477,8 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k, window=0):
         extra["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(
-                _fwd_vmem(block_q, block_k, d, dv, itm)))
+                _fwd_vmem(block_q, block_k, d, dv, itm)
+                + sparse * _mask_vmem(block_q, block_k)))
 
     def k_block(i, j):
         if window:      # step j of q block i, from its window's first block
@@ -453,7 +496,9 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k, window=0):
                          lambda b_, i, j: (kv_head(b_), k_block(i, j), 0)),
             pl.BlockSpec((1, block_k, dv),
                          lambda b_, i, j: (kv_head(b_), k_block(i, j), 0)),
-        ],
+        ] + ([pl.BlockSpec((1, block_k, block_q),
+                           lambda b_, i, j: (b_ // h, k_block(i, j), i))]
+             if sparse else []),
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b_, i, j: (b_, 0, i)),
@@ -470,9 +515,11 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q, block_k, window=0):
         ] if n_k > 1 else [],
         interpret=interpret,
         # the kernel's name in a device trace
-        name="flash_window_fwd" if window else "flash_attention_fwd",
+        name="sparse_attention_fwd" if sparse else
+        "flash_window_fwd" if window else "flash_attention_fwd",
         **extra,
-    )(q.reshape(bh, t, d), k.reshape(-1, tk, d), v.reshape(-1, tk, dv))
+    )(q.reshape(bh, t, d), k.reshape(-1, tk, d), v.reshape(-1, tk, dv),
+      *([mask_t] if sparse else []))
     return out.reshape(b, h, t, dv), lse
 
 
@@ -521,7 +568,7 @@ def _kv_head_map(group):
 
 @jax.named_scope("flash_attention_bwd")   # plain XLA: found by this scope
 def _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale, block_k,
-                           g_lse=None, window=0):
+                           g_lse=None, window=0, mask_t=None):
     """Flash-attention-2 backward, blockwise over k in plain jax:
     P = exp(S - lse); dv = P^T g; ds = P * (g v^T - D); dq += ds k; dk += ds^T q.
 
@@ -546,7 +593,11 @@ def _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale, block_k,
         vs = jax.lax.dynamic_slice_in_dim(v32, j * block_k, block_k, axis=2)
         s = jnp.einsum("bhqd,bhkd->bhqk", q32, ks,
                        preferred_element_type=f32) * scale
-        if causal:
+        if mask_t is not None:      # a sparse call: [B, Tk, T], keys first
+            rows = jax.lax.dynamic_slice_in_dim(mask_t, j * block_k, block_k,
+                                                axis=1)
+            s = jnp.where(jnp.swapaxes(rows, 1, 2)[:, None] != 0, s, _NEG_INF)
+        elif causal:
             k_pos = j * block_k + jnp.arange(block_k)
             mask = _seen(q_pos[:, None], k_pos[None, :], window)
             s = jnp.where(mask[None, None], s, _NEG_INF)
@@ -573,7 +624,7 @@ def _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale, block_k,
 def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
                    *, scale, causal, block_q, block_k, n_q, n_k, group=1,
-                   window=0, q_steps):
+                   window=0, q_steps, mask_ref=None):
     """One (head, k block, q block) step of the flash backward. Works on
     the TRANSPOSED score tile ``s^T = k q^T`` [bk, bq]: dv and dk are then
     plain matmuls with the tile on the left, lse and delta broadcast along
@@ -638,7 +689,9 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         g = g_ref[0]                              # [bq, dv]
         # the forward kernel's precision policy (:func:`_dot`)
         st = _dot(k, q, ((1,), (1,))) * scale     # [bk, bq]
-        if causal:
+        if mask_ref is not None:                  # a sparse call's selection
+            st = _selected(st, mask_ref[0])
+        elif causal:
             st = _causal_mask(st, qi, ki, block_q, block_k, window=window)
         pt = jnp.exp(st - lse_ref[0])             # P^T, lse as a [1, bq] row
         dv_acc[kv] += _dot(pt.astype(g.dtype), g, ((1,), (0,)))
@@ -724,7 +777,7 @@ def _resolve_bwd_blocks(q, k, v, block_q, block_k):
 
 @jax.named_scope("flash_attention_bwd")   # prologue, kernel and epilogue
 def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
-                        block_k, g_lse=None, window=0):
+                        block_k, g_lse=None, window=0, mask_t=None):
     """The flash backward as ONE fused Pallas kernel: P is recomputed per
     (k block, q block) from the saved ``lse``; s, p, dp and ds never leave
     VMEM. Same contract as :func:`_fa_backward_blockwise`."""
@@ -755,6 +808,9 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
                                block_q=block_q, block_k=block_k, n_q=n_q,
                                n_k=n_k, group=group, window=window,
                                q_steps=q_steps)
+    sparse = mask_t is not None
+    if sparse:
+        kernel = functools.partial(_with_mask, kernel, 6)
     interpret = _interpret()
     extra = {}
     if not interpret:  # Mosaic-only hints: the interpreter takes none
@@ -764,7 +820,8 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
                 3 if grouped else 2),
             vmem_limit_bytes=_vmem_limit(
                 _bwd_vmem(block_q, block_k, t, d, dv, itm,
-                          tk if grouped else 0)))
+                          tk if grouped else 0)
+                + sparse * _mask_vmem(block_q, block_k)))
 
     def q_block(j, i):
         # causal: the q blocks above a k block's diagonal compute nothing,
@@ -811,7 +868,10 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
     dq, dk, dv_ = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, k_spec, v_spec, g_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, g_spec, row_spec, row_spec] + (
+            [spec((1, block_k, block_q),
+                  lambda hq, hkv, j, i: (hq // h, j, q_block(j, i)))]
+            if sparse else []),
         out_specs=[
             spec((1, t, d), lambda hq, hkv, j, i: (hq, 0, 0)),
             spec((1, kv_rows, d), kv_block),
@@ -829,10 +889,12 @@ def _fa_backward_pallas(q, k, v, out, lse, g, causal, scale, block_q,
         ],
         interpret=interpret,
         # the kernel's name in a device trace
-        name="flash_window_bwd" if window else "flash_attention_bwd",
+        name="sparse_attention_bwd" if sparse else
+        "flash_window_bwd" if window else "flash_attention_bwd",
         **extra,
     )(q.reshape(bh, t, d), k.reshape(-1, tk, d), v.reshape(-1, tk, dv),
-      g.reshape(bh, t, dv), lse.reshape(bh, 1, t), delta.reshape(bh, 1, t))
+      g.reshape(bh, t, dv), lse.reshape(bh, 1, t), delta.reshape(bh, 1, t),
+      *([mask_t] if sparse else []))
     return (dq.reshape(q.shape)[..., :d_out], dk.reshape(k.shape)[..., :d_out],
             dv_.reshape(v.shape)[..., :dv_out])
 
@@ -1060,3 +1122,126 @@ def _fa_lse_bwd(causal, scale, block_q, block_k, res, cots):
 
 
 flash_attention_with_lse.defvjp(_fa_lse_fwd, _fa_lse_bwd)
+
+
+# ------------------------------------------------------- sparse attention
+# Attention over a set of keys chosen query by query (a learned indexer's
+# top-k: DeepSeek-V3.2-Exp's sparse attention). The set comes as an int8
+# array [B, Tk, T], KEYS FIRST, as the kernels hold the score tile: entry
+# [b, s, t] is non-zero iff key s is in query t's set; one set a query,
+# shared by every head. The built form is the MASKED one: both kernels are
+# the causal kernels above (same bodies, same blocks, K and V at their own
+# heads), every causal block pair is visited, the selection's tile is
+# fetched beside the k block and the pairs outside the set are masked
+# where the diagonal would be. Nothing is skipped by data, so a call costs
+# what a causal call costs and does ``pairs_visited / pairs_selected`` times
+# the algorithm's work (4.5 at 2,048 of 16,384); the form that gathers the
+# selected rows would move 2 x topk x H_kv x D x itemsize bytes a query
+# (PERF.md §6, PR 38). Counters, at trace time: ``sparse_attention.calls``,
+# ``.pairs_selected`` / ``.pairs_visited`` (a call, a head),
+# ``.fallbacks`` (by reason: a call on a plain path that holds [H, T, T]),
+# ``.bwd_pallas``.
+def _count_sparse(mask_t, topk, visited, reason=None):
+    """One call's counters. ``pairs_selected`` is what the algorithm needs
+    (``sum_t min(t + 1, topk)`` a sequence, where the caller says what
+    ``topk`` built the set), ``visited`` what the path taken touches."""
+    from ... import telemetry
+    b, tk, t = mask_t.shape
+    telemetry.inc("sparse_attention.calls")
+    if topk:
+        k = min(topk, t)
+        telemetry.inc("sparse_attention.pairs_selected",
+                      b * (k * t - k * (k - 1) // 2))
+    telemetry.inc("sparse_attention.pairs_visited", b * visited)
+    if reason is not None:
+        telemetry.inc("sparse_attention.fallbacks", tag=reason)
+
+
+def _sparse_blocks(q, k, v, block_q, block_k):
+    """``((block_q, block_k), None)`` for the sparse forward kernel, or
+    ``(None, reason)``: :func:`_tile_blocks` under the forward's budget
+    with the selection's tile in it."""
+    if _platform() != "tpu" and not _interpret():
+        return None, "platform is not tpu"
+    t, tk, d, dv = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
+    if t != tk:
+        return None, "as many keys as queries are needed"
+    itm = jnp.dtype(q.dtype).itemsize
+    return _tile_blocks(
+        t, tk, block_q, block_k,
+        lambda bq, bk: _fwd_vmem(bq, bk, d, dv, itm) + _mask_vmem(bq, bk),
+        "one q block does not fit the VMEM budget")
+
+
+def _sparse_fwd_impl(q, k, v, mask_t, scale, block_q, block_k, topk):
+    import numpy as np
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+    t = q.shape[2]
+    blocks, refused = _sparse_blocks(q, k, v, block_q, block_k)
+    if blocks is None:
+        _count_sparse(mask_t, topk, t * t, refused)
+        out, _ = _xla_attention_lse(q, k, v, True, scale, mask_t=mask_t)
+        return out, (q, k, v, out, None, mask_t)
+    bq, bk = blocks
+    live = _live(True, np.arange(t // bq)[:, None],
+                 np.arange(t // bk)[None, :], bq, bk)
+    _count_sparse(mask_t, topk, int(live.sum()) * bq * bk)
+    out, lse = _fa_forward_pallas(*_pad_head_dim(q, k, v), True, scale, bq,
+                                  bk, mask_t=mask_t)
+    if out.shape[-1] != v.shape[-1]:
+        out = out[..., :v.shape[-1]]
+    return out, (q, k, v, out, lse, mask_t)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def sparse_attention(q, k, v, mask_t, scale=None, block_q=_BLOCK_Q,
+                     block_k=_BLOCK_K, topk=0):
+    """Attention of ``q`` [B, H, T, D] over the keys each query selected:
+    ``k``, ``v`` [B, H_kv, T, D] at their own heads (query head ``j`` reads
+    head ``j // (H / H_kv)``), ``mask_t`` [B, T, T] int8 with KEYS FIRST,
+    non-zero at [b, s, t] iff key ``s`` is in query ``t``'s set, which has
+    to be causal (``s <= t``: the kernels skip the blocks above the
+    diagonal unread) and non-empty. ``out[t] = sum_{s in S_t} softmax_{s in
+    S_t}(q_t . k_s * scale) v_s``. The set is a constant of the step: it
+    takes no gradient, and the backward reads the array the forward read.
+    ``topk`` only tells the counters what the algorithm needed. Kernels
+    ``sparse_attention_fwd`` / ``sparse_attention_bwd`` in a trace; off the
+    TPU (or a shape no block tiles) the plain path, which holds [H, T, T]
+    and counts in ``sparse_attention.fallbacks``."""
+    return _sparse_fwd_impl(q, k, v, mask_t, scale, block_q, block_k,
+                            topk)[0]
+
+
+def _sparse_bwd(scale, block_q, block_k, topk, res, g):
+    import numpy as np
+    from ... import telemetry
+    q, k, v, out, lse, mask_t = res
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+    no_grad = np.zeros(mask_t.shape, jax.dtypes.float0)
+    if lse is None:
+        _, vjp = jax.vjp(lambda q_, k_, v_: _xla_attention_lse(
+            q_, k_, v_, True, scale, mask_t=mask_t)[0], q, k, v)
+        return vjp(g) + (no_grad,)
+    itm = jnp.dtype(q.dtype).itemsize
+    t, tk, d, dv = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
+    whole = tk if _group(q, k) > 1 else 0
+    blocks, refused = _tile_blocks(
+        t, tk, block_q, block_k,
+        lambda bq, bk: _bwd_vmem(bq, bk, t, d, dv, itm, whole)
+        + _mask_vmem(bq, bk), "dq of one head does not fit the VMEM budget")
+    if blocks is None:
+        telemetry.inc("sparse_attention.fallbacks", tag="backward: " + refused)
+        grads = _fa_backward_blockwise(
+            q, k, v, out, lse.reshape(q.shape[:3]), g, True, scale,
+            _pick_block(tk, block_k, 1) or tk, mask_t=mask_t)
+    else:
+        telemetry.inc("sparse_attention.bwd_pallas")
+        with jax.named_scope("sparse_attention_bwd"):
+            grads = _fa_backward_pallas(q, k, v, out, lse, g, True, scale,
+                                        *blocks, mask_t=mask_t)
+    return tuple(grads) + (no_grad,)
+
+
+sparse_attention.defvjp(_sparse_fwd_impl, _sparse_bwd)

@@ -46,7 +46,8 @@ def test_rehearsal_one_chip_phases(tmp_path):
     assert list(phases) == ["device", "sync", "train_resnet50",
                             "train_bert_base", "flash_two_widths",
                             "flash_grouped", "window_attention",
-                            "routed_layer", "gluon_trainer",
+                            "sparse_attention", "routed_layer",
+                            "gluon_trainer",
                             "serve", "warm_start", "total"]
     assert max(phases["flash_two_widths"]["gaps"].values()) <= 2e-2
     assert max(phases["flash_grouped"]["gaps"].values()) <= 2e-2
@@ -60,6 +61,13 @@ def test_rehearsal_one_chip_phases(tmp_path):
         assert max(call["gaps"].values()) <= 2e-2
         assert call["pallas_flash"]["windowed"] == windowed
         assert call["pallas_flash"]["window_unskipped"] == windowed
+    # one layer of sparse attention: exact sets, and off the chip the plain
+    # path, which visits the whole square and says so
+    sparse = phases["sparse_attention"]
+    assert max(sparse["gaps"].values()) <= 2e-2
+    assert sparse["sparse_attention"]["fallbacks"] == 1
+    assert sparse["pairs"] == {"selected": 32 * 256 - 32 * 31 // 2,
+                               "visited": 256 * 256}
     rows = phases["routed_layer"]["rows"]
     assert 0 < rows["live"] <= rows["run"] < rows["total"]
     assert max(phases["routed_layer"]["gaps"].values()) <= 3e-2

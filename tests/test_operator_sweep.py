@@ -986,6 +986,8 @@ COVERED_ELSEWHERE = {
     # the hybrid stack's operators: against the plain reference
     "_contrib_short_conv": "test_short_conv.py",
     "_contrib_grouped_attention": "test_hybrid_lm.py",
+    "_contrib_index_select": "test_keye_vl2.py",
+    "_contrib_sparse_attention": "test_keye_vl2.py",
     # the sparse-label cross-entropy in one pass: against a float64 oracle
     "_contrib_log_softmax_pick": "test_loss_one_pass.py",
     "CTCLoss": "test_ctc.py",

@@ -372,3 +372,54 @@ def test_vocabulary_head_loss_reads_the_logits_once(one_chip, as_tpu):
     assert " fusion(" in whole[0] and "kind=kOutput" in whole[0]
     assert " gather(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+# one layer of the keye_vl2_30b_a3b cell: 32 query heads over 4 key/value
+# heads of 128, 16,384 positions, an indexer of 16 heads of 64, 2,048 keys
+_SPARSE_T = 16384
+
+
+def test_sparse_kernels_compile_to_mosaic(one_chip, as_tpu):
+    """Both sparse kernels at the cell's shapes as one differentiated
+    program: Mosaic calls under their own names, K and V at their own four
+    heads, no [32, T, T] (or [T, T] float32) array in the program, and no
+    call of the dense kernels beside them."""
+    from mxtpu import telemetry
+    t = _SPARSE_T
+    q, kv = _spec((1, 32, t, 128), one_chip), _spec((1, 4, t, 128), one_chip)
+    sets = _spec((1, t, t), one_chip, jnp.int8)
+
+    def loss(q, k, v, sets):
+        return fa.sparse_attention(q, k, v, sets, topk=2048).astype(
+            jnp.float32).sum()
+
+    fa.reset_dispatch_stats()
+    for name in ("calls", "fallbacks", "bwd_pallas"):
+        telemetry.reset_metric("sparse_attention." + name)
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, sets)
+    assert "sparse_attention_fwd" in text and "sparse_attention_bwd" in text
+    assert "flash_attention_fwd" not in text
+    assert "[32,16384,16384]" not in text and "f32[16384,16384]" not in text
+    assert "bf16[1,32,16384,128]{3,2,1,0} broadcast" not in text
+    assert [telemetry.value("sparse_attention." + n)
+            for n in ("calls", "fallbacks", "bwd_pallas")] == [1, 0, 1]
+    assert fa.DISPATCH_STATS["pallas"] == 0 and fa.DISPATCH_STATS["xla"] == 0
+
+
+def test_the_selection_compiles_by_blocks(one_chip, as_tpu):
+    """``_contrib_index_select`` at the cell's shapes: the sets leave as
+    one int8 [1, T, T]; no float32 array of the whole square is alive (a
+    block of 2,048 queries against 16,384 keys is the largest), and no
+    sort: the edge of a set is counted out."""
+    from mxtpu.ops.registry import get_op
+    t = _SPARSE_T
+    select = get_op("_contrib_index_select").fn
+    compiled = jax.jit(lambda *a: select(*a, num_heads=16, topk=2048)).lower(
+        _spec((1, t, 2048), one_chip), _spec((1024, 2048), one_chip),
+        _spec((64, 2048), one_chip), _spec((16, 2048), one_chip)).compile()
+    text = compiled.as_text()
+    assert "s8[1,16384,16384]" in text
+    assert "f32[1,16384,16384]" not in text and "f32[16384,16384]" not in text
+    assert "f32[1,16384,2048]" in text
+    assert " sort(" not in text and "TopK" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
