@@ -69,6 +69,10 @@ FULL = dict(
     # of 128, the 2,048 keys a query an indexer of 16 heads of 64 picks
     sparse=dict(heads=32, kv_heads=4, seq=16384, width=128, dim=2048,
                 index_heads=16, index_width=64, topk=2048, check_heads=8),
+    # one layer of Kimi Delta Attention: 32 heads of 128 over 8,192
+    # positions, chunks of 64; checked on the first heads and positions
+    kda=dict(heads=32, seq=8192, width=128, chunk=64, check_heads=2,
+             check_seq=1024),
     # one chip's share of a routed expert layer: 16 of 128 experts, top-6
     routed=dict(tokens=8192, dim=2048, width=768, held=16, total=128,
                 top_k=6),
@@ -92,6 +96,8 @@ TINY = dict(
                 check_heads=7),
     sparse=dict(heads=8, kv_heads=2, seq=256, width=16, dim=64,
                 index_heads=4, index_width=8, topk=32, check_heads=4),
+    kda=dict(heads=2, seq=96, width=16, chunk=16, check_heads=2,
+             check_seq=96),
     routed=dict(tokens=512, dim=64, width=32, held=2, total=16, top_k=3),
     train_steps=5, gluon_steps=3,
     # the toy memorizes its 8 images in three steps (loss 3.4 -> 0.02),
@@ -533,6 +539,83 @@ def phase_sparse_attention(n, seed, on_tpu):
                       "visited": stats["pairs_visited"]}}
 
 
+def phase_kda_attention(n, seed, on_tpu):
+    """One layer of Kimi Delta Attention, compiled, at shape ``n``: both
+    kernels (``kda_fwd`` / ``kda_bwd``) through ``jax.vjp`` of the public
+    function on bf16 operands with the log-decay over its whole range
+    (-5 to 0), against the float32 recurrence taken token by token on the
+    first ``check_heads`` heads: the output and the cotangents of q, k, v,
+    the log-decay and beta over the first ``check_seq`` positions (a
+    cotangent there depends on every later position, so the recurrence's
+    is taken with the output's cotangent zero past them, and the kernels'
+    too). Fails on the chip if a call took the plain path."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    from mxtpu import telemetry
+    kda = importlib.import_module("mxtpu.ops.pallas.kda")
+    h, t, w = n["heads"], n["seq"], n["width"]
+    ks = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 6)
+
+    def unit(key):
+        x = jax.random.normal(key, (1, t, h, w))
+        return (x / jnp.linalg.norm(x, axis=-1, keepdims=True)).reshape(
+            1, t, h * w).astype(jnp.bfloat16)
+
+    q, k = unit(ks[0]), unit(ks[1])
+    v = jax.random.normal(ks[2], (1, t, h * w)).astype(jnp.bfloat16)
+    g = -5.0 * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], (1, t, h * w)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, t, h))).astype(
+        jnp.bfloat16)
+    ch, ct = n["check_heads"], n["check_seq"]
+    do = jax.random.normal(ks[5], (1, t, h * w)).astype(jnp.bfloat16)
+    do = do * (jnp.arange(t) < ct)[None, :, None].astype(jnp.bfloat16)
+    names = ("calls", "fallbacks", "chunks")
+    for name in names:
+        telemetry.reset_metric("kda_attention." + name)
+    out, vjp = jax.vjp(lambda *a: kda.kda_attention(*a, n["chunk"]),
+                       q, k, v, g, beta)
+    got = (out,) + vjp(do)
+    stats = {name: telemetry.value("kda_attention." + name)
+             for name in names}
+    if on_tpu:
+        _check(stats["calls"] == 1 and stats["fallbacks"] == 0,
+               "a KDA kernel was left for the plain path: %s %s"
+               % (stats, telemetry.tagged("kda_attention.fallbacks")))
+    _check(stats["chunks"] == -(-t // n["chunk"]),
+           "the call counted %s" % stats)
+
+    def head(q, k, v, g, b):               # [T, .] of one head
+        def token(s, x):
+            q_t, k_t, v_t, g_t, b_t = x
+            s = jnp.exp(g_t)[:, None] * s
+            s = s + b_t * jnp.outer(k_t, v_t - s.T @ k_t)
+            return s, s.T @ q_t / jnp.sqrt(float(w))
+        return jax.lax.scan(token, jnp.zeros((w, w), jnp.float32),
+                            (q, k, v, g, b))[1]
+
+    def first(x, width):                   # the checked heads and positions
+        return x[0, :ct].reshape(ct, h, width)[:, :ch].astype(jnp.float32)
+
+    ops = (first(q, w), first(k, w), first(v, w), first(g, w),
+           first(beta, 1)[..., 0])
+    with jax.default_matmul_precision("highest"):
+        want, ref_vjp = jax.vjp(
+            jax.jit(jax.vmap(head, in_axes=1, out_axes=1)), *ops)
+        want = (want,) + ref_vjp(first(do, w))
+    mine = [first(a, w) for a in got[:5]] + [first(got[5], 1)[..., 0]]
+    gaps = {what: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+            for what, a, b in zip(("out", "dq", "dk", "dv", "dg", "dbeta"),
+                                  mine, want)}
+    # bf16 operands against float32: a chunk's state dropped, the decay
+    # misread or a sub-chunk's exponent out of range reads 0.1 or more
+    _check(max(gaps.values()) <= 3e-2,
+           "Kimi Delta Attention at %s is %s from the recurrence"
+           % (n, gaps))
+    return {"shape": n, "kda_attention": stats, "gaps": gaps}
+
+
 def phase_routed_layer(sizes, seed, on_tpu):
     """The routed expert layer alone at one chip's share of the experts:
     its output and the gradients of x, the router and the three expert
@@ -955,6 +1038,8 @@ def run(sizes, chips=1, seed=0, out=sys.stdout):
                   sizes["window"], seed, on_tpu)
             phase("sparse_attention", phase_sparse_attention,
                   sizes["sparse"], seed, on_tpu)
+            phase("kda_attention", phase_kda_attention, sizes["kda"], seed,
+                  on_tpu)
             phase("routed_layer", phase_routed_layer, sizes, seed, on_tpu)
             net = phase("gluon_trainer", phase_gluon_trainer, sizes, seed)
             phase("serve", phase_serve, sizes, seed, net)

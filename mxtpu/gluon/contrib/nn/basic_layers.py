@@ -138,9 +138,10 @@ class RoutedMoE(HybridBlock):
     chosen experts and scaled; no capacity, no token dropped. ``score``
     (``"softmax"``: the softmax over all experts, its chosen renormalised)
     and ``activation`` (``"relu"``: ReGLU experts) name another published
-    layer (mxtpu.parallel.moe.route_top_k, routed_ffn). Called with a
-    second input, the router scores that and the experts read the first
-    (a router placed ahead of attention reads its layer's input).
+    layer (mxtpu.parallel.moe.route_top_k, routed_ffn); ``n_group`` > 1
+    with ``topk_group`` is that paper's group limit on the choice. Called
+    with a second input, the router scores that and the experts read the
+    first (a router placed ahead of attention reads its layer's input).
 
     ``experts_held`` of the router's ``num_experts`` live in this block,
     starting at ``first_expert``: one chip's share of the layer under
@@ -155,7 +156,8 @@ class RoutedMoE(HybridBlock):
 
     def __init__(self, dim, hidden, num_experts, top_k, experts_held=None,
                  first_expert=0, scale=1.0, shared_hidden=0, grouped=True,
-                 score="sigmoid", activation="silu", **kwargs):
+                 score="sigmoid", activation="silu", n_group=1,
+                 topk_group=1, **kwargs):
         super().__init__(**kwargs)
         held = num_experts if experts_held is None else experts_held
         self._dim, self._hidden = dim, hidden
@@ -163,6 +165,8 @@ class RoutedMoE(HybridBlock):
         self._attrs = {"top_k": top_k, "first_expert": first_expert,
                        "scale": scale, "grouped": grouped, "score": score,
                        "activation": activation}
+        if n_group > 1:     # the router's group limit (route_top_k)
+            self._attrs.update(n_group=n_group, topk_group=topk_group)
         with self.name_scope():
             self.router = self.params.get("router_weight",
                                           shape=(num_experts, dim))

@@ -14,14 +14,18 @@ anywhere: ``h = x + op(norm1(x)); y = h + ffn(norm2(h))``.
   carry the ``window``), ``sparse_attention`` (the same block with a
   :class:`SparseIndexer` for a child: each query attends to the ``topk``
   keys a learned indexer scores highest, DeepSeek-Sparse-Attention's
-  selection) or ``latent_attention``
-  (:class:`~mxtpu.gluon.model_zoo.latent_moe.MultiHeadLatentAttention`);
+  selection), ``latent_attention``
+  (:class:`~mxtpu.gluon.model_zoo.latent_moe.MultiHeadLatentAttention`) or
+  ``kda`` (:class:`KimiDeltaAttention`: a gated delta rule with a decay a
+  channel, a recurrent state a head and no softmax);
 * ``ffn`` is a gated MLP in the first ``dense_layers`` blocks (there may
   be none) and a :class:`~mxtpu.gluon.contrib.nn.RoutedMoE` after them
   (which may hold one chip's share of each layer's experts); with
   ``router_ahead`` its router reads the layer's input ``x``, ahead of the
   operator, while its experts read ``norm2(h)``;
-* a final norm and a vocabulary head, tied to the embedding by default.
+* a final norm and a vocabulary head, tied to the embedding by default;
+* with ``recompute`` each block is recomputed in the backward from its
+  input (``jax.checkpoint``): nothing of a block's inside is kept.
 
 ``HybridLM(layers=["conv", "full_attention", "conv", ...])`` is LFM2's
 stack (``model_type: lfm2_moe``); ``layers=["full_attention",
@@ -33,11 +37,14 @@ with ``latent_attention`` in every layer. Trains under
 """
 from __future__ import annotations
 
-from ..block import HybridBlock
+import jax
+
+from ...ndarray import NDArray
+from ..block import HybridBlock, _IN_TRACE
 from .. import nn
 
 __all__ = ["HybridLM", "DecoderBlock", "GroupedQueryAttention",
-           "SparseIndexer", "OPERATORS"]
+           "KimiDeltaAttention", "SparseIndexer", "OPERATORS"]
 
 
 class SparseIndexer(HybridBlock):
@@ -138,6 +145,68 @@ class GroupedQueryAttention(HybridBlock):
             q, k, self.v(x), **self._attrs))
 
 
+class KimiDeltaAttention(HybridBlock):
+    """Kimi Delta Attention (Kimi Linear, arXiv:2510.26692 §3-4): linear
+    attention whose state a head, ``S`` in ``R^{head_dim x head_dim}``,
+    follows a gated delta rule with a decay a channel, ``S_t = (I - b_t
+    k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T``, ``o_t = S_t^T q_t /
+    sqrt(head_dim)``; no softmax, no position encoding.
+
+    ``q``, ``k``, ``v`` are projections of the input through a causal
+    depthwise filter of ``conv_size`` taps and SiLU, ``q`` and ``k`` then
+    L2-normed by head (op ``_contrib_kda_conv``); the log-decay is ``g =
+    lower_bound * sigmoid(exp(a_log[h]) * (x Wf + dt_bias))`` in float32
+    (op ``_contrib_kda_gate``: the bounded gate that the chunked kernels'
+    exponents rest on), ``b = sigmoid(b(x))`` a head; the output goes
+    through an RMSNorm over each head's entries (one learned scale of
+    ``head_dim``), an elementwise gate ``sigmoid(g(x))`` and the output
+    projection. The decay's and the gate's projections are full rank. The
+    recurrence runs by chunks through a Pallas kernel pair (op
+    ``_contrib_kda_attention``). No bias anywhere.
+
+    Input [B, T, dim]; output [B, T, dim]."""
+
+    def __init__(self, dim, num_heads, head_dim, conv_size=4,
+                 lower_bound=-5.0, epsilon=1e-6, chunk=64, **kwargs):
+        super().__init__(**kwargs)
+        self._head_dim, self._chunk = head_dim, chunk
+        self._lower_bound = lower_bound
+        width = num_heads * head_dim
+
+        def dense(units, prefix):
+            return nn.Dense(units, use_bias=False, flatten=False,
+                            prefix=prefix)
+
+        with self.name_scope():
+            self.q, self.k, self.v = (dense(width, n) for n in
+                                      ("q_", "k_", "v_"))
+            self.q_conv, self.k_conv, self.v_conv = (
+                self.params.get(n + "_conv_weight", shape=(width, conv_size))
+                for n in "qkv")
+            self.a_log = self.params.get("a_log", shape=(num_heads,))
+            self.dt_bias = self.params.get("dt_bias", shape=(width,))
+            # the decay's projection, a leaf of the block: its product's
+            # float32 result goes into the gate unrounded
+            self.f_weight = self.params.get("f_weight", shape=(width, dim))
+            self.b = dense(num_heads, "b_")
+            self.g = dense(width, "g_")
+            self.o_norm = nn.RMSNorm(epsilon=epsilon, prefix="onorm_")
+            self.proj = dense(dim, "proj_")
+
+    def hybrid_forward(self, F, x, *, q_conv, k_conv, v_conv, a_log,
+                       dt_bias, f_weight):
+        hd = self._head_dim
+        o = F._contrib_kda_attention(
+            F._contrib_kda_conv(self.q(x), q_conv, head_dim=hd),
+            F._contrib_kda_conv(self.k(x), k_conv, head_dim=hd),
+            F._contrib_kda_conv(self.v(x), v_conv),
+            F._contrib_kda_gate(x, f_weight, a_log, dt_bias,
+                                lower_bound=self._lower_bound),
+            F.sigmoid(self.b(x)), chunk=self._chunk)
+        o = self.o_norm(F.reshape(o, shape=(0, 0, -1, hd)))  # [B, T, H, hd]
+        return self.proj(F.reshape(o, shape=(0, 0, -1)) * F.sigmoid(self.g(x)))
+
+
 def _latent_attention(dim, **kwargs):
     from .latent_moe import MultiHeadLatentAttention
     return MultiHeadLatentAttention(dim, **kwargs)
@@ -154,6 +223,7 @@ OPERATORS = {
     # and with a ``topk`` among them: each query's keys picked by an indexer
     "sparse_attention": (GroupedQueryAttention, "attn_"),
     "latent_attention": (_latent_attention, "attn_"),
+    "kda": (KimiDeltaAttention, "kda_"),
 }
 
 
@@ -203,13 +273,20 @@ class HybridLM(HybridBlock):
     ``experts_held, first_expert, scale, shared_hidden, score,
     activation``), whose routers read their layer's input with
     ``router_ahead``. ``tie_head``: the head reads the embedding's weight,
-    whose gradient is the sum of both uses.
+    whose gradient is the sum of both uses. ``recompute``: in a traced
+    forward each block runs under ``jax.checkpoint`` with nothing kept but
+    its input, so a differentiated step holds one block's activations at a
+    time and runs every block's forward twice (the kernels' and the expert
+    layer's own ``custom_vjp`` rules among it); a property of the model,
+    set where the model is built, and counted at trace time in
+    ``train_step.blocks_recomputed``.
     """
 
     def __init__(self, vocab_size, dim, layers, operators, dense_hidden, moe,
                  dense_layers=1, epsilon=1e-6, tie_head=True,
-                 router_ahead=False, **kwargs):
+                 router_ahead=False, recompute=False, **kwargs):
         super().__init__(**kwargs)
+        self._recompute = recompute
         with self.name_scope():
             self.embed = nn.Embedding(vocab_size, dim, prefix="wte_")
             self.blocks = nn.HybridSequential(prefix="h_")
@@ -228,4 +305,12 @@ class HybridLM(HybridBlock):
                    if tie_head else {"prefix": "head_"}))
 
     def hybrid_forward(self, F, tokens):
-        return self.head(self.norm_f(self.blocks(self.embed(tokens))))
+        if not (self._recompute and _IN_TRACE.active):
+            return self.head(self.norm_f(self.blocks(self.embed(tokens))))
+        from ... import telemetry
+        x = self.embed(tokens)
+        for block in self.blocks._children.values():
+            telemetry.inc("train_step.blocks_recomputed")
+            x = NDArray(jax.checkpoint(
+                lambda data, block=block: block(NDArray(data))._data)(x._data))
+        return self.head(self.norm_f(x))

@@ -32,13 +32,17 @@ __all__ = ["LatentMoELM", "MultiHeadLatentAttention"]
 class MultiHeadLatentAttention(HybridBlock):
     """Causal latent attention without a query rank: ``q = x Wq``; ``x
     Wkva`` gives the latent ``c`` (``kv_rank`` wide) and the shared rotary
-    key; ``norm(c) Wkvb`` gives each head's position-free key and value."""
+    key; ``norm(c) Wkvb`` gives each head's position-free key and value.
+    ``head_gate``: each head's output is scaled by ``sigmoid(x Wgate)_h``
+    before the output projection (gated attention, arXiv:2505.06708, at its
+    head-wise granularity: ``Wgate`` is ``dim x num_heads``)."""
 
     def __init__(self, dim, num_heads, kv_rank, nope_dim, rope_dim, v_dim,
                  rope_theta=10000.0, rope_interleave=False, epsilon=1e-6,
-                 causal=True, **kwargs):
+                 causal=True, head_gate=False, **kwargs):
         super().__init__(**kwargs)
         self._kv_rank, self._rope_dim = kv_rank, rope_dim
+        self._v_dim = v_dim
         self._attrs = {"num_heads": num_heads, "nope_dim": nope_dim,
                        "rope_dim": rope_dim, "v_dim": v_dim,
                        "rope_theta": rope_theta,
@@ -51,6 +55,8 @@ class MultiHeadLatentAttention(HybridBlock):
             self.kv_norm = nn.RMSNorm(epsilon=epsilon, prefix="kvnorm_")
             self.kv_b = nn.Dense(num_heads * (nope_dim + v_dim),
                                  use_bias=False, flatten=False, prefix="kvb_")
+            self.gate = nn.Dense(num_heads, use_bias=False, flatten=False,
+                                 prefix="gate_") if head_gate else None
             self.proj = nn.Dense(dim, use_bias=False, flatten=False,
                                  prefix="proj_")
 
@@ -61,6 +67,10 @@ class MultiHeadLatentAttention(HybridBlock):
         k_rope = F.slice_axis(ckr, axis=-1, begin=r, end=r + self._rope_dim)
         out = F._contrib_latent_attention(
             self.q(x), self.kv_b(self.kv_norm(c)), k_rope, **self._attrs)
+        if self.gate is not None:
+            heads = F.reshape(out, shape=(0, 0, -1, self._v_dim))
+            out = F.reshape(heads * F.expand_dims(F.sigmoid(self.gate(x)), -1),
+                            shape=(0, 0, -1))
         return self.proj(out)
 
 

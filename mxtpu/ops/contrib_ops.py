@@ -634,7 +634,7 @@ def switch_moe(data, router, w1, b1, w2, b2, capacity_factor=1.25):
 @register("_contrib_routed_moe", aliases=("routed_moe",))
 def routed_moe(data, router, score_bias, w_gate, w_up, w_down, top_k=1,
                first_expert=0, scale=1.0, grouped=True, router_data=None,
-               score="sigmoid", activation="silu"):
+               score="sigmoid", activation="silu", n_group=1, topk_group=1):
     """Top-k routed gated experts without a capacity, over the experts held
     here (backs gluon.contrib.nn.RoutedMoE; mxtpu.parallel.moe.routed_ffn).
     data (..., D) is flattened to tokens; ``router_data`` (the same shape),
@@ -646,5 +646,6 @@ def routed_moe(data, router, score_bias, w_gate, w_up, w_down, top_k=1,
     out = routed_ffn(toks, router, score_bias, w_gate, w_up, w_down,
                      top_k=top_k, first_expert=first_expert, scale=scale,
                      grouped=grouped, router_x=router_data, score=score,
-                     activation=activation)
+                     activation=activation, n_group=n_group,
+                     topk_group=topk_group)
     return out.reshape(data.shape)

@@ -13,6 +13,8 @@ reference parity — XLA inserts the transposes when needed.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -769,23 +771,28 @@ def sparse_grouped_attention(q, k, v, selected, rope_theta=10000.0, topk=0):
         return _grouped_heads(q, k, v, rope_theta, attend)
 
 
-def _short_conv_plain(data, weight):
-    d, taps = weight.shape
-    t = data.shape[-2]
-    f32 = jnp.float32
-    gate_in, gate_out, x = (data[..., i * d:(i + 1) * d].astype(f32)
-                            for i in range(3))
-    w = weight.astype(f32)
-    z = gate_in * x
-    # c[t] = sum_j w[:, j] z[t - (taps - 1) + j], z zero before the start:
-    # shifted multiply-adds, which XLA fuses with both gates into passes
-    # over [B, T, D] (no grouped convolution: PERF.md §6)
+def _causal_taps(z, w):
+    """``c[t] = sum_j w[:, j] z[t - (taps - 1) + j]`` along axis -2, ``z``
+    zero before the start (float32 ``z`` [..., T, D], ``w`` [D, taps]):
+    shifted multiply-adds, which XLA fuses with what surrounds them into
+    passes over [B, T, D] (no grouped convolution: PERF.md §6)."""
+    taps = w.shape[1]
+    t = z.shape[-2]
     c = w[:, taps - 1] * z
     for j in range(taps - 1):
         back = taps - 1 - j
         pad = [(0, 0)] * (z.ndim - 2) + [(back, 0), (0, 0)]
         c = c + w[:, j] * jnp.pad(z, pad)[..., :t, :]
-    return (gate_out * c).astype(data.dtype)
+    return c
+
+
+def _short_conv_plain(data, weight):
+    d = weight.shape[0]
+    f32 = jnp.float32
+    gate_in, gate_out, x = (data[..., i * d:(i + 1) * d].astype(f32)
+                            for i in range(3))
+    w = weight.astype(f32)
+    return (gate_out * _causal_taps(gate_in * x, w)).astype(data.dtype)
 
 
 @jax.custom_vjp
@@ -814,6 +821,87 @@ def short_conv(data, weight):
     telemetry.inc("short_conv.layers")
     with jax.named_scope("short_conv"):
         return _short_conv(data, weight)
+
+
+def _kda_conv_plain(head_dim, data, weight):
+    f32 = jnp.float32
+    y = jax.nn.silu(_causal_taps(data.astype(f32), weight.astype(f32)))
+    if head_dim:
+        heads = y.reshape(y.shape[:-1] + (-1, head_dim))
+        y = (heads * lax.rsqrt(jnp.sum(jnp.square(heads), -1, keepdims=True)
+                               + 1e-6)).reshape(y.shape)
+    return y.astype(data.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _kda_conv(head_dim, data, weight):
+    return _kda_conv_plain(head_dim, data, weight)
+
+
+# as the gated filter's: the backward filters the projected input again
+_kda_conv.defvjp(
+    lambda head_dim, data, weight: (
+        _kda_conv_plain(head_dim, data, weight), (data, weight)),
+    lambda head_dim, kept, g: jax.vjp(
+        functools.partial(_kda_conv_plain, head_dim), *kept)[1](g))
+
+
+@register("_contrib_kda_conv", aliases=("kda_conv",))
+def kda_conv(data, weight, head_dim=0):
+    """The short filter of a Kimi-Delta-Attention layer (Kimi Linear,
+    arXiv:2510.26692 §4) on one of its projections: ``silu(conv_L(data))``
+    with ``conv_L`` the depthwise causal filter of :func:`short_conv`
+    (``weight`` [D, L]; the same tap loop), ``data`` [..., T, D]; with
+    ``head_dim`` > 0 each head's ``head_dim`` entries are then divided by
+    ``sqrt(sum of their squares + 1e-6)`` (the layer's L2 norm of q and
+    k), in the same pass. float32 arithmetic, one rounding to ``data``'s
+    dtype; the backward computes it again from ``data``. Scope
+    ``kda_conv``."""
+    with jax.named_scope("kda_conv"):
+        return _kda_conv(head_dim, data, weight)
+
+
+@register("_contrib_kda_gate", aliases=("kda_gate",))
+def kda_gate(data, weight, a_log, dt_bias, lower_bound=-5.0):
+    """The log-decay a channel of a Kimi-Delta-Attention layer in its
+    bounded form (flash-linear-attention's ``fla/ops/kda`` with
+    ``lower_bound``: the "safe gate"): ``g = lower_bound * sigmoid(exp(
+    a_log[h]) * (data weight^T + dt_bias))``, in ``(lower_bound, 0)``.
+    ``data`` [..., D] (the layer's normed input), ``weight`` [H * K, D]
+    (the decay's projection, no bias), ``a_log`` [H], ``dt_bias`` [H * K].
+    The product takes its operands as they are stored and leaves a float32
+    result that is never rounded: ``exp(a_log)`` reaches 16 and the
+    sigmoid's slope carries a bf16 step of the product into ``g``, whose
+    sums the chunked kernels exponentiate. Everything after it is float32.
+    The kernels' exponents rest on the bound. Returns float32 [..., H * K];
+    scope ``kda_gate``."""
+    f32 = jnp.float32
+    with jax.named_scope("kda_gate"):
+        f = jnp.einsum("...d,cd->...c", data, weight,
+                       precision=mxu_precision(data, weight),
+                       preferred_element_type=f32)
+        rate = jnp.repeat(jnp.exp(a_log.astype(f32)),
+                          weight.shape[0] // a_log.shape[0])
+        return lower_bound * jax.nn.sigmoid(rate * (f + dt_bias.astype(f32)))
+
+
+@register("_contrib_kda_attention", aliases=("kda_attention",))
+def kda_attention(q, k, v, g, beta, chunk=64):
+    """Kimi Delta Attention after its projections, filters and gates: by
+    head, ``S_t = (I - b_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + b_t k_t
+    v_t^T`` from ``S_0 = 0`` (float32, [K, V]) and ``o_t = S_t^T q_t /
+    sqrt(K)``. ``q``, ``k`` [B, T, H * K] (L2-normed by head), ``v`` [B, T,
+    H * V], ``g`` [B, T, H * K] float32 in [-5, 0) (:func:`kda_gate`),
+    ``beta`` [B, T, H]. By chunks of ``chunk`` tokens through the Pallas
+    pair ``kda_fwd`` / ``kda_bwd`` (:mod:`mxtpu.ops.pallas.kda`: the chunk's
+    WY factors, the state carried in VMEM); off the TPU the same chunks on
+    a plain path, counted in ``kda_attention.fallbacks``. Returns [B, T, H
+    * V]; scope ``kda_attention``, span ``kda_attention.trace``."""
+    from .. import telemetry
+    from .pallas.kda import kda_attention as attend
+    with telemetry.span("kda_attention.trace"), \
+            jax.named_scope("kda_attention"):
+        return attend(q, k, v, g.astype(jnp.float32), beta, chunk)
 
 
 @register("InstanceNorm")

@@ -113,7 +113,8 @@ def switch_ffn(x, router_w, w1, b1, w2, b2, capacity_factor=1.25):
     return out, aux
 
 
-def route_top_k(x, router_w, score_bias, top_k, scale=1.0, score="sigmoid"):
+def route_top_k(x, router_w, score_bias, top_k, scale=1.0, score="sigmoid",
+                n_group=1, topk_group=1):
     """A top-k router: ``s = score(x W^T)`` with the product in float32
     (``router_w`` is (E, D), a Dense weight); the ``top_k`` experts are
     chosen by ``s + score_bias`` (the selection bias steers the choice
@@ -122,13 +123,19 @@ def route_top_k(x, router_w, score_bias, top_k, scale=1.0, score="sigmoid"):
     w)``, both (T, top_k): int32 experts and float32 weights.
 
     ``score`` names the published router, a property of the model:
-    ``"sigmoid"`` is DeepSeek-V3's (arXiv:2412.19437 §2.1.2) without its
-    group limit, each expert scored alone (kanana-2, LFM2 with its expert
-    bias); ``"softmax"`` is the softmax over all E logits in float32, its
+    ``"sigmoid"`` is DeepSeek-V3's (arXiv:2412.19437 §2.1.2), each expert
+    scored alone (kanana-2, LFM2 with its expert bias); ``"softmax"`` is the softmax over all E logits in float32, its
     ``top_k`` largest renormalised, which equals the softmax over the
     chosen logits alone (Mixtral's, and SmallThinker's with
     ``moe_primary_router_apply_softmax`` and ``norm_topk_prob``); a model
-    without a selection bias holds that leaf at zero."""
+    without a selection bias holds that leaf at zero.
+
+    ``n_group`` > 1 is that paper's group limit (its released ``noaux_tc``
+    gate): the E experts are ``n_group`` equal groups in order, a group's
+    score is the sum of its two largest ``s + score_bias``, only the
+    ``topk_group`` best groups stay, and the ``top_k`` are chosen among
+    their experts (the others' biased scores read -inf). The weights are
+    still ``s`` at the chosen experts over their sum."""
     if score not in ("sigmoid", "softmax"):
         raise MXNetError("route_top_k: score %r is neither 'sigmoid' nor "
                          "'softmax'" % (score,))
@@ -137,11 +144,30 @@ def route_top_k(x, router_w, score_bias, top_k, scale=1.0, score="sigmoid"):
             "td,ed->te", x.astype(jnp.float32), router_w.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST)
         s = jax.nn.sigmoid(s) if score == "sigmoid" else jax.nn.softmax(s, -1)
-        _, idx = jax.lax.top_k(
-            s + jax.lax.stop_gradient(score_bias.astype(jnp.float32)), top_k)
+        biased = s + jax.lax.stop_gradient(score_bias.astype(jnp.float32))
+        if n_group > 1:
+            biased = _group_limited(biased, n_group, topk_group)
+        _, idx = jax.lax.top_k(biased, top_k)
         w = jnp.take_along_axis(s, idx, axis=-1)
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
     return idx, w
+
+
+def _group_limited(biased, n_group, topk_group):
+    """(T, E) biased scores with -inf outside each token's ``topk_group``
+    best of ``n_group`` groups (a group's score: its two largest)."""
+    t, e = biased.shape
+    if e % n_group or not 0 < topk_group <= n_group or e // n_group < 2:
+        raise MXNetError("route_top_k: %d experts do not make %d groups of "
+                         "two or more, %d of them kept"
+                         % (e, n_group, topk_group))
+    by_group = jnp.sum(jax.lax.top_k(
+        biased.reshape(t, n_group, e // n_group), 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(by_group, topk_group)           # (T, topk_group)
+    stays = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :],
+                    axis=1)                                 # (T, n_group)
+    return jnp.where(jnp.repeat(stays, e // n_group, axis=1), biased,
+                     -jnp.inf)
 
 
 # rows a grid step of XLA:TPU's grouped matmul kernel takes (its tile
@@ -253,7 +279,7 @@ def _grouped(a, b, sizes, dims=_ROWS):
 
 def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
                first_expert=0, scale=1.0, grouped=True, router_x=None,
-               score="sigmoid", activation="silu"):
+               score="sigmoid", activation="silu", n_group=1, topk_group=1):
     """Top-k routed gated FFN over the experts HELD here: a contiguous
     range of the router's experts (expert parallelism's share of a layer;
     all of them when ``w_gate`` holds as many as the router scores).
@@ -292,6 +318,8 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
         weights' gradient then goes through the router to ``router_x``,
         not to ``x``. Counted in ``moe.router_ahead``.
     score : the router's score function (:func:`route_top_k`).
+    n_group, topk_group : the router's group limit (:func:`route_top_k`;
+        1: none). Counted in ``moe.group_limited``.
     activation : the gate's: ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU:
         ``relu(x w_gate) * (x w_up)``). Like ``score`` a property of the
         model, named by its configuration.
@@ -331,8 +359,10 @@ def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
         telemetry.inc("moe.router_ahead")
     if score == "softmax":
         telemetry.inc("moe.score.softmax")
+    if n_group > 1:
+        telemetry.inc("moe.group_limited")
     idx, w = route_top_k(x if router_x is None else router_x, router_w,
-                         score_bias, top_k, scale, score)
+                         score_bias, top_k, scale, score, n_group, topk_group)
     if not grouped:
         from ..ops.precision_util import contract_acc
         local = idx - first_expert

@@ -98,7 +98,10 @@ _LOCK = threading.Lock()
 _COUNTERS = {}            # (name, tag-or-None) -> float
 _GAUGES = {}              # name -> float
 _HISTS = {}               # name -> [count, sum, min, max, reservoir-deque]
-EVENT_RING_CAP = 65536    # a reader that finds this many lost the head
+# a reader that finds this many lost the head. JAX reports a trace for
+# every jnp call under a trace, cached or not: one step with six pairs of
+# KDA kernels under recomputation is 63,258 events before its first call
+EVENT_RING_CAP = 262144
 _EVENTS = collections.deque(maxlen=EVENT_RING_CAP)
 #                         ^ (name, cat, ts_us, dur_us, tid)
 _RESERVOIR = 2048         # per-histogram quantile sample bound

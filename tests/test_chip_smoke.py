@@ -46,8 +46,8 @@ def test_rehearsal_one_chip_phases(tmp_path):
     assert list(phases) == ["device", "sync", "train_resnet50",
                             "train_bert_base", "flash_two_widths",
                             "flash_grouped", "window_attention",
-                            "sparse_attention", "routed_layer",
-                            "gluon_trainer",
+                            "sparse_attention", "kda_attention",
+                            "routed_layer", "gluon_trainer",
                             "serve", "warm_start", "total"]
     assert max(phases["flash_two_widths"]["gaps"].values()) <= 2e-2
     assert max(phases["flash_grouped"]["gaps"].values()) <= 2e-2
@@ -68,6 +68,12 @@ def test_rehearsal_one_chip_phases(tmp_path):
     assert sparse["sparse_attention"]["fallbacks"] == 1
     assert sparse["pairs"] == {"selected": 32 * 256 - 32 * 31 // 2,
                                "visited": 256 * 256}
+    # one layer of Kimi Delta Attention against the recurrence; off the
+    # chip the plain path, which says so
+    linear = phases["kda_attention"]
+    assert max(linear["gaps"].values()) <= 3e-2
+    assert linear["kda_attention"] == {"calls": 1, "fallbacks": 1,
+                                       "chunks": 6}
     rows = phases["routed_layer"]["rows"]
     assert 0 < rows["live"] <= rows["run"] < rows["total"]
     assert max(phases["routed_layer"]["gaps"].values()) <= 3e-2

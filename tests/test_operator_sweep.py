@@ -988,6 +988,9 @@ COVERED_ELSEWHERE = {
     "_contrib_grouped_attention": "test_hybrid_lm.py",
     "_contrib_index_select": "test_keye_vl2.py",
     "_contrib_sparse_attention": "test_keye_vl2.py",
+    "_contrib_kda_conv": "test_ling3_flash.py",
+    "_contrib_kda_gate": "test_ling3_flash.py",
+    "_contrib_kda_attention": "test_ling3_flash.py",
     # the sparse-label cross-entropy in one pass: against a float64 oracle
     "_contrib_log_softmax_pick": "test_loss_one_pass.py",
     "CTCLoss": "test_ctc.py",

@@ -15,6 +15,7 @@ reason (a second file can land on a worker that cannot load the library).
 """
 import importlib
 import re
+import time
 
 import pytest
 
@@ -404,6 +405,51 @@ def test_sparse_kernels_compile_to_mosaic(one_chip, as_tpu):
     assert [telemetry.value("sparse_attention." + n)
             for n in ("calls", "fallbacks", "bwd_pallas")] == [1, 0, 1]
     assert fa.DISPATCH_STATS["pallas"] == 0 and fa.DISPATCH_STATS["xla"] == 0
+
+
+def test_kda_kernels_compile_to_mosaic(one_chip, as_tpu):
+    """Both Kimi-Delta-Attention kernels at the Ling cell's shapes (one
+    layer, 8,192 positions, 32 heads of 128, bf16 with a float32 log-decay)
+    as one differentiated program: Mosaic calls under their own names, the
+    heads fetched from the projections' own [B, T, H * K] layout (no copy
+    of an operand), and nothing a token kept between them: the states at
+    the 128 chunk starts, 268 MB, are the program's only temporary."""
+    from mxtpu import telemetry
+    kda = importlib.import_module("mxtpu.ops.pallas.kda")
+    t = 8192
+    x = _spec((1, t, 4096), one_chip)
+    g = _spec((1, t, 4096), one_chip, jnp.float32)
+    beta = _spec((1, t, 32), one_chip)
+
+    def loss(*a):
+        return kda.kda_attention(*a).astype(jnp.float32).sum()
+
+    for name in ("calls", "fallbacks"):
+        telemetry.reset_metric("kda_attention." + name)
+    t0_us = time.perf_counter_ns() // 1000
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, x, x, g, beta).compile()
+    text = compiled.as_text()
+    # JAX reports a trace for every jnp call of the kernels' bodies, and
+    # each is a ring event: the cell's step (six such layers, the forward
+    # traced again under recomputation) has to leave the ring its head,
+    # or every reader of the program's spans falls silent there
+    traces = sum(1 for n, _c, ts, _d, _t in telemetry.events()
+                 if n == "jax.trace" and ts >= t0_us)
+    assert 0 < 6 * 2 * traces < telemetry.EVENT_RING_CAP // 2, traces
+    assert "kda_fwd" in text and "kda_bwd" in text
+    assert [telemetry.value("kda_attention." + n)
+            for n in ("calls", "fallbacks")] == [1, 0]
+    # a state a chunk and head, never a state a token
+    assert "f32[32,128,128,128]" in text
+    assert "f32[1,8192,32,128,128]" not in text
+    assert "f32[32,8192,128,128]" not in text
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert 32 * 128 * 128 * 128 * 4 <= temps < 0.3e9, temps
+    # the forward alone keeps nothing
+    alone = jax.jit(lambda *a: kda.kda_attention(*a)).lower(
+        x, x, x, g, beta).compile()
+    assert alone.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_the_selection_compiles_by_blocks(one_chip, as_tpu):
