@@ -624,7 +624,12 @@ def phase_routed_layer(sizes, seed, on_tpu):
     every token by every expert held. On the chip the device's memory is
     filled with NaN and freed first: the grouped kernel leaves the rows
     past the last group unwritten, and whatever they hold must not reach a
-    result. Prints the plan (rows live / run / laid out at most), the form
+    result. On the chip every grouped product, nine a rung, has to take the
+    Pallas grouped-matmul kernel (``moe.grouped_mm.pallas``; one left to
+    XLA's ``ragged_dot`` fails the phase with its reason), so the
+    comparison with the masked form is the kernel's on the chip; off the
+    chip the same nine are counted on either path. Prints the plan (rows
+    live / run / laid out at most), the form
     each rung's sum by token takes in each direction (``gather`` over every
     pair or ``scatter``-add of the rung's rows: ``moe._sums_by_gather``, the
     predicate the layer asks, and what the trace counted of each), and what
@@ -667,7 +672,8 @@ def phase_routed_layer(sizes, seed, on_tpu):
 
     f32 = lambda a: a.astype(jnp.float32)
     counters = ("moe.kept_bytes", "moe.bwd_products",
-                "moe.sum_by_token.gather", "moe.sum_by_token.scatter")
+                "moe.sum_by_token.gather", "moe.sum_by_token.scatter",
+                "moe.grouped_mm.pallas", "moe.grouped_mm.xla")
     for name in counters:
         telemetry.reset_metric(name)
     got = grads(True)
@@ -694,6 +700,13 @@ def phase_routed_layer(sizes, seed, on_tpu):
     _check(rows["live"] <= rows["run"] == min(
         r for r in plan.rungs if r >= rows["live"]),
         "the plan runs %s of the rungs %s" % (rows, plan.rungs))
+    products = {"pallas": kept["moe.grouped_mm.pallas"],
+                "xla": telemetry.tagged("moe.grouped_mm.xla")}
+    _check(products["pallas"] + kept["moe.grouped_mm.xla"]
+           == 9 * len(plan.rungs)
+           and not (on_tpu and kept["moe.grouped_mm.xla"]),
+           "the routed layer's %d rungs traced the grouped products %s"
+           % (len(plan.rungs), products))
     # the forward sums rows of the experts' dtype, the backward float32
     sums = {way: {str(r): "gather" if moe._sums_by_gather(
         r, rows["total"], size) else "scatter" for r in plan.rungs}
@@ -706,7 +719,8 @@ def phase_routed_layer(sizes, seed, on_tpu):
            "the routed layer's sums by token traced %s for %s" % (kept, sums))
     return {"shape": n, "rows": rows, "rungs": list(plan.rungs),
             "sums_by_token": sums, "kept_bytes": kept["moe.kept_bytes"],
-            "bwd_products": kept["moe.bwd_products"], "gaps": gaps}
+            "bwd_products": kept["moe.bwd_products"],
+            "grouped_products": products, "gaps": gaps}
 
 
 def _gluon_loop(sizes, seed, mesh=None):

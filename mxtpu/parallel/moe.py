@@ -251,30 +251,76 @@ def piece_plan(idx, first_expert, held, total):
     return PiecePlan(order, sizes, n_live, rung, rungs)
 
 
-# the grouped product over sorted rows, and the one over the sorted rows as
-# the contracted dimension that gives a weight's gradient: the two forms
-# XLA:TPU has a grouped kernel for
-_ROWS = jax.lax.RaggedDotDimensionNumbers(        # a[rows of e] @ b[e]
-    (((1,), (1,)), ((), ())), lhs_ragged_dimensions=(0,),
-    rhs_group_dimensions=(0,))
-_WEIGHTS = jax.lax.RaggedDotDimensionNumbers(     # a[rows of e].T @ b[rows of e]
-    (((0,), (0,)), ((), ())), lhs_ragged_dimensions=(0,),
-    rhs_group_dimensions=())
+# the grouped product over sorted rows, that against a weight as it lies
+# (contracted on its last dimension: a backward's ``a @ b[e].T``), and the
+# one over the sorted rows as the contracted dimension that gives a
+# weight's gradient: the kernel's three forms, and how ``ragged_dot`` says
+# each (the second only after a transposed copy of the weights)
+# (the names are the kernel's own, ``ops/pallas/grouped_matmul.py``; that
+# module is imported where a product is built and not with this one:
+# ``import mxtpu`` reaches this file, and Pallas is a second's import that
+# a program without a routed layer never needs)
+_ROWS, _ROWS_T, _WEIGHTS = "rows", "rows_t", "weights"
+_RAGGED = {
+    _ROWS: jax.lax.RaggedDotDimensionNumbers(     # a[rows of e] @ b[e]
+        (((1,), (1,)), ((), ())), lhs_ragged_dimensions=(0,),
+        rhs_group_dimensions=(0,)),
+    _WEIGHTS: jax.lax.RaggedDotDimensionNumbers(  # a[rows].T @ b[rows]
+        (((0,), (0,)), ((), ())), lhs_ragged_dimensions=(0,),
+        rhs_group_dimensions=()),
+}
 
 
-def _grouped(a, b, sizes, dims=_ROWS):
+class _Groups:
+    """The groups of a branch's sorted rows: each one's rows, and the
+    kernel's tile table over them, built at the first product the kernel
+    takes and shared by the branch's others (3 forward, 6 backward)."""
+
+    def __init__(self, sizes, rows):
+        self.sizes, self.rows, self._table = sizes, rows, None
+
+    @property
+    def table(self):
+        from ..ops.pallas import grouped_matmul as gmm
+        if self._table is None:
+            self._table = gmm.tile_table(
+                self.sizes, self.rows,
+                gmm.row_tile(self.rows, self.sizes.shape[0]))
+        return self._table
+
+
+def _grouped(a, b, groups, form=_ROWS):
     """``a[rows of group e] @ b[e]`` for every group, rows sorted by group
-    (with ``_WEIGHTS``, every group's ``a[rows].T @ b[rows]``):
-    ``ragged_dot_general``, which XLA:TPU lowers to one grouped matmul
-    kernel that visits only the row tiles the groups cover, under the
-    framework's MXU policy (``contract_acc``). Rows past the last group
-    cost nothing and are NOT WRITTEN: they hold what the memory held (NaN,
-    on a chip that has run anything else), so a caller selects them away
-    (``where``) before any arithmetic and never multiplies them by zero."""
+    (``_ROWS_T``: ``@ b[e].T``, ``b`` taken as it lies; ``_WEIGHTS``: every
+    group's ``a[rows].T @ b[rows]``), under the framework's MXU policy:
+    operands in one pass, a float32 accumulator, one rounding. The one
+    place the layer chooses who multiplies: the Pallas grouped-matmul
+    kernel (``ops/pallas/grouped_matmul.py``: the accumulator stays in
+    VMEM, the result is written once in the operands' dtype, no weight is
+    copied transposed) wherever it applies, else ``ragged_dot_general``,
+    XLA:TPU's grouped kernel, which leaves float32 for a second pass to
+    round. Both visit only the row tiles the groups cover. Rows past the
+    last group cost nothing and are NOT WRITTEN: they hold what the
+    memory held (NaN, on a chip that has run anything else), so a caller
+    selects them away (``where``) before any arithmetic and never
+    multiplies them by zero. Counted at trace time:
+    ``moe.grouped_mm.pallas`` (a product the kernel took),
+    ``moe.grouped_mm.xla`` (one left to ``ragged_dot``, by reason:
+    ``platform`` / ``dtype`` / ``lanes``); 0 is the number to expect of
+    the latter in a timed program."""
+    from .. import telemetry
+    from ..ops.pallas import grouped_matmul as gmm
     from ..ops.precision_util import contract_acc
+    reason = gmm.refusal(a, b)
+    if reason is None:
+        telemetry.inc("moe.grouped_mm.pallas")
+        return gmm.grouped_matmul(a, b, groups.table, form)
+    telemetry.inc("moe.grouped_mm.xla", tag=reason)
+    if form == _ROWS_T:
+        b, form = jnp.swapaxes(b, 1, 2), _ROWS
     return contract_acc(
-        lambda a, b, **kw: jax.lax.ragged_dot_general(a, b, sizes, dims, **kw),
-        a, b)
+        lambda a, b, **kw: jax.lax.ragged_dot_general(
+            a, b, groups.sizes, _RAGGED[form], **kw), a, b)
 
 
 def routed_ffn(x, router_w, score_bias, w_gate, w_up, w_down, top_k,
@@ -477,10 +523,11 @@ def _held_rows(rows, top_k, keep, x, w, w_gate, w_up, w_down, order, sizes,
     products, padded to all T*k rows, for :func:`_held_rows_bwd`."""
     with jax.named_scope("moe.dispatch"):
         xs = _rows_here(rows, top_k, x, order, sizes)[-1]
+    groups = _Groups(sizes, rows)
     with jax.named_scope("moe.experts"):
-        gate = _grouped(xs, w_gate, sizes)
-        up = _grouped(xs, w_up, sizes)
-        ys = _grouped(_GATES[activation](gate) * up, w_down, sizes)
+        gate = _grouped(xs, w_gate, groups)
+        up = _grouped(xs, w_up, groups)
+        ys = _grouped(_GATES[activation](gate) * up, w_down, groups)
     with jax.named_scope("moe.combine"):
         # float32 under the router's weights, summed by token. A row past
         # the live ones holds nothing defined: it is zeroed BEFORE it meets
@@ -505,18 +552,19 @@ def _held_rows_bwd(rows, top_k, g, gate, up, x, w, w_gate, w_up, w_down,
     f32 = jnp.float32
     last = rows == order.shape[0]       # the rung every ladder has
 
-    def mm(a, b, dims=_ROWS):
+    def mm(a, b, form):
         if last:
             telemetry.inc("moe.bwd_products")
-        return _grouped(a, b, sizes, dims)
+        return _grouped(a, b, groups, form)
 
-    def back(a, b):         # a[rows of e] @ b[e].T
-        return jnp.where(live, mm(a, jnp.swapaxes(b, 1, 2)), 0).astype(f32)
+    def back(a, b):         # a[rows of e] @ b[e].T, b as it lies
+        return jnp.where(live, mm(a, b, _ROWS_T), 0).astype(f32)
 
     with jax.named_scope("moe.dispatch"):
         pairs, tok, live, xs = _rows_here(rows, top_k, x, order, sizes)
         w_row = _weights_here(w, pairs)
         gy = jnp.where(live, g[tok], 0)
+    groups = _Groups(sizes, rows)
     with jax.named_scope("moe.experts"):
         gate = jnp.where(live, gate[:rows], 0)
         up = jnp.where(live, up[:rows], 0)

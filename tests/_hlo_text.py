@@ -34,13 +34,34 @@ def conditionals(text):
             if " conditional(" in line]
 
 
+_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+
+
+def is_grouped_kernel(line):
+    """Whether an instruction line is a call of the Pallas grouped-matmul
+    kernel: a custom call named ``grouped_matmul_<form>.<n>`` after the
+    kernel (``mxtpu/ops/pallas/grouped_matmul.py``)."""
+    found = _RESULT.match(line)
+    return bool(found) and found.group(3) == "custom-call" \
+        and found.group(1).startswith("grouped_matmul_")
+
+
 def grouped_kernels(text):
-    """XLA:TPU's grouped matmul kernels (``ragged-dot`` custom calls, their
-    metadata call apart) in each computation that holds any, sorted."""
-    counts = (sum("custom-call(" in line and "ragged-dot" in line
-                  and "ragged-dot-metadata" not in line for line in lines)
+    """The grouped-matmul kernel's calls in each computation that holds
+    any, sorted."""
+    counts = (sum(map(is_grouped_kernel, lines))
               for lines in computations(text).values())
     return sorted(n for n in counts if n)
+
+
+def producers(lines, *shapes):
+    """(name, opcode) of the instructions among ``lines`` whose result is
+    an array of one of ``shapes`` (tuples of ints), any element type."""
+    dims = {",".join(map(str, shape)) for shape in shapes}
+    found = (_RESULT.match(line) for line in lines)
+    return [(f.group(1), f.group(3)) for f in found
+            if f and f.group(2) in dims]
 
 
 def branch_computations(text):

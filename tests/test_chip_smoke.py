@@ -77,6 +77,11 @@ def test_rehearsal_one_chip_phases(tmp_path):
     rows = phases["routed_layer"]["rows"]
     assert 0 < rows["live"] <= rows["run"] < rows["total"]
     assert max(phases["routed_layer"]["gaps"].values()) <= 3e-2
+    # off the chip the grouped products are ragged_dot's, nine a rung, and
+    # the phase says why (on the chip the kernel takes all of them)
+    assert phases["routed_layer"]["grouped_products"] == {
+        "pallas": 0, "xla": {"platform": 9 * len(
+            phases["routed_layer"]["rungs"])}}
     for rec in phases.values():
         assert rec["seconds"] >= 0 and rec["compile_seconds"] >= 0
     assert phases["device"]["platform"] == "cpu"

@@ -431,9 +431,9 @@ def test_rows_past_the_last_group_hold_nothing_defined(small_tile, sum_form,
     if poisoned == "products":
         grouped = moe._grouped
 
-        def poison(a, b, sizes, *dims):
-            out = grouped(a, b, sizes, *dims)   # (rows, .) or (E, K, N)
-            return _dead_rows_poisoned(out, jnp.sum(sizes)) \
+        def poison(a, b, groups, *form):
+            out = grouped(a, b, groups, *form)  # (rows, .) or (E, K, N)
+            return _dead_rows_poisoned(out, jnp.sum(groups.sizes)) \
                 if out.ndim == 2 else out
 
         monkeypatch.setattr(moe, "_grouped", poison)

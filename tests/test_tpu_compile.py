@@ -245,15 +245,23 @@ def test_flash_window_compiles_to_mosaic(one_chip, as_tpu, window):
 
 def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
     """The expert layer at the cell's widths (16 of 128 experts held, 6
-    choices a token, 8,192 tokens): XLA:TPU takes ``ragged_dot`` as its
-    grouped matmul kernel, forward and both transposes; nothing is expanded
-    into a product over every expert held; a forward branch holds three
-    grouped kernels and a backward branch six (the hand-written backward
-    computes none again: nine before); and the only arrays of all T*k =
-    49,152 (token, slot) rows computed unconditionally are the two up
-    products the forward keeps for the backward, bf16 by the experts'
-    width: none by the model's width, none float32."""
-    from _hlo_text import arrays_outside_control_flow, grouped_kernels
+    choices a token, 8,192 tokens): every grouped product, forward and both
+    transposes, is a call of the Pallas grouped-matmul kernel under its own
+    name and none is left to ``ragged_dot``; nothing is expanded into a
+    product over every expert held; a forward branch holds three calls and
+    a backward branch six (the hand-written backward computes none again:
+    nine before); no branch holds a copy of an expert leaf, transposed or
+    not (``a @ b[e].T`` reads the leaf as it lies): the only arrays of a
+    leaf's shape made in a branch are the three weight gradients, the
+    kernel's own results; the lowered module holds ONE function for each
+    distinct (rows, widths, form), six a rung, called from every site that
+    has the shape; and the only arrays of all T*k = 49,152 (token, slot)
+    rows computed unconditionally are the two up products the forward keeps
+    for the backward, bf16 by the experts' width: none by the model's
+    width, none float32."""
+    from _hlo_text import (arrays_outside_control_flow, branch_computations,
+                           grouped_kernels, producers)
+    from mxtpu import telemetry
     from mxtpu.parallel import moe
     x = _spec((8192, 2048), one_chip)
     specs = (x, _spec((128, 2048), one_chip), _spec((128,), one_chip),
@@ -266,14 +274,27 @@ def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):
             x, router, bias, eg, eu, ed, top_k=6,
             scale=2.448).astype(jnp.float32)))
 
-    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 3, 4, 5)), *specs)
-    assert "ragged-dot" in text and "tpu_custom_call" in text
+    for name in ("pallas", "xla"):
+        telemetry.reset_metric("moe.grouped_mm." + name)
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4, 5))).lower(*specs)
+    assert moe._rungs(49152, 16, 128) == (8192, 16384, 49152)
+    assert [telemetry.value("moe.grouped_mm." + name)
+            for name in ("pallas", "xla")] == [27, 0]
+    # one lowered function a (rows, widths, form): gate and up share theirs
+    assert lowered.as_text().count("tpu_custom_call") == 6 * 3
+    text = lowered.compile().as_text()
+    assert "ragged-dot" not in text and "tpu_custom_call" in text
     # no [T*k, held, .] or [held, T*k, .] expansion of the products
     assert "49152,16,768" not in text and "16,49152,768" not in text
-    assert moe._rungs(49152, 16, 128) == (8192, 16384, 49152)
     assert "[8192,768]" in text and "[49152,768]" in text
     # three rungs, forward | backward
     assert grouped_kernels(text) == [3, 3, 3, 6, 6, 6]
+    for branches in branch_computations(text):
+        for lines in branches:
+            made = producers(lines, (16, 2048, 768), (16, 768, 2048))
+            assert all(op == "get-tuple-element" or name.startswith(
+                "grouped_matmul_weights") for name, op in made), made
+            assert sum(op == "custom-call" for _, op in made) in (0, 3)
     assert arrays_outside_control_flow(text, 49152, 2048) == []
     kept = arrays_outside_control_flow(text, 49152, 768)
     assert kept and all(line.count("[49152,768]")
@@ -291,7 +312,8 @@ def test_last_rung_sums_by_token_with_a_gather(one_chip, as_tpu):
     still scatter-adds its 22,016 float32 rows. Forced to the scatter-add
     everywhere (the parent's form) all four branches hold one. Prints
     ``memory_analysis()``'s temporaries of both."""
-    from _hlo_text import branch_computations, grouped_kernels
+    from _hlo_text import (branch_computations, grouped_kernels,
+                           is_grouped_kernel)
     from mxtpu.parallel import moe
     specs = (_spec((16384, 2048), one_chip), _spec((32, 2048), one_chip),
              _spec((32,), one_chip), _spec((8, 2048, 1792), one_chip),
@@ -313,7 +335,7 @@ def test_last_rung_sums_by_token_with_a_gather(one_chip, as_tpu):
         assert [len(branches) for branches in switches] == [2, 2]
         # the backward's branches hold six grouped kernels, the forward's 3
         switches.sort(key=lambda branches: sum(
-            "ragged-dot" in line for line in branches[0]))
+            map(is_grouped_kernel, branches[0])))
         return [[(sum(" scatter(" in line and "f32[16384,2048]" in
                       line.split(" scatter(")[0] for line in lines),
                   sum(" gather(" in line and "[16384,2048]" in
