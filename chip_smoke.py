@@ -73,6 +73,8 @@ FULL = dict(
     # positions, chunks of 64; checked on the first heads and positions
     kda=dict(heads=32, seq=8192, width=128, chunk=64, check_heads=2,
              check_seq=1024),
+    # that layer's short filter on one projection: four taps, heads of 128
+    kda_conv=dict(batch=1, seq=8192, dim=4096, taps=4, head_dim=128),
     # one chip's share of a routed expert layer: 16 of 128 experts, top-6
     routed=dict(tokens=8192, dim=2048, width=768, held=16, total=128,
                 top_k=6),
@@ -98,6 +100,7 @@ TINY = dict(
                 index_heads=4, index_width=8, topk=32, check_heads=4),
     kda=dict(heads=2, seq=96, width=16, chunk=16, check_heads=2,
              check_seq=96),
+    kda_conv=dict(batch=2, seq=96, dim=64, taps=4, head_dim=16),
     routed=dict(tokens=512, dim=64, width=32, held=2, total=16, top_k=3),
     train_steps=5, gluon_steps=3,
     # the toy memorizes its 8 images in three steps (loss 3.4 -> 0.02),
@@ -537,6 +540,57 @@ def phase_sparse_attention(n, seed, on_tpu):
     return {"shape": n, "sparse_attention": stats, "gaps": gaps,
             "pairs": {"selected": stats["pairs_selected"],
                       "visited": stats["pairs_visited"]}}
+
+
+def phase_kda_conv(n, seed, on_tpu):
+    """The short filter of a Kimi-Delta-Attention layer at shape ``n``,
+    through the operator (``_contrib_kda_conv``) and ``jax.vjp`` of it,
+    with a head's norm and without: on the chip both Pallas kernels
+    (``kda_conv_fwd`` / ``kda_conv_bwd``) against the plain function under
+    XLA, the value, ``d data`` and ``d weight``; on float32 data (no
+    rounding at the end: the arithmetic itself, to float32's rounding) and
+    on bf16 data (one rounding: an entry in some thousands falls the other
+    way). Fails on the chip if a pass took the plain path."""
+    import functools
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    from mxtpu import telemetry
+    from mxtpu.ops.registry import get_op
+    plain = importlib.import_module("mxtpu.ops.nn")._kda_conv_plain
+    op = get_op("_contrib_kda_conv").fn
+    shape = (n["batch"], n["seq"], n["dim"])
+    ks = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 3)
+    w = 0.5 * jax.random.normal(ks[1], (n["dim"], n["taps"]))
+    names = ("calls", "pallas", "xla")
+    for name in names:
+        telemetry.reset_metric("kda_conv." + name)
+    gaps = {}
+    for dtype, limit in (("float32", 2e-5), ("bfloat16", 8e-3)):
+        x = jax.random.normal(ks[0], shape).astype(dtype)
+        g = jax.random.normal(ks[2], shape).astype(dtype)
+        for head_dim in (n["head_dim"], 0):
+            got, vjp = jax.vjp(functools.partial(op, head_dim=head_dim),
+                               x, w)
+            want, ref_vjp = jax.vjp(jax.jit(functools.partial(
+                plain, head_dim)), x, w)
+            for what, a, b in zip(("out", "ddata", "dweight"),
+                                  (got,) + vjp(g), (want,) + ref_vjp(g)):
+                a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+                gap = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+                gaps["%s.%s.%s" % (dtype, "normed" if head_dim else "bare",
+                                   what)] = gap
+                _check(gap <= limit, "the short filter at %s (%s, heads of "
+                       "%d) has %s %g from the plain function's"
+                       % (n, dtype, head_dim, what, gap))
+    stats = {name: telemetry.value("kda_conv." + name) for name in names}
+    if on_tpu:
+        _check(stats == {"calls": 8, "pallas": 8, "xla": 0},
+               "a pass of the short filter was left for the plain path: "
+               "%s %s" % (stats, telemetry.tagged("kda_conv.xla")))
+    return {"shape": n, "kda_conv": stats,
+            "reasons": telemetry.tagged("kda_conv.xla"), "gaps": gaps}
 
 
 def phase_kda_attention(n, seed, on_tpu):
@@ -1053,6 +1107,8 @@ def run(sizes, chips=1, seed=0, out=sys.stdout):
             phase("sparse_attention", phase_sparse_attention,
                   sizes["sparse"], seed, on_tpu)
             phase("kda_attention", phase_kda_attention, sizes["kda"], seed,
+                  on_tpu)
+            phase("kda_conv", phase_kda_conv, sizes["kda_conv"], seed,
                   on_tpu)
             phase("routed_layer", phase_routed_layer, sizes, seed, on_tpu)
             net = phase("gluon_trainer", phase_gluon_trainer, sizes, seed)

@@ -833,17 +833,48 @@ def _kda_conv_plain(head_dim, data, weight):
     return y.astype(data.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _kda_conv(head_dim, data, weight):
-    return _kda_conv_plain(head_dim, data, weight)
+def _kda_conv_kernels(head_dim, data, weight):
+    """The Pallas pair of ``ops/pallas/short_filter.py`` if this call can
+    take it, else None; counted at trace time, a pass (the value, or the
+    two gradients) a count: ``kda_conv.calls``, then ``kda_conv.pallas`` or
+    ``kda_conv.xla`` by reason (``platform`` / ``dtype`` / ``lanes`` /
+    ``taps``). 0 is the number to expect of the latter in a timed
+    program."""
+    from .. import telemetry
+    from .pallas import short_filter
+    telemetry.inc("kda_conv.calls")
+    reason = short_filter.refusal(data, weight, head_dim)
+    if reason is None:
+        telemetry.inc("kda_conv.pallas")
+        return short_filter
+    telemetry.inc("kda_conv.xla", tag=reason)
+    return None
+
+
+def _kda_conv_value(head_dim, data, weight):
+    kernels = _kda_conv_kernels(head_dim, data, weight)
+    if kernels is None:
+        return _kda_conv_plain(head_dim, data, weight)
+    return kernels.forward(data, weight, head_dim)
+
+
+_kda_conv = jax.custom_vjp(_kda_conv_value, nondiff_argnums=(0,))
+
+
+def _kda_conv_bwd(head_dim, kept, g):
+    kernels = _kda_conv_kernels(head_dim, *kept)
+    if kernels is None:
+        return jax.vjp(functools.partial(_kda_conv_plain, head_dim),
+                       *kept)[1](g)
+    return kernels.backward(*kept, g, head_dim)
 
 
 # as the gated filter's: the backward filters the projected input again
+# (inside ``kda_conv_bwd``, in VMEM, where the kernels run)
 _kda_conv.defvjp(
     lambda head_dim, data, weight: (
-        _kda_conv_plain(head_dim, data, weight), (data, weight)),
-    lambda head_dim, kept, g: jax.vjp(
-        functools.partial(_kda_conv_plain, head_dim), *kept)[1](g))
+        _kda_conv_value(head_dim, data, weight), (data, weight)),
+    _kda_conv_bwd)
 
 
 @register("_contrib_kda_conv", aliases=("kda_conv",))
@@ -855,8 +886,12 @@ def kda_conv(data, weight, head_dim=0):
     ``head_dim`` > 0 each head's ``head_dim`` entries are then divided by
     ``sqrt(sum of their squares + 1e-6)`` (the layer's L2 norm of q and
     k), in the same pass. float32 arithmetic, one rounding to ``data``'s
-    dtype; the backward computes it again from ``data``. Scope
-    ``kda_conv``."""
+    dtype; the backward computes it again from ``data``.
+    :func:`_kda_conv_plain` is the definition; on the TPU the Pallas pair
+    ``kda_conv_fwd`` / ``kda_conv_bwd`` (:mod:`mxtpu.ops.pallas.short_filter`)
+    computes it, one kernel call a pass, counted at trace time in
+    ``kda_conv.calls`` / ``.pallas`` / ``.xla`` (by reason: a call left to
+    the plain function). Scope ``kda_conv``."""
     with jax.named_scope("kda_conv"):
         return _kda_conv(head_dim, data, weight)
 

@@ -47,7 +47,7 @@ def test_rehearsal_one_chip_phases(tmp_path):
                             "train_bert_base", "flash_two_widths",
                             "flash_grouped", "window_attention",
                             "sparse_attention", "kda_attention",
-                            "routed_layer", "gluon_trainer",
+                            "kda_conv", "routed_layer", "gluon_trainer",
                             "serve", "warm_start", "total"]
     assert max(phases["flash_two_widths"]["gaps"].values()) <= 2e-2
     assert max(phases["flash_grouped"]["gaps"].values()) <= 2e-2
@@ -74,6 +74,14 @@ def test_rehearsal_one_chip_phases(tmp_path):
     assert max(linear["gaps"].values()) <= 3e-2
     assert linear["kda_attention"] == {"calls": 1, "fallbacks": 1,
                                        "chunks": 6}
+    # that layer's short filter, the value and both gradients of four
+    # cases; off the chip the plain function, which says why
+    filters = phases["kda_conv"]
+    assert len(filters["gaps"]) == 12
+    assert max(v for k, v in filters["gaps"].items() if "float32" in k) <= 2e-6
+    assert max(filters["gaps"].values()) <= 4e-3
+    assert filters["kda_conv"] == {"calls": 8, "pallas": 0, "xla": 8}
+    assert filters["reasons"] == {"platform": 8}
     rows = phases["routed_layer"]["rows"]
     assert 0 < rows["live"] <= rows["run"] < rows["total"]
     assert max(phases["routed_layer"]["gaps"].values()) <= 3e-2

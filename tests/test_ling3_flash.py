@@ -9,6 +9,7 @@ ends, on the plain path and through both Pallas kernels under the
 interpreter; a sequence that is no multiple of the chunk; the group limit
 on a hand-built case; the head gate; recomputation on and off; the shares of the experts' holders adding up to the uncut layer; the
 counters; and the older cells' models unmoved."""
+import functools
 import importlib
 import json
 import math
@@ -33,6 +34,7 @@ from benchmark.reference import common as ref_common
 from benchmark.reference import ling3_flash as ref
 
 kda = importlib.import_module("mxtpu.ops.pallas.kda")
+short_filter = importlib.import_module("mxtpu.ops.pallas.short_filter")
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -341,6 +343,175 @@ def test_the_filter_is_causal_and_shares_lfm2s_taps():
     plain = jax.grad(lambda z, w: jnp.sum(jnp.sin(
         ops_nn._kda_conv_plain(16, z, w))), (0, 1))(z, w)
     assert all(_gap(a, b) <= 1e-6 for a, b in zip(grad, plain))
+
+
+# ------------------------------------------- the filter's Pallas pair
+def _filter_case(head_dim, taps, dtype, shape=(2, 80, 256), seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], shape).astype(dtype),
+            0.5 * jax.random.normal(ks[1], (shape[-1], taps)),
+            jax.random.normal(ks[2], shape).astype(dtype))
+
+
+def _filter_kernels(head_dim, tiles=(32, 128)):
+    """Both kernels under the interpreter at tiles of 32 rows, so that 80
+    positions are two whole tiles and a half."""
+    how = dict(head_dim=head_dim, tiles=tiles, interpret=True)
+    return (functools.partial(short_filter._forward, **how),
+            functools.partial(short_filter._backward, **how))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("taps", [3, 4])
+@pytest.mark.parametrize("head_dim", [0, 128])
+def test_the_filter_kernels_equal_the_plain_function(head_dim, taps, dtype):
+    """``kda_conv_fwd`` / ``kda_conv_bwd`` under the Pallas interpreter
+    against ``_kda_conv_plain`` and ``jax.vjp`` of it: the value, ``d
+    data`` and ``d weight``; two batch rows (the second row's start sees
+    zeros, not the first row's tail) over a sequence that is no whole
+    number of tiles."""
+    ops_nn = importlib.import_module("mxtpu.ops.nn")
+    x, w, g = _filter_case(head_dim, taps, dtype)
+    want, vjp = jax.vjp(functools.partial(ops_nn._kda_conv_plain, head_dim),
+                        x, w)
+    want_dx, want_dw = vjp(g)
+    fwd, bwd = _filter_kernels(head_dim)
+    got, (dx, dw) = fwd(x, w), bwd(x, w, g)
+    assert got.dtype == dx.dtype == x.dtype and dw.dtype == w.dtype
+    assert got.shape == dx.shape == x.shape and dw.shape == w.shape
+    # float32: the sums' order is the only difference; bf16: an entry in
+    # some thousands rounds the other way
+    limit = 1e-6 if dtype == "float32" else 2e-3
+    assert _gap(got, want) <= limit
+    assert _gap(dx, want_dx) <= limit
+    assert _gap(dw, want_dw) <= 1e-6
+    alone, (dx_alone, _) = fwd(x[1:], w), bwd(x[1:], w, g[1:])
+    assert np.array_equal(np.asarray(alone[0], np.float32),
+                          np.asarray(got[1], np.float32))
+    assert np.array_equal(np.asarray(dx_alone[0], np.float32),
+                          np.asarray(dx[1], np.float32))
+
+
+@pytest.mark.parametrize("at", [30, 63])
+@pytest.mark.parametrize("head_dim", [0, 128])
+def test_the_filter_kernels_reach_across_a_tile_boundary(head_dim, at):
+    """Tiles of 32 rows: a change of the data at position ``at`` moves the
+    outputs ``at .. at + 3`` only, over the boundary after it; a change of
+    the cotangent at ``at + 3`` moves ``d data`` at ``at .. at + 3``
+    only, back over it."""
+    x, w, g = _filter_case(head_dim, 4, "float32", shape=(1, 96, 256))
+    fwd, bwd = _filter_kernels(head_dim)
+
+    def moved(a, b):
+        return sorted(set(np.nonzero(np.asarray(a != b))[1].tolist()))
+
+    reach = list(range(at, at + 4))
+    assert moved(fwd(x.at[:, at].add(1.0), w), fwd(x, w)) == reach
+    assert moved(bwd(x, w, g.at[:, at + 3].add(1.0))[0],
+                 bwd(x, w, g)[0]) == reach
+
+
+FILTER_COUNTERS = ("kda_conv.calls", "kda_conv.pallas", "kda_conv.xla")
+
+
+@pytest.mark.parametrize("case,reason", [
+    ("interpreted", None), ("off the chip", "platform"),
+    ("whole numbers", "dtype"), ("a width of 192", "lanes"),
+    ("heads of 64", "lanes"), ("17 taps", "taps")])
+def test_the_filter_counts_its_path_and_reason(monkeypatch, case, reason):
+    """``kda_conv.calls`` / ``.pallas`` / ``.xla{reason}`` at trace time, a
+    pass a count (the value, then the two gradients), through the
+    operator; a refused call is the plain function's."""
+    ops_nn = importlib.import_module("mxtpu.ops.nn")
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    x, w, _ = _filter_case(0, 4, "float32", shape=(1, 32, 256))
+    head_dim = 0
+    if case in ("interpreted", "whole numbers", "17 taps"):
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    if case == "whole numbers":
+        x = (4 * x).astype(jnp.int32)
+    if case == "17 taps":
+        w = jnp.ones((256, 17)) / 17
+    if reason == "lanes":       # the chip's own rule; the plain path runs
+        monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
+        monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+        if case == "heads of 64":
+            head_dim = 64
+        else:
+            x, w = x[..., :192], w[:192]
+    for name in FILTER_COUNTERS:
+        telemetry.reset_metric(name)
+    op = functools.partial(get_op("_contrib_kda_conv").fn, head_dim=head_dim)
+    got = op(x, w)
+    assert _gap(got, ops_nn._kda_conv_plain(head_dim, x, w)) <= 1e-6
+    passes = 1
+    if case != "whole numbers":
+        jax.grad(lambda x, w: jnp.sum(jnp.sin(op(x, w))), (0, 1))(x, w)
+        passes = 3
+    counted = [telemetry.value(name) for name in FILTER_COUNTERS]
+    if reason is None:
+        assert counted == [passes, passes, 0]
+        assert telemetry.tagged("kda_conv.xla") == {}
+    else:
+        assert counted == [passes, 0, passes]
+        assert telemetry.tagged("kda_conv.xla") == {reason: passes}
+
+
+@pytest.mark.parametrize("case,want", [
+    ("no window", None), ("no filter traced", None),
+    ("both passes refused", 2), ("both passes on the kernels", 0)])
+def test_the_benchmark_reads_the_filters_fallbacks(monkeypatch, case, want):
+    """``kda_conv_fallbacks.train``: the passes counted under
+    ``kda_conv.xla`` once a window was measured, nothing from a program
+    that traced no filter (every other cell's, and the parent's)."""
+    from benchmark import run
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = json.load(f)["per_layer"][-1]
+    assert entry == {
+        "name": "kda_conv_fallbacks.train", "unit": "calls",
+        "better": "lower", "source": "program_counter",
+        "layer": "ops, kernels", "moves": "train_samples_per_s",
+        "workloads": ["ling3_flash.train_b1_s8192"]}
+    for name in FILTER_COUNTERS:
+        telemetry.reset_metric(name)
+    if case == "both passes on the kernels":
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    if want is not None:
+        x, w, _ = _filter_case(0, 4, "float32", shape=(1, 32, 128))
+        op = get_op("_contrib_kda_conv").fn
+        jax.grad(lambda x, w: jnp.sum(jnp.sin(op(x, w))), (0, 1))(x, w)
+    window = {"window": {"attempted": 0 if case == "no window" else 1}}
+    assert run.reader("kda_conv_fallbacks.train")(window) == want
+
+
+def test_the_filter_traces_a_body_once_a_shape(monkeypatch):
+    """Three calls of one shape and ``head_dim`` under one trace, and a
+    fourth without the norm: two forward bodies and two backward ones
+    traced, not four and four (each kernel is a jit of its own)."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    x, w, _ = _filter_case(128, 4, "bfloat16", shape=(1, 64, 256), seed=3)
+    op = get_op("_contrib_kda_conv").fn
+    bodies = {"fwd": 0, "bwd": 0}
+    for name in bodies:
+        kernel = getattr(short_filter, "_%s_kernel" % name)
+
+        def counting(*a, _kernel=kernel, _name=name, **kw):
+            bodies[_name] += 1
+            return _kernel(*a, **kw)
+
+        monkeypatch.setattr(short_filter, "_%s_kernel" % name, counting)
+    for name in FILTER_COUNTERS:
+        telemetry.reset_metric(name)
+
+    def loss(x, w):
+        q, k, k2 = (op(x * s, w, head_dim=128) for s in (1.0, 2.0, 3.0))
+        return jnp.sum(jnp.sin((q + k + k2 + op(x, w)).astype(jnp.float32)))
+
+    short_filter._forward.clear_cache()
+    short_filter._backward.clear_cache()
+    jax.jit(jax.grad(loss, (0, 1))).lower(x, w)
+    assert bodies == {"fwd": 2, "bwd": 2}
+    assert [telemetry.value(n) for n in FILTER_COUNTERS] == [8, 8, 0]
 
 
 def test_the_gate_is_the_bounded_form():

@@ -474,6 +474,95 @@ def test_kda_kernels_compile_to_mosaic(one_chip, as_tpu):
     assert alone.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("head_dim", [128, 0])
+def test_filter_kernels_compile_to_mosaic(one_chip, as_tpu, head_dim):
+    """The short filter's Pallas pair at the Ling cell's shape (8,192
+    positions of 4,096 channels, bf16, four taps; with a head's norm and
+    without): Mosaic calls under their own names, and no copy of the
+    projection beside them: the value's program holds no temporary, the
+    gradients' only the eight partial sums a tap of ``d weight``."""
+    from mxtpu import telemetry
+    from mxtpu.ops.registry import get_op
+    x, w = _spec((1, 8192, 4096), one_chip), _spec((4096, 4), one_chip)
+    op = get_op("_contrib_kda_conv").fn
+    for name in ("calls", "pallas", "xla"):
+        telemetry.reset_metric("kda_conv." + name)
+    value = jax.jit(lambda x, w: op(x, w, head_dim=head_dim)).lower(
+        x, w).compile()
+    assert "kda_conv_fwd" in value.as_text()
+    assert value.memory_analysis().temp_size_in_bytes < 1 << 20
+    grads = jax.jit(jax.grad(lambda x, w: op(x, w, head_dim=head_dim).astype(
+        jnp.float32).sum(), (0, 1))).lower(x, w).compile()
+    text = grads.as_text()
+    assert "kda_conv_bwd" in text and "f32[4,8,4096]" in text
+    # a pass of [8192, 4096] bf16 is 64 MiB: none is kept beside the
+    # cotangent (the sum's own broadcast, which a step never has)
+    assert grads.memory_analysis().temp_size_in_bytes < (64 << 20) + (2 << 20)
+    assert [telemetry.value("kda_conv." + n)
+            for n in ("calls", "pallas", "xla")] == [3, 3, 0]
+
+
+def test_kda_layer_compiles_to_the_filter_kernels(one_chip, as_tpu,
+                                                  monkeypatch):
+    """A Kimi-Delta-Attention layer at the Ling cell's widths (2,560 wide,
+    32 heads of 128, 8,192 positions, bf16) as one differentiated program:
+    three calls of ``kda_conv_fwd`` and three of ``kda_conv_bwd`` beside
+    the KDA pair; nothing else takes a name that starts ``kda_fwd`` or
+    ``kda_bwd`` (``benchmark/kernel_roofline.py`` sums by that prefix);
+    the filter's bodies are traced once a (shape, ``head_dim``), two of
+    each for the layer's six passes, and lowered to as many functions; and
+    the traces a layer reports still leave the event ring its head at the
+    cell's twelve (six layers, the forward again under recomputation)."""
+    import mxtpu as mx
+    from mxtpu import telemetry
+    from mxtpu.gluon.block import _run_traced
+    from mxtpu.gluon.model_zoo import hybrid_lm
+    short_filter = importlib.import_module("mxtpu.ops.pallas.short_filter")
+    layer = hybrid_lm.KimiDeltaAttention(2560, 32, 128, prefix="kda_")
+    params = list(layer.collect_params().values())
+    inner = {"kda_onorm_gamma": 128, "kda_proj_weight": 4096}
+    datas = [_spec(tuple(d or inner.get(p.name, 2560) for d in p.shape),
+                   one_chip) for p in params]
+
+    def loss(datas, x):
+        out, _ = _run_traced(params, datas, jax.random.PRNGKey(0), True,
+                             lambda: layer(mx.nd.NDArray(x)))
+        return out._data.astype(jnp.float32).sum()
+
+    bodies = {"fwd": 0, "bwd": 0}
+    for name in bodies:
+        kernel = getattr(short_filter, "_%s_kernel" % name)
+
+        def counting(*a, _kernel=kernel, _name=name, **kw):
+            bodies[_name] += 1
+            return _kernel(*a, **kw)
+
+        monkeypatch.setattr(short_filter, "_%s_kernel" % name, counting)
+    short_filter._forward.clear_cache()
+    short_filter._backward.clear_cache()
+    for name in ("kda_conv.calls", "kda_conv.pallas", "kda_conv.xla",
+                 "kda_attention.fallbacks"):
+        telemetry.reset_metric(name)
+    t0_us = time.perf_counter_ns() // 1000
+    lowered = jax.jit(jax.grad(loss)).lower(datas,
+                                            _spec((1, 8192, 2560), one_chip))
+    traces = sum(1 for n, _c, ts, _d, _t in telemetry.events()
+                 if n == "jax.trace" and ts >= t0_us)
+    assert 0 < 6 * 2 * traces < telemetry.EVENT_RING_CAP // 2, traces
+    assert bodies == {"fwd": 2, "bwd": 2}
+    assert [telemetry.value("kda_conv." + n)
+            for n in ("calls", "pallas", "xla")] == [6, 6, 0]
+    assert telemetry.value("kda_attention.fallbacks") == 0
+    # one lowered function a body, called from every site of its shape:
+    # the filter's four and the KDA pair
+    assert lowered.as_text().count("tpu_custom_call") == 4 + 2
+    text = lowered.compile().as_text()
+    kernels = re.findall(r"%(kda_\w+?)(?:\.\d+)? = .* custom-call\(", text)
+    assert sorted(kernels) == ["kda_bwd", "kda_conv_bwd", "kda_conv_bwd",
+                               "kda_conv_bwd", "kda_conv_fwd", "kda_conv_fwd",
+                               "kda_conv_fwd", "kda_fwd"], kernels
+
+
 def test_the_selection_compiles_by_blocks(one_chip, as_tpu):
     """``_contrib_index_select`` at the cell's shapes: the sets leave as
     one int8 [1, T, T]; no float32 array of the whole square is alive (a
