@@ -253,11 +253,11 @@ def _flash_small_gaps(fa, seed):
     (out, lse), vjp = jax.vjp(
         lambda q_, k_, v_: fa.flash_attention_with_lse(
             q_, k_, v_, True, None, 128, 128), q, k, v)
-    oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, True, 0.125,
-                                       128, g_lse=g_lse)
+    oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, fa.Mask(True),
+                                       0.125, 128, g_lse=g_lse)
     f32 = lambda x: np.asarray(x, np.float32)
     want = fa._xla_attention_lse(*(x.astype(jnp.float32) for x in (q, k, v)),
-                                 True, 0.125)
+                                 fa.Mask(True), 0.125)
     return {name: float(np.max(np.abs(f32(a) - f32(b))) / np.max(np.abs(f32(b))))
             for name, a, b in zip(("out", "lse", "dq", "dk", "dv"),
                                   (out, lse) + vjp((g, g_lse)),
@@ -358,7 +358,7 @@ def phase_flash_kernels(n, seed, on_tpu):
     f32 = lambda x: x[:, :checked if x.shape[1] == n["heads"]
                       else checked // group].astype(jnp.float32)
     want, ref_vjp = jax.vjp(
-        lambda *a: fa._xla_attention_lse(*a, True, n["qk"] ** -0.5),
+        lambda *a: fa._xla_attention_lse(*a, fa.Mask(True), n["qk"] ** -0.5),
         f32(q), f32(k), f32(v))
     want = want + ref_vjp((f32(g), f32(g_lse)))
     gaps = {name: float(jnp.max(jnp.abs(f32(a) - b)) / jnp.max(jnp.abs(b)))
@@ -454,8 +454,8 @@ def phase_window_attention(n, seed, on_tpu):
             lambda *a: _attention_by_rows(*a, window))(f32(q), f32(k), f32(v))
         want = (want_out,) + jax.jit(
             lambda *a: fa._fa_backward_blockwise(
-                *a, True, n["width"] ** -0.5,
-                min(1024, n["seq"]), window=window))(
+                *a, fa.Mask(True, window), n["width"] ** -0.5,
+                min(1024, n["seq"])))(
             f32(q), f32(k), f32(v), want_out, lse, f32(g))
         gaps = {what: float(jnp.max(jnp.abs(f32(a) - b)) / jnp.max(jnp.abs(b)))
                 for what, a, b in zip(("out", "dq", "dk", "dv"), got, want)}
@@ -528,7 +528,8 @@ def phase_sparse_attention(n, seed, on_tpu):
         *a, 0, mask_t=mask_t))(f32(q), f32(k), f32(v))
     want = (want_out,) + jax.jit(
         lambda *a: fa._fa_backward_blockwise(
-            *a, True, n["width"] ** -0.5, min(1024, t), mask_t=mask_t))(
+            *a, fa.Mask(True, selected=True), n["width"] ** -0.5,
+            min(1024, t), selection=mask_t))(
         f32(q), f32(k), f32(v), want_out, lse, f32(g))
     gaps = {what: float(jnp.max(jnp.abs(f32(a) - b)) / jnp.max(jnp.abs(b)))
             for what, a, b in zip(("out", "dq", "dk", "dv"), got, want)}

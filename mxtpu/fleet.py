@@ -651,7 +651,14 @@ def init(fleet_dir=None, coordinator_address=None, num_processes=None,
     if mem is not None:
         mem.rank, mem.num_hosts = rank, world  # autodetected identity wins
         mem.write("up")
-    if mem is not None:
+        if heartbeat:
+            # BEFORE the bring-up barrier: a host that waits there for a
+            # late peer is alive, and its "up" must not age meanwhile. A
+            # peer that arrived more than heartbeat_s() * heartbeat_miss()
+            # later found that write stale and, at its first barrier past
+            # bring-up, took the waiting host for dead (a start skew of 7 s
+            # between two children of one supervisor: PR 44)
+            mem.start_heartbeat()
         # board barrier: portable (XLA:CPU cannot run the psum-rendezvous
         # across processes at all), deadline-bounded, and the timeout
         # diagnosis IS the board. fail_on_dead off — during bring-up a
@@ -661,14 +668,13 @@ def init(fleet_dir=None, coordinator_address=None, num_processes=None,
             mem.barrier("bringup", timeout_s, clock=clock, sleeper=sleeper,
                         fail_on_dead=False)
         except FleetWedgeError:
+            mem.stop_heartbeat()
             raise on_timeout() from None
     else:
         _run_with_deadline(
             lambda: distributed.barrier("mxtpu_fleet_bringup"),
             timeout_s, on_timeout, clock=clock, sleeper=sleeper,
             thread_name="mxtpu-fleet-barrier")
-    if mem is not None and heartbeat:
-        mem.start_heartbeat()
     _log.info("fleet up: rank %d of %d hosts", rank, world)
     return Fleet(rank, world, membership=mem, fleet_dir=fleet_dir)
 

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mxtpu.ops.pallas.flash_attention import (_fa_backward_blockwise,
+from mxtpu.ops.pallas.flash_attention import (Mask, _fa_backward_blockwise,
                                               _xla_attention, flash_attention)
 
 
@@ -45,8 +45,8 @@ def test_blockwise_backward_math(causal):
         s = jnp.where(mask[None, None], s, -1e30)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
 
-    dq, dk, dv = _fa_backward_blockwise(q, k, v, out, lse, g, causal, scale,
-                                        block_k=16)
+    dq, dk, dv = _fa_backward_blockwise(q, k, v, out, lse, g, Mask(causal),
+                                        scale, block_k=16)
     np.testing.assert_allclose(np.asarray(dq), np.asarray(dq_ref),
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(dk), np.asarray(dk_ref),
@@ -83,7 +83,7 @@ def test_flash_attention_with_lse_matches_dense():
     v = jnp.asarray(rng.randn(2, 2, 16, 8).astype(np.float32))
     for causal in (False, True):
         out, lse = flash_attention_with_lse(q, k, v, causal, None, 8, 8)
-        ref_out, ref_lse = _xla_attention_lse(q, k, v, causal,
+        ref_out, ref_lse = _xla_attention_lse(q, k, v, Mask(causal),
                                               1.0 / (8 ** 0.5))
         np.testing.assert_allclose(out, ref_out, rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(lse, ref_lse, rtol=1e-5, atol=1e-5)
@@ -95,7 +95,8 @@ def test_flash_attention_with_lse_matches_dense():
             return flash_attention_with_lse(q_, k_, v_, causal, None, 8, 8)
 
         def ref(q_, k_, v_):
-            return _xla_attention_lse(q_, k_, v_, causal, 1.0 / (8 ** 0.5))
+            return _xla_attention_lse(q_, k_, v_, Mask(causal),
+                                      1.0 / (8 ** 0.5))
 
         _, vjp_fa = jax.vjp(fa, q, k, v)
         _, vjp_ref = jax.vjp(ref, q, k, v)
@@ -119,13 +120,14 @@ def test_blockwise_backward_g_lse_term():
     v = jnp.asarray(rng.randn(1, 2, 16, 8).astype(np.float32))
     scale = 1.0 / (8 ** 0.5)
     for causal in (False, True):
-        out, lse = _xla_attention_lse(q, k, v, causal, scale)
+        mask = Mask(causal)
+        out, lse = _xla_attention_lse(q, k, v, mask, scale)
         g = jnp.asarray(rng.randn(*out.shape).astype(np.float32))
         g_lse = jnp.asarray(rng.randn(*lse.shape).astype(np.float32))
-        dq, dk, dv = _fa_backward_blockwise(q, k, v, out, lse, g, causal,
+        dq, dk, dv = _fa_backward_blockwise(q, k, v, out, lse, g, mask,
                                             scale, block_k=8, g_lse=g_lse)
         _, vjp = jax.vjp(lambda q_, k_, v_:
-                         _xla_attention_lse(q_, k_, v_, causal, scale),
+                         _xla_attention_lse(q_, k_, v_, mask, scale),
                          q, k, v)
         for a, b in zip((dq, dk, dv), vjp((g, g_lse))):
             np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
@@ -198,10 +200,10 @@ def test_backward_block_divides_ragged_tk():
     k = jnp.asarray(rng.randn(*shape), jnp.float32)
     v = jnp.asarray(rng.randn(*shape), jnp.float32)
     scale = 8 ** -0.5
-    out, lse = fa._xla_attention_lse(q, k, v, False, scale)
+    out, lse = fa._xla_attention_lse(q, k, v, Mask(), scale)
     g = jnp.ones_like(out)
     # explicit ragged block request: 16 does not divide 24; resolver picks 12
-    dq, dk, dv = fa._fa_backward_blockwise(q, k, v, out, lse, g, False,
+    dq, dk, dv = fa._fa_backward_blockwise(q, k, v, out, lse, g, Mask(),
                                            scale, fa._pick_block(24, 16, 1))
     ref = jax.vjp(lambda a, b, c: fa._xla_attention(a, b, c, False, scale),
                   q, k, v)[1](g)
@@ -231,8 +233,8 @@ def test_head_dim_64_pads_instead_of_falling_back(monkeypatch):
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(1, 2, 512, 64), jnp.float32)
-    blocks = fa._resolve_blocks(q, q, q, 512, 512)
-    assert blocks is not None  # no fallback for D=64
+    blocks, refused = fa._plan(q, q, q, Mask(), 512, 512, "forward")
+    assert blocks is not None and refused is None  # no fallback for D=64
     # padding invariance of the attention math the kernel relies on:
     # zero-padded q/k leave scores unchanged, zero-padded v adds zero
     # output columns
@@ -246,8 +248,8 @@ def test_head_dim_64_pads_instead_of_falling_back(monkeypatch):
     np.testing.assert_allclose(np.asarray(base), np.asarray(padded),
                                rtol=1e-5, atol=1e-5)
     # lse is invariant too (ring attention merges on it)
-    _, lse_base = fa._xla_attention_lse(q, k, v, False, scale)
-    _, lse_pad = fa._xla_attention_lse(qp, kp, vp, False, scale)
+    _, lse_base = fa._xla_attention_lse(q, k, v, Mask(), scale)
+    _, lse_pad = fa._xla_attention_lse(qp, kp, vp, Mask(), scale)
     np.testing.assert_allclose(np.asarray(lse_base), np.asarray(lse_pad),
                                rtol=1e-5, atol=1e-5)
 
@@ -274,16 +276,15 @@ def test_the_cells_attention_runs_the_measured_blocks(monkeypatch, shape_q,
     q = jax.ShapeDtypeStruct(shape_q, jnp.bfloat16)
     v = jax.ShapeDtypeStruct(shape_q[:3] + (width_v,), jnp.bfloat16)
     asked = (fa._BLOCK_Q, fa._BLOCK_K)
-    assert fa._resolve_blocks(q, q, v, *asked) == blocks
-    assert fa._resolve_bwd_blocks(q, q, v, *asked) == (blocks, None)
+    mask = Mask(causal=shape_q[2] > 512)
+    for direction in ("forward", "backward"):
+        assert fa._plan(q, q, v, mask, *asked, direction) == (blocks, None)
     qp, vp = jax.eval_shape(fa._pad_head_dim, q, v)
     assert (qp.shape[-1], vp.shape[-1]) == padded
+    fa._count_forward(q, q, None, mask, 0, blocks, None)
     stats = dict(fa.DISPATCH_STATS.items())
     assert stats["pallas"] == 1 and stats["xla"] == 0
     assert not stats["fallback_reasons"]
-    causal = shape_q[2] > 512
-    t = shape_q[2]
-    fa._count_block_pairs(t // blocks[0], t // blocks[1], *blocks, causal)
     assert fa.DISPATCH_STATS["block_pairs"] == dict(
         zip(("skipped", "visible", "crossed"), pairs))
 
@@ -366,7 +367,8 @@ def test_pallas_forward_matches_xla(monkeypatch, causal, heads, t, tk, d, dv,
 
     def oracle(q_, k_, v_):
         return fa._xla_attention_lse(f32(q_), f32(k_[:, :, :seen]),
-                                     f32(v_[:, :, :seen]), causal, scale)
+                                     f32(v_[:, :, :seen]), Mask(causal),
+                                     scale)
 
     fa.reset_dispatch_stats()
     (out, lse), vjp = jax.vjp(kernel, q, k, v)
@@ -433,16 +435,17 @@ def test_pallas_backward_matches_oracles(monkeypatch, causal, t, tk, d,
     g_lse = (jnp.asarray(rng.randn(1, 2, t), jnp.float32)
              if with_g_lse else None)
     scale = d ** -0.5
-    out, lse = fa._xla_attention_lse(q, k, v, causal, scale)
-    blocks, refused = fa._resolve_bwd_blocks(q, k, v, want, want)
+    mask = Mask(causal)
+    out, lse = fa._xla_attention_lse(q, k, v, mask, scale)
+    blocks, refused = fa._plan(q, k, v, mask, want, want, "backward")
     assert refused is None
     assert blocks == ((t, tk) if want == 512 else (128, 128))
-    got = fa._fa_backward_pallas(q, k, v, out, lse, g, causal, scale,
+    got = fa._fa_backward_pallas(q, k, v, out, lse, g, mask, scale,
                                  *blocks, g_lse=g_lse)
-    oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, causal, scale,
+    oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, mask, scale,
                                        blocks[1], g_lse=g_lse)
     _, vjp = jax.vjp(lambda q_, k_, v_: fa._xla_attention_lse(
-        q_, k_, v_, causal, scale), q, k, v)
+        q_, k_, v_, mask, scale), q, k, v)
     plain = vjp((g, jnp.zeros_like(lse) if g_lse is None else g_lse))
     tol = 1e-5 if dtype == "float32" else 2e-2
     for name, a, b, c in zip(("dq", "dk", "dv"), got, oracle, plain):
@@ -469,7 +472,7 @@ def test_grad_reaches_the_backward_kernel(monkeypatch):
         return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
 
     def ref_lse(q, k, v):
-        out, lse = fa._xla_attention_lse(q, k, v, True, 64 ** -0.5)
+        out, lse = fa._xla_attention_lse(q, k, v, Mask(True), 64 ** -0.5)
         return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
 
     got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
@@ -510,14 +513,15 @@ def test_backward_refusal_is_counted_and_takes_the_oracle(monkeypatch):
         assert _gap(a, b) <= 1e-5
 
 
-def test_backward_blocks_follow_the_shapes():
+def test_backward_blocks_follow_the_shapes(monkeypatch):
     import importlib
     fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
     spec = lambda t, d=64, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
         (1, 1, t, d), dt)
     # the BERT cell: one block a head
     def resolve(q, k, block_q, block_k):
-        return fa._resolve_bwd_blocks(q, k, k, block_q, block_k)
+        return fa._plan(q, k, k, Mask(), block_q, block_k, "backward")
     assert resolve(spec(512), spec(512), 512, 512) == ((512, 512), None)
     # 768 = 2 x 384; a q length off the 128 lanes is one whole block
     assert resolve(spec(768), spec(768), 512, 512)[0] == (

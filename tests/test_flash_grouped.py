@@ -102,15 +102,16 @@ def test_blockwise_oracle_and_xla_path_take_groups(hq, hk, causal):
     q, k, v, g, g_lse = _operands(hq, hk, 128, 256, 32, 48, "float32")
     fa.reset_dispatch_stats()
     scale = 32 ** -0.5
+    mask = fa.Mask(causal)
     (out, lse), vjp = jax.vjp(
-        lambda *a: fa._xla_attention_lse(*a, causal, scale), q, k, v)
+        lambda *a: fa._xla_attention_lse(*a, mask, scale), q, k, v)
     got = (out, lse) + vjp((g, g_lse))
     assert fa.DISPATCH_STATS["kv_repeated"] == (1 if hq != hk else 0)
     want, ref_vjp = jax.vjp(lambda *a: _plain(*a, causal), q, k, v)
     want = want + ref_vjp((g, g_lse))
     for a, b in zip(got, want):
         assert a.shape == b.shape and _gap(a, b) <= 2e-5
-    dq, dk, dv = fa._fa_backward_blockwise(q, k, v, out, lse, g, causal,
+    dq, dk, dv = fa._fa_backward_blockwise(q, k, v, out, lse, g, mask,
                                            scale, 128, g_lse=g_lse)
     for a, b in zip((dq, dk, dv), want[2:]):
         assert a.shape == b.shape and _gap(a, b) <= 2e-5
@@ -173,7 +174,7 @@ def test_compiled_grouped_step_holds_no_kv_at_the_query_heads(monkeypatch):
     assert re.search(r"bf16\[(2,2|4),384,128\]", text)
 
 
-def test_backward_vmem_reckons_whole_heads_of_dk_dv():
+def test_backward_vmem_reckons_whole_heads_of_dk_dv(monkeypatch):
     """Grouped heads keep dk and dv of a whole key/value head in VMEM while
     its query heads pass: the reckoning grows by their rows, and the one
     block rule still answers for the cell's shape."""
@@ -182,6 +183,7 @@ def test_backward_vmem_reckons_whole_heads_of_dk_dv():
     assert whole - small == (8192 - 1024) * 256 * (4 + 2 * 2)
     q = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((2, 8, 8192, 128), jnp.bfloat16)
-    blocks, refused = fa._resolve_bwd_blocks(q, kv, kv, fa._BLOCK_Q,
-                                             fa._BLOCK_K)
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    blocks, refused = fa._plan(q, kv, kv, fa.Mask(True), fa._BLOCK_Q,
+                               fa._BLOCK_K, "backward")
     assert refused is None and blocks == (1024, 1024)

@@ -47,7 +47,8 @@ def test_forward_two_widths(monkeypatch, d, dv, t, dtype, causal):
     fa.reset_dispatch_stats()
     out, lse = fa.flash_attention_with_lse(q, k, v, causal, None, 128, 128)
     assert fa.DISPATCH_STATS["pallas"] == 1 and fa.DISPATCH_STATS["xla"] == 0
-    want, want_lse = fa._xla_attention_lse(q, k, v, causal, d ** -0.5)
+    want, want_lse = fa._xla_attention_lse(q, k, v, fa.Mask(causal),
+                                           d ** -0.5)
     assert out.shape == (1, 2, t, dv) and out.dtype == q.dtype
     tol = 1e-5 if dtype == "float32" else 2e-2
     assert _gap(out, want) <= tol
@@ -59,11 +60,12 @@ def test_backward_two_widths(monkeypatch, d, dv, t, dtype, causal):
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     q, k, v, g = _operands(d, dv, t, dtype)
     scale = d ** -0.5
-    out, lse = fa._xla_attention_lse(q, k, v, causal, scale)
-    blocks, refused = fa._resolve_bwd_blocks(q, k, v, 128, 128)
+    mask = fa.Mask(causal)
+    out, lse = fa._xla_attention_lse(q, k, v, mask, scale)
+    blocks, refused = fa._plan(q, k, v, mask, 128, 128, "backward")
     assert refused is None and blocks == (128, 128)
-    got = fa._fa_backward_pallas(q, k, v, out, lse, g, causal, scale, *blocks)
-    oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, causal, scale,
+    got = fa._fa_backward_pallas(q, k, v, out, lse, g, mask, scale, *blocks)
+    oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, mask, scale,
                                        128)
     _, vjp = jax.vjp(lambda q_, k_, v_: fa._xla_attention(
         q_, k_, v_, causal, scale), q, k, v)
@@ -97,7 +99,7 @@ def test_causal_backward_names_only_blocks_that_exist(n_q, n_k, bq, bk):
     that computes names its own block."""
     for j in range(n_k):
         for i in range(n_q):
-            got = int(fa._first_q_block(j, i, bq, bk, n_q))
+            got = int(fa.Mask(True).q_block(j, i, bq, bk, n_q))
             assert 0 <= got < n_q
             if j * bk <= i * bq + bq - 1:          # the kernel's ``run``
                 assert got == i
@@ -110,7 +112,7 @@ def test_causal_backward_names_only_blocks_that_exist(n_q, n_k, bq, bk):
     (2, 3, 128, 128), (3, 2, 128, 128), (1, 4, 200, 128),      # Tq != Tk
     (3, 5, 384, 128), (5, 3, 128, 384), (2, 8, 256, 128)])
 def test_block_predicate_sorts_every_pair(n_q, n_k, bq, bk):
-    """``_block_case`` against the mask itself, pair by pair: a pair called
+    """``Mask.block_case`` against the mask itself, pair by pair: a pair called
     visible has no masked position, a skipped pair no visible one, a
     crossed pair both. The forward's index map names ``j`` wherever the
     pair is not skipped, and only blocks inside the arrays (the chip halts
@@ -118,11 +120,12 @@ def test_block_predicate_sorts_every_pair(n_q, n_k, bq, bk):
     nothing); the ``pallas_flash.block_pairs`` counts are the brute-force
     ones."""
     want = {"skipped": 0, "visible": 0, "crossed": 0}
+    mask = fa.Mask(True)
     for i in range(n_q):
         for j in range(n_k):
             sees = (np.arange(i * bq, (i + 1) * bq)[:, None]
                     >= np.arange(j * bk, (j + 1) * bk)[None, :])
-            visible, crossed = fa._block_case(i, j, bq, bk)
+            visible, crossed = mask.block_case(i, j, bq, bk)
             assert isinstance(visible, bool) and isinstance(crossed, bool)
             assert not (visible and crossed)
             assert visible == bool(sees.all()), (i, j)
@@ -130,20 +133,20 @@ def test_block_predicate_sorts_every_pair(n_q, n_k, bq, bk):
             kind = ("visible" if visible else
                     "crossed" if crossed else "skipped")
             want[kind] += 1
-            named = int(fa._last_k_block(i, j, bq, bk))
+            named = int(mask.k_block(i, j, bq, bk))
             assert 0 <= named < n_k
             if kind != "skipped":
                 assert named == j
             else:          # the last block the q block needed, not j
-                assert named < j and any(fa._block_case(i, named, bq, bk))
+                assert named < j and mask.live(i, named, bq, bk)
     # the same answers on arrays, which is how a kernel's ids arrive
-    visible, crossed = fa._block_case(
+    visible, crossed = mask.block_case(
         jnp.arange(n_q)[:, None], jnp.arange(n_k)[None, :], bq, bk)
     assert int(visible.sum()) == want["visible"]
     assert int(crossed.sum()) == want["crossed"]
     for causal in (True, False):
         fa.reset_dispatch_stats()
-        fa._count_block_pairs(n_q, n_k, bq, bk, causal)
+        fa.Mask(causal).count_block_pairs(n_q, n_k, bq, bk)
         assert fa.DISPATCH_STATS["block_pairs"] == (want if causal else {
             "skipped": 0, "visible": n_q * n_k, "crossed": 0})
 
@@ -153,11 +156,11 @@ def test_block_pairs_of_the_two_cells():
     kanana cell at 512 x 512 has 120 / 120 / 16 pairs, a head of BERT's
     one visible pair."""
     fa.reset_dispatch_stats()
-    fa._count_block_pairs(16, 16, 512, 512, True)
+    fa.Mask(True).count_block_pairs(16, 16, 512, 512)
     assert fa.DISPATCH_STATS["block_pairs"] == {
         "skipped": 120, "visible": 120, "crossed": 16}
     fa.reset_dispatch_stats()
-    fa._count_block_pairs(1, 1, 512, 512, False)
+    fa.Mask().count_block_pairs(1, 1, 512, 512)
     assert fa.DISPATCH_STATS["block_pairs"] == {
         "skipped": 0, "visible": 1, "crossed": 0}
 

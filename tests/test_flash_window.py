@@ -94,7 +94,8 @@ def test_windowed_kernels_with_unequal_blocks(monkeypatch, hq, hk, bq, bk,
         lambda *a: fa.flash_attention(*a, True, None, bq, bk, window), q, k, v)
     got = (out,) + vjp(g)
     assert fa.DISPATCH_STATS["bwd_pallas"] == 1
-    assert fa._resolve_bwd_blocks(q, k, v, bq, bk) == ((bq, bk), None)
+    assert fa._plan(q, k, v, fa.Mask(True, window), bq, bk, "backward") == (
+        (bq, bk), None)
     for name, a, b in zip(("out", "dq", "dk", "dv"), got,
                           _want(q, k, v, g, window)):
         assert a.shape == b.shape and _gap(a, b) <= 2e-5, name
@@ -121,11 +122,11 @@ def test_xla_path_and_blockwise_oracle_take_the_window(hq, hk, window):
     t, scale = 320, 32 ** -0.5
     q, k, v, g = _operands(hq, hk, t, 32)
     want = _want(q, k, v, g, window)
+    mask = fa.Mask(True, window)
     (out, lse), vjp = jax.vjp(
-        lambda *a: fa._xla_attention_lse(*a, True, scale, window), q, k, v)
+        lambda *a: fa._xla_attention_lse(*a, mask, scale), q, k, v)
     got = (out,) + vjp((g, jnp.zeros_like(lse)))
-    oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, True, scale, 64,
-                                       window=window)
+    oracle = fa._fa_backward_blockwise(q, k, v, out, lse, g, mask, scale, 64)
     for name, a, b, c in zip(("out", "dq", "dk", "dv"), got, want,
                              (out,) + oracle):
         assert _gap(a, b) <= 2e-5 and _gap(c, b) <= 2e-5, name
@@ -218,7 +219,7 @@ def test_block_pair_counters_equal_the_closed_form(n, block, per_window, live,
 
     def counts(w):
         fa.reset_dispatch_stats()
-        fa._count_block_pairs(n, n, block, block, True, w)
+        fa.Mask(True, w).count_block_pairs(n, n, block, block)
         return fa.DISPATCH_STATS["block_pairs"]
 
     got = counts(window)
@@ -231,7 +232,7 @@ def test_block_pair_counters_equal_the_closed_form(n, block, per_window, live,
     assert got["visible"] + got["crossed"] == causal_live
     assert got["crossed"] == n
     # both grids hold the steps a block can need, no more
-    assert fa._window_steps(n, n, block, block, window) == (
+    assert fa.Mask(True, window).steps(n, n, block, block) == (
         min(per_window + 1, n), min(per_window + 1, n))
 
 
@@ -247,16 +248,17 @@ def test_block_case_against_the_mask_itself(bq, bk, window):
     i, j = np.arange(t)[:, None], np.arange(t)[None]
     seen = (j <= i) & (j > i - window)
     n_q, n_k = t // bq, t // bk
-    k_steps, q_steps = fa._window_steps(n_q, n_k, bq, bk, window)
+    mask = fa.Mask(True, window)
+    k_steps, q_steps = mask.steps(n_q, n_k, bq, bk)
     for qi in range(n_q):
         for ki in range(n_k):
             tile = seen[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
-            visible, crossed = fa._block_case(qi, ki, bq, bk, window)
+            visible, crossed = mask.block_case(qi, ki, bq, bk)
             assert bool(visible) == bool(tile.all())
             assert bool(visible or crossed) == bool(tile.any())
             if not (visible or crossed):
                 continue
-            k_lo = int(fa._first_k_block(qi, bq, bk, window))
+            k_lo = int(mask.first_k_block(qi, bq, bk))
             assert 0 <= ki - k_lo < k_steps
             assert 0 <= qi - ki * bk // bq < q_steps
-            assert qi <= int(fa._last_q_block(ki, bq, bk, n_q, window))
+            assert qi <= int(mask.last_q_block(ki, n_q, bq, bk))

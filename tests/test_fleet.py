@@ -315,6 +315,42 @@ def test_board_only_bringup_two_hosts_in_process(tmp_path, monkeypatch):
     f0.leave()
 
 
+def test_a_host_waiting_at_bringup_keeps_its_heartbeat(tmp_path, monkeypatch):
+    """A host that waits at the bring-up barrier for a late peer is alive
+    and says so: its heartbeat runs from the moment it is ``up``, not from
+    the barrier's end. With the heartbeat started after the barrier, a peer
+    that arrived later than the staleness bound (six xdist workers beside
+    two children: a start skew of seconds) found the waiting host's one
+    write stale at its first barrier past bring-up and took it for dead;
+    the acceptance test below then lost the wrong host and resumed from
+    scratch (PR 44)."""
+    monkeypatch.setenv("MXTPU_FLEET_HEARTBEAT_S", "0.05")
+    monkeypatch.setenv("MXTPU_FLEET_HEARTBEAT_MISS", "3")
+    board = str(tmp_path / "b")
+    out = {}
+
+    def bring(rankid):
+        out[rankid] = fleet.init(fleet_dir=board, num_processes=2,
+                                 process_id=rankid, timeout_s=60.0)
+    early = threading.Thread(target=bring, args=(0,))
+    early.start()
+    late = FleetMembership(board, 1, 2)
+    deadline = time.time() + 30.0
+    while 0 not in late.view() and time.time() < deadline:
+        time.sleep(0.01)
+    first = late.view()[0]["t"]
+    time.sleep(0.6)             # four times the staleness bound of 0.15 s
+    assert late.view()[0]["t"] > first      # it went on heartbeating
+    assert 0 not in late.dead_hosts()
+    bring(1)
+    early.join(timeout=30.0)
+    assert sorted(out) == [0, 1]
+    # past bring-up the late host does not take the early one for dead
+    assert out[1].check(step=0) == []
+    for f in out.values():
+        f.leave()
+
+
 def test_rejoin_stall_fault_exits_dedicated_code(tmp_path, monkeypatch):
     """Fault kind rejoin_stall@rank: the host publishes "stalled" on the
     board (its peers' deadline names it) and dies EXIT_REJOIN_STALL."""

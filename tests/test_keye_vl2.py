@@ -429,10 +429,10 @@ def test_the_plain_backward_reads_the_same_set(monkeypatch):
     log-sum-exp and the forward's set."""
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     q, k, v, g, mask_t = _heads(8)
-    out, res = fa._sparse_fwd_impl(q, k, v, mask_t, None, 128, 128, 32)
+    mask, scale = fa.Mask(True, selected=True), q.shape[-1] ** -0.5
+    out, _, res = fa._forward(q, k, v, mask_t, mask, scale, 128, 128, 32)
     got = fa._fa_backward_blockwise(q, k, v, out, res[4].reshape(q.shape[:3]),
-                                    g, True, q.shape[-1] ** -0.5, 64,
-                                    mask_t=mask_t)
+                                    g, mask, scale, 64, selection=mask_t)
     want = jax.grad(lambda *a: jnp.sum(_plain(*a, mask_t) * g),
                     (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):

@@ -228,8 +228,8 @@ def test_flash_window_compiles_to_mosaic(one_chip, as_tpu, window):
     assert kernels == ({"flash_window_fwd", "flash_window_bwd"} if window
                        else {"flash_attention_fwd", "flash_attention_bwd"})
     assert "bf16[1,4,16384,128]" in text        # dk, dv at the 4 heads
-    assert fa._resolve_bwd_blocks(q, kv, kv, fa._BLOCK_Q, fa._BLOCK_K) == (
-        (512, 1024), None)
+    assert fa._plan(q, kv, kv, fa.Mask(True, window), fa._BLOCK_Q,
+                    fa._BLOCK_K, "backward") == ((512, 1024), None)
     stats = dict(fa.DISPATCH_STATS.items())
     assert (stats["pallas"], stats["bwd_pallas"]) == (1, 1)
     assert stats["xla"] == 0 and stats["bwd_xla"] == 0

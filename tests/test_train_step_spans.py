@@ -224,10 +224,11 @@ def test_kernels_carry_their_name(kernel, monkeypatch):
     fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
     q = jnp.ones((1, 2, 128, 128), jnp.float32)
     if kernel == "flash_attention_fwd":
-        fn = lambda q: fa._fa_forward_pallas(q, q, q, False, 1.0, 128, 128)
+        fn = lambda q: fa._fa_forward_pallas(q, q, q, fa.Mask(), 1.0, 128,
+                                             128)
     else:
         fn = lambda q: fa._fa_backward_pallas(
-            q, q, q, q, q[..., 0], q, False, 1.0, 128, 128)
+            q, q, q, q, q[..., 0], q, fa.Mask(), 1.0, 128, 128)
     assert re.search(r"name=%s\b" % kernel, str(jax.make_jaxpr(fn)(q)))
     assert kernel in jax.jit(fn).lower(q).as_text(debug_info=True)
 
@@ -242,10 +243,10 @@ def test_flash_backward_is_found_by_its_scope(path, monkeypatch):
     lse = jnp.ones((1, 2, 128))
     if path == "blockwise":
         fn = lambda q: fa._fa_backward_blockwise(
-            q, q, q, q, lse, q, False, 1.0, 128)
+            q, q, q, q, lse, q, fa.Mask(), 1.0, 128)
     else:
         fn = lambda q: fa._fa_backward_pallas(
-            q, q, q, q, lse, q, False, 1.0, 128, 128)
+            q, q, q, q, lse, q, fa.Mask(), 1.0, 128, 128)
     text = jax.jit(fn).lower(q).as_text(debug_info=True)
     assert "flash_attention_bwd" in text
     # the prologue (delta's reduction) is inside the scope too
