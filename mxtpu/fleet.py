@@ -157,7 +157,10 @@ class FleetWedgeError(MXNetError):
 
 # ------------------------------------------------------------ status board
 def _atomic_write(path, payload):
-    tmp = "%s.%d.tmp" % (path, os.getpid())
+    # a name of the writer's own: a host's heartbeat thread and its main
+    # thread (``check``) write the same file, and with one name a process
+    # the second ``os.replace`` found its file already moved
+    tmp = "%s.%d.%d.tmp" % (path, os.getpid(), threading.get_ident())
     with open(tmp, "w") as f:
         f.write(payload)
         f.flush()

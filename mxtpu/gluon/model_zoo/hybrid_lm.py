@@ -44,7 +44,7 @@ from ..block import HybridBlock, _IN_TRACE
 from .. import nn
 
 __all__ = ["HybridLM", "DecoderBlock", "GroupedQueryAttention",
-           "KimiDeltaAttention", "SparseIndexer", "OPERATORS"]
+           "KimiDeltaAttention", "SparseIndexer", "OPERATORS", "gate_heads"]
 
 
 class SparseIndexer(HybridBlock):
@@ -77,6 +77,22 @@ class SparseIndexer(HybridBlock):
                                        **self._attrs)
 
 
+def gate_heads(F, out, gate, x, head_dim):
+    """The head-wise output gate of gated attention (arXiv:2505.06708):
+    ``o_h <- o_h * sigmoid(x Wg)_h`` on ``out`` [B, T, H * head_dim], with
+    ``gate`` the block that gives ``x Wg`` [B, T, H] from the layer's
+    normed input ``x``, before the output projection. The one spelling for
+    every block that has it (:class:`GroupedQueryAttention`,
+    :class:`~mxtpu.gluon.model_zoo.latent_moe.MultiHeadLatentAttention`);
+    scope ``head_gate``, counted in ``attention.head_gated``."""
+    from ... import telemetry
+    telemetry.inc("attention.head_gated")
+    with jax.named_scope("head_gate"):
+        heads = F.reshape(out, shape=(0, 0, -1, head_dim))
+        return F.reshape(heads * F.expand_dims(F.sigmoid(gate(x)), -1),
+                         shape=(0, 0, -1))
+
+
 class GroupedQueryAttention(HybridBlock):
     """Causal grouped-query attention (Ainslie et al., arXiv:2305.13245):
     ``num_heads`` query heads of ``head_dim`` (``dim // num_heads`` unless
@@ -88,9 +104,14 @@ class GroupedQueryAttention(HybridBlock):
     ``window = W > 0`` iff ``i - W < j <= i`` (``W`` keys, the query's own
     among them). ``qk_norm``: queries and keys go through an RMSNorm over a
     head's entries (one learned scale each; without it the block has no
-    such leaves). ``rope``: rotary over the whole head turns them; without
-    it the layer carries no position encoding at all. The defaults are
-    LFM2's attention layer.
+    such leaves). ``rope``: rotary turns them, over the whole head or, with
+    ``rotary_dim = R > 0``, over a head's first ``R`` entries alone, by
+    ``rope_theta``'s plain table or, with ``rope_scaling`` (the keys of a
+    ``rope_type: yarn`` group), YaRN's; without ``rope`` the layer carries
+    no position encoding at all. ``head_gate``: each head's output is
+    scaled by ``sigmoid(x Wgate)_h`` before the output projection
+    (:func:`gate_heads`; ``Wgate`` is ``dim x num_heads``). The defaults
+    are LFM2's attention layer.
 
     ``topk = K > 0`` (with ``index_heads`` and ``index_head_dim``): sparse
     attention. A :class:`SparseIndexer` reads the block's input and keeps
@@ -100,17 +121,19 @@ class GroupedQueryAttention(HybridBlock):
     def __init__(self, dim, num_heads, num_kv_heads, rope_theta=10000.0,
                  epsilon=1e-6, head_dim=None, qk_norm=True, rope=True,
                  window=0, topk=0, index_heads=16, index_head_dim=64,
-                 **kwargs):
+                 rotary_dim=0, rope_scaling=None, head_gate=False, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError("%d query heads do not divide over %d key/value"
                              " heads" % (num_heads, num_kv_heads))
         self._head_dim = head_dim = head_dim or dim // num_heads
         self._attrs = {"rope_theta": rope_theta, "window": window,
-                       "rope": rope}
-        if topk and (window or not rope):
+                       "rope": rope, "rotary_dim": rotary_dim,
+                       "rope_scaling": rope_scaling}
+        if topk and (window or not rope or rotary_dim or rope_scaling):
             raise ValueError("sparse attention (topk=%d) is written with "
-                             "rotary and without a window" % topk)
+                             "plain rotary over the whole head and without "
+                             "a window" % topk)
         self._topk = topk
         self._qk_norm = qk_norm
         with self.name_scope():
@@ -123,6 +146,8 @@ class GroupedQueryAttention(HybridBlock):
             if qk_norm:
                 self.q_norm = nn.RMSNorm(epsilon=epsilon, prefix="qnorm_")
                 self.k_norm = nn.RMSNorm(epsilon=epsilon, prefix="knorm_")
+            self.gate = nn.Dense(num_heads, use_bias=False, flatten=False,
+                                 prefix="gate_") if head_gate else None
             self.proj = nn.Dense(dim, use_bias=False, flatten=False,
                                  prefix="proj_")
             if topk:
@@ -138,11 +163,14 @@ class GroupedQueryAttention(HybridBlock):
         if self._qk_norm:
             k = self.k_norm(k)
         if self._topk:
-            return self.proj(F._contrib_sparse_attention(
+            out = F._contrib_sparse_attention(
                 q, k, self.v(x), self.indexer(x),
-                rope_theta=self._attrs["rope_theta"], topk=self._topk))
-        return self.proj(F._contrib_grouped_attention(
-            q, k, self.v(x), **self._attrs))
+                rope_theta=self._attrs["rope_theta"], topk=self._topk)
+        else:
+            out = F._contrib_grouped_attention(q, k, self.v(x), **self._attrs)
+        if self.gate is not None:
+            out = gate_heads(F, out, self.gate, x, self._head_dim)
+        return self.proj(out)
 
 
 class KimiDeltaAttention(HybridBlock):

@@ -24,7 +24,7 @@ from __future__ import annotations
 
 from ..block import HybridBlock
 from .. import nn
-from .hybrid_lm import HybridLM
+from .hybrid_lm import HybridLM, gate_heads
 
 __all__ = ["LatentMoELM", "MultiHeadLatentAttention"]
 
@@ -35,7 +35,8 @@ class MultiHeadLatentAttention(HybridBlock):
     key; ``norm(c) Wkvb`` gives each head's position-free key and value.
     ``head_gate``: each head's output is scaled by ``sigmoid(x Wgate)_h``
     before the output projection (gated attention, arXiv:2505.06708, at its
-    head-wise granularity: ``Wgate`` is ``dim x num_heads``)."""
+    head-wise granularity: ``Wgate`` is ``dim x num_heads``;
+    :func:`~mxtpu.gluon.model_zoo.hybrid_lm.gate_heads`)."""
 
     def __init__(self, dim, num_heads, kv_rank, nope_dim, rope_dim, v_dim,
                  rope_theta=10000.0, rope_interleave=False, epsilon=1e-6,
@@ -68,9 +69,7 @@ class MultiHeadLatentAttention(HybridBlock):
         out = F._contrib_latent_attention(
             self.q(x), self.kv_b(self.kv_norm(c)), k_rope, **self._attrs)
         if self.gate is not None:
-            heads = F.reshape(out, shape=(0, 0, -1, self._v_dim))
-            out = F.reshape(heads * F.expand_dims(F.sigmoid(self.gate(x)), -1),
-                            shape=(0, 0, -1))
+            out = gate_heads(F, out, self.gate, x, self._v_dim)
         return self.proj(out)
 
 

@@ -14,6 +14,7 @@ reference parity — XLA inserts the transposes when needed.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -524,35 +525,93 @@ def RMSNorm(data, gamma, axis=-1, eps=1e-6):
     return (x32 * inv).astype(data.dtype) * jnp.reshape(gamma, shape)
 
 
-def rotary(x, theta=10000.0, interleave=False, seq_axis=1):
+def yarn_inv_freq(theta, width, factor, original_max_position_embeddings,
+                  beta_fast=32.0, beta_slow=1.0):
+    """YaRN's frequency table (Peng et al., arXiv:2309.00071, as
+    transformers computes it) for ``width`` turned entries: of pair ``i``,
+    ``e_i = theta^(-2i/width)`` where it turns more than ``beta_fast``
+    times over the original context (kept), ``e_i / factor`` where it
+    turns fewer than ``beta_slow`` times (interpolated), a linear ramp
+    between the two pairs ``low = floor(c(beta_fast))`` and ``high =
+    ceil(c(beta_slow))``, ``c(b) = width ln(L / (2 pi b)) / (2 ln theta)``.
+    float64 on the host -> float32 [width / 2]."""
+    import numpy as np
+    half = width // 2
+    e = float(theta) ** (-np.arange(half, dtype=np.float64) * 2.0 / width)
+
+    def pair_turning(times):
+        return width * np.log(original_max_position_embeddings
+                              / (times * 2 * np.pi)) / (2 * np.log(theta))
+
+    low = max(np.floor(pair_turning(beta_fast)), 0)
+    high = min(np.ceil(pair_turning(beta_slow)), width - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0, 1)
+    return (e / factor * ramp + e * (1 - ramp)).astype(np.float32)
+
+
+def rotary(x, theta=10000.0, interleave=False, seq_axis=1, width=0,
+           scaling=None):
     """Rotary position embedding (Su et al., arXiv:2104.09864) over the
-    whole last axis of ``x``, positions 0..T-1 along ``seq_axis``. Pair i
+    last axis of ``x``, positions 0..T-1 along ``seq_axis``. Pair i
     turns by ``pos * theta^(-2i/D)``; ``interleave`` pairs the entries
     (2i, 2i+1), otherwise (i, i + D/2). The partner of each entry comes
     from a product with a constant signed permutation (exact in any
     dtype: one non-zero a column), so no array with a minor axis of 2 is
     ever laid out on the TPU's 128 lanes. Angles and the result's
-    arithmetic are float32; the result has ``x``'s dtype."""
+    arithmetic are float32; the result has ``x``'s dtype.
+
+    ``width = R > 0``: only the first ``R`` entries turn (``D`` is then
+    ``R`` in the above: a partial rotary, ``partial_rotary_factor = R /
+    D``); the rest pass as they are (cos 1, sin 0, no partner: bit for
+    bit). ``scaling``: the keys of a ``rope_type: yarn`` group (``factor``,
+    ``original_max_position_embeddings``, and optionally ``beta_fast``,
+    ``beta_slow``, ``attention_factor``): the table is
+    :func:`yarn_inv_freq`'s and cos and sin of the turned entries are
+    multiplied by ``attention_factor`` (``0.1 ln(factor) + 1`` unless
+    given). Scopes ``rotary`` / ``rotary_yarn``."""
     d = x.shape[-1]
-    half = d // 2
+    turned = width or d
+    half = turned // 2
     pos = jnp.arange(x.shape[seq_axis], dtype=jnp.float32)
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
-    ang = pos[:, None] * freq[None, :]                       # [T, D/2]
-    i = jnp.arange(half)
-    if interleave:
-        ang = jnp.repeat(ang, 2, axis=-1)
-        lo, hi = 2 * i, 2 * i + 1
+    if scaling is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / turned)
+        gain = None
     else:
-        ang = jnp.concatenate([ang, ang], axis=-1)
-        lo, hi = i, i + half
-    # partner[lo] = -x[hi], partner[hi] = x[lo]
-    swap = jnp.zeros((d, d), x.dtype).at[hi, lo].set(-1).at[lo, hi].set(1)
-    partner = jnp.matmul(x, swap, precision=mxu_precision(x, swap))
-    shape = [1] * x.ndim
-    shape[seq_axis % x.ndim], shape[-1] = x.shape[seq_axis], d
-    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
-    return (x.astype(jnp.float32) * cos
-            + partner.astype(jnp.float32) * sin).astype(x.dtype)
+        from .. import telemetry
+        telemetry.inc("rotary.scaled")
+        freq = jnp.asarray(yarn_inv_freq(
+            theta, turned, scaling["factor"],
+            scaling["original_max_position_embeddings"],
+            scaling.get("beta_fast", 32.0), scaling.get("beta_slow", 1.0)))
+        gain = scaling.get("attention_factor") or (
+            0.1 * math.log(scaling["factor"]) + 1.0)
+    with jax.named_scope("rotary" if scaling is None else "rotary_yarn"):
+        ang = pos[:, None] * freq[None, :]                   # [T, R/2]
+        i = jnp.arange(half)
+        if interleave:
+            ang = jnp.repeat(ang, 2, axis=-1)
+            lo, hi = 2 * i, 2 * i + 1
+        else:
+            ang = jnp.concatenate([ang, ang], axis=-1)
+            lo, hi = i, i + half
+        # partner[lo] = -x[hi], partner[hi] = x[lo]
+        swap = jnp.zeros((d, d), x.dtype).at[hi, lo].set(-1).at[lo, hi].set(1)
+        partner = jnp.matmul(x, swap, precision=mxu_precision(x, swap))
+        shape = [1] * x.ndim
+        shape[seq_axis % x.ndim], shape[-1] = x.shape[seq_axis], d
+
+        def table(fn, still):
+            t = fn(ang) if gain is None else fn(ang) * gain
+            if turned < d:  # the entries past the turned width stand still
+                t = jnp.pad(t, [(0, 0), (0, d - turned)],
+                            constant_values=still)
+            return t.reshape(shape)
+
+        cos, sin = table(jnp.cos, 1.0), table(jnp.sin, 0.0)
+        return (x.astype(jnp.float32) * cos
+                + partner.astype(jnp.float32) * sin).astype(x.dtype)
 
 
 register("_contrib_rotary_embedding", aliases=("rotary_embedding",))(rotary)
@@ -592,7 +651,8 @@ def latent_attention(q, kv, k_rope, num_heads=1, nope_dim=128, rope_dim=64,
 
 
 @register("_contrib_grouped_attention", aliases=("grouped_attention",))
-def grouped_attention(q, k, v, rope_theta=10000.0, window=0, rope=True):
+def grouped_attention(q, k, v, rope_theta=10000.0, window=0, rope=True,
+                      rotary_dim=0, rope_scaling=None):
     """Causal grouped-query attention (Ainslie et al., arXiv:2305.13245)
     after its projections and per-head norms: ``q`` [B, T, H, D], ``k`` [B,
     T, H_kv, D] and ``v`` [B, T, H_kv * D] (or [B, T, H_kv, D]), ``H`` a
@@ -600,7 +660,9 @@ def grouped_attention(q, k, v, rope_theta=10000.0, window=0, rope=True):
     H_kv)``. The mask: key ``j`` is visible to query ``i`` iff ``j <= i``,
     and with ``window = W > 0`` iff ``i - W < j <= i`` (a sliding window of
     ``W`` keys, the query's own among them: transformers' convention).
-    Rotary over the whole head (halves rotated) turns q and k; with
+    Rotary (halves rotated) turns q and k: over the whole head, or over the
+    first ``rotary_dim`` entries alone, and by ``rope_scaling``'s table
+    where one is given (:func:`rotary`'s ``width`` and ``scaling``); with
     ``rope=False`` nothing turns and nothing is added: the layer carries no
     position encoding (the global layers of a window / global stack, whose
     only order is the mask's). Both flash kernels take K and V at their
@@ -609,20 +671,23 @@ def grouped_attention(q, k, v, rope_theta=10000.0, window=0, rope=True):
     D]. The call sits under the scope ``window_attention`` with a window,
     ``gqa_attention`` without."""
     from .pallas import flash_attention
+    turn = {"theta": rope_theta, "width": rotary_dim,
+            "scaling": rope_scaling} if rope else None
     with jax.named_scope("window_attention" if window else "gqa_attention"):
         return _grouped_heads(
-            q, k, v, rope_theta if rope else None,
+            q, k, v, turn,
             lambda *qkv: flash_attention(*qkv, True, window=window))
 
 
-def _grouped_heads(q, k, v, rope_theta, attend):
-    """What the grouped operators share around their kernels: rotary over
-    the whole head (none with ``rope_theta=None``), heads first, ``attend``
-    on q [B, H, T, D] and k, v [B, H_kv, T, D], and back to [B, T, H * D]."""
+def _grouped_heads(q, k, v, turn, attend):
+    """What the grouped operators share around their kernels: rotary
+    (``turn``: :func:`rotary`'s keyword arguments, or None for none),
+    heads first, ``attend`` on q [B, H, T, D] and k, v [B, H_kv, T, D],
+    and back to [B, T, H * D]."""
     b, t, h, _ = q.shape
-    if rope_theta is not None:
-        q = rotary(q, rope_theta)
-        k = rotary(k, rope_theta)
+    if turn is not None:
+        q = rotary(q, **turn)
+        k = rotary(k, **turn)
     v = v.reshape(b, t, k.shape[2], -1)
     out = attend(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                  v.transpose(0, 2, 1, 3))                       # [B, H, T, D]
@@ -768,7 +833,7 @@ def sparse_grouped_attention(q, k, v, selected, rope_theta=10000.0, topk=0):
 
     with telemetry.span("sparse_attention.trace"), \
             jax.named_scope("sparse_attention"):
-        return _grouped_heads(q, k, v, rope_theta, attend)
+        return _grouped_heads(q, k, v, {"theta": rope_theta}, attend)
 
 
 def _causal_taps(z, w):

@@ -77,8 +77,9 @@ Design (TPU-first):
   takes the width of the operand it holds.
 * blocks: one rule for both kernels (:func:`_tile_blocks`): q on the 128
   lanes (a length off that granule is one whole block), k in 128s, 1024 x
-  1024 asked for where no caller names a pair, halved while a grid step
-  does not fit the VMEM budget.
+  1024 asked for where no caller names a pair (under a window narrower
+  than that, the window's width: :meth:`Mask.blocks`), halved while a grid
+  step does not fit the VMEM budget.
 * fallback: non-TPU platforms or non-divisible shapes use the XLA softmax
   path with the same signature (its backward is XLA's own). Why each
   fallback happened is counted in the reason-tagged
@@ -137,7 +138,8 @@ class _DispatchStatsView(collections.abc.Mapping):
 
     _KEYS = ("pallas", "xla", "fallback_reasons", "grouped", "kv_repeated",
              "bwd_pallas", "bwd_xla", "bwd_fallback_reasons", "block_pairs",
-             "windowed", "window_unskipped")
+             "windowed", "window_unskipped", "window_pairs_seen",
+             "window_pairs_visited")
     _TAGGED = {"fallback_reasons": "pallas_flash.fallback",
                "bwd_fallback_reasons": "pallas_flash.bwd_fallback",
                "block_pairs": "pallas_flash.block_pairs"}
@@ -276,6 +278,14 @@ class Mask(NamedTuple):
                          np.arange(n_q)[:, None], np.arange(n_k)[None, :],
                          block_q, block_k))
 
+    def pairs_seen(self, t):
+        """(query, key) pairs the mask lets through over ``t`` positions
+        (geometry only: a selection's count is its caller's)."""
+        if not self.causal:
+            return t * t
+        w = self.window or t
+        return w * t - w * (w - 1) // 2
+
     def count_block_pairs(self, n_q, n_k, block_q, block_k):
         """``pallas_flash.block_pairs{skipped,visible,crossed}``: one
         call's pairs a head, as :meth:`block_case` sorts them."""
@@ -312,6 +322,18 @@ class Mask(NamedTuple):
         """Bytes a grid step holds for the mask: a selection's int8 tile,
         double-buffered."""
         return 2 * block_q * block_k if self.selected else 0
+
+    def blocks(self, block_q, block_k):
+        """The pair of blocks a call asks :func:`_plan` for: what its caller
+        named, else 1,024 x 1,024 (``_BLOCK_Q``, ``_BLOCK_K``), under a
+        window narrower than that the window's width (in 128s): a q block of
+        1,024 under a window of 512 visits two k blocks of 1,024 for 512
+        keys a query, 3.9 times the window's pairs; at 512 x 512 it visits
+        2.0 times, and on the chip both kernels read a quarter faster
+        (PERF.md §6, PR 45). A window of 1,024 or more changes nothing."""
+        widest = _lanes(self.window) if self.window else _BLOCK_Q
+        return (block_q or min(_BLOCK_Q, widest),
+                block_k or min(_BLOCK_K, widest))
 
     def name(self, direction):
         """The kernels' name in a device trace (``direction``: ``fwd`` |
@@ -1000,7 +1022,13 @@ def _count_forward(q, k, selection, mask, topk, blocks, refused):
     if _group(q, k) > 1:
         telemetry.inc("pallas_flash.grouped")
     if mask.window:
+        # what the window sees against what the path taken visits, a head:
+        # the kernel's live blocks whole, or the plain path's square
         telemetry.inc("pallas_flash.windowed")
+        telemetry.inc("pallas_flash.window_pairs_seen", mask.pairs_seen(t))
+        telemetry.inc("pallas_flash.window_pairs_visited",
+                      t * tk if blocks is None
+                      else sum(mask.block_pairs(*pairs)) * bq * bk)
     if blocks is not None:
         telemetry.inc("pallas_flash.pallas")
         mask.count_block_pairs(*pairs)
@@ -1187,15 +1215,18 @@ def _scale(q, scale):
     return 1.0 / (q.shape[-1] ** 0.5) if scale is None else scale
 
 
-def flash_attention(q, k, v, causal=False, scale=None, block_q=_BLOCK_Q,
-                    block_k=_BLOCK_K, window=0):
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None, window=0):
     """Fused attention [B, H, T, D] -> [B, H, T, D]; falls back to XLA softmax
     off-TPU or for non-divisible shapes. ``causal``: key ``j`` is visible
     to query ``i`` iff ``j <= i``; with ``window = W > 0`` (causal only)
     iff ``i - W < j <= i``, the query's own key among the ``W``
-    (transformers' sliding-window mask)."""
-    return _attention(q, k, v, None, Mask.of(q, k, causal, window),
-                      _scale(q, scale), block_q, block_k, 0)
+    (transformers' sliding-window mask). ``block_q``, ``block_k``: the
+    blocks to ask for, else :meth:`Mask.blocks`' (1,024 x 1,024, under a
+    narrower window its width)."""
+    mask = Mask.of(q, k, causal, window)
+    return _attention(q, k, v, None, mask, _scale(q, scale),
+                      *mask.blocks(block_q, block_k), 0)
 
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,

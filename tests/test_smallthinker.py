@@ -284,9 +284,11 @@ def test_attention_blocks_of_the_two_kinds():
         "w_attn_v_weight", "w_attn_proj_weight", "w_norm2_gamma",
         "w_moe_router_weight", "w_moe_score_bias", "w_moe_w_gate",
         "w_moe_w_up", "w_moe_w_down"]
-    assert blk.op._attrs == {"rope_theta": 1.5e6, "window": 72, "rope": True}
+    whole = {"rotary_dim": 0, "rope_scaling": None}     # the plain table
+    assert blk.op._attrs == dict(whole, rope_theta=1.5e6, window=72,
+                                 rope=True)
     lfm2 = hybrid_lm.GroupedQueryAttention(16, 4, 2, prefix="a_")
-    assert lfm2._attrs == {"rope_theta": 10000.0, "window": 0, "rope": True}
+    assert lfm2._attrs == dict(whole, rope_theta=10000.0, window=0, rope=True)
     assert [n for n in lfm2.collect_params().keys()] == [
         "a_q_weight", "a_k_weight", "a_v_weight", "a_qnorm_gamma",
         "a_knorm_gamma", "a_proj_weight"]

@@ -40,6 +40,10 @@ RECORDED = {
         "d47ba0a9122a75e39b2809266ac7ea1b2201440e875d621f0d2530dff1db963a",
     "ling3_flash.train_b1_s8192":
         "5f473d697083f5bceb059bc1e0c770b33c750bd47f862d8066d22aaca91850d6",
+    # recorded at PR 45, which brought the cell; the eight above are the
+    # parent's still (one rotary, one head gate: no older cell's text moved)
+    "laguna_s_2_1.train_b1_s16384":
+        "ab41a901684cf6714ff770b792fdba8106359164082ddb498179e6f5ecffc00b",
 }
 
 

@@ -274,7 +274,8 @@ def test_dispatch_stats_is_view_over_registry():
     assert set(fa.DISPATCH_STATS.keys()) == \
         {"pallas", "xla", "fallback_reasons", "grouped", "kv_repeated",
          "bwd_pallas", "bwd_xla", "bwd_fallback_reasons", "block_pairs",
-         "windowed", "window_unskipped"}
+         "windowed", "window_unskipped", "window_pairs_seen",
+         "window_pairs_visited"}
     # equal heads: not a grouped call, and nothing was repeated
     assert fa.DISPATCH_STATS["grouped"] == 0
     assert fa.DISPATCH_STATS["kv_repeated"] == 0

@@ -241,6 +241,52 @@ def test_flash_window_compiles_to_mosaic(one_chip, as_tpu, window):
     pairs = stats["block_pairs"]
     assert pairs["visible"] + pairs["crossed"] == (70 if window else 136)
     assert pairs["skipped"] == 256 - (70 if window else 136)
+    # what ``flash_window_visit_ratio.train`` reads in that cell: 1.25
+    assert stats["window_pairs_seen"] == (58722304 if window else 0)
+    assert stats["window_pairs_visited"] == (70 * 1024 * 1024 if window
+                                             else 0)
+
+
+# the laguna_s_2_1 cell's windowed layers: 72 query heads over 8 key/value
+# heads of 128 (nine a group), one sequence of 16,384, a window of 512:
+# narrower than the 1,024 x 1,024 blocks a call asks for by default, so
+# the call asks for 512 x 512 (``Mask.blocks``) and visits twice the
+# window's pairs where 1,024-wide blocks visit 3.9 times
+def test_flash_narrow_window_compiles_to_mosaic(one_chip, as_tpu):
+    q = _spec((1, 72, 16384, 128), one_chip)
+    kv = _spec((1, 8, 16384, 128), one_chip)
+    fa.reset_dispatch_stats()
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, True, window=512)
+                       .astype(jnp.float32) ** 2)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    kernels = set(re.findall(
+        r"%\w*?(flash_(?:window|attention)_(?:fwd|bwd))[_.\d]* = \(", text))
+    assert kernels == {"flash_window_fwd", "flash_window_bwd"}
+    assert "bf16[1,8,16384,128]" in text        # dk, dv at the 8 heads
+    mask = fa.Mask(True, 512)
+    assert mask.blocks(None, None) == (512, 512)
+    assert mask.blocks(1024, None) == (1024, 512)      # a caller's is kept
+    assert fa.Mask(True, 4096).blocks(None, None) == (1024, 1024)
+    assert fa.Mask(True).blocks(None, None) == (1024, 1024)
+    for direction in ("forward", "backward"):
+        assert fa._plan(q, kv, kv, mask, 512, 512, direction) == (
+            (512, 512), None)
+    stats = dict(fa.DISPATCH_STATS.items())
+    assert (stats["pallas"], stats["bwd_pallas"]) == (1, 1)
+    assert stats["xla"] == 0 and stats["bwd_xla"] == 0
+    assert stats["grouped"] == 1 and stats["kv_repeated"] == 0
+    assert stats["windowed"] == 1 and stats["window_unskipped"] == 0
+    # q block i visits k blocks i - 1 and i: 63 of 1,024 block pairs
+    pairs = stats["block_pairs"]
+    assert pairs["visible"] + pairs["crossed"] == 63
+    assert stats["window_pairs_seen"] == 512 * 16384 - 512 * 511 // 2
+    assert stats["window_pairs_visited"] == 63 * 512 * 512
+    assert 1.99 < stats["window_pairs_visited"] / stats[
+        "window_pairs_seen"] < 2.01
+
 
 
 def test_routed_experts_compile_to_grouped_kernels(one_chip, as_tpu):

@@ -24,6 +24,7 @@ import sys
 
 SCOPES = ("kda_conv", "kda_gate", "kda_attention", "mla_attention",
           "gqa_attention", "window_attention", "sparse_attention",
+          "rotary", "rotary_yarn", "head_gate",
           "index_select", "short_conv", "flash_attention_bwd",
           "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
           "moe.shared", "softmax_ce", "optimizer")
