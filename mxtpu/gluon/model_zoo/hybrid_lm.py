@@ -25,7 +25,10 @@ anywhere: ``h = x + op(norm1(x)); y = h + ffn(norm2(h))``.
   operator, while its experts read ``norm2(h)``;
 * a final norm and a vocabulary head, tied to the embedding by default;
 * with ``recompute`` each block is recomputed in the backward from its
-  input (``jax.checkpoint``): nothing of a block's inside is kept.
+  input (``jax.checkpoint``), and of a block's inside only what its Pallas
+  kernels' forwards name is kept (:func:`kept_policy`: attention's output
+  and log-sum-exp rows where no window cuts the call, KDA's output and
+  chunk states), so the second forward runs everything but those kernels.
 
 ``HybridLM(layers=["conv", "full_attention", "conv", ...])`` is LFM2's
 stack (``model_type: lfm2_moe``); ``layers=["full_attention",
@@ -45,6 +48,19 @@ from .. import nn
 
 __all__ = ["HybridLM", "DecoderBlock", "GroupedQueryAttention",
            "KimiDeltaAttention", "SparseIndexer", "OPERATORS", "gate_heads"]
+
+
+def kept_policy():
+    """What a recomputed block keeps beside its input, as a policy of
+    ``jax.checkpoint``: the values its kernels' forward rules name, O(T)
+    bytes that cost a kernel's whole run to make again. A windowed
+    attention call names nothing (the same bytes for a window's work, and
+    with them Laguna's step does not load beside what its set-up holds:
+    PERF.md §6, PR 46). The kernel files own the names (and are imported
+    here, not with the model zoo)."""
+    from ...ops.pallas.flash_attention import KEPT_NAMES as flash
+    from ...ops.pallas.kda import KEPT_NAMES as kda
+    return jax.checkpoint_policies.save_only_these_names(*flash, *kda)
 
 
 class SparseIndexer(HybridBlock):
@@ -302,12 +318,14 @@ class HybridLM(HybridBlock):
     activation``), whose routers read their layer's input with
     ``router_ahead``. ``tie_head``: the head reads the embedding's weight,
     whose gradient is the sum of both uses. ``recompute``: in a traced
-    forward each block runs under ``jax.checkpoint`` with nothing kept but
-    its input, so a differentiated step holds one block's activations at a
-    time and runs every block's forward twice (the kernels' and the expert
-    layer's own ``custom_vjp`` rules among it); a property of the model,
-    set where the model is built, and counted at trace time in
-    ``train_step.blocks_recomputed``.
+    forward each block runs under ``jax.checkpoint``, which keeps the
+    block's input and what its attention (unwindowed) and KDA kernels'
+    forward rules name (:func:`kept_policy`), so a differentiated step
+    holds one block's activations at a time and runs every block's forward
+    twice (the expert layer's own ``custom_vjp`` rule among it) but for
+    those kernels, whose second run is dead code once their outputs are
+    held; a property of the model, set where the model is built, and
+    counted at trace time in ``train_step.blocks_recomputed``.
     """
 
     def __init__(self, vocab_size, dim, layers, operators, dense_hidden, moe,
@@ -336,9 +354,10 @@ class HybridLM(HybridBlock):
         if not (self._recompute and _IN_TRACE.active):
             return self.head(self.norm_f(self.blocks(self.embed(tokens))))
         from ... import telemetry
-        x = self.embed(tokens)
+        x, kept = self.embed(tokens), kept_policy()
         for block in self.blocks._children.values():
             telemetry.inc("train_step.blocks_recomputed")
             x = NDArray(jax.checkpoint(
-                lambda data, block=block: block(NDArray(data))._data)(x._data))
+                lambda data, block=block: block(NDArray(data))._data,
+                policy=kept)(x._data))
         return self.head(self.norm_f(x))

@@ -55,6 +55,14 @@ Design (TPU-first):
 * one core under the three public functions: one forward
   (:func:`_forward`), one backward (:func:`_backward`), one block rule
   (:func:`_plan`), registered twice (with and without the lse output).
+* what a recomputed caller keeps: the forward names its output and its
+  log-sum-exp rows (:data:`KEPT_NAMES`, ``checkpoint_name``; a windowed
+  call's go unnamed) inside the ``custom_vjp``'s forward rule, on the
+  kernel path and the plain one, so that a block under
+  ``jax.checkpoint(..., policy=save_only_these_names(*KEPT_NAMES))`` holds
+  them and its backward's second forward runs no such kernel
+  (``HybridLM(recompute=True)``; PERF.md §6, PR 46). Anywhere else a name
+  is the identity and lowers to nothing.
 * backward: custom_vjp, flash-attention-2 equations from the saved
   log-sum-exp. Where the forward ran the kernel, ONE fused Pallas kernel
   (``flash_attention_bwd``), grid (batch*heads, Tk/bk, Tq/bq) with the
@@ -100,6 +108,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 _NEG_INF = -1e30
@@ -1129,20 +1138,46 @@ _BLOCK_Q, _BLOCK_K = 1024, 1024
 
 
 # ------------------------------------------------------------ the one core
+# What a forward gives that its backward reads, by the names a caller's
+# ``jax.checkpoint(..., policy=save_only_these_names(*KEPT_NAMES))`` keeps:
+# O(T) bytes that cost O(T^2) work to make again. Named INSIDE the forward
+# rule, before the value parts into the output and the residual: a name put
+# on the ``custom_vjp``'s result would mark the output's copy alone, and
+# the residual, which is what the backward waits for, would still be made
+# again by a second run of the kernel. Under no checkpoint, or one whose
+# policy lists no name, a name is the identity and lowers to nothing.
+KEPT_NAMES = ("flash_out", "flash_lse")
+
+
+def _kept(out, lse, mask):
+    """A call under a window names nothing: its output is the same bytes
+    for O(T W) work (Laguna's three layers at a window of 512: 0.92 GB for
+    18.6 ms, 20 ms a GB where a full layer's are 134), and the one caller
+    that recomputes has no room for them (PERF.md §6, PR 46)."""
+    if mask.window:
+        return out, lse
+    return tuple(checkpoint_name(x, name)
+                 for x, name in zip((out, lse), KEPT_NAMES))
+
+
 def _forward(q, k, v, selection, mask, scale, block_q, block_k, topk):
     """The one forward under the three public functions: ``(out, lse
     [B, H, T], residual)``, on the kernel or, where :func:`_plan` refuses,
     on the plain path, whose residual holds no lse (its backward is XLA's
-    own)."""
+    own). ``out`` (at ``v``'s width) and the lse rows carry their
+    :data:`KEPT_NAMES` (under no window: :func:`_kept`) from here on, into
+    the output and the residual alike."""
     blocks, refused = _plan(q, k, v, mask, block_q, block_k, "forward")
     _count_forward(q, k, selection, mask, topk, blocks, refused)
     if blocks is None:
-        out, lse = _xla_attention_lse(q, k, v, mask, scale, selection)
+        out, lse = _kept(*_xla_attention_lse(q, k, v, mask, scale, selection),
+                        mask)
         return out, lse, (q, k, v, out, None, selection)
     out, lse = _fa_forward_pallas(*_pad_head_dim(q, k, v), mask, scale,
                                   *blocks, selection)
     if out.shape[-1] != v.shape[-1]:
         out = out[..., :v.shape[-1]]
+    out, lse = _kept(out, lse, mask)
     # the residual is the kernel's own (bh, 1, t) rows, which the backward
     # kernel reads as they are; the public lse is [B, H, T]
     return out, lse.reshape(q.shape[:3]), (q, k, v, out, lse, selection)

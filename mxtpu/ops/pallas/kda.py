@@ -40,7 +40,12 @@ positions, 32 heads of 128 and chunks of 64). ``kda_bwd`` walks them
 backwards, carries the state's cotangent in VMEM, and differentiates one
 chunk at a time from its inputs and its saved start state: the chunk's
 forward is computed again inside it and nothing else of the forward is
-kept. The chunk's arithmetic is ONE pair of plain functions
+kept. The forward rule names ``o`` and the chunk states
+(:data:`KEPT_NAMES`), so a caller recomputed under ``jax.checkpoint`` with
+``save_only_these_names(*KEPT_NAMES)`` holds them (335 MB a layer at those
+sizes) and its backward's second forward does not run ``kda_fwd`` again
+(``HybridLM(recompute=True)``; PERF.md §6, PR 46); anywhere else a name
+lowers to nothing. The chunk's arithmetic is ONE pair of plain functions
 (:func:`_prep`, :func:`_apply`) which the forward kernel calls, the
 backward kernel differentiates (``jax.vjp`` inside the kernel body) and
 the plain path scans: the three cannot drift apart. The state, the decay
@@ -60,13 +65,14 @@ import importlib
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 # the module (the package exports the function under the same name): its
 # platform check and interpreter flag are this kernel's too
 _fa = importlib.import_module(__package__ + ".flash_attention")
 
-__all__ = ["kda_attention"]
+__all__ = ["kda_attention", "KEPT_NAMES"]
 
 _SUB = 16            # tokens a sub-chunk
 _CHUNK = 64          # tokens a chunk
@@ -514,8 +520,20 @@ def _forward(q, k, v, g, beta, chunk, save):
     return _forward_pallas(q, k, v, g, beta, chunk, scale, save)
 
 
+# What the forward rule gives that is dear to make again, by the names a
+# caller's ``jax.checkpoint(..., policy=save_only_these_names(*KEPT_NAMES))``
+# keeps (as ``flash_attention.KEPT_NAMES``): ``states`` is the backward
+# kernel's; ``o`` is not in the residual, but what follows the call in a
+# recomputed block (the head's norm, the gate, the output projection) reads
+# it, so unless it is kept too the kernel runs again just to give it.
+KEPT_NAMES = ("kda_o", "kda_states")
+
+
 def _fwd_rule(q, k, v, g, beta, chunk):
     o, states = _forward(q, k, v, g, beta, chunk, True)
+    o = checkpoint_name(o, KEPT_NAMES[0])
+    if states is not None:      # the plain path has none
+        states = checkpoint_name(states, KEPT_NAMES[1])
     return o, (q, k, v, g, beta, states)
 
 

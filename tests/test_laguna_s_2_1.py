@@ -34,6 +34,8 @@ from benchmark.models import laguna_s_2_1 as model
 from benchmark.reference import common as ref_common
 from benchmark.reference import laguna_s_2_1 as ref
 
+from _jaxpr_count import calls, differentiated
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
 
@@ -297,6 +299,26 @@ def test_three_adam_steps_match_the_reference():
         assert "/%s/" % scope in text, scope
     telemetry.reset_metric("pallas_flash.window_pairs_seen")
     assert read({"window": {"attempted": 1}}) is None
+
+
+def test_a_recomputed_block_runs_its_kernels_forward_once(monkeypatch):
+    """The model as the cell builds it, differentiated and not run (the
+    interpreter, at 128 positions: the flash pair wants its keys in 128s):
+    five blocks recomputed, and the two full layers' forward kernel stands
+    in the jaxpr once for each backward: their second forward holds none
+    (``hybrid_lm.kept_policy``; under a bare checkpoint each stood twice,
+    as the three windowed layers' still does: their outputs are let go,
+    0.92 GB the cell's set-up has no room for)."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    cfg = dict(CFG, seq_len=128, items_per_sample=128)
+    net = model.build(cfg, SPECS, ref_common.init_params(SPECS, 5))
+    model._FIRST.clear()
+    telemetry.reset_metric("train_step.blocks_recomputed")
+    x, y = ref.sample_inputs(cfg, jax.random.PRNGKey(9), 2)
+    got = calls(differentiated(net, _loss_fn(), x, y))
+    assert telemetry.value("train_step.blocks_recomputed") == 5
+    assert got["flash_attention_fwd"] == got["flash_attention_bwd"] == 2
+    assert (got["flash_window_fwd"], got["flash_window_bwd"]) == (6, 3)
 
 
 # --------------------------------------------------------------- rotary

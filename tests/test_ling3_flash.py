@@ -33,6 +33,8 @@ from benchmark.models import ling3_flash as model
 from benchmark.reference import common as ref_common
 from benchmark.reference import ling3_flash as ref
 
+from _jaxpr_count import calls, differentiated, traced_loss
+
 kda = importlib.import_module("mxtpu.ops.pallas.kda")
 short_filter = importlib.import_module("mxtpu.ops.pallas.short_filter")
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -76,17 +78,7 @@ def _loss_fn(vocab=CFG["vocab_size"]):
 def _grads(net, x, y, vocab):
     """Loss and every trainable leaf's gradient through a traced step (the
     whole-step trainer's own forward, differentiated by jax)."""
-    from mxtpu.gluon.block import _run_traced
-    params = list(net.collect_params().values())
-    datas = [p.data()._data for p in params]
-    forward = _loss_fn(vocab)
-
-    def loss_of(datas):
-        out, _ = _run_traced(params, datas, jax.random.PRNGKey(0), True,
-                             lambda: forward(net, mx.nd.NDArray(x),
-                                             mx.nd.NDArray(y)))
-        return jnp.mean(out._data)
-
+    loss_of, datas = traced_loss(net, _loss_fn(vocab), x, y)
     return jax.jit(jax.value_and_grad(loss_of)), datas
 
 
@@ -636,6 +628,73 @@ def test_recomputation_changes_no_bit(case, program_grads):
     for a, spec in zip(g_off, SPECS):
         if spec[3]:
             assert _gap(g_on[spec[0]], a) <= 2e-6, spec[0]
+
+
+@pytest.mark.parametrize("path", ["plain", "kernels"])
+def test_a_recomputed_block_runs_its_kernels_forward_once(monkeypatch, path):
+    """The model as the cell builds it, differentiated and not run: every
+    kernel's forward stands in the jaxpr once for each backward, the
+    recomputed blocks' second forward holds none
+    (``hybrid_lm.kept_policy``; under a bare checkpoint each stood twice).
+    ``kernels``: under the interpreter, at 128 positions (the flash pair
+    wants its keys in 128s);
+    ``plain``: as tier-1 runs the model off the chip, where a KDA call is
+    a scan over chunks, which its backward rule differentiates: one
+    forward scan in the value, one in the rule, one reversed."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", str(int(path == "kernels")))
+    cfg = dict(CFG, seq_len=128, items_per_sample=128) \
+        if path == "kernels" else CFG
+    net = model.build(cfg, SPECS, ref_common.init_params(SPECS, 5))
+    model._FIRST.clear()
+    telemetry.reset_metric("train_step.blocks_recomputed")
+    x, y = ref.sample_inputs(cfg, jax.random.PRNGKey(9), 2)
+    got = calls(differentiated(net, _loss_fn(), x, y))
+    kinds = ref.kinds(cfg)
+    n_kda = kinds.count("kda")
+    assert telemetry.value("train_step.blocks_recomputed") == len(kinds)
+    if path == "kernels":
+        assert got["kda_fwd"] == got["kda_bwd"] == n_kda > 0
+        assert got["flash_attention_fwd"] == got["flash_attention_bwd"] \
+            == len(kinds) - n_kda > 0
+    else:
+        assert (got["scan"], got["scan.reverse"]) == (2 * n_kda, n_kda)
+
+
+@pytest.mark.parametrize("path", ["plain", "kernels"])
+def test_the_names_lower_to_nothing_without_a_checkpoint(monkeypatch, path):
+    """lfm2's model, which calls the flash kernel and builds no
+    checkpoint: its lowered step is the text it is with the names patched
+    to identities, on the plain path and through the kernels (the
+    interpreter, 128 positions)."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", str(int(path == "kernels")))
+    older = importlib.import_module("benchmark.models.lfm2_8b_a1b")
+    older_ref = importlib.import_module("benchmark.reference.lfm2_8b_a1b")
+    cfg = _config("lfm2_8b_a1b")
+    cfg.update(cfg["rehearsal"], dtype="float32")
+    if path == "kernels":
+        cfg.update(seq_len=128, items_per_sample=128)
+    specs = older_ref.param_specs(cfg)
+    net = older.build(cfg, specs, ref_common.init_params(specs, 5))
+    older._FIRST.clear()
+    x, y = older_ref.sample_inputs(cfg, jax.random.PRNGKey(9), 2)
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    fa.reset_dispatch_stats()
+
+    def text():
+        # MLIR numbers the private functions of one name (``@_where_174``)
+        # by what the process lowered before: not part of the program
+        f, datas = _grads(net, x, y, cfg["vocab_size"])
+        return re.sub(r"(@[A-Za-z_]\w*?)_\d+\b", r"\1",
+                      f.lower(datas).as_text())
+
+    named = text()
+    assert fa.DISPATCH_STATS["pallas" if path == "kernels" else "xla"] > 0
+    seen = []
+    for module in (fa, kda):
+        monkeypatch.setattr(module, "checkpoint_name",
+                            lambda x, name: seen.append(name) or x)
+    assert text() == named
+    assert set(seen) == set(fa.KEPT_NAMES)
 
 
 @pytest.mark.parametrize("name", ["lfm2_8b_a1b"])
