@@ -551,6 +551,42 @@ def yarn_inv_freq(theta, width, factor, original_max_position_embeddings,
     return (e / factor * ramp + e * (1 - ramp)).astype(np.float32)
 
 
+def _rotary_angles(t, turned, theta, scaling):
+    """``pos * freq`` [T, turned / 2] float32 and the gain on cos and sin
+    (None for 1): the plain table, or YaRN's from ``scaling``'s keys."""
+    half = turned // 2
+    pos = jnp.arange(t, dtype=jnp.float32)
+    if scaling is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / turned)
+        gain = None
+    else:
+        freq = jnp.asarray(yarn_inv_freq(
+            theta, turned, scaling["factor"],
+            scaling["original_max_position_embeddings"],
+            scaling.get("beta_fast", 32.0), scaling.get("beta_slow", 1.0)))
+        gain = scaling.get("attention_factor") or (
+            0.1 * math.log(scaling["factor"]) + 1.0)
+    return pos[:, None] * freq[None, :], gain
+
+
+def _rotary_paired(ang, interleave):
+    """[T, turned / 2] -> [T, turned]: each pair's angle at both of its
+    entries."""
+    if interleave:
+        return jnp.repeat(ang, 2, axis=-1)
+    return jnp.concatenate([ang, ang], axis=-1)
+
+
+def _rotary_table(fn, ang, gain, rest, still):
+    """cos or sin (``fn``) of ``ang`` [T, turned] -> [T, turned + rest]:
+    the ``rest`` entries past the turned width stand ``still`` (cos 1, sin
+    0)."""
+    t = fn(ang) if gain is None else fn(ang) * gain
+    if rest > 0:
+        t = jnp.pad(t, [(0, 0), (0, rest)], constant_values=still)
+    return t
+
+
 def rotary(x, theta=10000.0, interleave=False, seq_axis=1, width=0,
            scaling=None):
     """Rotary position embedding (Su et al., arXiv:2104.09864) over the
@@ -570,48 +606,115 @@ def rotary(x, theta=10000.0, interleave=False, seq_axis=1, width=0,
     ``beta_slow``, ``attention_factor``): the table is
     :func:`yarn_inv_freq`'s and cos and sin of the turned entries are
     multiplied by ``attention_factor`` (``0.1 ln(factor) + 1`` unless
-    given). Scopes ``rotary`` / ``rotary_yarn``."""
+    given). Scopes ``rotary`` / ``rotary_yarn``.
+
+    This is the definition and XLA's path. The grouped operators (:func:`grouped_attention`,
+    :func:`sparse_grouped_attention`) ask for the result heads first
+    through :func:`rotary_heads_first`, which on the TPU takes the Pallas
+    pair of :mod:`mxtpu.ops.pallas.rotary` where it can (the turn in place,
+    one pass each way) and says which in ``rotary.calls`` / ``.pallas`` /
+    ``.xla``; a
+    call of this function itself (latent attention's 64-wide turn, the
+    registered operator) is the plain path always and counts nowhere."""
     d = x.shape[-1]
     turned = width or d
     half = turned // 2
-    pos = jnp.arange(x.shape[seq_axis], dtype=jnp.float32)
-    if scaling is None:
-        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / turned)
-        gain = None
-    else:
+    if scaling is not None:
         from .. import telemetry
         telemetry.inc("rotary.scaled")
-        freq = jnp.asarray(yarn_inv_freq(
-            theta, turned, scaling["factor"],
-            scaling["original_max_position_embeddings"],
-            scaling.get("beta_fast", 32.0), scaling.get("beta_slow", 1.0)))
-        gain = scaling.get("attention_factor") or (
-            0.1 * math.log(scaling["factor"]) + 1.0)
+    ang, gain = _rotary_angles(x.shape[seq_axis], turned, theta, scaling)
     with jax.named_scope("rotary" if scaling is None else "rotary_yarn"):
-        ang = pos[:, None] * freq[None, :]                   # [T, R/2]
         i = jnp.arange(half)
-        if interleave:
-            ang = jnp.repeat(ang, 2, axis=-1)
-            lo, hi = 2 * i, 2 * i + 1
-        else:
-            ang = jnp.concatenate([ang, ang], axis=-1)
-            lo, hi = i, i + half
+        ang = _rotary_paired(ang, interleave)
+        lo, hi = (2 * i, 2 * i + 1) if interleave else (i, i + half)
         # partner[lo] = -x[hi], partner[hi] = x[lo]
         swap = jnp.zeros((d, d), x.dtype).at[hi, lo].set(-1).at[lo, hi].set(1)
         partner = jnp.matmul(x, swap, precision=mxu_precision(x, swap))
         shape = [1] * x.ndim
         shape[seq_axis % x.ndim], shape[-1] = x.shape[seq_axis], d
-
-        def table(fn, still):
-            t = fn(ang) if gain is None else fn(ang) * gain
-            if turned < d:  # the entries past the turned width stand still
-                t = jnp.pad(t, [(0, 0), (0, d - turned)],
-                            constant_values=still)
-            return t.reshape(shape)
-
-        cos, sin = table(jnp.cos, 1.0), table(jnp.sin, 0.0)
+        cos = _rotary_table(jnp.cos, ang, gain, d - turned, 1.0).reshape(shape)
+        sin = _rotary_table(jnp.sin, ang, gain, d - turned, 0.0).reshape(shape)
         return (x.astype(jnp.float32) * cos
                 + partner.astype(jnp.float32) * sin).astype(x.dtype)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("form", "back", "interpret"))
+def _rotary_kernel_pass(x, *, form, back, interpret):
+    """One pass of the Pallas pair with its tables: ``x`` [B, T, H, D] ->
+    [B, H, T, D], transposed by XLA and turned in place by ``rotary_turn``,
+    or (``back``) a cotangent [B, H, T, D] through ``rotary_unturn`` and
+    back to [B, T, H, D]. Jitted (and inlined where it is called) so that a
+    step traces the tables and the kernel once a shape and form, not once a
+    layer (``parallel/moe.py:_gathered_sum``)."""
+    from .pallas import rotary as kernels
+    theta, interleave, width, scaling = form
+    t, d = x.shape[2 if back else 1], x.shape[-1]
+    turned = width or d
+    ang, gain = _rotary_angles(t, turned, theta,
+                               dict(scaling) if scaling else None)
+    ang = _rotary_paired(ang, interleave)
+    cos = _rotary_table(jnp.cos, ang, gain, d - turned, 1.0)
+    sin = _rotary_table(jnp.sin, ang, gain, d - turned, 0.0)
+    swap = kernels.swap_matrix(d, width, interleave, x.dtype)
+    return (kernels.unturn if back else kernels.turn)(
+        x, cos, sin, swap, interpret=interpret)
+
+
+# nothing is kept for the transpose: the tables are positions alone
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _rotary_kernels(x, form, interpret):
+    return _rotary_kernel_pass(x, form=form, back=False, interpret=interpret)
+
+
+_rotary_kernels.defvjp(
+    lambda x, form, interpret: (_rotary_kernels(x, form, interpret), None),
+    lambda form, interpret, _, g: (_rotary_kernel_pass(
+        g, form=form, back=True, interpret=interpret),))
+
+
+def _rotary_by_kernels(x, theta=10000.0, interleave=False, width=0,
+                       scaling=None):
+    """``rotary(x, ...)`` of ``x`` [B, T, H, D] laid heads first, [B, H,
+    T, D], through the Pallas pair ``rotary_turn`` / ``rotary_unturn``, or
+    None where the call cannot take it
+    (:func:`mxtpu.ops.pallas.rotary.refusal`). Counted at trace time:
+    ``rotary.calls``, then ``rotary.pallas`` or ``rotary.xla`` by reason
+    (``platform`` / ``dtype`` / ``lanes`` / ``width``)."""
+    from .. import telemetry
+    from .pallas import rotary as kernels
+    telemetry.inc("rotary.calls")
+    reason = kernels.refusal(x, width)
+    if reason is not None:
+        telemetry.inc("rotary.xla", tag=reason)
+        return None
+    telemetry.inc("rotary.pallas")
+    if scaling is not None:
+        telemetry.inc("rotary.scaled")
+    form = (float(theta), bool(interleave), int(width),
+            tuple(sorted(scaling.items())) if scaling else None)
+    with jax.named_scope("rotary" if scaling is None else "rotary_yarn"):
+        return _rotary_kernels(x, form, kernels._fa._interpret())
+
+
+def rotary_heads_first(x, theta=10000.0, interleave=False, width=0,
+                       scaling=None):
+    """``rotary(x, ...)`` of ``x`` [B, T, H, D] (positions along axis 1)
+    laid heads first, [B, H, T, D], as the flash kernels take it. Where
+    the call can take them (a TPU, bf16 or float32, heads of whole lane
+    widths, an even turned width inside the head) XLA moves ``x`` heads
+    first (inside whatever fusion produced it, a per-head norm's scaling in
+    the models here) and the Pallas pair ``rotary_turn`` / ``rotary_unturn``
+    turns that array in place, one read and one write each way, with the
+    same numbers bit for bit; any other call is :func:`rotary` and a
+    transposition, as XLA fuses them, and is counted by its reason in
+    ``rotary.xla``: 0 is the number to expect there where heads are 128
+    wide."""
+    turned = _rotary_by_kernels(x, theta, interleave, width, scaling)
+    if turned is None:
+        turned = rotary(x, theta, interleave, 1, width,
+                        scaling).transpose(0, 2, 1, 3)
+    return turned
 
 
 register("_contrib_rotary_embedding", aliases=("rotary_embedding",))(rotary)
@@ -685,12 +788,18 @@ def _grouped_heads(q, k, v, turn, attend):
     heads first, ``attend`` on q [B, H, T, D] and k, v [B, H_kv, T, D],
     and back to [B, T, H * D]."""
     b, t, h, _ = q.shape
+    # :func:`rotary_heads_first` of q and of k (one choice: they differ in
+    # heads alone), the plain path's operations in the order they have
+    # always had in a step's text
+    turned = None
     if turn is not None:
-        q = rotary(q, **turn)
-        k = rotary(k, **turn)
+        turned = [_rotary_by_kernels(x, **turn) for x in (q, k)]
+        if turned[0] is None:
+            turned = None
+            q, k = rotary(q, **turn), rotary(k, **turn)
     v = v.reshape(b, t, k.shape[2], -1)
-    out = attend(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                 v.transpose(0, 2, 1, 3))                       # [B, H, T, D]
+    q, k = turned or (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3))
+    out = attend(q, k, v.transpose(0, 2, 1, 3))                 # [B, H, T, D]
     return out.transpose(0, 2, 1, 3).reshape(b, t, h * v.shape[-1])
 
 

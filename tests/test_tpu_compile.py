@@ -626,3 +626,46 @@ def test_the_selection_compiles_by_blocks(one_chip, as_tpu):
     assert "f32[1,16384,2048]" in text
     assert " sort(" not in text and "TopK" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_grouped_attention_turns_through_the_rotary_kernels(one_chip,
+                                                            as_tpu):
+    """``grouped_attention`` at Laguna's windowed shape (16,384 positions,
+    72 query heads over 8 key/value heads of 128, a window of 512, bf16):
+    q and k are turned by ``rotary_turn``, one call each, heads first and
+    in place, and their cotangents come back through ``rotary_unturn``;
+    the flash kernel takes the turn's own output; no call fell back. The
+    signed permutation is the kernels' operand (the MXU finds an entry's
+    partner), so the ``[128, 128]`` constant is still in the text; the
+    product with it is not: no dot stands under the ``rotary`` scope."""
+    from mxtpu import telemetry
+    from mxtpu.ops.registry import get_op
+    attend = get_op("_contrib_grouped_attention").fn
+    q = _spec((1, 16384, 72, 128), one_chip)
+    k = _spec((1, 16384, 8, 128), one_chip)
+    v = _spec((1, 16384, 8 * 128), one_chip)
+    for name in ("calls", "pallas", "xla"):
+        telemetry.reset_metric("rotary." + name)
+
+    def loss(q, k, v):
+        return jnp.sum(attend(q, k, v, window=512).astype(jnp.float32) ** 2)
+
+    value = _compiled_text(lambda q, k, v: attend(q, k, v, window=512),
+                           q, k, v)
+    names = re.findall(r"%(rotary_\w+?)(?:\.\d+)? = .* custom-call\(", value)
+    assert sorted(names) == ["rotary_turn", "rotary_turn"], names
+    assert "flash_window_fwd" in value
+    text = _compiled_text(jax.grad(loss, (0, 1, 2)), q, k, v)
+    names = re.findall(r"%(rotary_\w+?)(?:\.\d+)? = .* custom-call\(", text)
+    assert sorted(names) == ["rotary_turn", "rotary_turn", "rotary_unturn",
+                             "rotary_unturn"], names
+    for program in (value, text):
+        assert not re.search(r"(convolution|dot)\(.*rotary/dot_general",
+                             program)
+        assert "f32[128,128]" not in program
+    # q heads first is the turn's own output, written where its operand
+    # stood: no array of q's size stands between it and the flash kernel
+    turn = re.search(r"%rotary_turn[.\d]* = bf16\[1,72,16384,128\].*", value)
+    assert turn and "output_to_operand_aliasing" in turn.group(0)
+    assert [telemetry.value("rotary." + n)
+            for n in ("calls", "pallas", "xla")] == [4, 4, 0]
