@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 
 from ...block import HybridBlock
-from ...nn import BatchNorm, GatedMLP, HybridSequential, Embedding
+from ...nn import BatchNorm, Dense, GatedMLP, HybridSequential, Embedding
 
 __all__ = ["Concurrent", "HybridConcurrent", "Identity", "SparseEmbedding",
            "SyncBatchNorm", "SwitchMoE", "RoutedMoE"]
@@ -142,6 +142,9 @@ class RoutedMoE(HybridBlock):
     with ``topk_group`` is that paper's group limit on the choice. Called
     with a second input, the router scores that and the experts read the
     first (a router placed ahead of attention reads its layer's input).
+    ``shared_gate``: the shared experts' output is scaled by ``sigmoid(x
+    w_sg)``, one number a token (``w_sg``: ``dim x 1``), before it is
+    added.
 
     ``experts_held`` of the router's ``num_experts`` live in this block,
     starting at ``first_expert``: one chip's share of the layer under
@@ -157,8 +160,11 @@ class RoutedMoE(HybridBlock):
     def __init__(self, dim, hidden, num_experts, top_k, experts_held=None,
                  first_expert=0, scale=1.0, shared_hidden=0, grouped=True,
                  score="sigmoid", activation="silu", n_group=1,
-                 topk_group=1, **kwargs):
+                 topk_group=1, shared_gate=False, **kwargs):
         super().__init__(**kwargs)
+        if shared_gate and not shared_hidden:
+            raise ValueError("RoutedMoE: a gate on a shared expert that is "
+                             "not there (shared_hidden=0)")
         held = num_experts if experts_held is None else experts_held
         self._dim, self._hidden = dim, hidden
         self._num_experts, self._held = num_experts, held
@@ -177,6 +183,9 @@ class RoutedMoE(HybridBlock):
             self.w_up = self.params.get("w_up", shape=(held, dim, hidden))
             self.w_down = self.params.get("w_down",
                                           shape=(held, hidden, dim))
+            self.shared_gate = Dense(
+                1, use_bias=False, flatten=False, prefix="sgate_") \
+                if shared_gate else None
             self.shared = GatedMLP(dim, shared_hidden, prefix="shared_") \
                 if shared_hidden else None
 
@@ -191,7 +200,11 @@ class RoutedMoE(HybridBlock):
         if self.shared is None:
             return out
         with jax.named_scope("moe.shared"):
-            return out + self.shared(x)
+            shared = self.shared(x)
+            if self.shared_gate is None:
+                return out + shared
+        with jax.named_scope("moe.shared_gate"):
+            return out + shared * F.sigmoid(self.shared_gate(x))
 
     def __repr__(self):
         return "RoutedMoE(dim=%d, hidden=%d, experts=%d of %d, top_k=%d)" % (

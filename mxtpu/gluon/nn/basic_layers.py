@@ -284,13 +284,17 @@ class LayerNorm(HybridBlock):
 
 class RMSNorm(HybridBlock):
     """Root-mean-square normalization, a scale and no shift (op
-    ``RMSNorm``; no reference counterpart)."""
+    ``RMSNorm``; no reference counterpart). ``zero_centered``: the scale is
+    ``1 + gamma`` and ``gamma`` starts at zero."""
 
-    def __init__(self, axis=-1, epsilon=1e-6, gamma_initializer="ones",
-                 in_channels=0, **kwargs):
+    def __init__(self, axis=-1, epsilon=1e-6, gamma_initializer=None,
+                 in_channels=0, zero_centered=False, **kwargs):
         super().__init__(**kwargs)
         self._axis = axis
         self._epsilon = epsilon
+        self._zero_centered = zero_centered
+        if gamma_initializer is None:
+            gamma_initializer = "zeros" if zero_centered else "ones"
         with self.name_scope():
             self.gamma = self.params.get(
                 "gamma", shape=(in_channels,), init=gamma_initializer,
@@ -300,7 +304,8 @@ class RMSNorm(HybridBlock):
         self.gamma._shape_resolved((x.shape[self._axis],))
 
     def hybrid_forward(self, F, x, gamma):
-        return F.RMSNorm(x, gamma, axis=self._axis, eps=self._epsilon)
+        return F.RMSNorm(x, gamma, axis=self._axis, eps=self._epsilon,
+                         zero_centered=self._zero_centered)
 
 
 class GatedMLP(HybridBlock):

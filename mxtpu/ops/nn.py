@@ -513,15 +513,21 @@ def LayerNorm(data, gamma, beta, axis=-1, eps=1e-5, output_mean_var=False):
 
 
 @register("RMSNorm", aliases=("rms_norm",))
-def RMSNorm(data, gamma, axis=-1, eps=1e-6):
+def RMSNorm(data, gamma, axis=-1, eps=1e-6, zero_centered=False):
     """x / sqrt(mean(x^2) + eps) * gamma (Zhang & Sennrich,
     arXiv:1910.07467). No reference counterpart. f32 statistics even for
-    bf16 inputs, as :func:`LayerNorm`."""
+    bf16 inputs, as :func:`LayerNorm`. ``zero_centered``: the scale is ``1
+    + gamma`` (the leaf is the scale's distance from one, which weight
+    decay then pulls to a scale of 1 and not of 0), applied in float32
+    before the one rounding to ``data``'s dtype."""
     x32 = data.astype(jnp.float32)
     inv = lax.rsqrt(jnp.mean(jnp.square(x32), axis=axis, keepdims=True) + eps)
     shape = [1] * data.ndim
     ax = axis % data.ndim
     shape[ax] = data.shape[ax]
+    if zero_centered:
+        return (x32 * inv * (1.0 + jnp.reshape(gamma, shape).astype(
+            jnp.float32))).astype(data.dtype)
     return (x32 * inv).astype(data.dtype) * jnp.reshape(gamma, shape)
 
 
@@ -1111,6 +1117,47 @@ def kda_attention(q, k, v, g, beta, chunk=64):
     with telemetry.span("kda_attention.trace"), \
             jax.named_scope("kda_attention"):
         return attend(q, k, v, g.astype(jnp.float32), beta, chunk)
+
+
+@register("_contrib_gdn_gate", aliases=("gdn_gate",))
+def gdn_gate(data, weight, a_log, dt_bias):
+    """The log-decay a head of a Gated-DeltaNet layer (Yang et al.,
+    arXiv:2412.06464, Mamba-2's parametrisation): ``g = -exp(a_log) *
+    softplus(data weight^T + dt_bias)``, <= 0 and unbounded below (``exp(
+    a_log)`` reaches 16 under the usual initialiser, and ``g`` -20 a
+    token). ``data`` [..., D] (the layer's normed input), ``weight`` [H, D]
+    (no bias), ``a_log`` and ``dt_bias`` [H]. As :func:`kda_gate`, the
+    product takes its operands as they are stored and leaves a float32
+    result that is never rounded, and everything after it is float32.
+    Returns float32 [..., H]; scope ``gdn_gate``."""
+    f32 = jnp.float32
+    with jax.named_scope("gdn_gate"):
+        f = jnp.einsum("...d,hd->...h", data, weight,
+                       precision=mxu_precision(data, weight),
+                       preferred_element_type=f32)
+        return -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+            f + dt_bias.astype(f32))
+
+
+@register("_contrib_gated_delta_rule", aliases=("gated_delta_rule",))
+def gated_delta_rule(q, k, v, g, beta, key_heads=1, chunk=64):
+    """Gated DeltaNet after its projections, filters and gates: by value
+    head, ``S' = exp(g_t) S_{t-1}; S_t = S' + k_t (b_t (v_t - S'^T
+    k_t))^T`` from ``S_0 = 0`` (float32, [K, V]) and ``o_t = S_t^T q_t /
+    sqrt(K)``. ``q``, ``k`` [B, T, key_heads * K] (L2-normed by head),
+    ``v`` [B, T, H * V], ``g`` [B, T, H] float32 (:func:`gdn_gate`: one
+    decay a head, no bound), ``beta`` [B, T, H]; value head ``j`` reads key
+    head ``j // (H / key_heads)``. By chunks of ``chunk`` tokens through
+    the Pallas pair ``gdn_fwd`` / ``gdn_bwd`` (:mod:`mxtpu.ops.pallas.kda`,
+    the chunk plan of :func:`kda_attention`'s pair with the decay as a [C,
+    C] matrix of differences); off the TPU the same chunks on a plain
+    path, counted in ``gated_delta.fallbacks``. Returns [B, T, H * V];
+    scope ``gated_delta_rule``, span ``gated_delta.trace``."""
+    from .. import telemetry
+    from .pallas.kda import gated_delta_rule as rule
+    with telemetry.span("gated_delta.trace"), \
+            jax.named_scope("gated_delta_rule"):
+        return rule(q, k, v, g.astype(jnp.float32), beta, key_heads, chunk)
 
 
 @register("InstanceNorm")

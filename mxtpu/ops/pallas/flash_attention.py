@@ -866,18 +866,31 @@ def _plan(q, k, v, mask, block_q, block_k, direction):
     # are invariant to zero columns
     itm = jnp.dtype(q.dtype).itemsize
     if direction == "forward":
-        def step(bq, bk):
-            return _fwd_vmem(bq, bk, d, dv, itm)
-        too_big = "one q block does not fit the VMEM budget"
-    else:
-        whole = tk if _group(q, k) > 1 else 0
+        return _tile_blocks(
+            t, tk, block_q, block_k,
+            lambda bq, bk: _fwd_vmem(bq, bk, d, dv, itm) + mask.vmem(bq, bk),
+            "one q block does not fit the VMEM budget")
+    too_big = "dq of one head does not fit the VMEM budget"
+    # under grouped heads dk and dv of the whole key/value head where that
+    # fits; where no block does (16,384 keys of 256: 67 MB beside dq's 34),
+    # a k block of them a QUERY head, which XLA then sums (:func:`_kv_held`)
+    for whole in ((tk, 0) if _group(q, k) > 1 else (0,)):
+        blocks, reason = _tile_blocks(
+            t, tk, block_q, block_k,
+            lambda bq, bk: _bwd_vmem(bq, bk, t, d, dv, itm, whole)
+            + mask.vmem(bq, bk), too_big)
+        if reason != too_big:
+            break
+    return blocks, reason
 
-        def step(bq, bk):
-            return _bwd_vmem(bq, bk, t, d, dv, itm, whole)
-        too_big = "dq of one head does not fit the VMEM budget"
-    return _tile_blocks(t, tk, block_q, block_k,
-                        lambda bq, bk: step(bq, bk) + mask.vmem(bq, bk),
-                        too_big)
+
+def _kv_held(q, k, v, mask, block_q, block_k):
+    """Whether the grouped backward holds dk and dv of the whole key/value
+    head in VMEM (the layout :func:`_plan` tries first) at these blocks."""
+    itm = jnp.dtype(q.dtype).itemsize
+    return _group(q, k) > 1 and _bwd_vmem(
+        block_q, block_k, q.shape[2], q.shape[3], v.shape[3], itm,
+        k.shape[2]) + mask.vmem(block_q, block_k) <= _VMEM_BUDGET
 
 
 @jax.named_scope("flash_attention_bwd")   # prologue, kernel and epilogue
@@ -902,7 +915,13 @@ def _fa_backward_pallas(q, k, v, out, lse, g, mask, scale, block_q, block_k,
     b, h, t, d = q.shape
     hk, tk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = _group(q, k)
-    grouped = group > 1
+    # dk and dv of the whole key/value head in VMEM, summed over its query
+    # heads inside the kernel, or (not grouped, or too large for that) a k
+    # block of them a query head
+    grouped = _kv_held(q, k, v, mask, block_q, block_k)
+    if group > 1 and not grouped:
+        from ... import telemetry
+        telemetry.inc("pallas_flash.bwd_kv_by_query_head")
     bh = b * h
     n_q = t // block_q
     n_k = tk // block_k
@@ -910,7 +929,8 @@ def _fa_backward_pallas(q, k, v, out, lse, g, mask, scale, block_q, block_k,
     q_steps = mask.steps(n_q, n_k, block_q, block_k)[1]
     kernel = functools.partial(_fa_bwd_kernel, scale=scale, mask=mask,
                                block_q=block_q, block_k=block_k, n_q=n_q,
-                               n_k=n_k, group=group, q_steps=q_steps)
+                               n_k=n_k, group=group if grouped else 1,
+                               q_steps=q_steps)
     interpret = _interpret()
     extra = {}
     if not interpret:  # Mosaic-only hints: the interpreter takes none
@@ -938,8 +958,9 @@ def _fa_backward_pallas(q, k, v, out, lse, g, mask, scale, block_q, block_k,
         grid = (b * hk, group, n_k, q_steps)
         kv_rows = tk
     else:
-        def at(b_, j, i):
-            return b_, b_, j, i
+        def at(b_, j, i):       # K and V at their own head
+            return b_, (b_ if group == 1
+                        else b_ // h * hk + b_ % h // group), j, i
         grid = (bh, n_k, q_steps)
         kv_rows = block_k
 
@@ -955,7 +976,10 @@ def _fa_backward_pallas(q, k, v, out, lse, g, mask, scale, block_q, block_k,
                     lambda hq, hkv, j, i: (hq, 0, q_block(j, i)))
     # dk, dv: the k block of the step, or the key/value head whole
     kv_block = (lambda hq, hkv, j, i: (hkv, 0, 0)) if grouped else (
-        lambda hq, hkv, j, i: (hkv, j, 0))
+        lambda hq, hkv, j, i: (hq, j, 0))
+    # where XLA sums the group, it sums float32
+    kv_heads, kv_dtypes = (b * hk, (k.dtype, v.dtype)) \
+        if grouped or group == 1 else (bh, (f32, f32))
     dq, dk, dv_ = pl.pallas_call(
         kernel,
         grid=grid,
@@ -970,8 +994,8 @@ def _fa_backward_pallas(q, k, v, out, lse, g, mask, scale, block_q, block_k,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b * hk, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hk, tk, dv), v.dtype),
+            jax.ShapeDtypeStruct((kv_heads, tk, d), kv_dtypes[0]),
+            jax.ShapeDtypeStruct((kv_heads, tk, dv), kv_dtypes[1]),
         ],
         scratch_shapes=[
             pltpu.VMEM((t, d), f32),          # dq of the head
@@ -984,6 +1008,9 @@ def _fa_backward_pallas(q, k, v, out, lse, g, mask, scale, block_q, block_k,
     )(q.reshape(bh, t, d), k.reshape(-1, tk, d), v.reshape(-1, tk, dv),
       g.reshape(bh, t, dv), lse.reshape(bh, 1, t), delta.reshape(bh, 1, t),
       *([selection] if mask.selected else []))
+    if kv_heads != b * hk:
+        dk, dv_ = (x.reshape(b, hk, group, tk, -1).sum(2).astype(like.dtype)
+                   for x, like in ((dk, k), (dv_, v)))
     return (dq.reshape(q.shape)[..., :d_out], dk.reshape(k.shape)[..., :d_out],
             dv_.reshape(v.shape)[..., :dv_out])
 

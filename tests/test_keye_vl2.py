@@ -513,7 +513,8 @@ def test_the_block_with_a_topk_past_the_sequence_is_full_attention():
 def test_operators_of_the_sparse_kind():
     kinds = hybrid_lm.OPERATORS
     assert set(kinds) == {"conv", "full_attention", "window_attention",
-                          "sparse_attention", "latent_attention", "kda"}
+                          "sparse_attention", "latent_attention", "kda",
+                          "gated_delta_net"}
     # the grouped block under a name of its own, as the windowed kind is:
     # a stack's keyword arguments for the kind carry the ``topk``
     assert kinds["sparse_attention"] == kinds["window_attention"] == (

@@ -464,7 +464,8 @@ def test_the_benchmark_reads_the_filters_fallbacks(monkeypatch, case, want):
         "name": "kda_conv_fallbacks.train", "unit": "calls",
         "better": "lower", "source": "program_counter",
         "layer": "ops, kernels", "moves": "train_samples_per_s",
-        "workloads": ["ling3_flash.train_b1_s8192"]}]
+        "workloads": ["ling3_flash.train_b1_s8192",
+                      "qwen3_next_80b_a3b.train_b1_s16384"]}]
     for name in FILTER_COUNTERS:
         telemetry.reset_metric(name)
     if case == "both passes on the kernels":

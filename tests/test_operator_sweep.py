@@ -991,6 +991,8 @@ COVERED_ELSEWHERE = {
     "_contrib_kda_conv": "test_ling3_flash.py",
     "_contrib_kda_gate": "test_ling3_flash.py",
     "_contrib_kda_attention": "test_ling3_flash.py",
+    "_contrib_gdn_gate": "test_gated_delta_rule.py",
+    "_contrib_gated_delta_rule": "test_qwen3_next.py",
     # the sparse-label cross-entropy in one pass: against a float64 oracle
     "_contrib_log_softmax_pick": "test_loss_one_pass.py",
     "CTCLoss": "test_ctc.py",

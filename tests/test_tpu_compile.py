@@ -669,3 +669,93 @@ def test_grouped_attention_turns_through_the_rotary_kernels(one_chip,
     assert turn and "output_to_operand_aliasing" in turn.group(0)
     assert [telemetry.value("rotary." + n)
             for n in ("calls", "pallas", "xla")] == [4, 4, 0]
+
+
+def test_gdn_kernels_compile_to_mosaic(one_chip, as_tpu):
+    """Both Gated-DeltaNet kernels at the qwen3_next cell's shapes (one
+    layer, 16,384 positions, 32 value heads over 16 key heads of 128, bf16
+    with a float32 decay a HEAD) as one differentiated program: Mosaic
+    calls under their own names, q and k entering at their own 16 heads
+    (fetched at head ``j // 2`` by the index maps: no copy at 32), a key
+    head's cotangents leaving a value head each in float32 to be summed,
+    and nothing a token kept: the states at the 256 chunk starts, 537 MB,
+    and those two float32 arrays are the program's temporaries. The traces
+    one pair reports leave the event ring its head at the cell's three
+    layers under recomputation."""
+    from mxtpu import telemetry
+    kda = importlib.import_module("mxtpu.ops.pallas.kda")
+    t = 16384
+    qk, v = _spec((1, t, 2048), one_chip), _spec((1, t, 4096), one_chip)
+    g = _spec((1, t, 32), one_chip, jnp.float32)
+    beta = _spec((1, t, 32), one_chip)
+
+    def loss(*a):
+        return kda.gated_delta_rule(*a, 16, 64).astype(jnp.float32).sum()
+
+    for name in ("calls", "fallbacks"):
+        telemetry.reset_metric("gated_delta." + name)
+    t0_us = time.perf_counter_ns() // 1000
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qk, qk, v, g, beta).compile()
+    text = compiled.as_text()
+    traces = sum(1 for n, _c, ts, _d, _t in telemetry.events()
+                 if n == "jax.trace" and ts >= t0_us)
+    assert 0 < 3 * 2 * traces < telemetry.EVENT_RING_CAP // 2, traces
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "gdn_fwd" in text and "gdn_bwd" in text
+    assert "kda_fwd" not in text and "kda_bwd" not in text
+    assert [telemetry.value("gated_delta." + n)
+            for n in ("calls", "fallbacks")] == [1, 0]
+    # a state a chunk and value head, never a state a token; q and k never
+    # at the value heads in bf16
+    assert "f32[32,256,128,128]" in text
+    assert "f32[32,16384,128,128]" not in text
+    assert "f32[1,16384,4096]" in text          # dq, dk a value head
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    states, partials = 32 * 256 * 128 * 128 * 4, 2 * t * 4096 * 4
+    assert states + partials <= temps < states + partials + 0.3e9, temps
+    # the forward alone keeps nothing
+    alone = jax.jit(lambda *a: kda.gated_delta_rule(*a, 16, 64)).lower(
+        qk, qk, v, g, beta).compile()
+    assert alone.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_gated_attention_at_width_256_compiles_to_mosaic(one_chip, as_tpu):
+    """``grouped_attention`` at the qwen3_next cell's shape (16,384
+    positions, 16 query heads over 2 key/value heads of 256, rotary over a
+    head's first 64 entries, bf16), differentiated: both flash kernels and
+    both rotary kernels, no fallback. dk and dv of a whole key/value head
+    (67 MB beside dq's 34) do not fit the backward's VMEM, so they leave a
+    query head each in float32 (``pallas_flash.bwd_kv_by_query_head``) and
+    XLA sums the eight; K and V are never repeated."""
+    from mxtpu import telemetry
+    from mxtpu.ops.registry import get_op
+    attend = get_op("_contrib_grouped_attention").fn
+    q = _spec((1, 16384, 16, 256), one_chip)
+    k = _spec((1, 16384, 2, 256), one_chip)
+    v = _spec((1, 16384, 2 * 256), one_chip)
+    for name in ("rotary.calls", "rotary.pallas", "rotary.xla",
+                 "pallas_flash.bwd_kv_by_query_head"):
+        telemetry.reset_metric(name)
+    fa.reset_dispatch_stats()
+
+    def loss(q, k, v):
+        return jnp.sum(attend(q, k, v, rope_theta=1e7,
+                              rotary_dim=64).astype(jnp.float32) ** 2)
+
+    text = _compiled_text(jax.grad(loss, (0, 1, 2)), q, k, v)
+    names = re.findall(r"%(rotary_\w+?|flash_\w+?)(?:\.\d+)? = .* "
+                       r"custom-call\(", text)
+    assert sorted(names) == ["flash_attention_bwd", "flash_attention_fwd",
+                             "rotary_turn", "rotary_turn", "rotary_unturn",
+                             "rotary_unturn"], names
+    stats = dict(fa.DISPATCH_STATS.items())
+    assert (stats["pallas"], stats["bwd_pallas"]) == (1, 1), stats
+    assert stats["xla"] == 0 and stats["bwd_xla"] == 0, stats
+    assert stats["grouped"] == 1 and stats["kv_repeated"] == 0, stats
+    assert telemetry.value("pallas_flash.bwd_kv_by_query_head") == 1
+    assert [telemetry.value("rotary." + n)
+            for n in ("calls", "pallas", "xla")] == [2, 2, 0]
+    assert "f32[16,16384,256]" in text          # dk, dv a query head
+    assert "bf16[1,16,16384,256]{3,2,1,0} broadcast" not in text
+    assert "f32[16384,16384]" not in text

@@ -22,12 +22,13 @@ import json
 import re
 import sys
 
-SCOPES = ("kda_conv", "kda_gate", "kda_attention", "mla_attention",
+SCOPES = ("kda_conv", "kda_gate", "kda_attention", "gdn_gate",
+          "gated_delta_rule", "gated_norm", "mla_attention",
           "gqa_attention", "window_attention", "sparse_attention",
-          "rotary", "rotary_yarn", "head_gate",
+          "rotary", "rotary_yarn", "head_gate", "element_gate",
           "index_select", "short_conv", "flash_attention_bwd",
           "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
-          "moe.shared", "softmax_ce", "optimizer")
+          "moe.shared", "moe.shared_gate", "softmax_ce", "optimizer")
 _HEAD = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$")
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
