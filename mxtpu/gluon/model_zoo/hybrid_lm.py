@@ -33,10 +33,11 @@ anywhere: ``h = x + op(norm1(x)); y = h + ffn(norm2(h))``.
 * a final norm and a vocabulary head, tied to the embedding by default;
 * with ``recompute`` each block is recomputed in the backward from its
   input (``jax.checkpoint``), and of a block's inside only what its Pallas
-  kernels' forwards name is kept (:func:`kept_policy`: attention's output
-  and log-sum-exp rows where no window cuts the call, either delta rule's
-  output and chunk states), so the second forward runs everything but
-  those kernels;
+  kernels' forwards and its routed layer name is kept (:func:`kept_policy`:
+  attention's output and log-sum-exp rows where no window cuts the call,
+  either delta rule's output and chunk states, the router's product, its
+  choice and the sorted plan), so the second forward runs everything but
+  those kernels, the router's product, its ``top_k`` and the plan's sort;
 * with ``zero_centered`` every norm of the stack scales by ``1 + w``.
 
 ``HybridLM(layers=["conv", "full_attention", "conv", ...])`` is LFM2's
@@ -63,14 +64,21 @@ __all__ = ["HybridLM", "DecoderBlock", "GroupedQueryAttention",
 def kept_policy():
     """What a recomputed block keeps beside its input, as a policy of
     ``jax.checkpoint``: the values its kernels' forward rules name, O(T)
-    bytes that cost a kernel's whole run to make again. A windowed
-    attention call names nothing (the same bytes for a window's work, and
-    with them Laguna's step does not load beside what its set-up holds:
-    PERF.md §6, PR 46). The kernel files own the names (and are imported
-    here, not with the model zoo)."""
+    bytes that cost a kernel's whole run to make again, and what its
+    routed layer names of its router's decision (``moe.KEPT_NAMES``: the
+    float32 product (T, E), the choice, the sorted order, sizes and rung),
+    which cost a 6-pass product, a full sort of (T, E) and an argsort of
+    T*k to make again, so a step routes once and both forwards read one
+    choice. A windowed attention call names nothing (the same bytes for a
+    window's work, and with them Laguna's step does not load beside what
+    its set-up holds: PERF.md §6, PR 46). The kernel files and the routed
+    layer's own the names (and are imported here, not with the model
+    zoo)."""
     from ...ops.pallas.flash_attention import KEPT_NAMES as flash
     from ...ops.pallas.kda import GDN_KEPT_NAMES as gdn, KEPT_NAMES as kda
-    return jax.checkpoint_policies.save_only_these_names(*flash, *kda, *gdn)
+    from ...parallel.moe import KEPT_NAMES as routed
+    return jax.checkpoint_policies.save_only_these_names(
+        *flash, *kda, *gdn, *routed)
 
 
 class SparseIndexer(HybridBlock):
@@ -435,12 +443,14 @@ class HybridLM(HybridBlock):
     follow its own keyword arguments). ``recompute``: in a traced
     forward each block runs under ``jax.checkpoint``, which keeps the
     block's input and what its attention (unwindowed) and delta-rule
-    kernels' forward rules name (:func:`kept_policy`), so a differentiated step
-    holds one block's activations at a time and runs every block's forward
-    twice (the expert layer's own ``custom_vjp`` rule among it) but for
-    those kernels, whose second run is dead code once their outputs are
-    held; a property of the model, set where the model is built, and
-    counted at trace time in ``train_step.blocks_recomputed``.
+    kernels' forward rules and its routed layer's router name
+    (:func:`kept_policy`), so a differentiated step holds one block's
+    activations at a time and runs every block's forward twice (the expert
+    layer's own ``custom_vjp`` rule among it) but for those kernels and
+    the router's product, ``top_k`` and sort, whose second run is dead
+    code once their outputs are held; a property of the model, set where
+    the model is built, and counted at trace time in
+    ``train_step.blocks_recomputed``.
     """
 
     def __init__(self, vocab_size, dim, layers, operators, dense_hidden, moe,

@@ -50,6 +50,15 @@ The gate's activation (``silu`` or ``relu``) and the router's score
 (``sigmoid`` or ``softmax``) are properties of a model, named by its
 configuration; a router may read another tensor than the experts do
 (``router_x``: a router placed ahead of attention).
+
+What the router decided carries names (:data:`KEPT_NAMES`,
+``jax.ad_checkpoint.checkpoint_name``): its float32 product, its choice,
+the scores picked there, and the sorted plan's order, sizes and rung. A caller that recomputes the
+layer under ``jax.checkpoint`` with a policy that keeps those names
+(``hybrid_lm.kept_policy``) routes once a step: the second forward runs no
+router product, no top-k, no gather of the picked scores and no argsort,
+and reads the very choice the first made. Under no checkpoint a name is the identity and lowers to
+nothing.
 """
 from __future__ import annotations
 
@@ -58,12 +67,30 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError
 
 __all__ = ["switch_ffn", "routed_ffn", "route_top_k", "piece_plan",
-           "shard_experts"]
+           "shard_experts", "KEPT_NAMES"]
+
+# what a routed layer names of its router's decision, for a caller's
+# ``jax.checkpoint(..., policy=save_only_these_names(*KEPT_NAMES))`` to keep
+# (as ``flash_attention.KEPT_NAMES``): the product ``x W^T`` (T, E) float32,
+# the choice (T, k) int32 and the scores picked there (T, k) float32 of
+# :func:`route_top_k`; the order (T*k,) int32, the sizes (held,) and the
+# rung () of :func:`piece_plan`. O(T*E) float32 and O(T*k) a layer,
+# against a float32 product, a full sort of (T, E) (what ``lax.top_k`` is
+# on a TPU), a gather of T*k scalars (10 ns each on a v5e: dearer than the
+# sort) and an argsort of T*k to make them again. The product is named BEFORE the score function: jax's
+# derivative rules of ``logistic`` and of the softmax read the function's
+# own output, which carries no name, so scores named after it were kept
+# AND their product made again for the rule; from the kept product the
+# score function is one pass over (T, E)
+KEPT_NAMES = ("route_logits", "route_choice", "route_picked", "route_order",
+              "route_sizes", "route_rung")
+_LOGITS, _CHOICE, _PICKED, _ORDER, _SIZES, _RUNG = KEPT_NAMES
 
 
 def switch_ffn(x, router_w, w1, b1, w2, b2, capacity_factor=1.25):
@@ -135,20 +162,25 @@ def route_top_k(x, router_w, score_bias, top_k, scale=1.0, score="sigmoid",
     score is the sum of its two largest ``s + score_bias``, only the
     ``topk_group`` best groups stay, and the ``top_k`` are chosen among
     their experts (the others' biased scores read -inf). The weights are
-    still ``s`` at the chosen experts over their sum."""
+    still ``s`` at the chosen experts over their sum.
+
+    The product, ``idx`` and the scores picked at ``idx`` are named
+    (:data:`KEPT_NAMES`): a recomputed caller whose policy keeps them makes
+    again the scores (their backward reads ``s``) and the weights'
+    normalisation, and neither the product, any ``top_k`` nor the gather."""
     if score not in ("sigmoid", "softmax"):
         raise MXNetError("route_top_k: score %r is neither 'sigmoid' nor "
                          "'softmax'" % (score,))
     with jax.named_scope("moe.route"):
-        s = jnp.einsum(
+        s = checkpoint_name(jnp.einsum(
             "td,ed->te", x.astype(jnp.float32), router_w.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST)
+            precision=jax.lax.Precision.HIGHEST), _LOGITS)
         s = jax.nn.sigmoid(s) if score == "sigmoid" else jax.nn.softmax(s, -1)
         biased = s + jax.lax.stop_gradient(score_bias.astype(jnp.float32))
         if n_group > 1:
             biased = _group_limited(biased, n_group, topk_group)
-        _, idx = jax.lax.top_k(biased, top_k)
-        w = jnp.take_along_axis(s, idx, axis=-1)
+        idx = checkpoint_name(jax.lax.top_k(biased, top_k)[1], _CHOICE)
+        w = checkpoint_name(jnp.take_along_axis(s, idx, axis=-1), _PICKED)
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
     return idx, w
 
@@ -236,18 +268,24 @@ def piece_plan(idx, first_expert, held, total):
     a group's tokens ascend), ahead of the pairs routed elsewhere; each
     expert's rows; and the smallest of the static row counts
     (:func:`_rungs`) that holds every live row. The layer runs exactly
-    ``rungs[rung]`` rows: this function is the whole of that decision."""
+    ``rungs[rung]`` rows: this function is the whole of that decision.
+    ``order``, ``sizes`` and ``rung`` are named (:data:`KEPT_NAMES`), so a
+    recomputed caller whose policy keeps them sorts once; ``n_live`` is
+    the sizes' sum, made again."""
     rungs = _rungs(idx.size, held, total)
     with jax.named_scope("moe.dispatch"):
         local = idx.reshape(-1) - first_expert
         # pairs of experts held elsewhere sort behind the last group
         key = jnp.where((local >= 0) & (local < held), local, held)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
-                        dtype=jnp.int32)
+        order = checkpoint_name(
+            jnp.argsort(key, stable=True).astype(jnp.int32), _ORDER)
+        sizes = checkpoint_name(
+            jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32), _SIZES)
         n_live = jnp.sum(sizes)
-        rung = jnp.sum(n_live > jnp.asarray(rungs[:-1], jnp.int32),
-                       dtype=jnp.int32)
+        rung = checkpoint_name(
+            jnp.sum(n_live > jnp.asarray(rungs[:-1], jnp.int32),
+                    dtype=jnp.int32), _RUNG)
     return PiecePlan(order, sizes, n_live, rung, rungs)
 
 

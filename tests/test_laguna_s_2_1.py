@@ -34,7 +34,7 @@ from benchmark.models import laguna_s_2_1 as model
 from benchmark.reference import common as ref_common
 from benchmark.reference import laguna_s_2_1 as ref
 
-from _jaxpr_count import calls, differentiated
+from _jaxpr_count import calls, differentiated, router_ops
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
@@ -308,17 +308,27 @@ def test_a_recomputed_block_runs_its_kernels_forward_once(monkeypatch):
     in the jaxpr once for each backward: their second forward holds none
     (``hybrid_lm.kept_policy``; under a bare checkpoint each stood twice,
     as the three windowed layers' still does: their outputs are let go,
-    0.92 GB the cell's set-up has no room for)."""
+    0.92 GB the cell's set-up has no room for). The four routed layers'
+    routers stand once each (``moe.KEPT_NAMES``)."""
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     cfg = dict(CFG, seq_len=128, items_per_sample=128)
     net = model.build(cfg, SPECS, ref_common.init_params(SPECS, 5))
     model._FIRST.clear()
     telemetry.reset_metric("train_step.blocks_recomputed")
     x, y = ref.sample_inputs(cfg, jax.random.PRNGKey(9), 2)
-    got = calls(differentiated(net, _loss_fn(), x, y))
+    closed = differentiated(net, _loss_fn(), x, y)
+    got = calls(closed)
     assert telemetry.value("train_step.blocks_recomputed") == 5
     assert got["flash_attention_fwd"] == got["flash_attention_bwd"] == 2
     assert (got["flash_window_fwd"], got["flash_window_bwd"]) == (6, 3)
+    # and it routes once: one choice over all the experts, one sort, one
+    # router product and one gather of the picked scores for each routed
+    # layer, none in the second forward
+    routed = router_ops(closed, cfg["num_experts"])
+    n_routed = sum(s[0].endswith("_moe_router_weight") for s in SPECS)
+    assert n_routed > 0 and routed == {
+        "top_k.full": n_routed, "sort": n_routed, "score": n_routed,
+        "picked": n_routed, "top_k": n_routed}, routed
 
 
 # --------------------------------------------------------------- rotary

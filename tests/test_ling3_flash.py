@@ -33,7 +33,7 @@ from benchmark.models import ling3_flash as model
 from benchmark.reference import common as ref_common
 from benchmark.reference import ling3_flash as ref
 
-from _jaxpr_count import calls, differentiated, traced_loss
+from _jaxpr_count import calls, differentiated, router_ops, traced_loss
 
 kda = importlib.import_module("mxtpu.ops.pallas.kda")
 short_filter = importlib.import_module("mxtpu.ops.pallas.short_filter")
@@ -641,7 +641,9 @@ def test_a_recomputed_block_runs_its_kernels_forward_once(monkeypatch, path):
     wants its keys in 128s);
     ``plain``: as tier-1 runs the model off the chip, where a KDA call is
     a scan over chunks, which its backward rule differentiates: one
-    forward scan in the value, one in the rule, one reversed."""
+    forward scan in the value, one in the rule, one reversed. Either way
+    a routed layer's router stands once (``moe.KEPT_NAMES``): its product,
+    its choice, the group limit's two ``top_k``s and the plan's sort."""
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", str(int(path == "kernels")))
     cfg = dict(CFG, seq_len=128, items_per_sample=128) \
         if path == "kernels" else CFG
@@ -649,7 +651,8 @@ def test_a_recomputed_block_runs_its_kernels_forward_once(monkeypatch, path):
     model._FIRST.clear()
     telemetry.reset_metric("train_step.blocks_recomputed")
     x, y = ref.sample_inputs(cfg, jax.random.PRNGKey(9), 2)
-    got = calls(differentiated(net, _loss_fn(), x, y))
+    closed = differentiated(net, _loss_fn(), x, y)
+    got = calls(closed)
     kinds = ref.kinds(cfg)
     n_kda = kinds.count("kda")
     assert telemetry.value("train_step.blocks_recomputed") == len(kinds)
@@ -659,13 +662,21 @@ def test_a_recomputed_block_runs_its_kernels_forward_once(monkeypatch, path):
             == len(kinds) - n_kda > 0
     else:
         assert (got["scan"], got["scan.reverse"]) == (2 * n_kda, n_kda)
+    # and it routes once: one choice over all the experts, one sort, one
+    # router product and one gather of the picked scores for each routed
+    # layer, none in the second forward
+    routed = router_ops(closed, cfg["num_experts"])
+    n_routed = sum(s[0].endswith("_moe_router_weight") for s in SPECS)
+    assert n_routed > 0 and routed == {
+        "top_k.full": n_routed, "sort": n_routed, "score": n_routed,
+        "picked": n_routed, "top_k": 3 * n_routed}, routed
 
 
 @pytest.mark.parametrize("path", ["plain", "kernels"])
 def test_the_names_lower_to_nothing_without_a_checkpoint(monkeypatch, path):
-    """lfm2's model, which calls the flash kernel and builds no
-    checkpoint: its lowered step is the text it is with the names patched
-    to identities, on the plain path and through the kernels (the
+    """lfm2's model, which calls the flash kernel and the routed layer and
+    builds no checkpoint: its lowered step is the text it is with the
+    names (the kernel's and the router's) patched to identities, on the plain path and through the kernels (the
     interpreter, 128 positions)."""
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", str(int(path == "kernels")))
     older = importlib.import_module("benchmark.models.lfm2_8b_a1b")
@@ -691,11 +702,12 @@ def test_the_names_lower_to_nothing_without_a_checkpoint(monkeypatch, path):
     named = text()
     assert fa.DISPATCH_STATS["pallas" if path == "kernels" else "xla"] > 0
     seen = []
-    for module in (fa, kda):
+    moe = importlib.import_module("mxtpu.parallel.moe")
+    for module in (fa, kda, moe):
         monkeypatch.setattr(module, "checkpoint_name",
                             lambda x, name: seen.append(name) or x)
     assert text() == named
-    assert set(seen) == set(fa.KEPT_NAMES)
+    assert set(seen) == set(fa.KEPT_NAMES + moe.KEPT_NAMES)
 
 
 @pytest.mark.parametrize("name", ["lfm2_8b_a1b"])

@@ -31,7 +31,7 @@ from benchmark.models import qwen3_next_80b_a3b as model
 from benchmark.reference import common as ref_common
 from benchmark.reference import qwen3_next_80b_a3b as ref
 
-from _jaxpr_count import calls, differentiated, traced_loss
+from _jaxpr_count import calls, differentiated, router_ops, traced_loss
 
 kda = importlib.import_module("mxtpu.ops.pallas.kda")
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -226,15 +226,26 @@ def test_the_step_counts_what_it_traced(program_grads):
 def test_a_recomputed_block_runs_the_rule_forward_once(monkeypatch):
     """Under ``recompute`` the kept names (read from the kernel file) hold
     the rule's output and chunk states: the differentiated step has three
-    ``gdn_fwd`` for its three layers, not six."""
+    ``gdn_fwd`` for its three layers, not six; and (from
+    ``moe.KEPT_NAMES``) what the four routers decided: four ``top_k``s,
+    four sorts and four router products, not eight."""
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     leaves = ref_common.init_params(SPECS, 5)
     net = model.build(CFG, SPECS, leaves)
     model._FIRST.clear()
     x, y = ref.sample_inputs(CFG, jax.random.PRNGKey(9), 1)
-    found = calls(differentiated(net, _loss_fn(), x, y))
+    closed, cfg = differentiated(net, _loss_fn(), x, y), CFG
+    found = calls(closed)
     assert found["gdn_fwd"] == 3 and found["gdn_bwd"] == 3, found
     assert kda.GDN_KEPT_NAMES == ("gdn_o", "gdn_states")
+    # and it routes once: one choice over all the experts, one sort, one
+    # router product and one gather of the picked scores for each routed
+    # layer, none in the second forward
+    routed = router_ops(closed, cfg["num_experts"])
+    n_routed = sum(s[0].endswith("_moe_router_weight") for s in SPECS)
+    assert n_routed > 0 and routed == {
+        "top_k.full": n_routed, "sort": n_routed, "score": n_routed,
+        "picked": n_routed, "top_k": n_routed}, routed
 
 
 # --------------------------------------------------------- the share's tie

@@ -21,32 +21,38 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# recorded at PR 43 on both trees (the parent's, PR 42's, and the change's:
-# the same eight); ResNet's and BERT's are the ones PR 42 recorded
+# recorded at PR 49 on both trees, of the text with its private functions
+# counted anew (``step_text_hash.renumbered``): the parent's (PR 48's) and
+# the change's give the same seven below, which build no checkpoint. Their
+# raw texts differ in those numbers alone (``@_where_138`` ->
+# ``@_where_140``): the routed layer now names what its router decided
+# (``moe.KEPT_NAMES``), a name lowers to nothing, and MLIR's counter counts
+# it all the same
 RECORDED = {
     "resnet50_v1.train_b128":
-        "4d324d72e998330d43a5d274780dadd27311abd272a228cc7b853dc18155a2f3",
+        "124a0b1288c7adc099f18606ce942ef5740d6a9e74a62067ab0bd6b2715ba07a",
     "bert_base.train_b16_s512":
-        "5ad2020ef74b07df4e3028b3fbd00048ba31483ab93f3deb219e56f4cabeb0d6",
+        "94067e0637717ed0172e52c6358ad0368d9f7e4a9a752a9194dba44e3e798535",
     "kanana2_30b_a3b.train_b1_s8192":
-        "2dcb6a11a9364caa099bde42c68fbe056a87bc41959a44123f7b123f35626361",
+        "9f70b4d6ed5f5cf25b51b17472abc85ba560f230d72ce3096152e5e6d8282d7b",
     "lfm2_8b_a1b.train_b2_s8192":
-        "22ed49a71549d9551a2424e6ea0e66f4f15df447baf8de8f91fa7aca17720e23",
+        "34aa02d9f08978bc70cb192f28cfbe7d2fa6fc285d9b98295c7dde69dc5f979d",
     "smallthinker_21b_a3b.train_b1_s16384":
-        "57dd39bcefcb71d5a3c441462446db5dd3a17bd070e7cb7919818dd9c5e5dac3",
+        "63aa10c43bb21e1fc4dab6039f0a5e4e93ab878bced385562a2017e45f60354f",
     "resnet50_v1.train_dp4_b512":
-        "4ee981a0809c3ec0e498ab322750ae81d14e71192e8ca07bfe3ccfc735b0b752",
+        "a730bc03dd6dea4dc7b9c52b32319b1137610477a4fe72064af8a879af13088a",
     "keye_vl2_30b_a3b.train_b1_s16384":
-        "d47ba0a9122a75e39b2809266ac7ea1b2201440e875d621f0d2530dff1db963a",
-    # the two cells whose blocks are recomputed, recorded at PR 46: their
-    # checkpoints keep what the kernels' forwards name
-    # (``hybrid_lm.kept_policy``), so the backward's second forward lost its
-    # attention and KDA calls. The seven above build no checkpoint and are
-    # PR 45's still
+        "18e110a421e37ed353e547a7272622b9173e0baf62f3828bc8951c2d26a64ddd",
+    # the cells whose blocks are recomputed (qwen3_next's in its own file),
+    # which MOVED in PR 49: their checkpoints keep what the kernels'
+    # forwards and the routers name (``hybrid_lm.kept_policy``), so the
+    # backward's second forward lost its attention and KDA calls (PR 46) and
+    # its router products, ``top_k``s and sorts (PR 49). The parent's read
+    # d83b666d... and 6d1f5d85... under the same renumbering
     "ling3_flash.train_b1_s8192":
-        "e95aeb1ed8abece6bdb0fd5c76dd36e65f6baa358d0e8a1a303031e89b5cf796",
+        "884fc13007d58338c2aae9eeaa1c3b41e31e9e0a4bad1c744190202de85ce351",
     "laguna_s_2_1.train_b1_s16384":
-        "4e2210359e614e0ef53439995fd46efe179bd81018e3521ae1cdc23bcc4707be",
+        "5b84279d81fad8b0c402cca346ff545118672ec4de89d47094e47f887502a972",
     # ``qwen3_next_80b_a3b.train_b1_s16384`` (PR 48) is recorded in a file
     # of its own, ``tests/test_step_text_hash_qwen3_next.py``: a case is a
     # whole rehearsal set-up, and the run cannot end before its longest file
@@ -87,3 +93,21 @@ def test_a_cells_step_lowers_to_the_recorded_text(name, capsys, monkeypatch):
     filtered = telemetry.value("kda_conv.calls")
     assert (filtered > 0) == name.startswith(("ling3_flash", "qwen3_next"))
     assert telemetry.value("kda_conv.pallas") == 0 and "kda_conv_" not in text
+
+
+@pytest.mark.parametrize("text,same", [
+    # two lowerings more ahead of them move MLIR's numbers and nothing else
+    ("call @_where_140(%1) call @_where_152(%2) call @_where_140(%3) "
+     "func @_where_140 func @_where_152 call @clip(%4)", True),
+    # another function called at a site is another program
+    ("call @_where_138(%1) call @_where_148(%2) call @_where_148(%3) "
+     "func @_where_138 func @_where_148 call @clip(%4)", False),
+], ids=["renumbered", "another-callee"])
+def test_private_functions_are_counted_anew(text, same):
+    was = ("call @_where_138(%1) call @_where_148(%2) call @_where_138(%3) "
+           "func @_where_138 func @_where_148 call @clip(%4)")
+    renumbered = _tool().renumbered
+    assert renumbered(was) == (
+        "call @_where_1(%1) call @_where_2(%2) call @_where_1(%3) "
+        "func @_where_1 func @_where_2 call @clip(%4)")
+    assert (renumbered(text) == renumbered(was)) == same

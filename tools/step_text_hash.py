@@ -9,20 +9,43 @@ that is meant to leave a cell alone can show it before any chip time.
 (every cell of ``BENCHMARK.json`` by default; a four-chip cell wants
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4``). One line a cell:
 its name, the text's length, the hash.
+
+The private functions of one name are counted anew (:func:`renumbered`):
+the numbers MLIR gives them are no part of the program.
 """
 import hashlib
 import json
 import os
+import re
 import sys
 
 
+def renumbered(text):
+    """``text`` with the private functions of one name numbered 1, 2, ...
+    in the order they first appear. MLIR tells them apart by a counter that
+    every lowering of the module before them has moved (JAX lowers each new
+    primitive, shape and parameters as a function of the primitive's name
+    and inlines it), so ``@_where_138`` becomes ``@_where_140`` when two
+    ``checkpoint_name`` equations, which lower to nothing, stand ahead of
+    it: the same program under another sha256 (PERF.md section 6, PR 46 and
+    PR 49)."""
+    seen = {}
+
+    def count(match):
+        numbers = seen.setdefault(match.group(1), {})
+        return "%s_%d" % (match.group(1), numbers.setdefault(
+            match.group(2), len(numbers) + 1))
+    return re.sub(r"(@[A-Za-z_]\w*?)_(\d+)\b", count, text)
+
+
 def step_text(name):
-    """The lowered text (no locations) of cell ``name``'s step, built as
-    the cell's runner builds it at rehearsal sizes."""
+    """The lowered text (no locations, :func:`renumbered`) of cell
+    ``name``'s step, built as the cell's runner builds it at rehearsal
+    sizes."""
     from benchmark import run
     cell = run.Cell(name, rehearse=True)
     step = cell.module("runners").setup(cell, 7)["step"]
-    return step._jit.lower(*step._last_abstract).as_text()
+    return renumbered(step._jit.lower(*step._last_abstract).as_text())
 
 
 def main(argv):
