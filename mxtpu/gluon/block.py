@@ -132,6 +132,8 @@ class _BlockScope(threading.local):
 
     @staticmethod
     def create(prefix, params, hint):
+        """-> (the block's full prefix, its ParameterDict, its own name:
+        the prefix less the enclosing block's)."""
         current = getattr(_BlockScope._current, "value", None)
         if current is None:
             if prefix is None:
@@ -141,7 +143,7 @@ class _BlockScope(threading.local):
                 params = ParameterDict(prefix)
             else:
                 params = ParameterDict(params.prefix, params)
-            return prefix, params
+            return prefix, params, prefix
         if prefix is None:
             count = current._counter.get(hint, 0)
             current._counter[hint] = count + 1
@@ -151,7 +153,7 @@ class _BlockScope(threading.local):
             params = ParameterDict(parent.prefix + prefix, parent._shared)
         else:
             params = ParameterDict(params.prefix, params)
-        return current._block.prefix + prefix, params
+        return current._block.prefix + prefix, params, prefix
 
     def __enter__(self):
         if self._block._empty_prefix:
@@ -184,7 +186,10 @@ class Block:
 
     def __init__(self, prefix=None, params=None):
         self._empty_prefix = prefix == ""
-        self._prefix, self._params = _BlockScope.create(
+        # ``_own_name``: what ``__call__`` names the block's operations in
+        # a traced region (a block without a prefix takes its key among
+        # its parent's children, ``register_child``)
+        self._prefix, self._params, self._own_name = _BlockScope.create(
             prefix, params, self._alias())
         self._name = self._prefix[:-1] if self._prefix.endswith("_") else self._prefix
         self._scope = _BlockScope(self)
@@ -249,6 +254,8 @@ class Block:
         if name is None:
             name = str(len(self._children))
         self._children[name] = block
+        if not block._own_name:
+            block._own_name = name
 
     def register_forward_hook(self, hook):
         handle = _HookHandle(self._forward_hooks)
@@ -323,7 +330,18 @@ class Block:
     def __call__(self, *args):
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
-        out = self.forward(*args)
+        if _IN_TRACE.active and self._own_name:
+            # a block's name is a scope: every operation traced here
+            # carries the model's own path in its metadata
+            # (``.../h_/decoderblock3_/attn_/q_/dot_general``, under
+            # ``transpose(jvp(...))`` in a backward, ``rematted_computation``
+            # in a recomputed forward), which ``xprof.step_operations``
+            # reads back from the executable. Names only: no operation
+            # changes, and the eager path pays nothing
+            with jax.named_scope(self._own_name):
+                out = self.forward(*args)
+        else:
+            out = self.forward(*args)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
         return out

@@ -483,9 +483,13 @@ class HybridLM(HybridBlock):
             return self.head(self.norm_f(self.blocks(self.embed(tokens))))
         from ... import telemetry
         x, kept = self.embed(tokens), kept_policy()
-        for block in self.blocks._children.values():
-            telemetry.inc("train_step.blocks_recomputed")
-            x = NDArray(jax.checkpoint(
-                lambda data, block=block: block(NDArray(data))._data,
-                policy=kept)(x._data))
+        # the blocks are called one by one, not through the stack: its
+        # name is given here, so that a layer's path reads the same
+        # (``h_/decoderblock3_/...``) recomputed or not
+        with jax.named_scope(self.blocks._own_name):
+            for block in self.blocks._children.values():
+                telemetry.inc("train_step.blocks_recomputed")
+                x = NDArray(jax.checkpoint(
+                    lambda data, block=block: block(NDArray(data))._data,
+                    policy=kept)(x._data))
         return self.head(self.norm_f(x))

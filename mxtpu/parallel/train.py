@@ -253,7 +253,6 @@ class ShardedTrainStep:
         self._in_fmt = None
         self._in_sig = None
         self._policy = None
-        self._last_abstract = None
 
     # ------------------------------------------------------------- placement
     def _place(self, data, sharding, local=False):
@@ -316,17 +315,10 @@ class ShardedTrainStep:
             in_specs = [P(self._data_axis)] * n_inputs
         self._in_shardings = [NamedSharding(mesh, s) for s in in_specs]
 
-    def _build(self, in_fmt, n_inputs, example_args=None):
-        from ..ops.registry import policy_key
-        # retrace watchdog: one compile per batch structure — after the
-        # first step this site must stay flat (an in_fmt change means the
-        # caller reshaped its batch pytree mid-run); recorded at the
-        # bottom of this builder where the finished jit can ride
-        # compiled= into the xprof ledger
-        retrace_prov = {
-            "block": type(self._block).__name__, "n_inputs": n_inputs,
-            "donate": bool(self._donate),
-            "policy_key": list(policy_key())}
+    def _jitted(self, in_fmt, n_inputs):
+        """The whole step as a plain ``jax.jit`` over this instance's
+        shardings: what :meth:`_build` hands the compile service to lower
+        and compile, and what :meth:`lowered` lowers anew."""
         trainable = self._trainable
         loss_blk, forward = self._loss, self._forward
         rule, static = self._rule, self._static
@@ -401,23 +393,37 @@ class ShardedTrainStep:
                         new_datas[i] = a.astype(new_datas[i].dtype)
             return new_datas, new_states, loss_val
 
-        mesh = self._mesh
-        repl = NamedSharding(mesh, P())
+        repl = NamedSharding(self._mesh, P())
         self._resolve_in_shardings(n_inputs)
-        donate = (0, 1) if self._donate else ()
+        return jax.jit(
+            sharded_train_step,
+            in_shardings=(self._param_shardings,
+                          list(self._state_shardings),
+                          None, None, self._in_shardings),
+            out_shardings=(self._param_shardings,
+                           list(self._state_shardings),
+                           repl),
+            donate_argnums=(0, 1) if self._donate else ())
 
-        def build():
-            return jax.jit(
-                sharded_train_step,
-                in_shardings=(self._param_shardings,
-                              list(self._state_shardings),
-                              None, None, self._in_shardings),
-                out_shardings=(self._param_shardings,
-                               list(self._state_shardings),
-                               repl),
-                donate_argnums=donate)
-
+    def _build(self, in_fmt, n_inputs, example_args=None):
+        """Resolve the step through the compile service, ahead of time:
+        with placed example arguments the service traces, lowers and
+        compiles ONCE (what a plain jit's first call does) and hands back
+        that ``Compiled`` (the executable ledger keeps it with its
+        operation table, ``xprof.step_operations``); without them (a step
+        called under an outer trace) a plain jit, as before."""
         from .. import compile_service as csvc
+        from ..ops.registry import policy_key
+        # retrace watchdog: one compile per batch structure — after the
+        # first step this site must stay flat (an in_fmt change means the
+        # caller reshaped its batch pytree mid-run); the service reports
+        # the miss with the finished executable riding compiled= into the
+        # xprof ledger
+        retrace_prov = {
+            "block": type(self._block).__name__, "n_inputs": n_inputs,
+            "donate": bool(self._donate),
+            "policy_key": list(policy_key())}
+        loss_blk, forward = self._loss, self._forward
         in_shapes = None
         if example_args is not None:
             in_shapes = tuple((tuple(d.shape), str(d.dtype))
@@ -431,8 +437,9 @@ class ShardedTrainStep:
                 else "-",
                 csvc.source_token(forward) if forward is not None
                 else "-"),
-            signature=(tuple(in_fmt), n_inputs, in_shapes, repr(static),
-                       type(self._opt).__name__, tuple(trainable),
+            signature=(tuple(in_fmt), n_inputs, in_shapes,
+                       repr(self._static), type(self._opt).__name__,
+                       tuple(self._trainable),
                        self._wd,
                        tuple((tuple(d.shape), str(d.dtype))
                              for d in self._param_datas)),
@@ -444,12 +451,14 @@ class ShardedTrainStep:
                       tuple(str(s) for s in self._param_shardings),
                       tuple(repr(jax.tree_util.tree_map(str, s))
                             for s in self._state_shardings)),
-            donation=donate, device=csvc.device_token(mesh=mesh),
+            donation=(0, 1) if self._donate else (),
+            device=csvc.device_token(mesh=self._mesh),
             nonce=csvc.instance_nonce(self))
         entry = csvc.get_or_build(
-            key, build, provenance=retrace_prov,
+            key, lambda: self._jitted(in_fmt, n_inputs),
+            provenance=retrace_prov,
             example_args=csvc.concrete_args(example_args)
-            if example_args is not None else None)
+            if example_args is not None else None, aot=True)
         return entry.fn
 
     def _plan_fingerprint(self):
@@ -510,8 +519,10 @@ class ShardedTrainStep:
             # placement consumed) commits only on SUCCESS — a transient
             # build failure must not leave a stale-policy executable or
             # mismatched shardings looking current on the next step.
-            # The span covers the first call of what was built too: a
-            # plain jit traces, lowers and compiles on that dispatch
+            # The span covers the first call of what was built too. The
+            # service builds ahead of time: the one Python trace, lowering
+            # and compile of the step lie in the build, and the first call
+            # runs the ``Compiled`` it will run ever after
             with telemetry.span("train_step.build"):
                 try:
                     self._jit = self._build(in_fmt, len(in_datas),
@@ -522,10 +533,6 @@ class ShardedTrainStep:
                 self._in_fmt = in_fmt
                 self._policy = policy
                 self._in_sig = in_sig
-                # abstract shapes for compiled_step_flops; invariant per
-                # (in_fmt, shapes), so captured on the rebuild alone
-                self._last_abstract = jax.tree_util.tree_map(
-                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
                 new_datas, new_states, loss = self._jit(*args)
         else:
             with telemetry.span("train_step.launch"):
@@ -549,19 +556,29 @@ class ShardedTrainStep:
         return [st for st, t in zip(self._opt_states, self._trainable) if t]
 
     def compiled(self):
-        """The step's compiled executable (``as_text()``,
-        ``cost_analysis()``, ``memory_analysis()``). Requires at least one
-        __call__ (shapes must be known). A plain jit is re-lowered at the
-        captured abstract signature — one extra compile, which jax's
-        persistent cache serves when it is on."""
-        if self._jit is None or self._last_abstract is None:
+        """The step's compiled executable, the very one that runs
+        (``as_text()``, ``cost_analysis()``, ``memory_analysis()``): the
+        handle the compile service built ahead of time in the first call's
+        ``train_step.build``. Nothing is traced, lowered or compiled here.
+        Requires at least one __call__."""
+        if self._jit is None:
             raise MXNetError("run at least one step before asking for the "
                              "compiled step")
-        if hasattr(self._jit, "cost_analysis"):
-            # the compile service handed back an AOT executable (disk-warm
-            # or spill path): its own analyses are the exact HLO that runs
-            return self._jit
-        return self._jit.lower(*self._last_abstract).compile()
+        if not hasattr(self._jit, "cost_analysis"):
+            raise MXNetError("this step was built under an outer trace and "
+                             "runs a plain jit: it holds no executable")
+        return self._jit
+
+    def lowered(self):
+        """The step traced and lowered ANEW at the signature it last ran
+        at (``as_text()`` is its StableHLO, ``as_text(debug_info=True)``
+        with the names): one more Python trace and lowering, for tools and
+        tests that compare programs. The step's own path never calls it.
+        Requires at least one __call__."""
+        args = jax.tree_util.tree_map(
+            lambda info: jax.ShapeDtypeStruct(info.shape, info.dtype),
+            self.compiled().args_info[0])
+        return self._jitted(self._in_fmt, len(self._in_sig)).lower(*args)
 
     def compiled_step_flops(self):
         """FLOPs of one compiled step per XLA's own cost model.
@@ -569,7 +586,7 @@ class ShardedTrainStep:
         The analog of the reference's per-op FLOP counting in its benchmark
         scripts — but measured on the exact fused HLO that runs, not a
         hand-derived formula. Requires at least one __call__ (shapes must be
-        known); pays one extra (cached-HLO) compile.
+        known); compiles nothing.
         """
         from .. import perf_model
         flops = perf_model.flops_of(self.compiled())

@@ -36,6 +36,18 @@ footprints vs the device limit → ``memory.overcommit``), and a
 per-device memory stats, and (in serving) the KVCacheAccountant view, so
 an HBM OOM leaves a post-mortem instead of just a dead process.
 
+The operation table (:func:`step_operations`): a site whose executable
+arrives compiled (``parallel.train_step`` does: the compile service builds
+it ahead of time) keeps its ``Compiled`` handle in its ledger entry, and on
+first request the entry's table is parsed from ``compiled.as_text()``:
+every instruction of the running executable with its computation, opcode,
+``op_name`` (the ``jax.named_scope`` path it was traced under: the blocks'
+own names, the operators' scopes, the transforms) and the ``conditional`` /
+``while`` it runs inside. Joined with a device trace's seconds by
+instruction name it is the step's time by the program's own names
+(:func:`transform_of`, :func:`scope_path`). Never on the step's path, never
+a trace or a lowering: an entry without a handle answers None.
+
 Gating: ``MXTPU_XPROF=0`` skips the wrap at compile-record time (a
 construction-time lever like ``MXTPU_SERVE_INT8`` — flipping it mid-run
 affects new compiles, not executables already cached) and disables the
@@ -48,6 +60,7 @@ import itertools
 import logging
 import numbers
 import os
+import re
 import threading
 import time
 
@@ -57,7 +70,8 @@ __all__ = ["enabled", "memwatch_interval", "attach", "watch", "ledger",
            "ledger_snapshot", "resolve", "executed_flops", "summary",
            "device_memory", "poll_memory", "ensure_memwatch",
            "stop_memwatch", "preflight", "site_footprint", "is_oom",
-           "oom_flight",
+           "oom_flight", "operation_table", "step_operations",
+           "transform_of", "scope_path",
            "MFUMeter", "TRAIN_SITES", "reset"]
 
 _log = logging.getLogger("mxtpu.xprof")
@@ -313,9 +327,10 @@ def _resolve_entry(entry):
 
 
 def _resolve_entry_locked(entry):
-    pre = entry.pop("_compiled", None)
+    pre = entry.get("_compiled")
     if pre is not None:
-        # the AOT handle was captured at attach time: no re-lowering
+        # the AOT handle was captured at attach time (and stays: the
+        # operation table is read from it): no re-lowering
         try:
             _fill_from_compiled(entry, pre)
         except Exception as e:  # noqa: BLE001 — diagnostics degrade
@@ -339,6 +354,123 @@ def _resolve_entry_locked(entry):
     except Exception as e:  # noqa: BLE001 — diagnostics degrade, never kill
         entry["error"] = "%s: %s" % (type(e).__name__, e)
     entry["resolved"] = True
+
+
+# ---------------------------------------------------------- operation table
+_HLO_HEAD = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$")
+_HLO_INSTR = re.compile(r"^\s*(ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# the computations an instruction runs: a fusion's and a call's (``calls``,
+# ``to_apply``), a switch's branches, a loop's condition and body
+_HLO_CALLEES = re.compile(
+    r"(?:calls|to_apply|condition|body|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+# the operations whose computations' instructions are events of a device
+# trace in their own right, nested in the operation's own event
+CONTROL_FLOW = ("conditional", "while", "call")
+
+
+def operation_table(text):
+    """A compiled module's text (``Compiled.as_text()``) -> {instruction:
+    {"computation", "opcode", "op_name", "inside"}}: the computation that
+    holds the instruction, its opcode, the ``op_name`` of its metadata
+    ("" where it has none; a fusion without one of its own takes its fused
+    root's, or failing that the last one inside it), and the ``conditional``
+    / ``while`` / ``call`` instruction whose branch or body holds it (None
+    in the entry computation, and in a computation only a fusion or a
+    reduction applies: those instructions are no events of a trace).
+    Instruction names are the compiler's: they match a trace of the same
+    executable and no other."""
+    table, members, caller, fused = {}, {}, {}, {}
+    comp = None
+    for line in text.splitlines():
+        head = _HLO_HEAD.match(line)
+        if head:
+            comp = head.group(2)
+            continue
+        instr = _HLO_INSTR.match(line)
+        if comp is None or not instr:
+            continue
+        root, name, opcode = instr.groups()
+        op_name = _HLO_OP_NAME.search(line)
+        table[name] = {"computation": comp, "opcode": opcode,
+                       "op_name": op_name.group(1) if op_name else "",
+                       "inside": None}
+        members.setdefault(comp, []).append((name, bool(root)))
+        for one, several in _HLO_CALLEES.findall(line):
+            for callee in [one] if one else re.findall(r"[\w.\-]+", several):
+                if opcode == "fusion":
+                    fused[name] = callee
+                elif opcode in CONTROL_FLOW:
+                    caller[callee] = name
+    for name, callee in fused.items():
+        if not table[name]["op_name"]:
+            inner = members.get(callee, ())
+            named = [table[n]["op_name"] for n, root in inner if root] + [
+                table[n]["op_name"] for n, _ in reversed(inner)]
+            table[name]["op_name"] = next((o for o in named if o), "")
+    for name, row in table.items():
+        row["inside"] = caller.get(row["computation"])
+    return table
+
+
+def transform_of(op_name):
+    """Which part of a training step an ``op_name`` was traced under:
+    ``"optimizer"`` (the step's own scope), ``"recomputed"`` (a
+    ``jax.checkpoint``'s second forward, ``rematted_computation``),
+    ``"backward"`` (``transpose(...)``), else ``"forward"``; None for an
+    operation without a name."""
+    if "/" not in op_name:          # none, or an argument's own name
+        return None
+    if "/optimizer/" in op_name or op_name.endswith("/optimizer"):
+        return "optimizer"
+    if "rematted_computation" in op_name:
+        return "recomputed"
+    if "transpose(" in op_name:
+        return "backward"
+    return "forward"
+
+
+def scope_path(op_name):
+    """An ``op_name``'s words in order: a scope stands between slashes or
+    inside a transform's brackets (``transpose(jvp(kda_conv))/jit(_backward)
+    /mul`` -> transpose, jvp, kda_conv, jit, _backward, mul), so a block's
+    name (it ends in ``_``, or is its key among its parent's children) and
+    an operator's scope are found the same way in the forward, the
+    recomputed forward and the backward."""
+    return re.findall(r"[\w.\-]+", op_name)
+
+
+# the parse takes a second or two of a large step's text: its own lock, so
+# that an MFU meter's resolve on another thread never waits for it
+_TABLE_LOCK = threading.Lock()
+
+
+def _newest(site):
+    with _LOCK:
+        dq = _SITES.get(site)
+        return dq[-1] if dq else None
+
+
+def step_operations(site="parallel.train_step"):
+    """:func:`operation_table` of the executable ``site`` runs (its newest
+    ledger entry's), parsed on first request from the handle the entry
+    holds and kept with it; None where the entry holds no ``Compiled``.
+    Host work on text the compiler already has: nothing is traced, lowered
+    or compiled, and the step object need not be alive."""
+    entry = _newest(site)
+    if entry is None or entry.get("_compiled") is None:
+        return None
+    with _TABLE_LOCK:
+        if "_operations" not in entry:
+            t0 = time.perf_counter()
+            text = entry["_compiled"].as_text()
+            entry["_operations"] = operation_table(text)
+            entry["operations"] = {
+                "instructions": len(entry["_operations"]),
+                "text_bytes": len(text),
+                "parse_s": time.perf_counter() - t0}
+        return entry["_operations"]
 
 
 def _public(entry):

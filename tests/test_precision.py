@@ -70,7 +70,7 @@ def test_whole_resnet_step_precision():
     step = ShardedTrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
                             data_parallel_mesh(), optimizer="sgd")
     step(x, y)
-    txt = step._jit.lower(*step._last_abstract).as_text()
+    txt = step.lowered().as_text()
     convs = re.findall(r"convolution.*", txt)
     assert convs
     bad = [c for c in convs if "HIGHEST" in c]

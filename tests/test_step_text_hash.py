@@ -13,9 +13,11 @@ and the plain path is lowered, so a kernel PR moves none of these, its own
 cell's included; what it lowers for the chip is ``test_tpu_compile.py``'s.
 A file of its own: a case is a whole rehearsal set-up, and the tier-1 run
 hands out work by file."""
+import functools
 import hashlib
 import importlib.util
 import os
+import re
 
 import pytest
 
@@ -67,21 +69,58 @@ def _tool():
     return mod
 
 
-@pytest.mark.parametrize("name", sorted(RECORDED))
-def test_a_cells_step_lowers_to_the_recorded_text(name, capsys, monkeypatch):
+@functools.lru_cache(maxsize=None)
+def _built(name):
+    """One rehearsal set-up a cell for both tests below: the step's lowered
+    text, the operation table of the executable it ran
+    (``xprof.step_operations``), its blocks' paths and the kernel counters
+    its trace moved; the step itself is freed."""
+    from mxtpu import telemetry, xprof
+    for counter in ("kda_conv.calls", "kda_conv.pallas"):
+        telemetry.reset_metric(counter)
+    step, net = _tool().step_of(name)
+    counted = (telemetry.value("kda_conv.calls"),
+               telemetry.value("kda_conv.pallas"))
+    events = len(telemetry.events())
+    handle = step.compiled()
+    table = xprof.step_operations()
+    # reading the executable and its table traced and lowered nothing
+    assert [e[0] for e in telemetry.events()[events:]] == []
+    assert handle.as_text().startswith("HloModule jit_sharded_train_step")
+    text = _tool().step_text(name, step)
+    return text, table, _paths(net), bool(getattr(net, "_recompute", 0)), \
+        counted
+
+
+def _paths(block, above=()):
+    """The path of every block under ``block`` that holds parameters of its
+    own, as ``Block.__call__`` names it: own names from the top's children
+    down."""
+    found = []
+    for child in block._children.values():
+        path = above + (child._own_name,)
+        if child._reg_params:
+            found.append("/".join(path))
+        found += _paths(child, path)
+    return found
+
+
+def _cell(name, monkeypatch):
     import jax
     from benchmark import run
     from benchmark.models import common
-    from mxtpu import telemetry
     from mxtpu.parallel import data_parallel_mesh
     # the tier-1 run has eight devices on the host: the program's mesh is
     # the cell's chips, as the reference's is (``train_steps._devices``)
     chips = run.Cell(name, rehearse=True).chips
     monkeypatch.setattr(common, "data_parallel_mesh",
                         lambda: data_parallel_mesh(jax.devices()[:chips]))
-    for counter in ("kda_conv.calls", "kda_conv.pallas"):
-        telemetry.reset_metric(counter)
-    text = _tool().step_text(name)
+    return _built(name)
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED))
+def test_a_cells_step_lowers_to_the_recorded_text(name, capsys, monkeypatch):
+    text, _table, _blocks, _recomputes, counted = _cell(name, monkeypatch)
     capsys.readouterr()             # the models' notes are the benchmark's
     got = hashlib.sha256(text.encode()).hexdigest()
     assert got == RECORDED[name], (
@@ -90,9 +129,46 @@ def test_a_cells_step_lowers_to_the_recorded_text(name, capsys, monkeypatch):
         % (name, len(text), got))
     # only the two delta-rule cells filter at all, and off the TPU never on
     # a kernel
-    filtered = telemetry.value("kda_conv.calls")
-    assert (filtered > 0) == name.startswith(("ling3_flash", "qwen3_next"))
-    assert telemetry.value("kda_conv.pallas") == 0 and "kda_conv_" not in text
+    assert (counted[0] > 0) == name.startswith(("ling3_flash", "qwen3_next"))
+    assert counted[1] == 0 and "kda_conv_" not in text
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED))
+def test_a_cells_table_holds_every_blocks_path(name, capsys, monkeypatch):
+    """The executable the cell's step ran names its operations by the
+    model's own path (``Block.__call__``): every block that holds
+    parameters is found under the forward and under the backward
+    (``transpose(``), and where the blocks are recomputed every layer under
+    ``rematted_computation`` too."""
+    from benchmark import step_scopes
+    from mxtpu import xprof
+    _text, table, blocks, recomputes, _counted = _cell(name, monkeypatch)
+    capsys.readouterr()
+    assert len(blocks) > 10
+    seen = {"forward": set(), "recomputed": set(), "backward": set()}
+    for row in table.values():
+        transform = xprof.transform_of(row["op_name"])
+        if transform in seen:
+            seen[transform].add(tuple(step_scopes.scopes_of(
+                xprof.scope_path(row["op_name"]))))
+
+    def within(block, scopes):      # in order; operators' scopes between
+        rest = iter(scopes)
+        return all(part in rest for part in block.split("/"))
+
+    for transform in ("forward", "backward"):
+        # (an indexer picks keys: a choice, which takes no gradient)
+        lost = [b for b in blocks
+                if not any(within(b, path) for path in seen[transform])
+                and not (transform == "backward" and b.endswith("/indexer_"))]
+        assert not lost, (transform, lost)
+    assert bool(seen["recomputed"]) == recomputes
+    if recomputes:
+        layers = {step_scopes.kind_of(b.split("/") + ["x"])[1]
+                  for b in blocks if b.startswith("h_/")}
+        again = {step_scopes.kind_of(list(path) + ["x"])[1]
+                 for path in seen["recomputed"]}
+        assert len(layers) > 1 and again == layers
 
 
 @pytest.mark.parametrize("text,same", [

@@ -14,3 +14,8 @@ def test_the_cells_step_lowers_to_the_recorded_text(capsys, monkeypatch):
     monkeypatch.setitem(base.RECORDED, CELL, RECORDED)
     base.test_a_cells_step_lowers_to_the_recorded_text(CELL, capsys,
                                                        monkeypatch)
+
+
+def test_the_cells_table_holds_every_blocks_path(capsys, monkeypatch):
+    base.test_a_cells_table_holds_every_blocks_path(CELL, capsys,
+                                                    monkeypatch)

@@ -197,7 +197,7 @@ def test_device_side_names():
 
 def _stablehlo(step, batch):
     step(*batch)
-    return step._jit.lower(*step._last_abstract).as_text()
+    return step.lowered().as_text()
 
 
 def test_the_scopes_change_no_operation(monkeypatch):
@@ -213,7 +213,7 @@ def test_the_scopes_change_no_operation(monkeypatch):
     named = _step(prefix="a_")
     monkeypatch.undo()
     named(*batch)
-    debug = named._jit.lower(*named._last_abstract).as_text(debug_info=True)
+    debug = named.lowered().as_text(debug_info=True)
     assert "optimizer" in debug and "forward" in debug
 
 

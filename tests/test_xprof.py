@@ -136,7 +136,7 @@ def test_wrapped_jit_forwards_attributes():
         "demo.site", None, compiled=jax.jit(lambda a: a * 2))
     a = jnp.ones((4,), jnp.float32)
     fn(a)
-    # .lower() keeps working through the wrapper (compiled_step_flops path)
+    # .lower() keeps working through the wrapper (an AOT caller's path)
     c = fn.lower(jax.ShapeDtypeStruct((4,), jnp.float32)).compile()
     assert perf_model.flops_of(c) is not None or True  # no raise is the pin
 

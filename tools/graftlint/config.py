@@ -64,6 +64,19 @@ JIT_ALLOWLIST: Dict[Tuple[str, str], Dict[str, str]] = {
                      "registry.policy_key — FusedUpdater._cached_jit; the "
                      "mesh-native Trainer shares this cache",
     },
+    ("mxtpu/parallel/train.py", "_jitted"): {
+        "site": "parallel.train_step",
+        "service": True,
+        "reason": "ShardedTrainStep._jitted only BUILDS the step's jit "
+                  "(``lowered()`` lowers the same function anew for the "
+                  "tools that compare programs); the cache front door is "
+                  "ShardedTrainStep._build, which resolves every miss "
+                  "through compile_service.get_or_build, ahead of time",
+        "cache_key": "canonical_key(site='parallel.train_step', ...) in "
+                     "ShardedTrainStep._build: batch structure and shapes, "
+                     "optimizer rule, parameter shapes, shardings, "
+                     "donation, mesh + registry.policy_key",
+    },
     ("mxtpu/serving/engine.py", "_build_for"): {
         "site": "serving.predict",
         "service": True,

@@ -38,14 +38,21 @@ def renumbered(text):
     return re.sub(r"(@[A-Za-z_]\w*?)_(\d+)\b", count, text)
 
 
-def step_text(name):
-    """The lowered text (no locations, :func:`renumbered`) of cell
-    ``name``'s step, built as the cell's runner builds it at rehearsal
-    sizes."""
+def step_of(name):
+    """Cell ``name``'s train step after its first steps, built as the
+    cell's runner builds it at rehearsal sizes, and its network."""
     from benchmark import run
     cell = run.Cell(name, rehearse=True)
-    step = cell.module("runners").setup(cell, 7)["step"]
-    return renumbered(step._jit.lower(*step._last_abstract).as_text())
+    state = cell.module("runners").setup(cell, 7)
+    return state["step"], state["net"]
+
+
+def step_text(name, step=None):
+    """The lowered text (no locations, :func:`renumbered`) of cell
+    ``name``'s step (``ShardedTrainStep.lowered``: the step traced and
+    lowered anew at the signature it ran at)."""
+    step = step or step_of(name)[0]
+    return renumbered(step.lowered().as_text())
 
 
 def main(argv):

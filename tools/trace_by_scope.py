@@ -1,116 +1,88 @@
 #!/usr/bin/env python
-"""A traced run's device time by ``jax.named_scope``: the benchmark's
-``chiprun_out/benchmark/<cell>/trace_ops.json`` (every operation's seconds
-over the traced stretch) joined with the compiled step's text, whose
-instructions carry their scope in ``metadata={op_name="..."}``. The text is
-the running program's own (``ShardedTrainStep.compiled().as_text()``, which
-JAX's cache serves after set-up): instruction names are the compiler's and
-only match a trace of the same executable.
+"""A traced run's device time by the program's own names, printed. The join
+is the program's and the benchmark's: ``mxtpu.xprof`` keeps the operation
+table of the executable the step runs (every instruction's ``op_name``: the
+blocks' names, the operators' scopes, the transforms), and a traced run of
+the benchmark joins it with the trace's seconds by instruction and writes
+``chiprun_out/benchmark/<cell>/step_by_scope.json``
+(``benchmark/step_scopes.py``: a switch or a loop by its self time, what
+runs inside it under its own name). This prints that file:
 
-    python tools/trace_by_scope.py trace_ops.json step_hlo.txt [scope ...]
+    python tools/trace_by_scope.py step_by_scope.json [--by-transform]
 
-Prints ms a step: every operation of the entry computation under the
-innermost of the named scopes its ``op_name`` holds (the model zoo's and
-the operators' by default), else under ``(backward)``, ``(recomputed)`` or
-``(forward)`` by the transform it was traced under; a Pallas kernel under
-its own name as well. A ``conditional`` / ``while`` is one operation of the
-entry computation and is counted whole, under ``(switches)``; what runs
-inside its branches is listed apart, by scope, and is NOT added again.
+or joins a kept trace with a kept compiled text first (an older run's
+``trace_ops.json`` and the ``as_text()`` of the SAME executable: instruction
+names are the compiler's and match no other):
+
+    python tools/trace_by_scope.py trace_ops.json step_hlo.txt [--by-transform]
+
+Milliseconds a step by scope (a layer's number starred), most first;
+``--by-transform`` adds the forward / recomputed / backward / optimizer
+columns a scope; then the split by layer kind and by layer, and the
+longest operations (``fusion.1364``) under the names the program gave them.
 """
-import collections
 import json
-import re
+import os
 import sys
 
-SCOPES = ("kda_conv", "kda_gate", "kda_attention", "gdn_gate",
-          "gated_delta_rule", "gated_norm", "mla_attention",
-          "gqa_attention", "window_attention", "sparse_attention",
-          "rotary", "rotary_yarn", "head_gate", "element_gate",
-          "index_select", "short_conv", "flash_attention_bwd",
-          "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
-          "moe.shared", "moe.shared_gate", "softmax_ce", "optimizer")
-_HEAD = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$")
-_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(")
-_OP_NAME = re.compile(r'op_name="([^"]*)"')
-_CALLS = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
+sys.path.insert(0, os.getcwd())         # the checkout's, not this file's
 
 
-def instructions(text):
-    """-> ({instruction: (computation, opcode, op_name)}, the entry
-    computation's name, {fusion instruction: its fused computation})."""
-    found, entry, comp, fused = {}, None, None, {}
-    for line in text.splitlines():
-        head = _HEAD.match(line)
-        if head:
-            comp = head.group(2)
-            entry = comp if head.group(1) else entry
-            continue
-        instr = _INSTR.match(line)
-        if comp is None or not instr:
-            continue
-        name, opcode = instr.groups()
-        op_name = _OP_NAME.search(line)
-        found[name] = (comp, opcode, op_name.group(1) if op_name else "")
-        callee = _CALLS.search(line)
-        if opcode == "fusion" and callee:
-            fused[name] = callee.group(1)
-    return found, entry, fused
+def matrix(paths):
+    with open(paths[0]) as f:
+        found = json.load(f)
+    if len(paths) == 1:
+        return found
+    from benchmark import step_scopes
+    from mxtpu import xprof
+    with open(paths[1]) as f:
+        table = xprof.operation_table(f.read())
+    return step_scopes.join(found, table)
 
 
-def scope_of(op_name, scopes):
-    # a scope stands between slashes, or inside the transforms' brackets:
-    # ``transpose(jvp(kda_conv))/jit(_backward)/...``
-    for part in reversed(re.findall(r"[\w.\-]+", op_name)):
-        if part in scopes:
-            return part
-    if "rematted_computation" in op_name:
-        return "(recomputed)"
-    if "transpose(" in op_name:
-        return "(backward)"
-    return "(forward)" if op_name else "(no op_name)"
+def rows(title, table, columns, by_transform, out):
+    total = sum(sum(row.values()) for row in table.values())
+    out("-- %s: %.3f" % (title, total))
+    if by_transform:
+        out("  %-52s %9s  %s" % ("", "ms", "  ".join(
+            "%10s" % c for c in columns)))
+    for key, row in sorted(table.items(), key=lambda kv: -sum(
+            kv[1].values())):
+        line = "  %-52s %9.3f" % (key, sum(row.values()))
+        if by_transform:
+            line += "  " + "  ".join("%10.3f" % row[c] for c in columns)
+        out(line)
 
 
-def main(argv):
-    with open(argv[0]) as f:
-        trace = json.load(f)
-    with open(argv[1]) as f:
-        text = f.read()
-    scopes = tuple(argv[2:]) or SCOPES
-    step = max(trace["modules"], key=lambda m: sum(trace["modules"][m]))
-    steps = len(trace["modules"][step])
-    found, entry, fused = instructions(text)
-    # a fusion without an op_name of its own takes its fused root's
-    by_comp = collections.defaultdict(list)
-    for name, (comp, _, op_name) in found.items():
-        by_comp[comp].append(op_name)
-    top, inside, kernels = (collections.Counter() for _ in range(3))
-    unmatched = 0.0
-    for name, seconds in trace["ops"].items():
-        ms = 1e3 * seconds / steps
-        if name not in found:
-            unmatched += ms
-            continue
-        comp, opcode, op_name = found[name]
-        if not op_name and name in fused:
-            op_name = next((o for o in reversed(by_comp[fused[name]]) if o),
-                           "")
-        if opcode in ("conditional", "while"):
-            scope = "(switches)"
-        else:
-            scope = scope_of(op_name, scopes)
-        (top if comp == entry else inside)[scope] += ms
-        if opcode == "custom-call":
-            kernels[re.sub(r"\.\d+$", "", name)] += ms
-    print("%s: %d steps, %.3f ms a step on the device; matched %.3f, "
-          "unmatched names %.3f" % (step, steps, 1e3 * sum(
-              trace["modules"][step]) / steps, sum(top.values()), unmatched))
-    for title, table in (("entry computation", top),
-                         ("inside the switches (counted above)", inside),
-                         ("custom calls, by name (counted above)", kernels)):
-        print("-- %s: %.3f" % (title, sum(table.values())))
-        for scope, ms in table.most_common():
-            print("  %-28s %9.3f" % (scope, ms))
+def main(argv, out=print):
+    by_transform = "--by-transform" in argv
+    paths = [a for a in argv if not a.startswith("--")]
+    found = matrix(paths)
+    if found is None:
+        out("nothing to join: no module's run in the trace, or no "
+            "operation of it in the text (another executable's?)")
+        return 1
+    from benchmark.step_scopes import KINDS, TRANSFORMS
+    out("%s: %.3f ms a step on the device over %g runs a chip; names "
+        "matched %.2f%%, unattributed %.2f%%, outside every block %.2f%%, "
+        "inside switches and loops %.3f ms" % (
+            found.get("cell", paths[0]), found["step_ms"],
+            found["runs_a_chip"], found["matched_pct"],
+            found["unattributed_pct"], found["outside_blocks_pct"],
+            found["inside_switches_ms"]))
+    out("-- by transform: " + ", ".join(
+        "%s %.3f" % (t, found["by_transform"][t]) for t in TRANSFORMS)
+        + ", unattributed %.3f" % found["unattributed_ms"])
+    rows("by scope", found["by_scope"], TRANSFORMS, by_transform, out)
+    rows("by layer kind", found["by_kind"], TRANSFORMS, by_transform, out)
+    layers = {"layer %s" % k: v for k, v in found["by_layer"].items()}
+    rows("by layer", layers, KINDS, True, out)
+    out("-- the longest operations, by the names the program gave them")
+    for op in found.get("top_operations", ()):
+        out("  %-44s %9.3f  %-10s %s" % (op["operation"], op["ms"],
+                                         op["transform"], op["scope"]))
+    return 0
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    sys.exit(main(sys.argv[1:]))
